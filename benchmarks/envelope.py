@@ -3,9 +3,8 @@
 Reference rows (``release/benchmarks/README.md:9-31`` +
 ``release/perf_metrics/scalability/single_node.json``): 1M queued tasks,
 10k object args, 3k returns, 10k-object ``ray.get``, 100 GiB objects, 40k
-actors, PG churn.  This driver runs the same shapes scaled to the CI box
-(1 vCPU) with pass/fail gates; numbers land in ``benchmarks/README.md``
-next to the reference's.
+actors, PG churn.  This driver runs the same shapes scaled to one host
+with pass/fail gates.
 
     python benchmarks/envelope.py [--quick] [--only SECTION,...]
 

@@ -258,9 +258,9 @@ def serve_breakdown(args) -> dict:
         def timed(fn):
             """Run the full request set twice; report the SECOND pass —
             the first pass triggers jit compiles for every admission/
-            batch arity (compiles are cached cross-process by the
-            compile service, so whichever stage runs first would
-            otherwise eat them all and skew the layer deltas)."""
+            batch arity (the persistent compile cache is shared across
+            processes, so whichever stage runs first would otherwise
+            eat them all and skew the layer deltas)."""
             for _ in range(2):
                 t0 = time.perf_counter()
                 with concurrent.futures.ThreadPoolExecutor(

@@ -6,12 +6,29 @@ detection via /dev/accel* and /dev/vfio at ``tpu.py:134-154``, pod-type →
 ``TPU-v4`` accelerator_type labels ``:352-361``, the ``TPU-{type}-head``
 resource for slice gang-scheduling ``:326-372``, and per-worker chip
 isolation via ``TPU_VISIBLE_CHIPS``).
+
+One process per chip.  libtpu gives a chip to the first process that
+initializes a backend on it, so the ``TPU`` resource is also the device
+binding:
+
+- a worker's JAX platform follows its lease, not the environment.  Every
+  worker starts pinned to ``cpu`` (:func:`pin_jax_platform`); a worker
+  granted ``TPU: k`` is re-pinned to ``tpu`` with exactly the k chips the
+  raylet took off its free list visible (:func:`bind_tpu_chips`), so a
+  busy or missing chip raises instead of falling back to the host;
+  a zero-TPU worker on a TPU node that touches JAX gets the CPU;
+- the binding happens before the grant reaches the lease's owner and
+  only in a process with no live backend (a backend cannot be re-pointed)
+  — the raylet skips such workers for TPU leases;
+- a worker bound to chips dies with its lease, and the chips return to
+  the free list once it is gone.
 """
 
 from __future__ import annotations
 
 import glob
 import os
+import sys
 from typing import Dict, List, Optional
 
 
@@ -112,11 +129,55 @@ class TPUAcceleratorManager:
 
     @staticmethod
     def set_visible_chips(env: Dict[str, str], chip_ids: List[int]) -> None:
-        """Per-worker chip isolation for fractional TPU scheduling
-        (reference: CUDA_VISIBLE_DEVICES analog for TPU)."""
+        """Restrict a process to ``chip_ids`` of this host's chips
+        (reference: CUDA_VISIBLE_DEVICES analog for TPU).  The process
+        forms its own single-process topology over them — 1 or 2 chips
+        in a row, 4 as the host's 2x2."""
+        n = len(chip_ids)
         env[TPUAcceleratorManager.ENV_VISIBLE] = ",".join(
             str(i) for i in chip_ids)
-        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = f"1,1,{len(chip_ids)}"
+        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = \
+            "2,2,1" if n == 4 else f"1,{n},1"
+        env["TPU_PROCESS_BOUNDS"] = "1,1,1"
+
+
+def jax_backend_initialized() -> bool:
+    """True only when this process ALREADY brought a jax backend up.
+    Passive by construction: forcing backend init from a probe would
+    claim a chip for a process that holds no TPU lease, and break actors
+    that need ``jax.distributed.initialize()`` before any computation."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return bool(xla_bridge._backends)
+
+
+def pin_jax_platform(platform: str) -> None:
+    """Pin this process's JAX platform list to exactly ``platform``: no
+    fallback to another backend when it cannot initialize.  The env var
+    covers a later ``import jax`` (and child processes); the config
+    update covers a zygote-forked worker, where jax was imported — and
+    read the variable — before the fork."""
+    os.environ["JAX_PLATFORMS"] = platform
+    if "jax" in sys.modules:
+        import jax
+
+        jax.config.update("jax_platforms", platform)
+
+
+def bind_tpu_chips(chip_ids: List[int], node_chips: int) -> bool:
+    """Point this process at ``chip_ids`` of the node's ``node_chips``
+    and pin JAX to ``tpu``.  A lease for the whole host leaves libtpu's
+    own topology discovery alone (the host may be one of a multi-host
+    slice).  False when a backend is already live here: it can no
+    longer be bound."""
+    if jax_backend_initialized():
+        return False
+    if len(chip_ids) < node_chips:
+        TPUAcceleratorManager.set_visible_chips(os.environ, chip_ids)
+    pin_jax_platform("tpu")
+    return True
 
 
 def detect_resources() -> Dict[str, float]:

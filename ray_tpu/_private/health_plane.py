@@ -75,12 +75,12 @@ def _probe_payload(n: int = 96, iters: int = 30, seed: int = 7) -> Dict:
     process already runs a multi-device jax backend (never triggers
     backend init), and the SDC canary digest (int64 modular matmul
     chain — bit-exact on every honest backend)."""
-    import sys
     import time as _t
 
     import numpy as np
 
     import ray_tpu
+    from ray_tpu._private.accelerators import jax_backend_initialized
     from ray_tpu.util import health as _health
     from ray_tpu.util.fault_injection import fault_point as _fp
 
@@ -94,7 +94,7 @@ def _probe_payload(n: int = 96, iters: int = 30, seed: int = 7) -> Dict:
         a = (a @ b) / float(n)
         _fp("health.probe")
     out["elapsed_s"] = _t.monotonic() - t0
-    if "jax" in sys.modules:
+    if jax_backend_initialized():
         try:
             import jax
 

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import math
 import os
 import subprocess
 import sys
@@ -65,7 +66,7 @@ class _ZygoteChild:
 
 class WorkerHandle:
     __slots__ = ("worker_id", "addr", "pid", "proc", "client", "lease",
-                 "dedicated", "started_at", "idle_since")
+                 "dedicated", "started_at", "idle_since", "backend_live")
 
     def __init__(self, worker_id: bytes, addr: str, pid: int, proc):
         self.worker_id = worker_id
@@ -77,6 +78,9 @@ class WorkerHandle:
         self.dedicated = False
         self.started_at = time.time()
         self.idle_since: Optional[float] = None
+        # refused a chip binding: a jax backend is already up in there,
+        # so no TPU lease can ever be pointed at this process
+        self.backend_live = False
 
 
 class Raylet:
@@ -95,6 +99,10 @@ class Raylet:
         self.node_name = node_name
         self.total = ResourceSet(resources)
         self.available = self.total.copy()
+        # chip indexes no lease holds: a ``TPU: k`` lease takes k of them
+        # and its worker is bound to exactly those (accelerators.py —
+        # one process per chip); they return when that worker is gone
+        self._free_chips: List[int] = list(range(int(self.total.get("TPU"))))
         # explicit labels win; detected slice-topology labels (TPU VM
         # metadata env) fill the gaps so every raylet on a pod slice
         # advertises its slice/worker-index/ICI hints without operator
@@ -393,7 +401,9 @@ class Raylet:
         for rows in gathered:
             for row in rows or ():
                 # dedupe: workers on one host see the same local devices
-                key = row.get("device")
+                # — unless each was bound to its own chips, where every
+                # one of them has a device 0
+                key = (row.get("chips"), row.get("device"))
                 if key in seen:
                     continue
                 seen.add(key)
@@ -526,6 +536,7 @@ class Raylet:
             self.bundles.clear()
             self._bundle_totals.clear()
             self.available = self.total.copy()
+            self._free_chips = list(range(int(self.total.get("TPU"))))
             self.draining = False
             self.drain_reason = ""
             self.drain_deadline = 0.0
@@ -1512,7 +1523,15 @@ class Raylet:
                     # dispatch queue); try next waiter anyway
                     self._lease_waiters.rotate(-1)
                     continue
-                if not self.idle:
+                chips_needed = math.ceil(demand.get("TPU"))
+                if chips_needed > len(self._free_chips):
+                    # a fractional TPU demand still takes a whole chip
+                    # (one process per chip), so the chips can run out
+                    # before the resource does
+                    self._lease_waiters.rotate(-1)
+                    continue
+                worker = self._pop_idle(bindable=chips_needed > 0)
+                if worker is None:
                     # _max_workers bounds the REUSABLE task-worker pool;
                     # dedicated (actor) workers are one-per-actor and gated
                     # by resource accounting instead — a CPU-derived cap
@@ -1525,12 +1544,7 @@ class Raylet:
                         starting += 1
                     self._lease_waiters.rotate(-1)
                     continue
-                self._lease_waiters.popleft()
-                # LIFO: reuse the most-recently-idle worker so excess
-                # workers go cold and age out under a steady trickle
-                # (reference WorkerPool pops MRU for the same reason);
-                # eviction scans from the old end of the deque
-                worker = self.idle.pop()
+                waiter = self._lease_waiters.popleft()
                 worker.idle_since = None
                 pool.subtract(demand)
                 worker.lease = {
@@ -1541,14 +1555,83 @@ class Raylet:
                 if lease_token:
                     self._lease_tokens[lease_token] = worker
                 worker.dedicated = dedicated
-                if not fut.done():
-                    # node_id lets the owner avoid this node on a
-                    # worker-death retry (see handle_lease_worker's
-                    # avoid_node_ids)
-                    fut.set_result({"worker_addr": worker.addr,
-                                    "worker_id": worker.worker_id,
-                                    "node_id": self.node_id})
+                if chips_needed:
+                    worker.lease["tpu_chips"] = \
+                        self._free_chips[:chips_needed]
+                    del self._free_chips[:chips_needed]
+                    asyncio.ensure_future(
+                        self._bind_and_grant(worker, waiter))
+                elif not fut.done():
+                    fut.set_result(self._grant_reply(worker))
                 made_progress = True
+
+    def _pop_idle(self, bindable: bool) -> Optional[WorkerHandle]:
+        """LIFO: reuse the most-recently-idle worker so excess workers
+        go cold and age out under a steady trickle (reference WorkerPool
+        pops MRU for the same reason); eviction scans from the old end
+        of the deque.  ``bindable`` skips workers known to hold a live
+        jax backend — a TPU lease cannot be pointed at those."""
+        if not self.idle:
+            return None
+        if not (bindable and self.idle[-1].backend_live):
+            return self.idle.pop()
+        for h in reversed(self.idle):
+            if not h.backend_live:
+                self.idle.remove(h)
+                return h
+        return None
+
+    def _grant_reply(self, worker: WorkerHandle) -> Dict[str, Any]:
+        # node_id lets the owner avoid this node on a worker-death retry
+        # (see handle_lease_worker's avoid_node_ids)
+        return {"worker_addr": worker.addr, "worker_id": worker.worker_id,
+                "node_id": self.node_id}
+
+    async def _bind_and_grant(self, worker: WorkerHandle, waiter) -> None:
+        """Grant a TPU lease: bind the worker to the lease's chips FIRST,
+        so no task can reach it before its platform is pinned.  A worker
+        that refuses (a backend is already up in there) goes back to the
+        pool for CPU leases only, and the waiter is queued again."""
+        lease, fut = worker.lease, waiter[6]
+        client = RpcClient(worker.addr)
+        try:
+            bound = await client.call(
+                "bind_tpu_chips", chip_ids=lease["tpu_chips"],
+                node_chips=int(self.total.get("TPU")), timeout=10.0)
+        except Exception:  # noqa: BLE001 — dying / wedged worker
+            bound = None
+        finally:
+            await client.close()
+        if worker.lease is not lease:
+            # the lease ended under us: released by its token (the owner
+            # is gone) or the worker died (the waiter is still owed one)
+            if fut.done():
+                return
+            if lease.get("abandoned"):
+                fut.set_exception(RuntimeError(
+                    "lease abandoned: owner released token"))
+            else:
+                self._lease_waiters.appendleft(waiter)
+                self._pump_leases()
+            return
+        if bound:
+            if fut.done():  # nobody is waiting for it any more
+                await self.handle_return_lease(worker.worker_id)
+            else:
+                fut.set_result(self._grant_reply(worker))
+            return
+        logger.info("worker %s cannot take TPU chips %s (%s)",
+                    worker.worker_id.hex()[:8], lease["tpu_chips"],
+                    "live jax backend" if bound is False else "no reply")
+        worker.lease = None
+        worker.dedicated = False
+        worker.backend_live = True
+        self._release_lease_resources(lease)
+        worker.idle_since = time.monotonic()
+        self.idle.appendleft(worker)
+        if not fut.done():
+            self._lease_waiters.appendleft(waiter)
+        self._pump_leases()
 
     def _max_workers(self) -> int:
         cpus = self.total.get("CPU")
@@ -1566,6 +1649,9 @@ class Raylet:
             pool = (self.bundles.get(pg_id) or {}).get(idx)
         if pool is not None:
             pool.add(lease["demand"])
+        chips = lease.pop("tpu_chips", None)
+        if chips:
+            self._free_chips = sorted(self._free_chips + chips)
 
     async def handle_release_lease_token(self, lease_token: str) -> bool:
         """Compensation path for a grant whose reply never reached the
@@ -1583,6 +1669,8 @@ class Raylet:
         h = self._lease_tokens.pop(lease_token, None)
         if (h is not None and h.lease is not None
                 and h.lease.get("token") == lease_token):
+            # a grant still binding its chips must not be queued again
+            h.lease["abandoned"] = True
             return await self.handle_return_lease(h.worker_id)
         # not granted yet: abandon the queued waiter carrying this token
         # (the pump's fut.done() check discards it)
@@ -1601,17 +1689,33 @@ class Raylet:
         h = self.workers.get(worker_id)
         if h is None:
             return False
-        if h.lease is not None:
-            self._release_lease_resources(h.lease)
-            h.lease = None
-        if h.dedicated:
-            # dedicated (actor) workers die with their lease
+        lease, h.lease = h.lease, None
+        chips = (lease or {}).get("tpu_chips")
+        if lease is not None and not chips:
+            self._release_lease_resources(lease)
+        if h.dedicated or chips:
+            # dedicated (actor) workers die with their lease; so does a
+            # worker bound to chips — its backend holds them, so it can
+            # neither serve another lease nor wait in the pool
             await self._kill_worker(h)
+            if chips:
+                # the chips are free when the process is GONE: a new
+                # holder that beats its exit finds them busy
+                await self._wait_exited(h)
+                self._release_lease_resources(lease)
         else:
             h.idle_since = time.monotonic()
             self.idle.append(h)
         self._pump_leases()
         return True
+
+    @staticmethod
+    async def _wait_exited(h: WorkerHandle):
+        """Wait for a killed worker's process to be gone (_kill_worker
+        handed it to the reaper loop, which SIGKILLs one that acked the
+        exit but wedged in teardown)."""
+        while h.proc.poll() is None:
+            await asyncio.sleep(0.05)
 
     async def _evict_idle_worker(self, h: WorkerHandle, floor: int):
         """Idle eviction with an owner-state handshake: the worker

@@ -2429,6 +2429,15 @@ class CoreWorker:
 
         return device_memory_stats()
 
+    async def handle_bind_tpu_chips(self, chip_ids: List[int],
+                                    node_chips: int) -> bool:
+        """Raylet -> worker, just before a ``TPU`` lease is granted: see
+        exactly these chips and nothing but the TPU platform.  False
+        when a jax backend is already live here (it cannot be bound)."""
+        from ray_tpu._private.accelerators import bind_tpu_chips
+
+        return bind_tpu_chips(chip_ids, node_chips)
+
     async def handle_kill_actor(self, no_restart: bool = True) -> bool:
         logger.info("actor %s killed", self.actor_id.hex() if self.actor_id else "?")
         asyncio.ensure_future(self._terminate_self())

@@ -57,6 +57,7 @@ import struct
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from ray_tpu._private.accelerators import jax_backend_initialized
 from ray_tpu.experimental.channel.shared_memory_channel import (
     Channel,
     ChannelClosedError,
@@ -102,26 +103,9 @@ class EndpointInfo:
         return self.platform not in ("", "none") and bool(self.device_ids)
 
 
-def _jax_backend_initialized() -> bool:
-    """True only when this process ALREADY brought a jax backend up.  The
-    probe must be passive: forcing backend init here would both drag a
-    TPU runtime into actors that never use jax and break actors that need
-    ``jax.distributed.initialize()`` before any computation."""
-    import sys
-
-    if "jax" not in sys.modules:
-        return False
-    try:
-        from jax._src import xla_bridge
-
-        return bool(getattr(xla_bridge, "_backends", None))
-    except Exception:  # noqa: BLE001 — private-API drift: stay passive
-        return False
-
-
 def local_endpoint_info() -> EndpointInfo:
     """Probe THIS process, without side effects (see
-    :func:`_jax_backend_initialized`).  Under the ICI emulation a
+    ``accelerators.jax_backend_initialized``).  Under the ICI emulation a
     not-yet-initialized cpu process reports platform from the
     environment so negotiation still sees matching endpoints."""
     node_id = ""
@@ -134,7 +118,7 @@ def local_endpoint_info() -> EndpointInfo:
     except Exception:  # noqa: BLE001 — no runtime: pid still disambiguates
         pass
     platform, device_ids, process_index = "none", (), 0
-    if _jax_backend_initialized():
+    if jax_backend_initialized():
         try:
             import jax
 

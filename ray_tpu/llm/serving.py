@@ -87,10 +87,9 @@ class _EngineHost:
     # this window, hold the next step briefly so CONCURRENT requests
     # (dribbling in one actor RPC at a time) coalesce into one batch.
     # Stepping on the first arrival alone burns a whole decode window at
-    # batch arity 1 — measured on CPU: replica throughput swung 870-5800
-    # tok/s run-to-run purely on arrival/step interleaving; on a real
-    # chip every step is a ~100 ms sync, so a wasted window costs more.
-    # A lone request pays at most ~settle ms of extra latency.
+    # batch arity 1 — seen on the CPU: replica throughput swung several
+    # fold run-to-run purely on arrival/step interleaving.  A lone
+    # request pays at most ~settle ms of extra latency.
     ADMISSION_SETTLE_S = 0.004
 
     # fallback generation budget when the request carries no deadline

@@ -43,25 +43,13 @@ def _opt_state_shardings(optimizer, param_shapes, param_shardings, mesh):
     param sharding; scalars (step counts) are replicated."""
     replicated = NamedSharding(mesh, P())
     opt_shapes = jax.eval_shape(optimizer.init, param_shapes)
-    try:
-        return optax.tree_map_params(
-            optimizer,
-            lambda _, sh: sh,
-            opt_shapes,
-            param_shardings,
-            transform_non_params=lambda _: replicated,
-        )
-    except Exception:
-        # Fallback: match leaves to params by shape, replicate the rest.
-        shape_to_sh = {}
-        jax.tree.map(
-            lambda s, sh: shape_to_sh.setdefault(s.shape, sh),
-            param_shapes, param_shardings,
-        )
-        return jax.tree.map(
-            lambda s: shape_to_sh.get(getattr(s, "shape", None), replicated),
-            opt_shapes,
-        )
+    return optax.tree_map_params(
+        optimizer,
+        lambda _, sh: sh,
+        opt_shapes,
+        param_shardings,
+        transform_non_params=lambda _: replicated,
+    )
 
 
 class ShardedTrainer:

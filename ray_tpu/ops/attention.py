@@ -108,28 +108,6 @@ def _blockwise_step(q, k, v, m, l, o, *, qpos, kpos, scale, window=None):
     return m_new, l_new, o_new
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs, check_vma=True):
-    """shard_map across jax versions (jax.shard_map vs experimental).
-
-    check_vma=False is needed when the body contains ops opaque to the
-    varying-axis type system (e.g. pallas_call).
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(
-                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check_vma,
-            )
-        except TypeError:
-            return jax.shard_map(
-                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs
-            )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def ring_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -190,13 +168,10 @@ def ring_attention(
         l0 = jnp.zeros((b, h_loc, sq), jnp.float32)
         o0 = jnp.zeros((b, sq, h_loc, d), jnp.float32)
         # Mark the accumulators device-varying so the loop carry typechecks
-        # under shard_map's varying-axis tracking (jax>=0.9).
-        if hasattr(jax.lax, "pcast"):
-            m0, l0, o0 = jax.lax.pcast(
-                (m0, l0, o0), tuple(mesh.axis_names), to="varying"
-            )
-        elif hasattr(jax.lax, "pvary"):
-            m0, l0, o0 = jax.lax.pvary((m0, l0, o0), tuple(mesh.axis_names))
+        # under shard_map's varying-axis tracking.
+        m0, l0, o0 = jax.lax.pcast(
+            (m0, l0, o0), tuple(mesh.axis_names), to="varying"
+        )
         # Last block: compute only — its rotated K/V would be discarded, so
         # running the final ppermute pair would waste two ICI collectives.
         k_l, v_l, m, l, o = jax.lax.fori_loop(
@@ -213,7 +188,7 @@ def ring_attention(
     # check_vma=False: outputs are trivially replicated over mesh axes the
     # specs never mention (e.g. a size-1 "pp"), which the static VMA check
     # cannot infer through the ppermute ring.
-    return _shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=(qspec, qspec, qspec), out_specs=qspec,
         check_vma=False,
     )(q, k, v)
@@ -282,13 +257,15 @@ def dot_product_attention(
         # The pallas_call is opaque to GSPMD: run it per-shard under
         # shard_map, with batch sharded over dp/fsdp and heads over tp
         # (sequence is whole per device since sp==1 on this path).
+        # check_vma=False: pallas_call is also opaque to the
+        # varying-axis type system.
         batch_axes = tuple(
             a for a in ("dp", "fsdp") if a in mesh.axis_names
         )
         head_axis = "tp" if "tp" in mesh.axis_names else None
         qspec = P(batch_axes if batch_axes else None, None, head_axis, None)
         kvspec = qspec
-        return _shard_map(
+        return jax.shard_map(
             lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=causal),
             mesh=mesh,
             in_specs=(qspec, kvspec, kvspec),

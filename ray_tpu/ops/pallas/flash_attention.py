@@ -29,11 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU extensions are unavailable on some CPU-only jaxlib builds
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -428,15 +424,9 @@ def flash_attention(
 ) -> jnp.ndarray:
     """Flash attention. q: [b, s, h, d]; k, v: [b, s, kv_h, d].
 
-    Block defaults of 1024 measured fastest on v5e (grid-overhead bound at
-    smaller blocks).  Off-TPU this runs the Pallas interpreter (slow; tests
-    use small shapes); if the Pallas TPU extensions are missing entirely it
-    falls back to the jnp reference implementation.
+    Off-TPU this runs the Pallas interpreter (slow; tests use small
+    shapes).
     """
-    if pltpu is None:  # pragma: no cover
-        from ray_tpu.ops.attention import reference_attention
-
-        return reference_attention(q, k, v, causal=causal)
     if jax.default_backend() != "tpu":
         interpret = True
     return _flash(q, k, v, causal, block_q, block_k, interpret)

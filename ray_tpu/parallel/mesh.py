@@ -28,6 +28,7 @@ from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 MESH_AXES: Tuple[str, ...] = ("dp", "fsdp", "pp", "tp", "sp")
@@ -174,20 +175,17 @@ def create_mesh(
 ) -> Mesh:
     """Build a Mesh over ``devices`` (default: all visible devices).
 
-    Uses ``jax.experimental.mesh_utils`` when available so the logical mesh
-    layout matches the physical ICI torus (contiguous inner axes).
+    Over the full device set, ``mesh_utils`` lays the logical mesh out
+    on the physical ICI torus (contiguous inner axes) — and raises for a
+    shape the topology cannot carry, rather than handing back an
+    arbitrary order.  An explicit subset is taken in the order given.
     """
     if devices is None:
         devices = jax.devices()
     shape = mesh_shape_for(len(devices), config)
-    try:
-        from jax.experimental import mesh_utils
-
-        if devices is jax.devices() or list(devices) == list(jax.devices()):
-            dev_array = mesh_utils.create_device_mesh(shape)
-        else:
-            dev_array = np.asarray(devices).reshape(shape)
-    except Exception:
+    if list(devices) == list(jax.devices()):
+        dev_array = mesh_utils.create_device_mesh(shape)
+    else:
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, axis_names)
 
@@ -217,13 +215,13 @@ def create_hybrid_mesh(
     # result shape is their elementwise product, so dp == num_slices lands
     # on the DCN boundary and fsdp/pp/tp/sp stay within a slice's ICI torus.
     dcn_shape = (num_slices,) + (1,) * (len(MESH_AXES) - 1)
-    try:
-        from jax.experimental import mesh_utils
-
+    if hasattr(devices[0], "slice_index"):
         dev_array = mesh_utils.create_hybrid_device_mesh(
             ici_shape, dcn_shape, devices=devices
         )
-    except Exception:
+    else:
+        # devices that carry no slice identity (the virtual CPU devices
+        # of the tests): consecutive runs of the list stand in for slices
         dev_array = np.asarray(devices).reshape(
             (num_slices,) + ici_shape[1:]
         )

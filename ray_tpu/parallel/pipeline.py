@@ -15,15 +15,9 @@ axis like dp/fsdp/tp/sp, implemented the TPU way:
   that dim (each pp shard runs its own stage), and the inter-stage hop
   is ``jnp.roll`` on the sharded dim — which XLA lowers to exactly the
   ``collective-permute`` ring a manual ``ppermute`` would issue.  No
-  ``shard_map`` at all: earlier revisions ran the schedule in a
-  partial-manual ``shard_map`` (``pp`` manual, the rest auto), but
-  mixing manual and auto subgroups is unreliable across jax/XLA
-  versions — 0.4.x rejects the region's ``axis_index`` with
-  "UNIMPLEMENTED: PartitionId" at execution and hard-aborts
-  (``IsManualSubgroup`` check) on scalar bridges between the manual
-  and auto halves.  Sharding annotations alone express the same
-  program portably, and dp/fsdp/tp stay auto-partitioned inside each
-  stage for free;
+  ``shard_map`` at all: sharding annotations alone express the
+  program, and dp/fsdp/tp stay auto-partitioned inside each stage for
+  free;
 - reverse-mode AD transposes the roll (a roll the other way), so the
   backward pass is the mirrored pipeline schedule for free.  With
   per-layer remat the live state per stage is one microbatch activation

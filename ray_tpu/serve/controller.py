@@ -525,6 +525,14 @@ class ServeController:
                             st["version"] += 1
                     continue
                 except Exception:
+                    with self._lock:
+                        st = self._deployments.get(name) or {}
+                        if key in st.get("starting", {}):
+                            # still inside __init__ (weights, backend
+                            # bring-up): its calls queue behind it.  Not
+                            # a failure — a replica whose process died
+                            # is _prune_dead_replicas's to replace
+                            continue
                     # a slow check (e.g. the replica is jit-compiling and
                     # holding the GIL) is not death: replace only after
                     # consecutive failures

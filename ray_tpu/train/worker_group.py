@@ -39,10 +39,16 @@ class WorkerStatus:
 class TrainWorker:
     """Actor hosting one training process; runs the user loop in a thread.
 
-    TPU-first: each worker owns the chips its raylet isolated for it; the
-    jax process inside forms (or joins) the mesh.  On multi-host slices the
-    controller passes coordinator address/process ids so workers can call
-    ``jax.distributed.initialize`` (GSPMD mesh over the pod slice).
+    One process per chip (``_private/accelerators.py``): with
+    ``ScalingConfig(use_tpu=True, chips_per_worker=k)`` the worker's lease
+    carries ``TPU: k``, and before the actor is created its raylet binds
+    the process to exactly k of the node's free chips and pins JAX to
+    ``tpu`` — the loop gets those chips or an error, never the host.
+    Without ``use_tpu`` the worker holds no chip and JAX in it is the
+    CPU.  The jax process inside forms (or joins) the mesh.  On
+    multi-host slices the controller passes coordinator address/process
+    ids so workers can call ``jax.distributed.initialize`` (GSPMD mesh
+    over the pod slice).
     """
 
     def __init__(self):

@@ -41,13 +41,10 @@ def ensure_cpu_collectives_backend() -> None:
     """Select the gloo implementation for CPU cross-process collectives.
 
     Must run BEFORE the backend is first touched; harmless on TPU hosts
-    (only the cpu client reads the knob) and on older jaxlib without it.
-    Shared by every jax.distributed entry point in the framework.
+    (only the cpu client reads the knob).  Shared by every
+    jax.distributed entry point in the framework.
     """
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — older jaxlib without the knob
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def ensure_jax_distributed(coordinator_address: str, num_processes: int,
@@ -96,12 +93,10 @@ def ensure_jax_distributed(coordinator_address: str, num_processes: int,
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    from ray_tpu.ops.attention import _shard_map as sm
-
     # check_vma=False: ops like all_gather produce replicated outputs the
     # varying-axis checker cannot statically infer.
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class XlaMeshGroup(BaseGroup):
@@ -383,7 +378,7 @@ class XlaDistributedGroup(BaseGroup):
     # the internal KV with per-(src,dst,tag) sequence numbers; in-graph
     # transfers between ranks should use the mesh collectives (ppermute
     # via the jitted program) instead — this path is for small control
-    # tensors, and its cost is measured in benchmarks/README.md.
+    # tensors.
 
     def _p2p_key(self, src: int, dst: int, tag: int, seq: int) -> bytes:
         return (f"collective/{self.group_name}/p2p/"

@@ -319,33 +319,33 @@ def device_memory_stats() -> List[Dict[str, Any]]:
     """Per-device memory occupancy for this process's accelerators.
 
     Uses ``jax.local_devices()[i].memory_stats()`` where the backend
-    exposes it (PJRT TPU/GPU; ``bytes_in_use`` / ``bytes_limit``).  Only
-    consulted when jax is *already imported* in this process — a raylet
-    or CPU-only worker must never pay (or trigger) backend init just to
-    report stats.  Returns ``[]`` when there is nothing to report, and
-    rows shaped ``{"device", "kind", "bytes_in_use", "bytes_limit",
-    "occupancy"}`` otherwise."""
-    import sys
+    exposes it (PJRT TPU/GPU; ``bytes_in_use`` / ``peak_bytes_in_use`` /
+    ``bytes_limit``).  Only consulted when this process has *already
+    brought a backend up* — ``jax.local_devices()`` on a backend-less
+    process would initialize one, which claims a chip for a raylet or
+    CPU-only worker that holds no TPU lease and permanently breaks a
+    later ``jax.distributed.initialize()`` in that worker.  Returns
+    ``[]`` when there is nothing to report, and rows shaped ``{"device",
+    "chips", "kind", "device_kind", "bytes_in_use", "peak_bytes_in_use",
+    "bytes_limit", "occupancy"}`` otherwise.  ``kind`` is the platform;
+    ``chips`` is the host chip set this process was bound to ("" = the
+    whole host) — a chip-bound process numbers its devices from 0 again,
+    so ``device`` alone does not tell two such processes apart."""
+    import os
 
-    if "jax" not in sys.modules:
-        return []
-    try:
-        import jax
-        from jax._src import xla_bridge
+    from ray_tpu._private.accelerators import (TPUAcceleratorManager,
+                                               jax_backend_initialized)
 
-        # merely IMPORTED is not enough: jax.local_devices() on a
-        # backend-less process would initialize one — which costs
-        # seconds, and permanently breaks a later
-        # jax.distributed.initialize() in that worker
-        if not getattr(xla_bridge, "_backends", None):
-            return []
-        devices = jax.local_devices()
-    except Exception:  # noqa: BLE001 — backend not initialized / dead
+    if not jax_backend_initialized():
         return []
+    import jax
+
+    chips = os.environ.get(TPUAcceleratorManager.ENV_VISIBLE, "")
     out: List[Dict[str, Any]] = []
-    for d in devices:
-        row: Dict[str, Any] = {"device": str(d),
-                               "kind": getattr(d, "platform", "")}
+    for d in jax.local_devices():
+        row: Dict[str, Any] = {"device": str(d), "chips": chips,
+                               "kind": d.platform,
+                               "device_kind": d.device_kind}
         stats = None
         try:
             stats = d.memory_stats()
@@ -356,6 +356,7 @@ def device_memory_stats() -> List[Dict[str, Any]]:
             limit = stats.get("bytes_limit") or stats.get(
                 "bytes_reservable_limit")
             row["bytes_in_use"] = in_use
+            row["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
             row["bytes_limit"] = limit
             if in_use is not None and limit:
                 row["occupancy"] = round(in_use / limit, 4)

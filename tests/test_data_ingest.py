@@ -253,6 +253,9 @@ def test_get_local_object_locations(ray_start):
 
     big = ray_tpu.put(np.ones(512 * 1024, np.uint8))  # shm-resident
     small = ray_tpu.put(7)                            # inline
+    # put() hands the location to the IO loop thread; a get() is queued
+    # behind it, so the table is written by the time it returns
+    ray_tpu.get([big, small])
     locs = get_local_object_locations([big, small])
     me = ray_tpu.get_runtime_context().get_node_id()
     assert locs[big] == me

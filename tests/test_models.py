@@ -151,8 +151,8 @@ class TestShardedTraining:
 
 
 class TestTrainerLevers:
-    """Round-5 MFU levers: correctness on CPU (the chip measurements
-    live in benchmarks/mfu_sweep.py and benchmarks/README.md)."""
+    """Round-5 MFU levers: correctness on CPU (the chip side is
+    benchmarks/mfu_sweep.py; not measured on record)."""
 
     def test_grad_accumulation_matches_full_batch(self):
         """accum_steps=k over the SAME effective batch must produce the

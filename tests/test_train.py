@@ -389,8 +389,7 @@ class TestElasticEndToEnd:
             # a jitted global psum so every step actually RUNS on the
             # re-formed mesh (not just describes it)
             from jax.experimental import multihost_utils
-            from ray_tpu.ops.attention import _shard_map
-            psum = jax.jit(_shard_map(
+            psum = jax.jit(jax.shard_map(
                 lambda t: jax.lax.psum(t, "dp"), mesh=mesh,
                 in_specs=(P("dp"),), out_specs=P(), check_vma=False))
 
