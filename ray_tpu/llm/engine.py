@@ -37,6 +37,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ray_tpu._private import tracing
 from ray_tpu.models.generation import SamplingParams
 from ray_tpu.models.llama import LlamaConfig
 
@@ -98,6 +99,17 @@ class Request:
     # its first sampled token, holding its blocks for export (the KV
     # handoff to a decode replica) instead of releasing them
     prefill_only: bool = False
+    # tracing (wall clock, like every host span): when the request (last)
+    # entered the queue — at submit, and again at a preemption — when it
+    # left the queue for a slot, and when its decode began; ``trace_ctx``
+    # is the submitter's span context (the ``serve.request`` root under
+    # serve), so the per-request spans ``engine.queue_wait`` /
+    # ``engine.prefill`` / ``engine.decode`` join the request's trace in
+    # ``util.state.timeline()``
+    t_queued: float = 0.0
+    t_admit: float = 0.0
+    t_decode: float = 0.0
+    trace_ctx: Optional[tracing.SpanContext] = None
 
     def __post_init__(self):
         if self.n_prompt < 0:
@@ -438,7 +450,8 @@ class LLMEngine:
         sampling = sampling or SamplingParams(
             stop_token_id=getattr(self.tokenizer, "eos_id", None))
         req = Request(next(self._ids), list(prompt), sampling,
-                      prefill_only=prefill_only)
+                      prefill_only=prefill_only, t_queued=time.time(),
+                      trace_ctx=tracing.current())
         if len(req.prompt_tokens) >= self.max_len:
             raise ValueError(
                 f"prompt of {len(req.prompt_tokens)} tokens >= engine "
@@ -498,13 +511,145 @@ class LLMEngine:
 
     def step(self) -> List[GenerationOutput]:
         """Admit queued requests into free slots (prefix-cached prefill),
-        run ONE decode step for all active slots, retire finished."""
+        run ONE decode step for all active slots, retire finished.
+
+        The step is tiled by ``tracing.annotate`` phases (``engine.admit``,
+        ``engine.first_tokens``, ``engine.verify``,
+        ``engine.prepare_window``, ``engine.dispatch_window``,
+        ``engine.fetch_window``, ``engine.emit``, ``engine.retire``) under
+        one ``engine.step``: in a profiler trace they say what the host
+        was doing in every idle gap of the device.  One annotation per
+        phase, never one per token."""
+        used = self.B - self.free_slot_count()
+        with tracing.annotate("engine.step", queued=len(self._queue),
+                              slots_used=used):
+            return self._step_phases()
+
+    def _step_phases(self) -> List[GenerationOutput]:
         import jax
         import jax.numpy as jnp
 
         # 0. place adopted (already-prefilled, KV grafted) requests into
         # free slots: no prefill dispatch at all — the shipped blocks ARE
         # the cache, the first token came with the handoff
+        if self._adopt_queue:
+            with tracing.annotate("engine.admit", kind="adopt") as ann:
+                ann.set_metadata(n=self._place_adopted())
+
+        # 1. admit — prefills dispatch back-to-back; the first tokens of
+        # ALL admissions are sampled and fetched in ONE host sync
+        admitted: List[Tuple[int, Any]] = []
+        budget = self.prefill_chunk or None  # tokens of prefill this step
+        for i in range(self.B):
+            if self._slots[i] is None and self._queue:
+                with tracing.annotate("engine.admit") as ann:
+                    res = self._admit(i, budget)
+                    ann.set_metadata(**self._admit_stats(i, res))
+                if res is None:
+                    break  # out of blocks: stop admitting this step
+                kind, payload, used = res
+                if budget is not None:
+                    budget -= used
+                if kind == "partial":
+                    break  # head request still prefilling; slot stays free
+                admitted.append((i, payload))
+                if budget is not None and budget <= 0:
+                    break  # spent: further walks would only defer
+        if admitted:
+            with tracing.annotate("engine.first_tokens", n=len(admitted)):
+                self._key, k = jax.random.split(self._key)
+                lg = self._stack(*[d for _, d in admitted])[:, 0]
+                temps = np.asarray([self._slots[i].sampling.temperature
+                                    for i, _ in admitted], np.float32)
+                first = np.asarray(self._sample(lg, k, jnp.asarray(temps)))
+                now = time.time()
+                for (i, _), tok in zip(admitted, first):
+                    req = self._slots[i]
+                    self._request_span(
+                        "engine.prefill", req, req.t_admit, now,
+                        prompt_tokens=len(req.prompt_tokens),
+                        cached_tokens=req.cached_prefix_len)
+                    req.t_decode = now
+                    self._record_token(i, req, int(tok))
+                # the step's device temporaries die inside the phase that
+                # made them, not at the frame's exit under no phase:
+                # freeing a device buffer gives up the interpreter lock,
+                # and what the thread then waits belongs to the phase
+                del admitted, lg, k, first
+
+        active = [i for i in range(self.B) if self._slots[i] is not None
+                  and not self._slots[i].done]
+        if active and self.G:
+            with tracing.annotate("engine.verify"):
+                if self._try_speculate(active):
+                    active = []  # this step's tokens came from the verify
+        if active:
+            with tracing.annotate("engine.prepare_window"):
+                # arm timing starts BEFORE block growth / mirror refresh /
+                # uploads so the window arm carries the same per-step host
+                # costs the verify arm does (symmetric bandit comparison)
+                t_arm = self._arm_clock()
+                # ensure every active slot has blocks for the whole
+                # window; preempt the youngest request if the pool is
+                # exhausted
+                active = self._ensure_decode_blocks(active, horizon=self.K)
+                if active:
+                    # adaptive window: never decode past what the
+                    # longest-running active request can still accept
+                    window_k = self._window_arity(active)
+                    self._refresh_device_mirrors()
+                    if self._dev is None:
+                        tok_d = jnp.asarray(self._next_token)
+                        cur_d = jnp.asarray(self._cur_len)
+                    else:
+                        tok_d, cur_d = self._dev
+                    key_d = self._key
+        if active:
+            with tracing.annotate("engine.dispatch_window", k=window_k,
+                                  active=len(active)):
+                toks = []
+                for _ in range(window_k):  # device-chained: no host sync
+                    tok_d, cur_d, key_d, self.pool = self._decode1(
+                        self.params, tok_d, cur_d, self._tables_d,
+                        self.pool, key_d, self._temps_d)
+                    toks.append(tok_d)
+                self._key = key_d
+                self._dev = (tok_d, cur_d)
+            with tracing.annotate("engine.fetch_window"):
+                # ONE host sync for the whole window_k * B window
+                window = np.asarray(self._stack(*toks))
+            with tracing.annotate("engine.emit") as ann:
+                if self.G:
+                    self._spec_streak = 0
+                    # per-ARITY EMA: short windows have different sync
+                    # amortization (and their own _stack compiles), so
+                    # each arity gets its own sample stream — the verify
+                    # gate compares against the arity it would displace
+                    self._observe_arm(("window", window_k), window_k,
+                                      self._arm_clock() - t_arm)
+                emitted = 0
+                for step in range(window_k):
+                    for i in active:
+                        req = self._slots[i]
+                        if req is None or req.done:
+                            continue  # stopped mid-window: drop the tail
+                        self._cur_len[i] += 1
+                        self._record_token(i, req, int(window[step, i]))
+                        emitted += 1
+                ann.set_metadata(tokens=emitted)
+                del toks, window  # as above: freed inside the phase
+
+        # 3. retire
+        with tracing.annotate("engine.retire") as ann:
+            out = self._retire()
+            ann.set_metadata(n=len(out))
+        return out
+
+    def _place_adopted(self) -> int:
+        """Move adopted requests from the adopt queue into free slots;
+        returns how many were placed."""
+        placed = 0
+        now = time.time()
         for i in range(self.B):
             if not self._adopt_queue:
                 break
@@ -518,89 +663,19 @@ class LLMEngine:
             self._tables[i] = 0
             self._tables[i, :len(req.blocks)] = req.blocks
             self._dev_dirty = True
+            req.t_admit = req.t_decode = now
+            self._request_span("engine.queue_wait", req, req.t_queued, now)
+            placed += 1
+        return placed
 
-        # 1. admit — prefills dispatch back-to-back; the first tokens of
-        # ALL admissions are sampled and fetched in ONE host sync
-        admitted: List[Tuple[int, Any]] = []
-        budget = self.prefill_chunk or None  # tokens of prefill this step
-        for i in range(self.B):
-            if self._slots[i] is None and self._queue:
-                res = self._admit(i, budget)
-                if res is None:
-                    break  # out of blocks: stop admitting this step
-                kind, payload, used = res
-                if budget is not None:
-                    budget -= used
-                if kind == "partial":
-                    break  # head request still prefilling; slot stays free
-                admitted.append((i, payload))
-                if budget is not None and budget <= 0:
-                    break  # spent: further walks would only defer
-        if admitted:
-            self._key, k = jax.random.split(self._key)
-            lg = self._stack(*[d for _, d in admitted])[:, 0]
-            temps = np.asarray([self._slots[i].sampling.temperature
-                                for i, _ in admitted], np.float32)
-            first = np.asarray(self._sample(lg, k, jnp.asarray(temps)))
-            for (i, _), tok in zip(admitted, first):
-                self._record_token(i, self._slots[i], int(tok))
-
-        active = [i for i in range(self.B) if self._slots[i] is not None
-                  and not self._slots[i].done]
-        if active and self.G and self._try_speculate(active):
-            active = []  # tokens for this step came from the verify pass
-        if active:
-            # arm timing starts BEFORE block growth / mirror refresh /
-            # uploads so the window arm carries the same per-step host
-            # costs the verify arm does (symmetric bandit comparison)
-            t_arm = self._arm_clock()
-            # ensure every active slot has blocks for the whole window;
-            # preempt the youngest request if the pool is exhausted
-            active = self._ensure_decode_blocks(active, horizon=self.K)
-        if active:
-            # adaptive window: never decode past what the longest-running
-            # active request can still accept
-            window_k = self._window_arity(active)
-            self._refresh_device_mirrors()
-            if self._dev is None:
-                tok_d = jnp.asarray(self._next_token)
-                cur_d = jnp.asarray(self._cur_len)
-            else:
-                tok_d, cur_d = self._dev
-            key_d = self._key
-            toks = []
-            for _ in range(window_k):  # device-chained: no host sync inside
-                tok_d, cur_d, key_d, self.pool = self._decode1(
-                    self.params, tok_d, cur_d, self._tables_d, self.pool,
-                    key_d, self._temps_d)
-                toks.append(tok_d)
-            self._key = key_d
-            self._dev = (tok_d, cur_d)
-            # ONE host sync for the whole window_k * B window
-            window = np.asarray(self._stack(*toks))
-            if self.G:
-                self._spec_streak = 0
-                # per-ARITY EMA: short windows have different sync
-                # amortization (and their own _stack compiles), so each
-                # arity gets its own sample stream — the verify gate
-                # compares against the arity it would displace
-                self._observe_arm(("window", window_k), window_k,
-                                  self._arm_clock() - t_arm)
-            for step in range(window_k):
-                for i in active:
-                    req = self._slots[i]
-                    if req is None or req.done:
-                        continue  # stopped mid-window: discard the tail
-                    self._cur_len[i] += 1
-                    self._record_token(i, req, int(window[step, i]))
-
-        # 3. retire
+    def _retire(self) -> List[GenerationOutput]:
         out = []
         while self._failed:
             req = self._failed.pop()
             out.append(GenerationOutput(
                 req.request_id, req.prompt_tokens[:req.n_prompt], [],
                 text="", error=req.error))
+        now = time.time()
         for i in range(self.B):
             req = self._slots[i]
             if req is not None and req.done:
@@ -616,10 +691,42 @@ class LLMEngine:
                     for bid in req.blocks:
                         self.blocks.release(bid)
                     req.blocks = []
+                    self._request_span("engine.decode", req, req.t_decode,
+                                       now, tokens=len(toks))
                 self._slots[i] = None
                 self._tables[i] = 0
                 self._dev_dirty = True
         return out
+
+    def _admit_stats(self, i: int, res) -> Dict[str, Any]:
+        """The ``engine.admit`` annotation's stats, read off what
+        ``_admit(i, ...)`` just did: ``kind`` full (the request now in
+        slot i), partial (a chunk of the queue head's prompt) or none
+        (pool pressure)."""
+        if res is None:
+            return {"kind": "none"}
+        kind, _, prefilled = res
+        req = self._slots[i] if kind == "full" else self._queue[0]
+        stats = {"kind": kind, "rid": req.request_id,
+                 "prompt_tokens": len(req.prompt_tokens),
+                 "bucket": _bucket(prefilled, self.max_len)
+                 if prefilled else 0}
+        if kind == "full":
+            stats["cached_tokens"] = req.cached_prefix_len
+            stats["queue_wait_ms"] = round(
+                (req.t_admit - req.t_queued) * 1e3, 3)
+        return stats
+
+    def _request_span(self, name: str, req: Request, start: float,
+                      end: float, **attrs) -> None:
+        """One per-request span in the HOST buffer (explicit stamps cannot
+        be a profiler annotation), under the submitter's trace."""
+        if not tracing.is_enabled():
+            return
+        ctx = (req.trace_ctx or tracing.current_or_root()).child()
+        attrs["request_id"] = req.request_id
+        tracing.record_span(name, start, end, ctx, kind="engine",
+                            attrs=attrs)
 
     def generate(self, prompts, sampling: Optional[SamplingParams] = None
                  ) -> List[GenerationOutput]:
@@ -770,7 +877,8 @@ class LLMEngine:
             stop_token_id=getattr(self.tokenizer, "eos_id", None))
         req = Request(next(self._ids), prompt, sp,
                       out_tokens=list(handoff.get("out_tokens", [])),
-                      blocks=bids, n_prompt=int(handoff.get("n_prompt", n)))
+                      blocks=bids, n_prompt=int(handoff.get("n_prompt", n)),
+                      t_queued=time.time(), trace_ctx=tracing.current())
         req.cached_prefix_len = n
         # re-evaluate finish conditions locally: the prefill side's first
         # token may already exhaust the budget (max_tokens=1) or the
@@ -911,6 +1019,9 @@ class LLMEngine:
         req.cached_prefix_len = cached_len
         self._queue.popleft()
         self._slots[i] = req
+        req.t_admit = time.time()
+        self._request_span("engine.queue_wait", req, req.t_queued,
+                           req.t_admit)
 
         logits = self._run_prefill(suffix, cached_len, req.blocks,
                                    hit_blocks)
@@ -1072,6 +1183,12 @@ class LLMEngine:
         req.out_tokens = []
         req.cached_prefix_len = 0
         req.chain_keys = None  # prompt changed: recompute on re-admit
+        # its decode so far ends here and a second queue wait begins
+        now = time.time()
+        self._request_span("engine.decode", req, req.t_decode, now,
+                           tokens=len(req.prompt_tokens) - req.n_prompt,
+                           preempted=True)
+        req.t_queued = now
         self._queue.appendleft(req)
         self._slots[i] = None
         self._tables[i] = 0

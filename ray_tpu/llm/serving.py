@@ -25,6 +25,7 @@ Two deployment topologies (``docs/llm_serving.md``):
 
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import time
@@ -32,6 +33,7 @@ import uuid
 from typing import Any, Dict, List, Optional
 
 from ray_tpu import serve
+from ray_tpu._private import tracing
 
 # engine-stats publish cadence (GCS KV ns "llm", key
 # engine/<deployment>/<replica>) — the pool autoscaler's engine-signal
@@ -81,6 +83,14 @@ class _EngineHost:
     engine's state.  The loop also publishes ``engine.stats()`` to the
     GCS KV every :data:`STATS_PUBLISH_INTERVAL_S` — the autoscaler /
     dashboard signal feed.
+
+    Tracing: the engine thread is covered by ``tracing.annotate`` phases
+    (``serve.lock_wait`` where it had to wait, the engine's own
+    ``engine.step`` tree, ``serve.deliver``, ``serve.publish_stats``,
+    ``serve.settle``, ``serve.idle``) and a request thread's wait for the
+    lock is a ``serve.lock_wait`` with ``who=submit``.  They are written only while
+    a profiler session runs: :meth:`start_profile` / :meth:`stop_profile`
+    start one in this process, the only one that can trace its chip.
     """
 
     # Admission settle: when free slots remain and a submit landed within
@@ -117,6 +127,34 @@ class _EngineHost:
         self._loop = threading.Thread(target=self._engine_loop, daemon=True)
         self._loop.start()
 
+    @contextlib.contextmanager
+    def _submitting(self):
+        """Hold ``_lock`` on a request thread that hands the engine a
+        request; its wait for the lock is a ``serve.lock_wait`` phase
+        with ``who=submit``."""
+        with tracing.annotate("serve.lock_wait", who="submit"):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
+
+    def start_profile(self, trace_dir: str) -> bool:
+        """Start a JAX profiler session in THIS process (callable over
+        the replica handle): device ops and every ``tracing`` span and
+        annotation of the process land in one trace under ``trace_dir``
+        until :meth:`stop_profile`."""
+        import jax
+
+        jax.profiler.start_trace(trace_dir)
+        return True
+
+    def stop_profile(self) -> bool:
+        import jax
+
+        jax.profiler.stop_trace()
+        return True
+
     def _on_token(self, request_id: int, tok: int):
         q = self._token_queues.get(request_id)
         if q is not None:
@@ -124,7 +162,15 @@ class _EngineHost:
 
     def _engine_loop(self):
         while not self._stop:
-            with self._lock:
+            # take a free lock at once, as ``with self._lock`` would: the
+            # hand-over between this thread and the submitters is a race
+            # of microseconds (a Python lock is not fair), and a phase
+            # opened before the attempt would tilt it.  Only a wait that
+            # really happens is a ``serve.lock_wait``.
+            if not self._lock.acquire(blocking=False):
+                with tracing.annotate("serve.lock_wait", who="engine"):
+                    self._lock.acquire()
+            try:
                 busy = self.engine.has_unfinished()
                 settle = False
                 outs = []
@@ -150,22 +196,32 @@ class _EngineHost:
                     if not settle:
                         outs = self.engine.step()
                         self._last_step = time.monotonic()
-                for out in outs:
-                    slot = self._waiters.pop(out.request_id, None)
-                    if slot is not None:
-                        slot["output"] = out
-                        slot["event"].set()
+                if outs:
+                    with tracing.annotate("serve.deliver", n=len(outs)):
+                        for out in outs:
+                            slot = self._waiters.pop(out.request_id, None)
+                            if slot is not None:
+                                slot["output"] = out
+                                slot["event"].set()
+            finally:
+                self._lock.release()
             self._maybe_publish_stats()
             if settle:
-                time.sleep(0.001)
+                with tracing.annotate("serve.settle"):
+                    time.sleep(0.001)
             elif not busy:
-                time.sleep(0.005)
+                with tracing.annotate("serve.idle"):
+                    time.sleep(0.005)
 
     def _maybe_publish_stats(self):
         now = time.monotonic()
         if now - self._last_publish < STATS_PUBLISH_INTERVAL_S:
             return
         self._last_publish = now
+        with tracing.annotate("serve.publish_stats"):
+            self._publish_stats()
+
+    def _publish_stats(self):
         try:
             import ray_tpu
             from ray_tpu.experimental import internal_kv
@@ -242,7 +298,7 @@ class _EngineHost:
         budget = self._budget_s() if budget is None else budget
         sp = self._sampling_from_body(body)
         slot = {"event": threading.Event(), "output": None}
-        with self._lock:
+        with self._submitting():
             rid = self.engine.submit(body["prompt"], sp)
             self._waiters[rid] = slot
             self._last_submit = time.monotonic()
@@ -320,7 +376,7 @@ class _EngineHost:
         sp = self._sampling_from_body(body)
         slot = {"event": threading.Event(), "output": None}
         tq: "queue_mod.Queue" = queue_mod.Queue()
-        with self._lock:
+        with self._submitting():
             rid = self.engine.submit(body["prompt"], sp)
             self._waiters[rid] = slot
             self._token_queues[rid] = tq
@@ -450,7 +506,7 @@ class LLMPrefillServer(_EngineHost):
         deadline = time.monotonic() + budget
         sp = self._sampling_from_body(body)
         slot = {"event": threading.Event(), "output": None}
-        with self._lock:
+        with self._submitting():
             rid = self.engine.submit(body["prompt"], sp,
                                      prefill_only=True)
             self._waiters[rid] = slot
@@ -562,7 +618,7 @@ class LLMDecodeServer(_EngineHost):
         entry: Dict[str, Any] = {"request_id": None, "first_tokens":
                                  list(handoff.get("out_tokens", [])),
                                  "t": time.monotonic()}
-        with self._lock:
+        with self._submitting():
             try:
                 rid = self.engine.adopt_prefilled(handoff)
             except Exception:  # noqa: BLE001 — incompatible handoff

@@ -48,9 +48,12 @@ class StepLedger:
 
     Emissions: a ``train_step_bucket_s`` histogram series per bucket, a
     ``step_breakdown/<group>/<rank>`` KV record for the dashboard's
-    step-breakdown panel (throttled), and a ``train.step`` span in the
-    current trace.  Standalone-constructible (``StepLedger(group_name=
-    "bench")``) — bench.py uses it without a session.
+    step-breakdown panel (throttled), a ``train.step`` span in the
+    current trace, and — while a profiler session runs
+    (``train.profile()``) — ``train.step`` / ``train.<bucket>``
+    annotations in the profiler's trace, on the device's clock.
+    Standalone-constructible (``StepLedger(group_name="bench")``) —
+    bench.py uses it without a session.
     """
 
     BUCKETS = ("data_wait", "h2d", "compute", "collective_wait",
@@ -89,9 +92,15 @@ class StepLedger:
 
     @contextlib.contextmanager
     def bucket(self, name: str) -> Iterator[None]:
+        from ray_tpu._private import tracing
+
         t0 = time.perf_counter()
         try:
-            yield
+            # in a profiler trace the bucket is ``train.<name>`` on the
+            # device's clock (auto-attributed durations arrive through
+            # ``note`` after the fact and cannot be)
+            with tracing.annotate("train." + name):
+                yield
         finally:
             self.note(name, time.perf_counter() - t0)
 
@@ -112,7 +121,8 @@ class StepLedger:
         t0 = time.perf_counter()
         start_wall = time.time()
         try:
-            yield self
+            with tracing.annotate("train.step", step=self._step_idx + 1):
+                yield self
         finally:
             wall = time.perf_counter() - t0
             tracing.unregister_duration_sink(token)
