@@ -268,7 +268,8 @@ class LLMEngine:
         import jax.numpy as jnp
 
         from ray_tpu.models.llama import llama_init
-        from ray_tpu.models.paged_generation import (init_kv_pool,
+        from ray_tpu.models.paged_generation import (decode_attention_path,
+                                                     init_kv_pool,
                                                      paged_decode_sample,
                                                      prefill_suffix)
 
@@ -300,8 +301,13 @@ class LLMEngine:
         # fetched ONCE per window — the host↔device round trip
         # amortizes over window*slots tokens
         self.K = max(1, decode_window)
+        # the decode step's attention, read off what is in front of us:
+        # "paged_kernel" (live blocks read in place) for a dense pool on
+        # one TPU device without speculation, "gather" for the rest
+        self.attn = decode_attention_path(self.pool, mesh=mesh,
+                                          spec_tokens=spec_tokens)
         self._decode1 = jax.jit(
-            functools.partial(paged_decode_sample, cfg=cfg),
+            functools.partial(paged_decode_sample, cfg=cfg, attn=self.attn),
             donate_argnums=(4,))
         self._stack = jax.jit(lambda *ts: jnp.stack(ts))
         from ray_tpu.models.paged_generation import sample_token_batch
@@ -597,6 +603,7 @@ class LLMEngine:
                     # adaptive window: never decode past what the
                     # longest-running active request can still accept
                     window_k = self._window_arity(active)
+                    live = int(self._cur_len[active].sum())  # host mirror
                     self._refresh_device_mirrors()
                     if self._dev is None:
                         tok_d = jnp.asarray(self._next_token)
@@ -605,8 +612,9 @@ class LLMEngine:
                         tok_d, cur_d = self._dev
                     key_d = self._key
         if active:
-            with tracing.annotate("engine.dispatch_window", k=window_k,
-                                  active=len(active)):
+            with tracing.annotate(
+                    "engine.dispatch_window", k=window_k, active=len(active),
+                    live_tokens=live, attn=self.attn):
                 toks = []
                 for _ in range(window_k):  # device-chained: no host sync
                     tok_d, cur_d, key_d, self.pool = self._decode1(
@@ -918,6 +926,7 @@ class LLMEngine:
             "block_pressure": round(1.0 - available / capacity, 4),
             "block_size": self.bs,
             "kv_cache_dtype": self.kv_cache_dtype or "native",
+            "attn": self.attn,
             "prefix_cache": dict(self.blocks.stats),
             "prefill_chunks": self.prefill_stats["chunks"],
             "spec": dict(self.spec_stats),

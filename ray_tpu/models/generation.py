@@ -97,13 +97,16 @@ def _gqa_attend_quant(q, k_q, ks, v_q, vs, mask):
 
 
 def _layer_with_cache(x, lp, layer_kv, *, cfg, cos, sin, mask,
-                      positions=None):
+                      positions=None, attend=None):
     """One decoder layer reading/returning its kv (cache-enabled twin of
     ``llama._decoder_layer``; same weights, ragged-mask attention).
 
     ``layer_kv(k, v)`` merges with the cache and returns either
     ``(k_all, v_all)`` (dense) or ``(k_q, ks, v_q, vs)`` (int8 values +
-    per-token-head scales — routed through the scale-folded attend)."""
+    per-token-head scales — routed through the scale-folded attend).
+    ``attend(q, k, v) -> [b, s, H, hd]`` replaces both the merge and the
+    masked attention for a caller that never materializes the merged cache
+    (the paged decode kernel); ``layer_kv`` and ``mask`` are then unused."""
     b, s, h = x.shape
     hd = cfg.resolved_head_dim
     dt = cfg.dtype
@@ -113,11 +116,14 @@ def _layer_with_cache(x, lp, layer_kv, *, cfg, cos, sin, mask,
     v = (y @ lp["wv"].astype(dt)).reshape(b, s, cfg.num_kv_heads, hd)
     q = apply_rope(q, cos, sin, positions)
     k = apply_rope(k, cos, sin, positions)
-    merged = layer_kv(k, v)  # merge with cache; returns full keys/vals
-    if len(merged) == 4:
-        attn = _gqa_attend_quant(q, *merged, mask)
+    if attend is not None:
+        attn = attend(q, k, v)
     else:
-        attn = _gqa_attend(q, merged[0], merged[1], mask)
+        merged = layer_kv(k, v)  # merge with cache; full keys/vals
+        if len(merged) == 4:
+            attn = _gqa_attend_quant(q, *merged, mask)
+        else:
+            attn = _gqa_attend(q, merged[0], merged[1], mask)
     x = x + (attn.reshape(b, s, -1) @ lp["wo"].astype(dt))
     y = rms_norm(x, lp["mlp_norm"])
     act = swiglu(y @ lp["w_gate"].astype(dt), y @ lp["w_up"].astype(dt))

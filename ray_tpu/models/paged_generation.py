@@ -9,11 +9,24 @@ TPU-native redesign of the same ideas:
   KVH, hd]``; a sequence's cache is a **block table** (int32 indices into
   the pool).  Capacity is blocks, not slots×max_len — short requests stop
   reserving worst-case memory, and identical prompt prefixes share blocks.
-* All shapes are static: the decode step gathers each sequence's blocks
-  with ``jnp.take`` (``[b, MB·bs]`` keys, MB = max_len/block_size) and
-  masks by ``cur_len`` — one compiled program forever, XLA-friendly, no
-  dynamic shapes.  Block 0 is a reserved scratch block: table padding and
-  masked scatter lanes land there, so no write needs a branch.
+* All shapes are static — one compiled decode program forever — and the
+  decode step's attention takes one of two paths, chosen by
+  ``decode_attention_path`` from what it can see (the pool's keys, a
+  mesh, speculation, the backend), never by a knob:
+
+  - ``"paged_kernel"`` (dense pool, one query token, one device, TPU):
+    ``ops/pallas/paged_attention.py`` reads each slot's *live* blocks in
+    place out of the stacked pool, by block table and length; a slot
+    whose table row is all scratch (a freed slot) reads nothing.  What a
+    step moves follows the tokens held, not the tables' capacity.
+  - ``"gather"`` (int8 pool, ``paged_verify_step``, an engine with a
+    mesh, any other backend): gather every slot's whole table
+    (``[b, MB·bs]`` keys, MB = max_len/block_size) and mask by
+    ``cur_len``.  XLA-friendly, sharding-transparent, and the plain
+    reference the kernel is tested against.
+
+  Block 0 is a reserved scratch block: table padding and masked scatter
+  lanes land there, so no write needs a branch.
 * Prefix-cached prefill runs per request (b=1): the cached prefix KV is
   gathered from the pool, only the suffix runs through the layers (RoPE
   offset by ``start_pos``), and the suffix KV is scattered back into
@@ -85,8 +98,9 @@ def _store_kv(pool, i, blk, off, k, v):
 # BLOCK-TABLE CAPACITY (MB*bs == the engine's max_len, in tokens) above
 # which the int8 decode path keeps KV quantized through attention
 # (scale-folded dots) instead of dequantizing eagerly in the gather.
-# Capacity — not the sequences' true lengths — is the right knob: the
-# decode step always gathers the full static table width, so the
+# Capacity — not the sequences' true lengths — is the right knob: an
+# int8 pool always decodes on the "gather" path, which gathers the full
+# static table width (the paged kernel has no int8 arm yet), so the
 # dequant-materialization cost scales with capacity.  Measured crossover
 # on v5e @ 7B: eager wins at max_len 176 (295 vs 230 tok/s — the
 # int8-operand dot's mixed-precision path is slower), folded wins at
@@ -102,17 +116,35 @@ def _gather_kv(pool, i, block_tables, dt):
     ``(k, v)`` below ``INT8_FOLD_MIN_CONTEXT`` tokens of table CAPACITY
     (max_len), still-quantized ``(k_q, ks, v_q, vs)`` above it (consumed
     by the scale-folded attend) — see the crossover note above."""
-    k = pool["k"][i][block_tables]
-    v = pool["v"][i][block_tables]
+    k = pool["k"][i, block_tables]
+    v = pool["v"][i, block_tables]
     if "k_scale" in pool:
-        ks = pool["k_scale"][i][block_tables]
-        vs = pool["v_scale"][i][block_tables]
+        ks = pool["k_scale"][i, block_tables]
+        vs = pool["v_scale"][i, block_tables]
         MB, bs = k.shape[1], k.shape[2]
         if MB * bs >= INT8_FOLD_MIN_CONTEXT:  # static at trace time
             return k, ks, v, vs
         k = k.astype(dt) * ks.astype(dt)[..., None]
         v = v.astype(dt) * vs.astype(dt)[..., None]
     return k, v
+
+
+def decode_attention_path(pool, *, mesh=None, spec_tokens: int = 0) -> str:
+    """Which attention the decode step runs, from what can be seen:
+    ``"paged_kernel"`` for a dense pool on one TPU device, ``"gather"``
+    for an int8 pool (no kernel arm yet), a mesh (the pool is sharded over
+    KV heads; the kernel is not under ``shard_map`` yet), speculation (the
+    S > 1 verify has no kernel arm, and its greedy acceptance is held
+    token-exact against the decode window, so both arms run one attention)
+    and every backend but TPU (``ops/attention.py`` keeps Pallas off the
+    CPU path the same way).  Mosaic wants a page's ``(token, kv head)``
+    rows and the head dim tile-aligned; other shapes gather."""
+    bs, kvh, hd = pool["k"].shape[2:]
+    if ("k_scale" in pool or mesh is not None or spec_tokens
+            or jax.default_backend() != "tpu"
+            or hd % 128 or (bs * kvh) % 16):
+        return "gather"
+    return "paged_kernel"
 
 
 def _lm_head(params, cfg, x):
@@ -124,14 +156,22 @@ def _lm_head(params, cfg, x):
 
 
 def paged_decode_step(params, token, cur_len, block_tables, pool,
-                      cfg: LlamaConfig):
+                      cfg: LlamaConfig, attn: str | None = None):
     """One token for every slot against block-table caches.
 
     token ``[b]`` int32; cur_len ``[b]`` write positions; block_tables
     ``[b, MB]`` int32 pool indices (pad with 0 = scratch).  Returns
     ``(logits [b, vocab], pool)`` with each sequence's new KV written at
     ``block_tables[i, cur_len // bs][cur_len % bs]``.
+
+    ``attn``: the ``decode_attention_path`` the caller resolved (the engine
+    knows its mesh and whether it speculates); None resolves it here from
+    the pool and the backend.  On the kernel path a slot whose first table
+    entry is the scratch block holds no request (the engine zeroes a freed
+    slot's row): it attends over nothing and its logits are discarded.
     """
+    if attn is None:
+        attn = decode_attention_path(pool)
     b = token.shape[0]
     MB = block_tables.shape[1]
     bs = pool["k"].shape[2]
@@ -149,6 +189,8 @@ def paged_decode_step(params, token, cur_len, block_tables, pool,
     rows = jnp.arange(b)
     blk = block_tables[rows, cur_len // bs]  # [b] target block per seq
     off = cur_len % bs
+    # the kernel's view of a slot: positions 0..cur_len, or nothing
+    lengths = jnp.where(block_tables[:, 0] != 0, cur_len + 1, 0)
 
     for i, lp in _stacked_layers(params):
         def merge(k, v, i=i):
@@ -161,8 +203,22 @@ def paged_decode_step(params, token, cur_len, block_tables, pool,
             g = _gather_kv(pool, i, block_tables, dt)
             return tuple(a.reshape(b, MB * bs, *a.shape[3:]) for a in g)
 
-        x, _ = _layer_with_cache(x, lp, merge, cfg=cfg, cos=cos, sin=sin,
-                                 mask=mask, positions=positions)
+        def attend(q, k, v, i=i):
+            nonlocal pool
+            # imported where it is used, as ops/attention.py does with the
+            # flash kernel: a process that never takes the kernel path
+            # (every CPU worker, the driver) never loads Pallas
+            from ray_tpu.ops.pallas.paged_attention import paged_attention
+
+            pool = _store_kv(pool, i, blk, off, k[:, 0], v[:, 0])
+            return paged_attention(
+                q[:, 0], pool["k"], pool["v"], block_tables, lengths,
+                layer=i, window=cfg.sliding_window)[:, None]
+
+        x, _ = _layer_with_cache(
+            x, lp, merge, cfg=cfg, cos=cos, sin=sin, mask=mask,
+            positions=positions,
+            attend=attend if attn == "paged_kernel" else None)
     return _lm_head(params, cfg, x)[:, 0], pool
 
 
@@ -275,7 +331,7 @@ def paged_verify_step(params, tokens, cur_len, block_tables, pool,
 
 
 def paged_decode_sample(params, token, cur_len, block_tables, pool, key,
-                        temps, cfg: LlamaConfig):
+                        temps, cfg: LlamaConfig, attn: str | None = None):
     """One decode step with ON-DEVICE sampling, shaped for host-free
     chaining: every output the next step needs (token, position, PRNG key)
     is returned as a device array, so the engine can dispatch K steps
@@ -295,7 +351,7 @@ def paged_decode_sample(params, token, cur_len, block_tables, pool, key,
     ML = block_tables.shape[1] * pool["k"].shape[2]
     safe_cur = jnp.minimum(cur_len, ML - 1)
     logits, pool = paged_decode_step(params, token, safe_cur, block_tables,
-                                     pool, cfg=cfg)
+                                     pool, cfg=cfg, attn=attn)
     key, sub = jax.random.split(key)
     nxt = sample_token_batch(logits, sub, temps)
     return nxt, cur_len + 1, key, pool

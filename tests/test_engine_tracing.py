@@ -107,6 +107,25 @@ def test_engine_step_is_tiled_by_its_phases(tiny, tmp_path, case, kwargs):
     # compile every program first; the profiled pass sends other prompts
     # of the same lengths (these would hit the prefix cache)
     eng.generate(prompts(10), sp)
+    # what each window's slots hold as it is dispatched, from the host
+    # mirror: _window_arity is the last thing prepare_window asks (the
+    # verify arm asks it too, for the window it would displace)
+    held, verifying = [], []
+    arity, speculate = eng._window_arity, eng._try_speculate
+
+    def recording_arity(active):
+        if not verifying:
+            held.append(int(sum(eng._cur_len[i] for i in active)))
+        return arity(active)
+
+    def flagged_speculate(active):
+        verifying.append(True)
+        try:
+            return speculate(active)
+        finally:
+            verifying.pop()
+    eng._window_arity = recording_arity
+    eng._try_speculate = flagged_speculate
     with _Profile(tmp_path) as prof:
         outs = eng.generate(prompts(20), sp)
     assert all(len(o.token_ids) == 22 for o in outs)
@@ -152,8 +171,11 @@ def test_engine_step_is_tiled_by_its_phases(tiny, tmp_path, case, kwargs):
     assert {s["prompt_tokens"] for s in full} == {8, 41, 5}
     assert all(s["queue_wait_ms"] >= 0 and s["bucket"] >= 1
                and s["cached_tokens"] % eng.bs == 0 for s in full)
-    for e in prof.named("engine.dispatch_window"):
+    windows = prof.named("engine.dispatch_window")
+    for e in windows:
         assert 1 <= e[3]["k"] <= 4 and 1 <= e[3]["active"] <= 2
+        assert e[3]["attn"] == eng.attn == "gather"  # CPU backend
+    assert [e[3]["live_tokens"] for e in windows] == held
     emitted = sum(e[3]["tokens"] for e in prof.named("engine.emit"))
     assert sum(e[3]["n"] for e in prof.named("engine.first_tokens")) == 3
     if case != "speculative":

@@ -401,6 +401,65 @@ def test_paged_engine_matches_full_recompute(tiny_model):
                                                 [o.token_ids for o in outs])
 
 
+def test_paged_kernel_engine_matches_full_recompute(tiny_model, monkeypatch):
+    """The decode step's other attention path, the paged kernel (forced
+    here through the Pallas interpreter by patching the module's path
+    predicate: on the CPU backend it says "gather"), returns token for
+    token what the cache-free full-recompute reference does."""
+    from ray_tpu.llm import LLMEngine
+    from ray_tpu.models import paged_generation as pg
+    from ray_tpu.models.generation import generate
+
+    cfg, params = tiny_model
+    sp = SamplingParams(temperature=0.0, max_tokens=6)
+    prompts = [[3, 4, 5, 6, 7], [9, 8]]
+    ref = generate(params, cfg, prompts, sp, key=jax.random.PRNGKey(0))
+    monkeypatch.setattr(pg, "decode_attention_path",
+                        lambda pool, **seen: "paged_kernel")
+    # 3 slots for 2 requests: one slot's table row stays all scratch
+    eng = LLMEngine(cfg, params, batch_slots=3, max_len=64, block_size=4)
+    assert eng.stats()["attn"] == "paged_kernel"
+    outs = eng.generate(prompts, sp)
+    assert [o.token_ids for o in outs] == ref, (ref,
+                                                [o.token_ids for o in outs])
+
+
+@pytest.mark.parametrize("backend,kwargs,want", [
+    ("cpu", {}, "gather"),
+    ("tpu", {}, "paged_kernel"),
+    ("tpu", {"kv_cache_dtype": "int8"}, "gather"),
+    ("tpu", {"mesh": "tp1"}, "gather"),
+    ("tpu", {"spec_tokens": 2}, "gather"),
+], ids=["cpu_backend", "tpu_dense", "int8_pool", "mesh", "spec_tokens"])
+def test_engine_reads_its_attention_path_off_its_input(monkeypatch, backend,
+                                                       kwargs, want):
+    """Who takes the kernel is decided by the pool's keys, the mesh,
+    speculation and the backend, with no argument of its own, and
+    ``stats()`` reports it.  (Nothing is run: on this CPU a "tpu" backend
+    is only what the predicate is told.)"""
+    from ray_tpu.llm import LLMEngine
+    from ray_tpu.parallel.mesh import MeshConfig, create_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if "mesh" in kwargs:
+        kwargs = {"mesh": create_mesh(MeshConfig(dp=1, tp=1),
+                                      devices=jax.devices()[:1])}
+    cfg = LlamaConfig.tiny(num_layers=1, hidden_size=256, num_heads=2,
+                           num_kv_heads=1, head_dim=128)
+    eng = LLMEngine(cfg, llama_init(jax.random.PRNGKey(1), cfg),
+                    batch_slots=2, max_len=32, block_size=16, **kwargs)
+    assert eng.attn == eng.stats()["attn"] == want
+
+
+def test_pages_mosaic_cannot_tile_gather(tiny_model, monkeypatch):
+    """head_dim 16: not a lane-aligned page, so no kernel even on TPU."""
+    from ray_tpu.models import paged_generation as pg
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pool = pg.init_kv_pool(tiny_model[0], 4, 16)
+    assert pg.decode_attention_path(pool) == "gather"
+
+
 def test_prefix_cache_reuses_blocks(tiny_model):
     """A second request sharing a long prompt prefix reuses the cached
     blocks (vllm_models.py:123-127 automatic prefix caching) and still
