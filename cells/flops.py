@@ -1,10 +1,12 @@
-"""The yardstick's arithmetic: peaks, parameter counts, operations and bytes.
+"""The yardstick's arithmetic that every family shares: the chip's peaks
+and the flash kernels' operations and bytes (attention alone, whatever the
+layer around it).  Parameter counts, a step's operations and a decode
+step's bytes depend on the architecture: ``cells/families/<name>.py``.
 
-Copied from ``bench.py`` (``PEAK_FLOPS``, ``peak_flops_per_chip``,
-``train_flops_per_step``) so that no later PR can move a utilisation by
-editing the program's copy.  Everything here is computed from a
-configuration file's ``model`` group (a plain dict) and a cell's shapes;
-nothing imports ``ray_tpu`` or ``jax``.
+Copied from ``bench.py`` (``PEAK_FLOPS``, ``peak_flops_per_chip``) so that
+no later PR can move a utilisation by editing the program's copy.
+Everything here is computed from a configuration file's ``model`` group
+(a plain dict) and a cell's shapes; nothing imports ``ray_tpu`` or ``jax``.
 """
 
 import json
@@ -30,38 +32,11 @@ def head_dim(m: dict) -> int:
     return m.get("head_dim") or m["hidden_size"] // m["num_heads"]
 
 
-def layer_params(m: dict) -> int:
-    """Parameters of one decoder layer: q, k, v, o, gate, up, down, 2 norms."""
-    h, hd = m["hidden_size"], head_dim(m)
-    q, kv = m["num_heads"] * hd, m["num_kv_heads"] * hd
-    return h * q + 2 * h * kv + q * h + 3 * h * m["mlp_dim"] + 2 * h
-
-
-def num_params(m: dict) -> int:
-    embed = m["vocab_size"] * m["hidden_size"]
-    head = 0 if m.get("tie_embeddings") else embed
-    return (embed + head + m["num_layers"] * layer_params(m)
-            + m["hidden_size"])
-
-
-def train_flops_per_step(m: dict, batch: int, seq: int) -> float:
-    """Operations the forward and backward passes need for one step.
-
-    6 per matmul parameter per token (forward 2, backward 4; the
-    embedding lookup is not a matmul), plus causal attention: QK^T and
-    PV are 4*s*s*h*hd forward and 8 backward per sequence and layer,
-    halved by the causal mask.  Recomputation is not counted.
-    """
-    n_matmul = num_params(m) - m["vocab_size"] * m["hidden_size"]
-    dense = 6 * n_matmul * batch * seq
-    attn = (12 * m["num_layers"] * batch * seq * seq * m["num_heads"]
-            * head_dim(m) * 0.5)
-    return dense + attn
-
-
 def flash_flops_per_step(m: dict, batch: int, seq: int) -> float:
-    """Operations of the flash kernels alone (forward, dQ, dK/dV): the
-    attention term of ``train_flops_per_step``."""
+    """Operations of the flash kernels alone (forward, dQ, dK/dV): QK^T
+    and PV are 4*s*s*h*hd forward and 8 backward per sequence and layer,
+    halved by the causal mask.  The attention term of a family's
+    ``train_flops_per_step``."""
     return (12 * m["num_layers"] * batch * seq * seq * m["num_heads"]
             * head_dim(m) * 0.5)
 
@@ -77,21 +52,3 @@ def flash_bytes_per_step(m: dict, batch: int, seq: int) -> float:
     dq = 3 * q + 2 * kv + q
     dkv = 3 * q + 2 * kv + 2 * kv
     return m["num_layers"] * (fwd + dq + dkv)
-
-
-def weight_bytes(m: dict) -> int:
-    return num_params(m) * DTYPE_BYTES[m["param_dtype"]]
-
-
-def kv_bytes_per_token(m: dict) -> int:
-    """K and V of one position over all layers, in the cache's type."""
-    return (2 * m["num_layers"] * m["num_kv_heads"] * head_dim(m)
-            * DTYPE_BYTES[m.get("dtype", "bfloat16")])
-
-
-def decode_step_bytes(m: dict, live_tokens: float) -> float:
-    """Bytes one decode step has to move: every weight once (the
-    embedding table is looked up, not read: left out) and the live
-    keys and values of the batch once."""
-    embed = m["vocab_size"] * m["hidden_size"] * DTYPE_BYTES[m["param_dtype"]]
-    return weight_bytes(m) - embed + live_tokens * kv_bytes_per_token(m)

@@ -1,13 +1,13 @@
 """Layer: model step.  The decode program's share of its roofline: the
 bytes one step has to move (every weight but the embedding table once,
-and the live keys and values once: ``flops.decode_step_bytes``, live
+and the live keys and values once: the family's ``decode_step_bytes``, live
 tokens from the polled block count) over the HBM peak, over the median
 device time of the program in the trace.  One token a slot: ~30
 operations a byte, far left of the ridge (240): memory bounds."""
 
 import statistics
 
-from cells import flops, trace
+from cells import trace
 
 
 def read(ctx):
@@ -19,6 +19,6 @@ def read(ctx):
         return None
     live = (sum(p["blocks_used"] for p in polls) / len(polls)
             * ctx["engine"]["block_size"])
-    least = (flops.decode_step_bytes(ctx["model"], live)
+    least = (ctx["family"].decode_step_bytes(ctx["model"], live)
              / ctx["peaks"]["hbm_bytes_per_s"])
     return 100.0 * least / statistics.median(runs)

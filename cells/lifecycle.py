@@ -114,7 +114,7 @@ class Cluster:
         self.chips, self.rehearse = chips, rehearse
         self.session_dir = None
         self.waited_s = 0.0
-        self.init_wall = None
+        self.init_wall = self.started_wall = None
         self.serve_started = False
 
     def start(self):
@@ -135,6 +135,7 @@ class Cluster:
             # the TPU resource comes from detection; the workers' output stays
             # in the session's logs, whose tails a failed run prints
             ray_tpu.init(log_to_driver=False)
+        self.started_wall = time.time()
         self.session_dir = ray_tpu._node_services.session_dir
         if not self.rehearse:
             found = ray_tpu.cluster_resources().get("TPU", 0)

@@ -15,6 +15,12 @@ on the *same* seeded parameters: ``embed [V, H]``, ``layers`` with each
 leaf stacked over depth (``wq [L, H, heads*hd]`` ... ``w_down [L, M, H]``,
 norms ``[L, H]``), ``final_norm [H]``, ``lm_head [H, V]``.
 
+The control of the comparison that decides ``correct`` is this file too:
+with ``control_dtype`` in ``model`` (tests and ``tools/control.py`` only,
+never a measured run) every matrix product with a weight rounds both
+operands to that 8-bit float first, with one scale a tensor, as an fp8
+path of the program would; sums stay float32.
+
 Departures from the published model: none in the mathematics; RMSNorm's
 epsilon is 1e-6 as the program's (the published ``rms_norm_eps`` is 1e-5;
 both are far below the activations' mean square of order 1).
@@ -29,6 +35,18 @@ EPS = 1e-6
 def _rms_norm(x, scale):
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     return x / jnp.sqrt(var + EPS) * scale
+
+
+def _mm(a, b, model):
+    dtype = model.get("control_dtype")
+    if dtype is None:
+        return a @ b
+
+    def rounded(x):  # the gradient passes the rounding straight through
+        scale = jnp.max(jnp.abs(x)) / float(jnp.finfo(dtype).max)
+        return x + jax.lax.stop_gradient(
+            (x / scale).astype(dtype).astype(jnp.float32) * scale - x)
+    return rounded(a) @ rounded(b)
 
 
 def _rope(x, theta):
@@ -47,9 +65,11 @@ def _layer(x, lp, model):
     hd = model.get("head_dim") or model["hidden_size"] // nh
     lp = {k: v.astype(jnp.float32) for k, v in lp.items()}
     y = _rms_norm(x, lp["attn_norm"])
-    q = _rope((y @ lp["wq"]).reshape(s, nh, hd), model["rope_theta"])
-    k = _rope((y @ lp["wk"]).reshape(s, nkv, hd), model["rope_theta"])
-    v = (y @ lp["wv"]).reshape(s, nkv, hd)
+    q = _rope(_mm(y, lp["wq"], model).reshape(s, nh, hd),
+              model["rope_theta"])
+    k = _rope(_mm(y, lp["wk"], model).reshape(s, nkv, hd),
+              model["rope_theta"])
+    v = _mm(y, lp["wv"], model).reshape(s, nkv, hd)
     causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
     group = nh // nkv
 
@@ -64,10 +84,11 @@ def _layer(x, lp, model):
     outs = [attend(q[:, g * group:(g + 1) * group], k[:, g], v[:, g])
             for g in range(nkv)]
     attn = jnp.concatenate(outs, axis=1).reshape(s, nh * hd)
-    x = x + attn @ lp["wo"]
+    x = x + _mm(attn, lp["wo"], model)
     y = _rms_norm(x, lp["mlp_norm"])
-    gate = y @ lp["w_gate"]
-    x = x + (jax.nn.sigmoid(gate) * gate * (y @ lp["w_up"])) @ lp["w_down"]
+    gate = _mm(y, lp["w_gate"], model)
+    x = x + _mm(jax.nn.sigmoid(gate) * gate * _mm(y, lp["w_up"], model),
+                lp["w_down"], model)
     return x
 
 
@@ -79,7 +100,7 @@ def _from_embeddings(params, x, model):
         body = jax.checkpoint(lambda x, lp: (_layer(x, lp, model), None))
         x, _ = jax.lax.scan(body, x, params["layers"])
         x = _rms_norm(x, params["final_norm"].astype(jnp.float32))
-        return x @ params["lm_head"].astype(jnp.float32)
+        return _mm(x, params["lm_head"].astype(jnp.float32), model)
 
 
 def _nll(lg, targets):

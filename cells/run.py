@@ -3,10 +3,12 @@
     python3 cells/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is an entry of ``BENCHMARK.json``'s ``workloads``; its
-configuration is ``cells/configs/<config>.json``, its traffic mix
-``cells/traffic/<traffic>.json`` (whose ``runner`` field picks the training
-or the serving runner), and each metric it reports has a reader of its own,
-``cells/end_to_end/<metric>.py`` or ``cells/layer_metrics/<metric>.py``.
+configuration is ``cells/configs/<config>.json`` (whose ``family`` names
+the model's file, ``cells/families/<family>.py``), its traffic mix
+``cells/traffic/<traffic>.json`` (whose ``runner`` names the runner,
+``cells/<runner>_runner.py``), and each metric it reports has a reader of
+its own, ``cells/end_to_end/<metric>.py`` or
+``cells/layer_metrics/<metric>.py``.
 With ``--trace 0`` the last line of stdout carries the cell's end-to-end
 metrics, with ``--trace 1`` its per-layer metrics.
 
@@ -22,6 +24,7 @@ import time
 T0 = time.time()  # set-up is counted from here
 
 import argparse  # noqa: E402
+import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -83,6 +86,8 @@ def prepare(workload, seed, seconds, trace, rehearse):
     inherits, and the context the runner gets (``cells/tools`` use it
     too)."""
     sys.path.insert(0, ROOT)
+    from cells import families
+
     bench = load_json(ROOT, "BENCHMARK.json")
     cell = next((w for w in bench["workloads"]
                  if w["name"] == workload), None)
@@ -91,12 +96,13 @@ def prepare(workload, seed, seconds, trace, rehearse):
                          f"BENCHMARK.json")
     config = load_json(HERE, "configs", cell["config"] + ".json")
     traffic = load_json(HERE, "traffic", cell["traffic"] + ".json")
+    family = families.load(config["family"])
     model, engine = dict(config["model"]), dict(config.get("engine", {}))
     if rehearse:
         toy = load_json(HERE, "rehearse.json")
         print("REHEARSAL on the CPU at toy shapes: not a chip result",
               flush=True)
-        model.update(toy["model"])
+        model.update(family.TOY_MODEL)
         engine.update(toy["engine"] if engine else {})
         traffic = _merge(traffic, toy["traffic"][traffic["runner"]])
         os.environ["JAX_PLATFORMS"] = "cpu"
@@ -124,9 +130,9 @@ def prepare(workload, seed, seconds, trace, rehearse):
     shutil.rmtree(trace_dir, ignore_errors=True)
     os.makedirs(trace_dir, exist_ok=True)
     return bench, cell, {
-        "cluster": lifecycle.Cluster(cell["chips"], rehearse),
-        "config": config, "model": model, "engine": engine,
-        "traffic": traffic, "seed": seed,
+        "t0": T0, "cluster": lifecycle.Cluster(cell["chips"], rehearse),
+        "config": config, "family": family, "model": model,
+        "engine": engine, "traffic": traffic, "seed": seed,
         "seconds": seconds if seconds is not None else bench["run_seconds"],
         "trace": trace, "trace_dir": trace_dir, "rehearse": rehearse,
         "chips": cell["chips"]}
@@ -149,7 +155,6 @@ def main():
     model, engine, config = ctx["model"], ctx["engine"], ctx["config"]
 
     from cells import flops, trace as trace_mod
-    from cells import serve_runner, train_runner
     from ray_tpu._private.accelerators import jax_backend_initialized
 
     def on_alarm(signum, frame):
@@ -157,8 +162,7 @@ def main():
     signal.signal(signal.SIGALRM, on_alarm)
     signal.alarm(TIME_LIMIT_S)
 
-    runner = {"train": train_runner, "serve": serve_runner}[
-        traffic["runner"]]
+    runner = importlib.import_module(f"cells.{traffic['runner']}_runner")
     reach = {}
     run = None
     failed = True
@@ -198,8 +202,9 @@ def main():
     if trace:
         first, last = trace_mod.span(trace)  # first to last device operation
         trace_window_s = (last - first) / 1e9
-    rctx = {"run": run, "model": model, "engine": engine,
-            "traffic": traffic, "config": config, "chips": cell["chips"],
+    rctx = {"run": run, "family": ctx["family"], "model": model,
+            "engine": engine, "traffic": traffic, "config": config,
+            "chips": cell["chips"],
             "trace": trace, "trace_window_s": trace_window_s,
             "setup_s": run["wall_window"] - T0,
             "chip_reach_s": reach.get("s"),

@@ -24,21 +24,20 @@ REFERENCE_LIMIT_S = 900.0
 
 # ------------------------------------------------------- on the chip, after
 
-def reference_check(model, seed, samples, pad_to):
+def reference_check(family, model, seed, samples, pad_to):
     """Runs in a task that holds the chip after ``serve.shutdown()``: the
-    same seeded weights, the plain reference over prompt + returned
-    tokens, and for every returned token how far its reference logit lies
-    under that position's largest."""
+    same seeded weights, the family's plain reference over prompt +
+    returned tokens, and for every returned token how far its reference
+    logit lies under that position's largest."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from cells import reference
-    from cells.train_worker import _model_config
-    from ray_tpu.models.llama import llama_init
+    from cells import families
 
-    cfg = _model_config(model)
-    params = llama_init(jax.random.PRNGKey(seed), cfg)
+    fam = families.load(family)
+    reference = fam.reference()
+    params = fam.init(jax.random.PRNGKey(seed), fam.config(model))
 
     @jax.jit
     def gaps(params, tokens):
@@ -67,6 +66,7 @@ class ServeRun:
         self.cluster = ctx["cluster"]
         self.traffic = ctx["traffic"]
         self.model = ctx["model"]
+        self.family = ctx["family"]
         self.engine = dict(ctx["engine"])
         self.rehearse = ctx["rehearse"]
         self.seed = ctx["seed"] % (2 ** 31 - 1)
@@ -81,7 +81,6 @@ class ServeRun:
         from ray_tpu.serve.controller import get_controller
 
         from cells.tokenizer import IdTokenizer
-        from cells.train_worker import _model_config
 
         with socket.socket() as s:
             s.bind(("127.0.0.1", 0))
@@ -91,7 +90,7 @@ class ServeRun:
             "host": "127.0.0.1", "port": port,
             "request_timeout_s": WARMUP_REQUEST_LIMIT_S})
         self.cluster.serve_started = True
-        kwargs = dict(self.engine, cfg=_model_config(self.model),
+        kwargs = dict(self.engine, cfg=self.family.config(self.model),
                       seed=self.seed,
                       tokenizer=IdTokenizer(self.model["vocab_size"]))
         app = build_llm_deployment(
@@ -208,7 +207,8 @@ class ServeRun:
         task = ray_tpu.remote(reference_check).options(
             num_tpus=0 if self.rehearse else 1)
         return ray_tpu.get(task.remote(
-            self.model, self.seed, samples, self.engine["max_len"]),
+            self.ctx["config"]["family"], self.model, self.seed, samples,
+            self.engine["max_len"]),
             timeout=REFERENCE_LIMIT_S)
 
 

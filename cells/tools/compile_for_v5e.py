@@ -20,8 +20,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-import functools  # noqa: E402
-
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
@@ -29,7 +27,7 @@ from jax.experimental import topologies  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from cells.train_worker import _model_config  # noqa: E402
+from cells import families, train_worker  # noqa: E402
 
 jax.config.update("jax_enable_compilation_cache", False)
 # the program asks jax.default_backend() whether to run its Pallas kernel
@@ -54,16 +52,15 @@ def report(name, compiled):
 
 
 def train(cell, config, traffic, topo):
-    from ray_tpu.models.training import default_optimizer, make_llama_trainer
     from ray_tpu.parallel.mesh import MESH_AXES, resolve_mesh_config
 
-    cfg = _model_config(config["model"])
+    fam = families.load(config["family"])
+    cfg = fam.config(config["model"])
     n = cell["chips"]
     shape = resolve_mesh_config(config["scaling"]["mesh"]).resolve(n)
     devs = np.array(topo.devices[:n]).reshape(shape)
     mesh = Mesh(devs, MESH_AXES)
-    tr = make_llama_trainer(cfg, mesh, optimizer=default_optimizer(
-        **traffic["optimizer"]))
+    tr = fam.make_trainer(cfg, mesh, traffic["optimizer"])
     state = jax.eval_shape(tr._state_init, jax.random.PRNGKey(0))
     state = jax.tree.map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
@@ -74,44 +71,34 @@ def train(cell, config, traffic, topo):
     with mesh:
         report(f"train step {cell['name']}",
                tr._jit_step.lower(state, batch).compile())
+        # the set-up's comparison shares the chips with the state
+        group = jax.ShapeDtypeStruct(
+            (min(traffic["batch"], n), traffic["seq"] + 1), jnp.int32,
+            sharding=tr.batch_sharding)
+        logits = jax.ShapeDtypeStruct(
+            (group.shape[0], traffic["seq"], cfg.vocab_size), jnp.float32,
+            sharding=tr.batch_sharding)
+        programs = train_worker.reference_programs(
+            fam, cfg, config["model"], mesh)
+        one = jax.ShapeDtypeStruct(group.shape[1:], jnp.int32,
+                                   sharding=NamedSharding(mesh, P()))
+        for name, args in (("compare", (logits, group)), ("loss", (group,)),
+                           ("gradient", (one,))):
+            report(f"reference {name}", programs[name].lower(
+                state["params"], *args).compile())
 
 
 def serve(cell, config, traffic, topo):
-    from ray_tpu.models.llama import llama_init
-    from ray_tpu.models.paged_generation import (init_kv_pool,
-                                                 paged_decode_sample,
-                                                 prefill_suffix)
-
-    cfg = _model_config(config["model"])
-    e = config["engine"]
+    fam = families.load(config["family"])
     one = SingleDeviceSharding(topo.devices[0])
-
-    def on(tree):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one), tree)
-    B, bs = e["batch_slots"], e["block_size"]
-    MB = -(-e["max_len"] // bs)
-    params = on(jax.eval_shape(
-        functools.partial(llama_init, cfg=cfg), jax.random.PRNGKey(0)))
-    pool = on(jax.eval_shape(
-        lambda: init_kv_pool(cfg, e.get("num_blocks") or B * MB + 1, bs)))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)  # noqa
-    key = on(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
-    report("decode step", jax.jit(
-        functools.partial(paged_decode_sample, cfg=cfg),
-        donate_argnums=(4,)).lower(
-            params, i32(B), i32(B), i32(B, MB), pool, key,
-            jax.ShapeDtypeStruct((B,), jnp.float32, sharding=one)
-        ).compile())
-    S = max(traffic["warmup"]["prompt_lengths"])
-    hd = cfg.resolved_head_dim
-    empty = jax.ShapeDtypeStruct(
-        (cfg.num_layers, 0, cfg.num_kv_heads, hd), cfg.dtype, sharding=one)
-    report(f"prefill of {S} tokens", jax.jit(
-        functools.partial(prefill_suffix, cfg=cfg),
-        donate_argnums=(9,)).lower(
-            params, i32(1, S), i32(), i32(), empty, empty, i32(),
-            i32(S), i32(S), pool).compile())
+    programs = fam.serve_programs(
+        fam.config(config["model"]), config["engine"],
+        max(traffic["warmup"]["prompt_lengths"]))
+    for name, fn, donated, args in programs:
+        args = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), args)
+        report(name, jax.jit(fn, donate_argnums=donated).lower(
+            *args).compile())
 
 
 def main():

@@ -13,7 +13,8 @@ def run(ctx):
     result = JaxTrainer(
         train_loop,
         train_loop_config={
-            "model": ctx["model"], "traffic": traffic, "seed": ctx["seed"],
+            "family": config["family"], "model": model,
+            "traffic": traffic, "seed": ctx["seed"],
             "seconds": ctx["seconds"], "rehearse": ctx["rehearse"],
             "trace_dir": ctx["trace_dir"] if ctx["trace"] else None},
         scaling_config=ScalingConfig(
@@ -27,10 +28,18 @@ def run(ctx):
     steps = len(m["step_s"])
     tokens = steps * traffic["batch"] * traffic["seq"]
     losses = m["losses"]
-    print(f"cells: worker reached its chip in "
-          f"{m['wall_reached'] - m['wall_enter']:.1f}s, build "
-          f"{m['build_s']:.1f}s, reference {m['reference_s']:.1f}s, "
-          f"warm-up {m['warmup_s']:.1f}s; {steps} steps in "
+    parts = ", ".join(
+        f"{name} {p['s']:.1f}s ({p['programs_s']:.1f}s of it building or "
+        f"loading programs)" for name, p in m["setup_parts"].items())
+    cluster = ctx["cluster"]
+    print(f"cells: before the worker's loop "
+          f"{m['wall_enter'] - ctx['t0']:.1f}s (to init() "
+          f"{cluster.init_wall - ctx['t0']:.1f}s, init() "
+          f"{cluster.started_wall - cluster.init_wall:.1f}s, fit() to the "
+          f"loop {m['wall_enter'] - cluster.started_wall:.1f}s); "
+          f"worker reached its chip in "
+          f"{m['wall_reached'] - m['wall_enter']:.1f}s, {parts}; "
+          f"{steps} steps in "
           f"{m['window_s']:.2f}s; loss {losses[0]:.4f} -> {losses[-1]:.4f}; "
           f"mesh {m['mesh']}; reference {m['reference']}; first step "
           f"{m['first_step']}", flush=True)

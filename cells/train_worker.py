@@ -12,63 +12,102 @@ until ``seconds`` have passed, each ended by ``block_until_ready``.
 import time
 
 
-def _model_config(model: dict):
-    import jax.numpy as jnp
-
-    from ray_tpu.models.llama import LlamaConfig
-
-    kw = dict(model)
-    for key in ("dtype", "param_dtype"):
-        if key in kw:
-            kw[key] = jnp.dtype(kw[key])
-    return LlamaConfig(**kw)
-
-
-def reference_check(cfg, model, mesh, params, tokens, n_seq):
-    """Before the first step, on the parameters it starts from: the
-    program's forward pass (``llama_apply``) against the plain reference's
-    logits on ``n_seq`` sequences; the reference's loss on every sequence
-    of the batch; and the reference's gradient, by float32 autodiff, with
-    respect to the embedded tokens of sequence 0."""
+def reference_programs(fam, cfg, model, mesh):
+    """The set-up's comparison programs (``tools/compile_for_v5e.py``
+    compiles them too).  Over a group of sequences ``[g, s + 1]``: the
+    program's forward pass (the family's ``apply``); the reference's
+    logits set against given ones, inside one program so that they never
+    leave it (handed out by ``logits``, for ``tools/control.py``, they
+    are gathered onto every chip); the reference's loss.  The
+    reference is written for one sequence and mapped over the group,
+    which is sharded as the batch is, one sequence a chip: the chips work
+    side by side.  For one sequence: the reference's gradient with
+    respect to the embedded tokens, by float32 autodiff (over a group it
+    would keep 9.7 GB of temporaries a chip beside the four-chip cell's
+    state, by the compiler's count)."""
     import jax
     import jax.numpy as jnp
 
-    from cells import reference
-    from ray_tpu.models.llama import llama_apply
+    ref = fam.reference()
 
-    with mesh:
-        sys_logits = jax.jit(
-            lambda p, t: llama_apply(p, t, cfg, mesh=mesh))(
-                params, tokens[:n_seq, :-1])
-    ref_fn = jax.jit(lambda p, t: reference.logits(p, t, model))
-    ref_loss = jax.jit(lambda p, t: reference.loss(p, t, model))
-    ref_grad = jax.jit(
-        lambda p, t: reference.embedding_gradient(p, t, model))
-
-    @jax.jit
-    def compare(sys_lg, ref_lg, targets):
+    def compare(sys_lg, ref_lg, tokens):
         def nll(lg):
             logp = jax.nn.log_softmax(lg, axis=-1)
             return -jnp.mean(jnp.take_along_axis(
-                logp, targets[:, None], axis=-1))
+                logp, tokens[1:, None], axis=-1))
         err = jnp.max(jnp.abs(sys_lg - ref_lg)) / jnp.maximum(
             1.0, jnp.max(jnp.abs(ref_lg)))
         return err, nll(sys_lg), nll(ref_lg)
 
-    rows, losses = [], []
-    for i in range(tokens.shape[0]):
-        if i < n_seq:
-            ref_lg = ref_fn(params, tokens[i, :-1])
-            err, l_sys, l_ref = compare(sys_logits[i], ref_lg,
-                                        tokens[i, 1:])
-            rows.append({"logit_err": float(err),
-                         "loss_system": float(l_sys),
-                         "loss_reference": float(l_ref)})
-            losses.append(float(l_ref))
-        else:
-            losses.append(float(ref_loss(params, tokens[i])))
-    return {"rows": rows, "batch_loss": sum(losses) / len(losses),
-            "embedding_gradient": ref_grad(params, tokens[0])}
+    def over_group(one):
+        return jax.jit(lambda p, *groups: jax.vmap(
+            lambda *seqs: one(p, *seqs))(*groups))
+
+    return {
+        "system": jax.jit(lambda p, group: fam.apply(
+            p, group[:, :-1], cfg, mesh)),
+        "logits": over_group(lambda p, seq: ref.logits(p, seq[:-1], model)),
+        "compare": over_group(lambda p, lg, seq: compare(
+            lg, ref.logits(p, seq[:-1], model), seq)),
+        "loss": over_group(lambda p, seq: ref.loss(p, seq, model)),
+        "gradient": jax.jit(
+            lambda p, seq: ref.embedding_gradient(p, seq, model)),
+    }
+
+
+def seeded(tr, cfg, traffic, seed):
+    """The state and the one batch of a run, on the devices, from its
+    seed."""
+    import jax
+
+    key = jax.random.PRNGKey(seed % (2 ** 31 - 1))
+    state = tr.init_state(jax.random.fold_in(key, 0))
+    tokens = jax.random.randint(
+        jax.random.fold_in(key, 1),
+        (traffic["batch"], traffic["seq"] + 1), 0, cfg.vocab_size)
+    return state, tr.shard_batch({"tokens": tokens})
+
+
+def reference_check(programs, mesh, params, tokens, group):
+    """Before the first step, on the parameters it starts from: the
+    program's logits against the reference's on the first ``group``
+    sequences; the reference's loss on every sequence of the batch, a
+    group at a time; and the reference's gradient for sequence 0."""
+    import jax
+
+    def rows(i):  # spread like the batch: one sequence a chip
+        return jax.device_put(tokens[i:i + group], tokens.sharding)
+
+    first = rows(0)
+    with mesh:
+        sys_logits = programs["system"](params, first)
+    err, l_sys, l_ref = programs["compare"](params, sys_logits, first)
+    out = [{"logit_err": float(e), "loss_system": float(a),
+            "loss_reference": float(b)}
+           for e, a, b in zip(err, l_sys, l_ref)]
+    losses = [r["loss_reference"] for r in out]
+    for i in range(group, tokens.shape[0], group):
+        losses += [float(x) for x in programs["loss"](params, rows(i))]
+    return {"rows": out, "batch_loss": sum(losses) / len(losses),
+            "embedding_gradient": jax.block_until_ready(
+                programs["gradient"](params, tokens[0]))}
+
+
+def seen_once(tokens):
+    """Sequence 0's input ids, and its positions whose token occurs once
+    in the whole batch."""
+    import numpy as np
+
+    inputs = np.asarray(tokens)[:, :-1]
+    counts = np.bincount(inputs.ravel())
+    return inputs[0], np.nonzero(counts[inputs[0]] == 1)[0]
+
+
+def one_minus_cos(a, b):
+    import jax.numpy as jnp
+
+    return float(1.0 - jnp.sum(a * b) / (
+        jnp.linalg.norm(a) * jnp.linalg.norm(b)))
 
 
 def step_check(state, metrics, tokens, ref):
@@ -85,23 +124,18 @@ def step_check(state, metrics, tokens, ref):
     compared: 1 - cosine.  Also the types the state is kept in."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
     adam = [s for s in jax.tree.leaves(
         state["opt_state"], is_leaf=lambda x: hasattr(x, "mu"))
         if hasattr(s, "mu")]
-    inputs = np.asarray(tokens)[:, :-1]
-    counts = np.bincount(inputs.ravel())
-    once = np.nonzero(counts[inputs[0]] == 1)[0]
-    g_sys = adam[0].mu["embed"][inputs[0][once]].astype(jnp.float32)
-    g_ref = ref["embedding_gradient"][once]
-    cos = jnp.sum(g_sys * g_ref) / (
-        jnp.linalg.norm(g_sys) * jnp.linalg.norm(g_ref))
+    ids, once = seen_once(tokens)
+    g_sys = adam[0].mu["embed"][ids[once]].astype(jnp.float32)
     kept = state["params"], adam[0].mu, adam[0].nu
     return {"loss_step": float(metrics["loss"]),
             "loss_reference": ref["batch_loss"],
             "grad_rows": int(len(once)),
-            "grad_one_minus_cos": float(1.0 - cos),
+            "grad_one_minus_cos": one_minus_cos(
+                g_sys, ref["embedding_gradient"][once]),
             "grad_norm": float(metrics["grad_norm"]),
             "state_dtypes": sorted({str(x.dtype)
                                     for x in jax.tree.leaves(kept)})}
@@ -118,38 +152,45 @@ def train_loop(config):
             f"the worker sees platform {devices[0].platform!r}, not tpu")
 
     from ray_tpu import train
-    from ray_tpu.models.training import default_optimizer, make_llama_trainer
 
-    programs = [0]  # programs built or loaded from the cache, ever
+    from cells import families
 
-    def on_event(name, *a, **kw):
+    # programs built or loaded from the cache, ever, and the seconds taken
+    built = {"n": 0, "s": 0.0}
+
+    def on_event(name, seconds, **kw):
         if name == "/jax/core/compile/backend_compile_duration":
-            programs[0] += 1
+            built["n"] += 1
+            built["s"] += seconds
     jax.monitoring.register_event_duration_secs_listener(on_event)
 
-    model, traffic = config["model"], config["traffic"]
-    cfg = _model_config(model)
-    mesh = train.get_context().get_mesh()
-    t0 = time.perf_counter()
-    tr = make_llama_trainer(
-        cfg, mesh, optimizer=default_optimizer(**traffic["optimizer"]))
-    key = jax.random.PRNGKey(config["seed"] % (2 ** 31 - 1))
-    state = tr.init_state(jax.random.fold_in(key, 0))
-    tokens = jax.random.randint(
-        jax.random.fold_in(key, 1),
-        (traffic["batch"], traffic["seq"] + 1), 0, cfg.vocab_size)
-    batch = tr.shard_batch({"tokens": tokens})
-    jax.block_until_ready((state, batch))
-    build_s = time.perf_counter() - t0
+    def clock():
+        return time.perf_counter(), built["s"]
 
-    t0 = time.perf_counter()
-    n_seq = min(traffic["batch"], len(devices))
-    ref = reference_check(cfg, model, mesh, state["params"],
-                          batch["tokens"], n_seq)
-    reference_s = time.perf_counter() - t0
+    def since(mark):
+        """Seconds of a part of set-up, and how many of them went into
+        building programs or reading them from the cache."""
+        return {"s": time.perf_counter() - mark[0],
+                "programs_s": built["s"] - mark[1]}
+
+    model, traffic = config["model"], config["traffic"]
+    fam = families.load(config["family"])
+    cfg = fam.config(model)
+    mesh = train.get_context().get_mesh()
+    mark = clock()
+    tr = fam.make_trainer(cfg, mesh, traffic["optimizer"])
+    state, batch = seeded(tr, cfg, traffic, config["seed"])
+    jax.block_until_ready((state, batch))
+    parts = {"build": since(mark)}
+
+    mark = clock()
+    ref = reference_check(
+        reference_programs(fam, cfg, model, mesh), mesh, state["params"],
+        batch["tokens"], min(traffic["batch"], len(devices)))
+    parts["reference"] = since(mark)
 
     losses = []
-    t0 = time.perf_counter()
+    mark = clock()
     for i in range(traffic["warmup_steps"]):
         state, m = tr.step(state, batch)
         jax.block_until_ready((state, m))
@@ -157,11 +198,11 @@ def train_loop(config):
         if i == 0:
             first_step = step_check(state, m, batch["tokens"], ref)
             del ref["embedding_gradient"]
-    warmup_s = time.perf_counter() - t0
+    parts["warmup"] = since(mark)
 
     trace_on = False
     trace_at = traffic["trace"]["start_step"] if config["trace_dir"] else -1
-    programs_before = programs[0]
+    programs_before = built["n"]
     step_s = []
     wall_window = time.time()
     w0 = time.perf_counter()
@@ -181,7 +222,7 @@ def train_loop(config):
         elif now - w0 >= config["seconds"] and not trace_on:
             break
     window_s = time.perf_counter() - w0
-    programs_in_window = programs[0] - programs_before
+    programs_in_window = built["n"] - programs_before
 
     stats = [d.memory_stats() or {} for d in jax.local_devices()]
     train.report({
@@ -191,8 +232,7 @@ def train_loop(config):
         "mesh": {a: int(n) for a, n in mesh.shape.items()},
         "wall_enter": wall_enter, "wall_reached": wall_reached,
         "wall_window": wall_window,
-        "build_s": build_s, "reference_s": reference_s,
-        "warmup_s": warmup_s,
+        "setup_parts": parts,
         "window_s": window_s, "step_s": step_s, "losses": losses,
         "programs_in_window": programs_in_window,
         "reference": ref["rows"], "first_step": first_step,
