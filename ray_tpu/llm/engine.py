@@ -39,7 +39,6 @@ import numpy as np
 
 from ray_tpu._private import tracing
 from ray_tpu.models.generation import SamplingParams
-from ray_tpu.models.llama import LlamaConfig
 
 
 class ByteTokenizer:
@@ -255,7 +254,7 @@ class _BlockManager:
 
 
 class LLMEngine:
-    def __init__(self, cfg: LlamaConfig, params=None, *,
+    def __init__(self, cfg, params=None, *,
                  tokenizer: Optional[Any] = None, batch_slots: int = 8,
                  max_len: Optional[int] = None, block_size: int = 16,
                  num_blocks: Optional[int] = None, decode_window: int = 16,
@@ -267,12 +266,16 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models.llama import llama_init
-        from ray_tpu.models.paged_generation import (decode_attention_path,
-                                                     init_kv_pool,
-                                                     paged_decode_sample,
-                                                     prefill_suffix)
+        from ray_tpu.models.served import served_model
 
+        # the programs come from the model whose configuration this is
+        # (models/served.py): init, pool, suffix prefill, decode-and-sample,
+        # prefix gather, and optionally verify and parameter specs
+        self.model = model = served_model(cfg)
+        model.require("speculative decoding's verify step (spec_tokens)",
+                      not spec_tokens or model.verify_step is not None)
+        model.require("parameter specs for an engine with a mesh",
+                      mesh is None or model.param_specs is not None)
         self.cfg = cfg
         self.mesh = mesh
         self.tokenizer = tokenizer or default_tokenizer(cfg.vocab_size)
@@ -284,15 +287,15 @@ class LLMEngine:
         # prefix sharing + short requests usually need far less)
         self.num_blocks = num_blocks or (self.B * self.MB + 1)
         if params is None:
-            params = llama_init(jax.random.PRNGKey(seed), cfg)
+            params = model.init(jax.random.PRNGKey(seed), cfg)
         self.params = params
         self._key = jax.random.PRNGKey(seed + 1)
 
         # kv_cache_dtype="int8": ~half the pool HBM -> ~2x the slots fit
         # next to the weights (vLLM kv_cache_dtype, TPU-native)
         self.kv_cache_dtype = kv_cache_dtype
-        self.pool = init_kv_pool(cfg, self.num_blocks, self.bs,
-                                 kv_dtype=kv_cache_dtype)
+        self.pool = model.init_pool(cfg, self.num_blocks, self.bs,
+                                    kv_dtype=kv_cache_dtype)
         if mesh is not None:
             self._shard_over_mesh(mesh)
         self.blocks = _BlockManager(self.num_blocks)
@@ -302,20 +305,32 @@ class LLMEngine:
         # amortizes over window*slots tokens
         self.K = max(1, decode_window)
         # the decode step's attention, read off what is in front of us:
-        # "paged_kernel" (live blocks read in place) for a dense pool on
-        # one TPU device without speculation, "gather" for the rest
-        self.attn = decode_attention_path(self.pool, mesh=mesh,
-                                          spec_tokens=spec_tokens)
+        # "paged_kernel" / "latent_kernel" (live blocks read in place) for
+        # a dense / latent pool on one TPU device without speculation,
+        # "gather" for the rest
+        self.attn = model.decode_attention_path(self.pool, mesh=mesh,
+                                                spec_tokens=spec_tokens)
         self._decode1 = jax.jit(
-            functools.partial(paged_decode_sample, cfg=cfg, attn=self.attn),
+            functools.partial(model.decode_sample, cfg=cfg, attn=self.attn),
             donate_argnums=(4,))
         self._stack = jax.jit(lambda *ts: jnp.stack(ts))
         from ray_tpu.models.paged_generation import sample_token_batch
 
         self._prefill = jax.jit(
-            functools.partial(prefill_suffix, cfg=cfg),
+            functools.partial(model.prefill_suffix, cfg=cfg),
             donate_argnums=(9,))  # the pool (avoid a full second copy)
         self._sample = jax.jit(sample_token_batch)
+        # the small integer sums a model's programs return beside the rest
+        # (ServedModel.counters): a window's ride to the host in the
+        # tokens' array, a prefill's with the first tokens
+        # (decode windows under the model's names beside "decode_steps",
+        # prefills under "prefill_<name>" beside "prefill_calls")
+        self.counters = dict.fromkeys(
+            (*model.counters, "decode_steps",
+             *(f"prefill_{n}" for n in model.counters), "prefill_calls")
+            if model.counters else (), 0)
+        self._stack_counted = jax.jit(lambda toks, counts: jnp.concatenate(
+            [jnp.stack(toks), jnp.stack(counts)], axis=1))
         # prompt-lookup speculative decoding (vLLM's ngram method,
         # TPU-native): host drafts from each request's own history, one
         # batched paged_verify_step forward checks pending + G drafts,
@@ -360,9 +375,8 @@ class LLMEngine:
         # initialized by reset_spec_state (the one place defaults live).
         self.reset_spec_state()
         if self.G:
-            from ray_tpu.models.paged_generation import paged_verify_step
             self._verify = jax.jit(
-                functools.partial(paged_verify_step, cfg=cfg),
+                functools.partial(model.verify_step, cfg=cfg),
                 donate_argnums=(4,))
 
         # chunked prefill (vLLM's feature TPU-natively): cap the prompt
@@ -403,6 +417,10 @@ class LLMEngine:
         self._dev_dirty = True
         # per-token hook for streaming consumers: on_token(request_id, tok)
         self.on_token: Optional[Any] = None
+        # a model's prefill counters, summed on the device (one shape,
+        # however many prefills) until the first tokens' fetch takes them
+        self._prefill_sum: Optional[Any] = None
+        self._prefill_calls = 0
 
     def _shard_over_mesh(self, mesh) -> None:
         """Tensor-parallel inference: place params by the logical-axis rule
@@ -417,7 +435,6 @@ class LLMEngine:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from ray_tpu.models.llama import llama_param_specs
         from ray_tpu.parallel.sharding import (TP_INFERENCE_RULES,
                                                shard_tree)
 
@@ -431,7 +448,8 @@ class LLMEngine:
                 raise ValueError(
                     f"num_heads={self.cfg.num_heads} not divisible by "
                     f"tp={tp}")
-        self.params = shard_tree(self.params, llama_param_specs(self.cfg),
+        self.params = shard_tree(self.params,
+                                 self.model.param_specs(self.cfg),
                                  mesh, TP_INFERENCE_RULES)
         # pool tensors: [L, blocks, bs, KVH, hd] (values) and
         # [L, blocks, bs, KVH] (int8 scales) — KVH is axis 3 in both.
@@ -451,6 +469,8 @@ class LLMEngine:
 
     def submit(self, prompt, sampling: Optional[SamplingParams] = None, *,
                prefill_only: bool = False) -> int:
+        self.model.require("the prefill/decode handoff (prefill_only)",
+                           not prefill_only or self.model.handoff)
         if isinstance(prompt, str):
             prompt = self.tokenizer.encode(prompt)
         sampling = sampling or SamplingParams(
@@ -564,10 +584,22 @@ class LLMEngine:
         if admitted:
             with tracing.annotate("engine.first_tokens", n=len(admitted)):
                 self._key, k = jax.random.split(self._key)
-                lg = self._stack(*[d for _, d in admitted])[:, 0]
-                temps = np.asarray([self._slots[i].sampling.temperature
-                                    for i, _ in admitted], np.float32)
-                first = np.asarray(self._sample(lg, k, jnp.asarray(temps)))
+                # padded to a power of two (the last row again, greedy):
+                # a number of admissions not seen before would lower three
+                # small programs here, on this thread, with the device idle
+                rows = [d for _, d in admitted]
+                rows += rows[-1:] * (_bucket(len(rows), self.B) - len(rows))
+                lg = self._stack(*rows)[:, 0]
+                temps = np.zeros(len(rows), np.float32)
+                temps[:len(admitted)] = [
+                    self._slots[i].sampling.temperature for i, _ in admitted]
+                first = self._sample(lg, k, jnp.asarray(temps))
+                if self._prefill_sum is not None:
+                    first, counts = jax.device_get(
+                        (first, self._prefill_sum))
+                    self._count(counts, self._prefill_calls, "prefill_")
+                    self._prefill_sum, self._prefill_calls = None, 0
+                first = np.asarray(first)[:len(admitted)]
                 now = time.time()
                 for (i, _), tok in zip(admitted, first):
                     req = self._slots[i]
@@ -581,7 +613,7 @@ class LLMEngine:
                 # made them, not at the frame's exit under no phase:
                 # freeing a device buffer gives up the interpreter lock,
                 # and what the thread then waits belongs to the phase
-                del admitted, lg, k, first
+                del admitted, rows, lg, k, first
 
         active = [i for i in range(self.B) if self._slots[i] is not None
                   and not self._slots[i].done]
@@ -615,17 +647,27 @@ class LLMEngine:
             with tracing.annotate(
                     "engine.dispatch_window", k=window_k, active=len(active),
                     live_tokens=live, attn=self.attn):
-                toks = []
+                toks, counts = [], []
                 for _ in range(window_k):  # device-chained: no host sync
-                    tok_d, cur_d, key_d, self.pool = self._decode1(
+                    tok_d, cur_d, key_d, self.pool, *extra = self._decode1(
                         self.params, tok_d, cur_d, self._tables_d,
                         self.pool, key_d, self._temps_d)
                     toks.append(tok_d)
+                    counts += extra
                 self._key = key_d
                 self._dev = (tok_d, cur_d)
-            with tracing.annotate("engine.fetch_window"):
-                # ONE host sync for the whole window_k * B window
-                window = np.asarray(self._stack(*toks))
+            with tracing.annotate("engine.fetch_window") as ann:
+                # ONE host sync for the whole window_k * B window (a
+                # model's counters ride in the same array)
+                if counts:
+                    window = np.asarray(self._stack_counted(toks, counts))
+                    ann.set_metadata(k=window_k, active=len(active),
+                                     **self._count(
+                                         window[:, self.B:].sum(axis=0),
+                                         steps=window_k))
+                    window = window[:, :self.B]
+                else:
+                    window = np.asarray(self._stack(*toks))
             with tracing.annotate("engine.emit") as ann:
                 if self.G:
                     self._spec_streak = 0
@@ -645,7 +687,7 @@ class LLMEngine:
                         self._record_token(i, req, int(window[step, i]))
                         emitted += 1
                 ann.set_metadata(tokens=emitted)
-                del toks, window  # as above: freed inside the phase
+                del toks, counts, window  # as above: freed inside the phase
 
         # 3. retire
         with tracing.annotate("engine.retire") as ann:
@@ -770,6 +812,8 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
+        self.model.require("the prefill/decode handoff (export_kv)",
+                           self.model.handoff)
         req = self._exports.pop(request_id)
         if self._gather_blocks is None:
             self._gather_blocks = jax.jit(
@@ -824,6 +868,8 @@ class LLMEngine:
         through the ordinary path)."""
         import jax.numpy as jnp
 
+        self.model.require("the prefill/decode handoff (adopt_prefilled)",
+                           self.model.handoff)
         kv = handoff["kv"]
         if handoff.get("kv_cache_dtype") != self.kv_cache_dtype:
             raise ValueError(
@@ -931,6 +977,8 @@ class LLMEngine:
             "prefill_chunks": self.prefill_stats["chunks"],
             "spec": dict(self.spec_stats),
             "handoff": dict(self.handoff_stats),
+            "model": self.model.name,
+            "counters": dict(self.counters),
             "devices": device_memory_stats(),
         }
 
@@ -1073,8 +1121,6 @@ class LLMEngine:
         last-position logits as a device array."""
         import jax.numpy as jnp
 
-        from ray_tpu.models.paged_generation import gather_prefix
-
         S = _bucket(len(suffix), self.max_len)
         pad_tok = list(suffix) + [0] * (S - len(suffix))
         # pool coordinates for each padded suffix lane (pads -> scratch 0)
@@ -1087,12 +1133,17 @@ class LLMEngine:
         P = _bucket(len(hit_blocks), self.MB) if hit_blocks else 0
         prefix_ids = np.zeros(P, np.int32)
         prefix_ids[:len(hit_blocks)] = hit_blocks
-        pk, pv = gather_prefix(self.pool, jnp.asarray(prefix_ids))
-        logits, self.pool = self._prefill(
+        pk, pv = self.model.gather_prefix(self.pool, jnp.asarray(prefix_ids),
+                                          self.cfg)
+        logits, self.pool, *extra = self._prefill(
             self.params, jnp.asarray([pad_tok], jnp.int32),
             jnp.int32(len(suffix)), jnp.int32(cached_len),
             pk, pv, jnp.int32(cached_len),
             jnp.asarray(dst_b), jnp.asarray(dst_o), self.pool)
+        for counts in extra:  # fetched with the first tokens
+            self._prefill_sum = counts if self._prefill_sum is None \
+                else self._prefill_sum + counts
+            self._prefill_calls += 1
         return logits
 
     def _admit_chunk(self, i: int, req: Request, hit_blocks: List[int],
@@ -1402,6 +1453,18 @@ class LLMEngine:
                 or len(req.prompt_tokens) + len(req.out_tokens)
                 >= self.max_len - 1):
             req.done = True
+
+    def _count(self, sums, steps: int = 0, kind: str = "") -> Dict[str, int]:
+        """Add fetched counter sums (``ServedModel.counters``' order) to
+        ``self.counters``: a decode window's under the model's names and
+        ``decode_steps``, prefills' (``kind="prefill_"``) under names and
+        a count of calls of their own, so that each sum has its
+        denominator.  Returns what was added, by the model's names."""
+        added = {n: int(v) for n, v in zip(self.model.counters, sums)}
+        for name, v in added.items():
+            self.counters[kind + name] += v
+        self.counters["prefill_calls" if kind else "decode_steps"] += steps
+        return added
 
     def _refresh_device_mirrors(self):
         """Re-upload the tables/temps device mirrors iff a host-side slot

@@ -45,26 +45,16 @@ KV_NAMESPACE = "llm"
 def _build_engine(engine_kwargs: Optional[Dict[str, Any]],
                   tensor_parallel_size: int):
     """Shared engine construction (by-name config so the DRIVER never has
-    to import jax; inference weights default to bf16)."""
+    to import jax; inference weights default to bf16).  ``model`` names a
+    preset of any served model (``ray_tpu/models/served.py``)."""
     from ray_tpu.llm.engine import LLMEngine
-    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.models.served import preset
 
     kw = dict(engine_kwargs or {})
     cfg = kw.pop("cfg", None)
     model = kw.pop("model", None)
     if cfg is None:
-        if model:
-            import dataclasses
-
-            import jax.numpy as jnp
-
-            cfg = getattr(LlamaConfig, model)()
-            if model != "tiny":
-                cfg = dataclasses.replace(
-                    cfg, param_dtype=jnp.bfloat16,
-                    max_seq_len=kw.get("max_len", cfg.max_seq_len))
-        else:
-            cfg = LlamaConfig.tiny()
+        cfg = preset(model or "tiny", serve_max_len=kw.get("max_len", 0))
     mesh = None
     if tensor_parallel_size > 1:
         from ray_tpu.parallel import MeshConfig, create_mesh
@@ -112,7 +102,9 @@ class _EngineHost:
         self.engine = _build_engine(engine_kwargs, tensor_parallel_size)
         self._lock = threading.Lock()
         self._waiters: Dict[int, Any] = {}  # request_id -> {event, output}
-        self._token_queues: Dict[int, Any] = {}  # request_id -> queue.Queue
+        # request_id -> queue.Queue of token lists (a step's tokens each)
+        self._token_queues: Dict[int, Any] = {}
+        self._fresh: Dict[int, List[int]] = {}  # this step's, by request
         self.engine.on_token = self._on_token
         self._stop = False
         self._last_submit = 0.0  # monotonic; admission-settle signal
@@ -156,9 +148,21 @@ class _EngineHost:
         return True
 
     def _on_token(self, request_id: int, tok: int):
-        q = self._token_queues.get(request_id)
-        if q is not None:
-            q.put(tok)
+        # engine thread, lock held: a step's tokens wait here and go to
+        # their streams at once (``_hand_out``)
+        self._fresh.setdefault(request_id, []).append(tok)
+
+    def _hand_out(self):
+        """Lock held.  Give every stream the tokens its request got in
+        the step just taken: ONE ``queue.put`` a request and step, not one
+        a token (a put wakes the request's thread, and some tens of
+        threads woken sixteen times a window keep the interpreter lock
+        from the engine thread)."""
+        fresh, self._fresh = self._fresh, {}
+        for rid, toks in fresh.items():
+            q = self._token_queues.get(rid)
+            if q is not None:
+                q.put(toks)
 
     def _engine_loop(self):
         while not self._stop:
@@ -196,8 +200,9 @@ class _EngineHost:
                     if not settle:
                         outs = self.engine.step()
                         self._last_step = time.monotonic()
-                if outs:
+                if outs or self._fresh:
                     with tracing.annotate("serve.deliver", n=len(outs)):
+                        self._hand_out()  # before its waiter is woken
                         for out in outs:
                             slot = self._waiters.pop(out.request_id, None)
                             if slot is not None:
@@ -318,25 +323,32 @@ class _EngineHost:
     def _stream_tokens(self, rid: int, slot: Dict[str, Any], tq,
                        deadline: float, seed_tokens: List[int]):
         """Yield one ``{"token_id", "text", "index"}`` chunk per decoded
-        token and a final ``{"done": True, ...}`` summary.  Incremental
-        decode emits the delta of the CUMULATIVE decode, holding back a
-        trailing replacement char (an incomplete multi-byte sequence at
-        the boundary) until the bytes completing it arrive — per-token
-        decode would turn every multi-byte character into mojibake.
+        token and a final ``{"done": True, ...}`` summary; the chunks of
+        one engine step (a decode window's tokens) leave as one
+        :class:`~ray_tpu.serve.StreamBatch`, which the caller's handle
+        takes apart again.  Decoding is incremental and holds back text
+        that ends in a replacement char (an incomplete multi-byte
+        sequence at the boundary) until the bytes completing it arrive —
+        per-token decode would turn every multi-byte character into
+        mojibake; it decodes only the ids since the last text that was
+        streamed (with the ids of that text before them, for a
+        tokenizer whose text depends on what precedes), never the whole
+        answer a token.
         ``seed_tokens`` are tokens produced before this consumer attached
         (the disaggregated handoff's prefill-sampled first token)."""
         import queue as queue_mod
 
         from ray_tpu.exceptions import DeadlineExceededError
 
+        decode = self.engine.tokenizer.decode
         index = 0
         all_ids: List[int] = []
-        emitted = ""  # stable decoded prefix already streamed
-        pending = list(seed_tokens)
+        # ids[lead:read] gave the text streamed last, ids[read:] none yet
+        lead = read = 0
+        emitted = 0  # characters streamed so far
+        fresh = list(seed_tokens)
         while True:
-            if pending:
-                tok = pending.pop(0)
-            else:
+            if not fresh:
                 if slot["event"].is_set() and tq.empty():
                     break
                 if time.time() > deadline:
@@ -347,22 +359,30 @@ class _EngineHost:
                 if not self._loop.is_alive():
                     raise RuntimeError("engine loop died mid-generation")
                 try:
-                    tok = tq.get(timeout=0.05)
+                    fresh = tq.get(timeout=0.05)
                 except queue_mod.Empty:
                     continue
-            all_ids.append(int(tok))
-            full = self.engine.tokenizer.decode(all_ids)
-            stable = full.rstrip("�")
-            delta = stable[len(emitted):]
-            if delta:
-                yield {"token_id": int(tok), "text": delta,
-                       "index": index}
-                index += 1
-            emitted = stable
+            chunks = serve.StreamBatch()
+            for tok in fresh:
+                all_ids.append(int(tok))
+                before = decode(all_ids[lead:read])
+                text = decode(all_ids[lead:])
+                if len(text) > len(before) and not text.endswith("\ufffd"):
+                    chunks.append({"token_id": int(tok),
+                                   "text": text[len(before):],
+                                   "index": index})
+                    index += 1
+                    emitted += len(text) - len(before)
+                    lead, read = read, len(all_ids)
+            fresh = []
+            if len(chunks) == 1:
+                yield chunks[0]
+            elif chunks:
+                yield chunks
         out = slot["output"]
         if out.error:
             raise RuntimeError(out.error)
-        tail = out.text[len(emitted):]
+        tail = out.text[emitted:]
         if tail:  # flush any held-back suffix so chunks sum to text
             yield {"token_id": -1, "text": tail, "index": index}
         yield {"done": True, "generated_text": out.text,
@@ -400,16 +420,28 @@ class _EngineHost:
 
     def _teardown_engine_host(self):
         self._stop = True
-        try:
+
+        def drop(key):
             # best-effort: drop this replica's engine-stats record so a
             # scaled-down replica doesn't pin a KV entry until the
             # dashboard's stale sweep catches it
-            from ray_tpu.experimental import internal_kv
+            try:
+                from ray_tpu.experimental import internal_kv
 
-            internal_kv._internal_kv_del(
-                f"engine/{self._deployment}/{self._replica_id}".encode(),
-                namespace=KV_NAMESPACE)
-        except Exception:  # noqa: BLE001 — interpreter/cluster teardown
+                internal_kv._internal_kv_del(key, namespace=KV_NAMESPACE)
+            except Exception:  # noqa: BLE001 — interpreter/cluster teardown
+                pass
+
+        # on a thread of its own: the host and its engine's ``on_token``
+        # hook form a cycle, so ``__del__`` runs wherever the collector
+        # does — also on a thread that holds a lock (the span buffer's)
+        # which the RPC's loop thread is waiting for, and a blocking
+        # delete there never returns
+        try:
+            key = f"engine/{self._deployment}/{self._replica_id}".encode()
+            threading.Thread(target=drop, args=(key,), daemon=True,
+                             name="llm-stats-drop").start()
+        except Exception:  # noqa: BLE001 — half-built host, interpreter teardown
             pass
 
     def __del__(self):
@@ -796,6 +828,14 @@ def build_llm_deployment(engine_kwargs: Optional[Dict[str, Any]] = None,
         opts["ray_actor_options"] = {"num_tpus": num_tpus_per_replica}
     if autoscaling_config is not None:
         opts["autoscaling_config"] = autoscaling_config
+    # a replica admits as many requests as its engine has slots (and
+    # queues twice that), never fewer than the class's own 32 / 64: behind
+    # 32 admitted requests a 128-slot engine decodes 32 at a time while the
+    # rest wait upstream of it
+    slots = int((engine_kwargs or {}).get("batch_slots") or 0)
+    if slots > LLMServer.config.max_ongoing_requests:
+        opts.update(max_ongoing_requests=slots,
+                    max_queued_requests=2 * slots)
     return LLMServer.options(**opts).bind(engine_kwargs, tensor_parallel_size)
 
 

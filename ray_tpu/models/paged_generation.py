@@ -130,19 +130,34 @@ def _gather_kv(pool, i, block_tables, dt):
 
 
 def decode_attention_path(pool, *, mesh=None, spec_tokens: int = 0) -> str:
-    """Which attention the decode step runs, from what can be seen:
-    ``"paged_kernel"`` for a dense pool on one TPU device, ``"gather"``
-    for an int8 pool (no kernel arm yet), a mesh (the pool is sharded over
-    KV heads; the kernel is not under ``shard_map`` yet), speculation (the
-    S > 1 verify has no kernel arm, and its greedy acceptance is held
-    token-exact against the decode window, so both arms run one attention)
-    and every backend but TPU (``ops/attention.py`` keeps Pallas off the
-    CPU path the same way).  Mosaic wants a page's ``(token, kv head)``
-    rows and the head dim tile-aligned; other shapes gather."""
+    """Which attention the decode step runs, from what can be seen, never
+    from a knob.  Which pool takes which arm of
+    ``ops/pallas/paged_attention.py``:
+
+    * a dense pool ``{"k", "v": [L, NB, bs, KVH, hd]}`` on one TPU device
+      -> ``"paged_kernel"`` (the dense arm: two pools of equal head
+      width, ``hd ** -0.5``);
+    * a latent pool ``{"kv": [A, NB, bs, W]}`` (``models/longcat.py``: one
+      row a token that all heads share) on one TPU device ->
+      ``"latent_kernel"`` (the latent arm: one pool, the values its
+      leading columns, the caller's scale);
+    * ``"gather"`` for an int8 pool (no kernel arm yet), a mesh (the pool
+      is sharded over KV heads; the kernel is not under ``shard_map``
+      yet), speculation (the S > 1 verify has no kernel arm, and its
+      greedy acceptance is held token-exact against the decode window, so
+      both arms run one attention) and every backend but TPU
+      (``ops/attention.py`` keeps Pallas off the CPU path the same way).
+
+    Mosaic wants a page's rows and the row's width tile-aligned; other
+    shapes gather."""
+    off_kernel = (mesh is not None or spec_tokens
+                  or jax.default_backend() != "tpu")
+    if "kv" in pool:
+        bs, width = pool["kv"].shape[2:]
+        return ("gather" if off_kernel or width % 128 or bs % 16
+                else "latent_kernel")
     bs, kvh, hd = pool["k"].shape[2:]
-    if ("k_scale" in pool or mesh is not None or spec_tokens
-            or jax.default_backend() != "tpu"
-            or hd % 128 or (bs * kvh) % 16):
+    if ("k_scale" in pool or off_kernel or hd % 128 or (bs * kvh) % 16):
         return "gather"
     return "paged_kernel"
 

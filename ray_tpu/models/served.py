@@ -1,0 +1,147 @@
+"""What ``LLMEngine`` needs of a model, and which models supply it.
+
+The engine is handed a configuration and finds its programs from the
+configuration's type: ``served_model(cfg)`` returns the model's
+:class:`ServedModel`.  A model is served when it supplies
+
+* ``init(key, cfg) -> params``;
+* ``init_pool(cfg, num_blocks, block_size, kv_dtype) -> pool``: a dict of
+  arrays whose axis 1 is the block (block 0 the scratch block); it raises
+  for a ``kv_dtype`` it does not store;
+* ``prefill_suffix(params, tokens[1, S], length, start_pos, *prefix,
+  prefix_len, dst_blocks[S], dst_offsets[S], pool, cfg=) -> (logits[1, V],
+  pool, *counters)``: a prompt's suffix against its cached prefix
+  (``paged_generation.prefill_suffix`` is the contract's text);
+* ``gather_prefix(pool, blocks[P], cfg) -> prefix``: the pair of arrays
+  ``prefill_suffix`` takes for a list of cached blocks;
+* ``decode_sample(params, token[B], cur_len[B], block_tables[B, MB], pool,
+  key, temps[B], cfg=, attn=) -> (token, cur_len, key, pool, *counters)``:
+  one step with on-device sampling, everything the next step needs a
+  device array;
+* ``decode_attention_path(pool, mesh=, spec_tokens=) -> str``: which
+  attention the decode step runs, from what it sees;
+
+and optionally ``verify_step`` (speculation), ``param_specs`` (an engine
+with a mesh), ``handoff`` (``export_kv`` / ``adopt_prefilled`` know its
+pool) and ``counters``: the names of the small integer sums its prefill
+and decode programs return beside the rest (one int32 vector, fetched with
+the window's tokens, summed into ``LLMEngine.stats()["counters"]``).
+What a model leaves out the engine refuses by name at construction or at
+the call, never by a wrong answer.
+
+``presets`` are the configurations the model offers by name
+(``build_llm_deployment({"model": "<name>"})`` resolves one through
+``preset`` without the driver importing ``jax``).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any, Callable, Dict, Optional, Tuple
+
+
+@dataclasses.dataclass(frozen=True)
+class ServedModel:
+    name: str
+    init: Callable
+    init_pool: Callable
+    prefill_suffix: Callable
+    gather_prefix: Callable
+    decode_sample: Callable
+    decode_attention_path: Callable
+    presets: Dict[str, Callable[[], Any]]
+    # the presets at toy widths, written in the float32 the CPU tests run
+    test_presets: Tuple[str, ...] = ()
+    verify_step: Optional[Callable] = None
+    param_specs: Optional[Callable] = None
+    handoff: bool = False
+    counters: Tuple[str, ...] = ()
+
+    def require(self, what: str, have: bool) -> None:
+        if not have:
+            raise NotImplementedError(
+                f"{self.name} does not supply {what} yet (see "
+                f"docs/llm_serving.md, 'Which options each model supports')")
+
+
+def _llama() -> ServedModel:
+    from ray_tpu.models import paged_generation as pg
+    from ray_tpu.models.llama import (LlamaConfig, llama_init,
+                                      llama_param_specs)
+
+    return ServedModel(
+        name="llama", init=llama_init, init_pool=pg.init_kv_pool,
+        prefill_suffix=pg.prefill_suffix,
+        gather_prefix=lambda pool, blocks, cfg: pg.gather_prefix(pool,
+                                                                 blocks),
+        decode_sample=pg.paged_decode_sample,
+        # looked up at the call, as the engine did before it read a model
+        decode_attention_path=lambda pool, **seen: pg.decode_attention_path(
+            pool, **seen),
+        presets={n: getattr(LlamaConfig, n) for n in (
+            "tiny", "llama2_7b", "llama2_13b", "llama3_8b")},
+        test_presets=("tiny",),
+        verify_step=pg.paged_verify_step, param_specs=llama_param_specs,
+        handoff=True)
+
+
+def _longcat() -> ServedModel:
+    from ray_tpu.models import longcat as lc
+    from ray_tpu.models.paged_generation import decode_attention_path
+
+    return ServedModel(
+        name="longcat_flash", init=lc.longcat_init,
+        init_pool=lc.init_latent_pool,
+        prefill_suffix=lc.latent_prefill_suffix,
+        gather_prefix=lc.gather_latent_prefix,
+        decode_sample=lc.latent_decode_sample,
+        decode_attention_path=decode_attention_path,
+        presets={"longcat_flash_tiny": lc.LongcatConfig.tiny,
+                 "longcat_flash": lc.LongcatConfig},
+        test_presets=("longcat_flash_tiny",),
+        counters=("moe_pairs_held", "moe_experts_hit", "moe_zero_picks"))
+
+
+# configuration class -> the function that builds its ServedModel: a model's
+# modules are imported when it is first asked for, so that a process which
+# serves one model loads one model
+_MODELS = {"LlamaConfig": _llama, "LongcatConfig": _longcat}
+
+
+@functools.lru_cache(maxsize=None)
+def _load(cls_name: str) -> ServedModel:
+    return _MODELS[cls_name]()
+
+
+def served_model(cfg) -> ServedModel:
+    """The programs of the model whose configuration ``cfg`` is."""
+    name = type(cfg).__name__
+    if name not in _MODELS:
+        raise TypeError(
+            f"LLMEngine cannot serve a {name}: no served model is "
+            f"registered for it (ray_tpu/models/served.py; served: "
+            f"{sorted(_MODELS)})")
+    return _load(name)
+
+
+def preset(name: str, *, serve_max_len: Optional[int] = None):
+    """A configuration by its preset's name, from whichever served model
+    offers it.  With ``serve_max_len`` it comes as a deployment runs it:
+    weights in bf16 and a rotary table as long as the engine's ``max_len``
+    (0: the preset's own), except the model's ``test_presets``, which stay
+    as written."""
+    offered = []
+    for owner in _MODELS:
+        model = _load(owner)
+        if name in model.presets:
+            cfg = model.presets[name]()
+            if serve_max_len is not None and name not in model.test_presets:
+                import jax.numpy as jnp
+
+                cfg = dataclasses.replace(
+                    cfg, param_dtype=jnp.bfloat16,
+                    max_seq_len=serve_max_len or cfg.max_seq_len)
+            return cfg
+        offered += sorted(model.presets)
+    raise ValueError(f"unknown model preset {name!r}; offered: {offered}")

@@ -1,0 +1,112 @@
+"""An expert layer that is told which experts it holds.
+
+Expert parallelism gives a chip a contiguous range of a layer's routed
+experts.  The router scores all of them; the chip computes what ITS experts
+add for the tokens routed to them, and what the absent experts would add is
+somebody else's part of the sum (on one chip of a cut deployment: left out).
+
+``route_top_k`` is the router (softmax scores, an additive selection bias
+that picks but does not weigh, no renormalisation).  ``held_experts_ffn`` is
+the dispatch: **sorted and grouped**, never a dense all-experts product,
+no capacity and no dropped token.
+
+* every (token, pick) pair gets a key: its expert's index in the held range,
+  or a sentinel past it; one stable sort puts the held pairs first, grouped
+  by expert;
+* the held pairs are walked in chunks of ``chunk`` rows by a loop whose trip
+  count is ``ceil(held pairs / chunk)``, a run-time value: the work follows
+  the pairs that landed here, and no bound on them is ever assumed;
+* a chunk gathers its tokens' rows, runs the three SwiGLU products as
+  ``jax.lax.ragged_dot`` over the chunk's group sizes (on a TPU a Mosaic
+  grouped matmul: an expert's weights are read once for the rows it got),
+  weighs each row and scatter-adds it to its token.
+
+The chunk is the trade between reading an expert's weights again (every
+chunk reads the weights of the experts it holds rows for) and computing on
+padding rows (the last chunk is padded to ``chunk``).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.ops.layers import swiglu
+
+
+def route_top_k(y, w_router, bias, k: int, scale: float):
+    """y ``[T, H]``, w_router ``[H, N]``, bias ``[N]`` -> (idx ``[T, k]``
+    int32, weight ``[T, k]`` float32).
+
+    ``p = softmax(y W_r)`` in float32 over all N outputs; the k largest of
+    ``p + bias`` are picked; a pick's weight is its own ``p`` (no bias, not
+    renormalised) times ``scale``.  The product runs at the highest
+    precision: a pick is a discrete choice, and N is small."""
+    logits = jnp.matmul(y.astype(jnp.float32), w_router.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    p = jax.nn.softmax(logits, axis=-1)
+    _, idx = lax.top_k(p + bias.astype(jnp.float32), k)
+    weight = jnp.take_along_axis(p, idx, axis=-1) * scale
+    return idx.astype(jnp.int32), weight
+
+
+def default_chunk(pairs: int) -> int:
+    """Rows a chunk of the dispatch holds for ``pairs`` (token, pick)
+    pairs: an eighth of them (at an even routing over 48 times as many
+    experts as are held, a fraction of that lands here), in whole MXU
+    tiles, between 256 and 4096."""
+    return int(min(4096, max(256, -(-pairs // 8 // 128) * 128)))
+
+
+def held_experts_ffn(y, idx, weight, w_gate, w_up, w_down, *, first: int,
+                     live=None, chunk: int | None = None):
+    """What the held experts add to each token.
+
+    y ``[T, H]``; idx / weight ``[T, k]`` from the router (global expert
+    ids); w_gate / w_up ``[E, H, F]``, w_down ``[E, F, H]``: the weights of
+    experts ``first .. first + E - 1``.  ``live`` ``[T]`` bool: tokens that
+    are padding (a freed slot, a bucket's tail) are routed nowhere.
+    Returns (out ``[T, H]`` float32, pairs, experts_hit): the weighted sum
+    over the held experts a token picked, how many (token, pick) pairs
+    landed on held experts, and how many held experts got at least one.
+    """
+    T, k = idx.shape
+    E = w_gate.shape[0]
+    N = T * k
+    C = chunk or default_chunk(N)
+    local = idx - first
+    held = (local >= 0) & (local < E)
+    if live is not None:
+        held &= live[:, None]
+    key = jnp.where(held, local, E).reshape(N)
+    order = jnp.argsort(key, stable=True)  # held pairs first, by expert
+    counts = jnp.sum(key[:, None] == jnp.arange(E)[None, :], axis=0,
+                     dtype=jnp.int32)  # [E]
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    total = ends[-1]
+    # padded by one chunk so that the last chunk's slice never clamps
+    token_of = jnp.pad(order // k, (0, C)).astype(jnp.int32)
+    w_sorted = jnp.pad(weight.reshape(N)[order], (0, C))
+    dt = y.dtype
+
+    def one_chunk(i, out):
+        lo = i * C
+        rows = lax.dynamic_slice(token_of, (lo,), (C,))
+        w = lax.dynamic_slice(w_sorted, (lo,), (C,))
+        valid = lo + jnp.arange(C) < total
+        sizes = jnp.clip(ends, lo, lo + C) - jnp.clip(starts, lo, lo + C)
+        x = y[rows]
+        gate = lax.ragged_dot(x, w_gate.astype(dt), sizes)
+        up = lax.ragged_dot(x, w_up.astype(dt), sizes)
+        o = lax.ragged_dot(swiglu(gate, up), w_down.astype(dt), sizes,
+                           preferred_element_type=jnp.float32)
+        # a row past the last group belongs to no expert: whatever the
+        # grouped product left there is not read
+        o = jnp.where(valid[:, None], o * w[:, None], 0.0)
+        return out.at[rows].add(o)
+
+    out = lax.fori_loop(0, (total + C - 1) // C, one_chunk,
+                        jnp.zeros((T, y.shape[1]), jnp.float32))
+    return out, total, jnp.sum(counts > 0, dtype=jnp.int32)

@@ -21,6 +21,18 @@ strided sublane read of packed bf16) is ever taken.  Keys, values and
 probabilities enter the matmuls in the pool's dtype, scores, softmax and the
 accumulator are float32: what ``models/generation._gqa_attend`` does on the
 gathered cache, which stays the reference this kernel is tested against.
+
+Two arms of the one kernel, picked by what the caller hands it:
+
+* ``paged_attention`` (the dense arm): two pools of equal head width,
+  ``[L, NB, bs, KVH, hd]`` keys and values, ``hd ** -0.5``: a
+  Llama/Mistral cache (``models/paged_generation.py``).
+* ``latent_paged_attention`` (the latent arm): ONE pool ``[L, NB, bs, W]``
+  of latent rows, one row a token that all H heads share.  A page is copied
+  once; the keys are its whole width, the values its first ``value_width``
+  columns (a lane-aligned slice of what is already in VMEM), and the scale
+  is the caller's: absorbed latent attention (``models/longcat.py``), whose
+  softmax scale belongs to the up-projected head, not to the row's width.
 """
 
 from __future__ import annotations
@@ -37,9 +49,14 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, k_hbm,
-            v_hbm, o_ref, kbuf, vbuf, sems, state, *, window, pages,
-            block_size, max_blocks, scale):
+def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, *refs,
+            window, pages, block_size, max_blocks, scale, value_width=None):
+    if value_width is None:  # dense arm: a pool of keys and one of values
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, state = refs
+        pools = ((k_hbm, kbuf), (v_hbm, vbuf))
+    else:  # latent arm: the values are columns of the key page
+        k_hbm, o_ref, kbuf, sems, state = refs
+        pools = ((k_hbm, kbuf),)
     s = pl.program_id(0)
     layer = layer_ref[0]
     nslots = pl.num_programs(0)
@@ -52,8 +69,8 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, k_hbm,
         state[1] = 0  # 1: that block's copies are already in flight
         # pages a block does not copy keep what the buffer held before:
         # finite (zeros, then older pages), so a masked 0 x stale stays 0
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
+        for _, buffer in pools:
+            buffer[...] = jnp.zeros_like(buffer)
 
     def first_token(length):
         return 0 if window is None else jnp.maximum(length - window, 0)
@@ -73,8 +90,7 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, k_hbm,
             @pl.when((page >= lo_page) & (page < n_pages))
             def _():
                 blk = tab_ref[slot * max_blocks + page]
-                for w, (hbm, buffer) in enumerate(((k_hbm, kbuf),
-                                                   (v_hbm, vbuf))):
+                for w, (hbm, buffer) in enumerate(pools):
                     dma = pltpu.make_async_copy(
                         hbm.at[layer, blk], buffer.at[buf, p],
                         sems.at[w, buf])
@@ -129,7 +145,8 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, k_hbm,
 
             copies(s, j, buf, "wait")
             k = kbuf[buf].reshape(rows, kbuf.shape[-1])
-            v = vbuf[buf].reshape(rows, vbuf.shape[-1])
+            v = (vbuf[buf].reshape(rows, vbuf.shape[-1])
+                 if value_width is None else k[:, :value_width])
             sc = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
             sc = sc * scale + group_ref[...]
@@ -144,12 +161,12 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, k_hbm,
                 preferred_element_type=jnp.float32)
             return m_new, l, acc
 
-        H, hd = q_ref.shape
+        H = q_ref.shape[0]
         m, l, acc = lax.fori_loop(
             j0, j1, body,
             (jnp.full((H, 1), _NEG_INF, jnp.float32),
              jnp.zeros((H, 1), jnp.float32),
-             jnp.zeros((H, hd), jnp.float32)))
+             jnp.zeros(o_ref.shape, jnp.float32)))
         o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
@@ -163,7 +180,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *, layer,
                     window: int | None = None,
                     pages_per_block: int | None = None,
                     interpret: bool | None = None):
-    """Attention of one query token a slot over its paged cache.
+    """The dense arm: attention of one query token a slot over its paged
+    cache of keys and values.
 
     q ``[b, H, hd]``; k_pool / v_pool ``[L, NB, bs, KVH, hd]`` (the whole
     stacked pool; ``layer`` an int or int32 scalar, a run-time value of the
@@ -184,55 +202,96 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *, layer,
                             interpret=interpret)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("window", "pages_per_block", "interpret"))
-def _paged_attention(q, k_pool, v_pool, block_tables, lengths, layer, *,
-                     window, pages_per_block, interpret):
-    b, H, hd = q.shape
-    L, NB, bs, KVH, _ = k_pool.shape
+def latent_paged_attention(q, pool, block_tables, lengths, *, layer,
+                           value_width: int, scale: float,
+                           pages_per_block: int | None = None,
+                           interpret: bool | None = None):
+    """The latent arm: H query heads of one token a slot against ONE shared
+    row a cached token.
+
+    q ``[b, H, W]`` (the absorbed query, as wide as a row); pool
+    ``[L, NB, bs, W]`` (``layer`` as above: here the attention block's
+    index in the stacked pool); block_tables / lengths as above.  Scores
+    are ``q . row * scale`` over the whole width; the probabilities weight
+    the row's first ``value_width`` columns, which are read out of the page
+    that the keys came in.  Returns ``[b, H, value_width]`` in q's dtype.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _latent_paged_attention(
+        q, pool, block_tables, lengths, jnp.asarray(layer, jnp.int32),
+        value_width=value_width, scale=scale,
+        pages_per_block=pages_per_block, interpret=interpret)
+
+
+def _call(kernel, q, pools, block_tables, lengths, layer, *, kv_heads,
+          out_width, pages_per_block, interpret):
+    """The one ``pallas_call`` of both arms.  ``pools``: the stacked
+    pool(s) with a page as a matrix ``[L, NB, bs * kv_heads, width]``."""
+    b, H, _ = q.shape
+    _, _, page_rows, width = pools[0].shape
     MB = block_tables.shape[1]
-    page_rows = bs * KVH
     P = pages_per_block or max(1, _BLOCK_ROWS // page_rows)
     P = min(P, MB)
     rows = P * page_rows
-    # a page as a matrix of (token, kv head) rows: a view, not a copy
-    k_pages = k_pool.reshape(L, NB, page_rows, hd)
-    v_pages = v_pool.reshape(L, NB, page_rows, hd)
     # constants of the program: which rows belong to a head's KV group,
     # and a row's position inside its block
     r = np.arange(rows, dtype=np.int32)[None, :]
     group = np.where(
-        r % KVH == np.arange(H, dtype=np.int32)[:, None] // (H // KVH),
-        0.0, _NEG_INF).astype(np.float32)  # [H, rows]
-    tok = r // KVH  # [1, rows]
-
-    kernel = functools.partial(
-        _kernel, window=window, pages=P, block_size=bs, max_blocks=MB,
-        scale=float(hd) ** -0.5)
+        r % kv_heads == np.arange(H, dtype=np.int32)[:, None]
+        // (H // kv_heads), 0.0, _NEG_INF).astype(np.float32)  # [H, rows]
+    tok = r // kv_heads  # [1, rows]
+    n = len(pools)
     return pl.pallas_call(
-        kernel,
+        functools.partial(kernel, pages=P, max_blocks=MB),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b,),
             in_specs=[
-                pl.BlockSpec((None, H, hd), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec((None, H, q.shape[2]), lambda s, *_: (s, 0, 0)),
                 pl.BlockSpec((H, rows), lambda s, *_: (0, 0)),
                 pl.BlockSpec((1, rows), lambda s, *_: (0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((None, H, hd), lambda s, *_: (s, 0, 0)),
+            ] + [pl.BlockSpec(memory_space=pl.ANY)] * n,
+            out_specs=pl.BlockSpec((None, H, out_width),
+                                   lambda s, *_: (s, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, P, page_rows, hd), k_pool.dtype),
-                pltpu.VMEM((2, P, page_rows, hd), v_pool.dtype),
+                pltpu.VMEM((2, P, page_rows, width), pool.dtype)
+                for pool in pools
+            ] + [
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((2,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, H, out_width), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(layer.reshape(1), lengths.astype(jnp.int32),
-      block_tables.reshape(-1).astype(jnp.int32), q, group, tok, k_pages,
-      v_pages)
+      block_tables.reshape(-1).astype(jnp.int32), q, group, tok, *pools)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("window", "pages_per_block", "interpret"))
+def _paged_attention(q, k_pool, v_pool, block_tables, lengths, layer, *,
+                     window, pages_per_block, interpret):
+    hd = q.shape[2]
+    L, NB, bs, KVH, _ = k_pool.shape
+    # a page as a matrix of (token, kv head) rows: a view, not a copy
+    k_pages = k_pool.reshape(L, NB, bs * KVH, hd)
+    v_pages = v_pool.reshape(L, NB, bs * KVH, hd)
+    kernel = functools.partial(_kernel, window=window, block_size=bs,
+                               scale=float(hd) ** -0.5)
+    return _call(kernel, q, (k_pages, v_pages), block_tables, lengths, layer,
+                 kv_heads=KVH, out_width=hd,
+                 pages_per_block=pages_per_block, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "value_width", "scale", "pages_per_block", "interpret"))
+def _latent_paged_attention(q, pool, block_tables, lengths, layer, *,
+                            value_width, scale, pages_per_block, interpret):
+    kernel = functools.partial(_kernel, window=None, block_size=pool.shape[2],
+                               scale=float(scale), value_width=value_width)
+    return _call(kernel, q, (pool,), block_tables, lengths, layer,
+                 kv_heads=1, out_width=value_width,
+                 pages_per_block=pages_per_block, interpret=interpret)

@@ -27,13 +27,14 @@ from ray_tpu.serve.replica import batch
 from ray_tpu.serve.router import (
     DeploymentHandle,
     DeploymentResponse,
+    StreamBatch,
     TwoStageHandle,
 )
 
 __all__ = [
     "Application", "AutoscalingConfig", "Deployment", "DeploymentConfig",
     "DeploymentHandle", "DeploymentResponse", "ReplicaContext",
-    "RequestContext", "TwoStageHandle", "batch",
+    "RequestContext", "StreamBatch", "TwoStageHandle", "batch",
     "context", "delete", "deployment",
     "get_app_handle", "get_deployment_handle", "get_multiplexed_model_id",
     "get_replica_context",

@@ -742,8 +742,18 @@ class DeploymentHandle:
         return DeploymentStreamingResponse(gen)
 
 
+class StreamBatch(list):
+    """Items a streaming deployment method has ready at once, yielded as
+    ONE value: the replica pays for one streamed return (serialization,
+    store, notify) instead of one an item, and the caller's
+    :class:`DeploymentStreamingResponse` hands the items out one by one,
+    so a consumer never sees the batch.  The LLM deployments yield a
+    decode window's tokens of a request this way."""
+
+
 class DeploymentStreamingResponse:
-    """Iterator over a streaming deployment call's yielded values."""
+    """Iterator over a streaming deployment call's yielded values (the
+    items of a :class:`StreamBatch` one by one)."""
 
     def __init__(self, ref_gen):
         self._gen = ref_gen
@@ -754,7 +764,11 @@ class DeploymentStreamingResponse:
         for ref in self._gen:
             # consumer-facing streaming iterator: blocking for the next
             # yielded value on the caller's own thread IS the API
-            yield ray_tpu.get(ref)  # raylint: disable=bounded-blocking -- caller-thread streaming consumption, not a control thread; replica death resolves the ref with an error
+            value = ray_tpu.get(ref)  # raylint: disable=bounded-blocking -- caller-thread streaming consumption, not a control thread; replica death resolves the ref with an error
+            if isinstance(value, StreamBatch):
+                yield from value
+            else:
+                yield value
 
     @property
     def ref_generator(self):

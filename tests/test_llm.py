@@ -401,6 +401,27 @@ def test_paged_engine_matches_full_recompute(tiny_model):
                                                 [o.token_ids for o in outs])
 
 
+@pytest.mark.parametrize("n", [3, 5])
+def test_admissions_share_programs_by_power_of_two(tiny_model, n):
+    """A step's admissions are padded to a power of two before their first
+    tokens are sampled: n requests admitted at once build no program that
+    the next power of two has not built, and get the tokens they get alone."""
+    from ray_tpu.llm import LLMEngine
+    from ray_tpu.models.generation import generate
+
+    cfg, params = tiny_model
+    sp = SamplingParams(temperature=0.0, max_tokens=4)
+    rng = np.random.default_rng(n)
+    prompts = [rng.integers(1, 200, 3 + i).tolist() for i in range(8)]
+    ref = generate(params, cfg, prompts[:n], sp, key=jax.random.PRNGKey(0))
+    eng = LLMEngine(cfg, params, batch_slots=8, max_len=64, block_size=4)
+    eng.generate(prompts[:1 << (n - 1).bit_length()], sp)  # 4 or 8 at once
+    built = (eng._stack._cache_size(), eng._sample._cache_size())
+    outs = eng.generate(prompts[:n], sp)
+    assert [o.token_ids for o in outs] == ref
+    assert (eng._stack._cache_size(), eng._sample._cache_size()) == built
+
+
 def test_paged_kernel_engine_matches_full_recompute(tiny_model, monkeypatch):
     """The decode step's other attention path, the paged kernel (forced
     here through the Pallas interpreter by patching the module's path

@@ -1,0 +1,367 @@
+"""LongCat-Flash's language model: the program against its plain reference.
+
+CPU, float32, tiny shapes.  The reference is the benchmark's
+(``cells/families/longcat_flash_reference.py``: written from the published
+architecture, importing nothing of the program): one set of equations for
+these tests and for the cell's ``correct``.
+
+(a) ``models/longcat.py``'s forward against the reference on seeded
+    weights, all experts and a held range;
+(b) the shares add up: the routed parts of all shares plus the
+    zero-compute part counted once are the uncut reference's layer;
+(c) ``LLMEngine`` with the tiny preset: prefill, then decode through the
+    latent cache, with a prefix hit and with a chunked prefill, on logits;
+(d) the kernel's latent arm against the gather path;
+(e) the control (weight products rounded to an 8-bit float) reads not
+    correct;
+(f) what the model does not supply raises.
+
+Tolerances: float32 on both sides, different orders of summation (the
+absorbed form against the plain one, grouped products against a loop over
+experts): logits of magnitude ~0.6 agree to 2e-5.  A returned token's gap
+under the reference's largest logit is 0 unless two logits tie to 2e-5.
+"""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cells.families import longcat_flash_reference as reference
+from ray_tpu.llm.engine import LLMEngine
+from ray_tpu.models.generation import SamplingParams
+from ray_tpu.models.longcat import (LongcatConfig, _moe, init_latent_pool,
+                                    latent_decode_step, longcat_apply,
+                                    longcat_init)
+from ray_tpu.models.paged_generation import decode_attention_path
+from ray_tpu.models.served import preset, served_model
+from ray_tpu.ops.pallas.paged_attention import latent_paged_attention
+
+TOL = 2e-5
+
+
+def _model(cfg):
+    """The configuration as the reference takes it: a plain dict."""
+    return dataclasses.asdict(cfg)
+
+
+def _params(cfg, seed=3):
+    """Seeded weights with a selection bias that matters (zeros at init)."""
+    params = longcat_init(jax.random.PRNGKey(seed), cfg)
+    for i, lp in enumerate(params["layers"]):
+        lp["router"]["bias"] = 0.02 * jax.random.normal(
+            jax.random.PRNGKey(seed + 1 + i), lp["router"]["bias"].shape)
+    return params
+
+
+# ------------------------------------------------------ (a) the forward
+
+@pytest.mark.parametrize("held", [None, (2, 4)],
+                         ids=["all-experts", "held-2..5"])
+def test_forward_matches_the_plain_reference(held):
+    cfg = LongcatConfig.tiny() if held is None else LongcatConfig.tiny(
+        first_expert=held[0], held_experts=held[1])
+    params = _params(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (2, 40), 0, 256)
+    got, stats = longcat_apply(params, tokens, cfg, return_stats=True)
+    for i in range(2):
+        want = reference.logits(params, tokens[i], _model(cfg))
+        assert float(jnp.max(jnp.abs(got[i] - want))) < TOL
+    # and the reference notices a wrong model: no selection bias
+    zeroed = jax.tree.map(lambda a: a, params)
+    for lp in zeroed["layers"]:
+        lp["router"]["bias"] = jnp.zeros_like(lp["router"]["bias"])
+    other = reference.logits(zeroed, tokens[0], _model(cfg))
+    assert float(jnp.max(jnp.abs(other - got[0]))) > 100 * TOL
+    # 2 layers x 80 tokens x 3 picks; a third of the router is zero-compute
+    pairs, hit, zero = (int(s) for s in stats)
+    assert 0 < zero < 480 and pairs + zero <= 480
+    assert (pairs + zero == 480) == (held is None)
+    assert 0 < hit <= 2 * cfg.num_held
+
+
+# ------------------------------------------------- (b) the shares add up
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """8 routed + 4 zero-compute experts in 4 shares of 2: every share
+    routes over all 12, computes its own two experts' part and the
+    zero-compute part; the routed parts plus the zero-compute part ONCE
+    are what the uncut reference gives for the layer."""
+    whole = LongcatConfig.tiny(num_layers=1)
+    lp = _params(whole, seed=11)["layers"][0]
+    # experts large enough that their part is of the stream's own size
+    lp["experts"] = jax.tree.map(lambda a: 6.0 * a, lp["experts"])
+    router, ep = lp["router"], lp["experts"]
+    model = _model(whole)
+
+    def share_of(c):
+        return (jax.tree.map(lambda a: a[2 * c:2 * c + 2], ep),
+                dict(model, first_expert=2 * c, held_experts=2))
+
+    h = jax.random.normal(jax.random.PRNGKey(12), (1, 24, 64))
+    live = jnp.ones((1, 24), bool)
+    want_routed, want_zero = reference.moe_parts(h[0], router, ep, model)
+    assert float(jnp.max(jnp.abs(want_routed))) > 0.3
+    total = jnp.zeros_like(h[0])
+    picks = 0
+    for c in range(4):
+        ep_c, model_c = share_of(c)
+        s, stats = _moe(h, router, ep_c, LongcatConfig.tiny(
+            num_layers=1, first_expert=2 * c, held_experts=2), live)
+        total += s[0] - want_zero  # this share's routed part
+        picks += int(stats[0])
+        # the program's share is the reference's, given the same range
+        ref_routed, ref_zero = reference.moe_parts(h[0], router, ep_c,
+                                                   model_c)
+        assert float(jnp.max(jnp.abs(s[0] - ref_routed - ref_zero))) < TOL
+    assert float(jnp.max(jnp.abs(total - want_routed))) < TOL
+    # every pick of a routed expert landed on exactly one share
+    chosen, _ = reference.route(h[0], router, model)
+    assert picks == int(jnp.sum(chosen < 8))
+    # and the layer: the expert branch joins the stream additively, so the
+    # uncut layer is share 0's with the other shares' routed parts added
+    # (taken where the branch reads: y = norm_post0(h1))
+    uncut = reference.layer(h[0], lp, model)
+    ep_0, model_0 = share_of(0)
+    share0 = reference.layer(h[0], dict(lp, experts=ep_0), model_0)
+    at0 = lp["attn"][0]
+    h1 = h[0] + reference._mla(
+        reference._rms_norm(h[0], at0["norm"], 1e-5), at0, model)
+    y = reference._rms_norm(h1, lp["ffn"][0]["norm"], 1e-5)
+    routed_all, _ = reference.moe_parts(y, router, ep, model)
+    routed_0, _ = reference.moe_parts(y, router, ep_0, model_0)
+    assert float(jnp.max(jnp.abs(routed_all - routed_0))) > 0.1
+    assert float(jnp.max(jnp.abs(
+        uncut - (share0 - routed_0 + routed_all)))) < TOL
+
+
+# ----------------------------------------------------- (c) the engine
+
+class _Ids:
+    """Token ids in, token ids out."""
+    eos_id = None
+    vocab_size = 256
+
+    def encode(self, text):
+        return [1]
+
+    def decode(self, ids):
+        return ""
+
+
+@pytest.mark.parametrize("chunk", [0, 16], ids=["whole", "chunked"])
+def test_engine_decodes_through_the_latent_cache(chunk):
+    cfg = preset("longcat_flash_tiny")
+    cfg = dataclasses.replace(cfg, first_expert=2, held_experts=4)
+    eng = LLMEngine(cfg, tokenizer=_Ids(), batch_slots=4, max_len=96,
+                    block_size=8, seed=5, prefill_chunk=chunk)
+    assert eng.model is served_model(cfg)
+    assert eng.attn == "gather" and set(eng.pool) == {"kv"}
+    rng = np.random.default_rng(0)
+    shared = rng.integers(0, 256, 24).tolist()
+    prompts = [shared + rng.integers(0, 256, n).tolist()
+               for n in (5, 17, 30)] + [rng.integers(0, 256, 9).tolist()]
+    sp = SamplingParams(max_tokens=12, temperature=0.0, stop_token_id=None)
+    # the first alone, so that the others find its blocks cached
+    outs = eng.generate(prompts[:1], sp) + eng.generate(prompts[1:], sp)
+    st = eng.stats()
+    assert st["model"] == "longcat_flash"
+    assert st["prefix_cache"]["prefix_blocks_reused"] >= 6
+    assert (st["prefill_chunks"] > 0) == bool(chunk)
+    c = st["counters"]
+    assert c["decode_steps"] > 0 and c["moe_pairs_held"] > 0
+    assert 0 < c["moe_experts_hit"] and 0 < c["moe_zero_picks"]
+    # prefills count under names and a denominator of their own: every
+    # prompt token picks 2 experts in each of the 2 layers' one router
+    assert c["prefill_calls"] >= len(prompts) and c["prefill_moe_pairs_held"]
+    assert 0 < c["prefill_moe_zero_picks"] <= 2 * 2 * sum(map(len, prompts))
+    eng.blocks.assert_integrity()
+    for prompt, out in zip(prompts, outs):
+        assert len(out.token_ids) == 12
+        seq = jnp.asarray(prompt + out.token_ids)
+        lg = reference.logits(eng.params, seq[:-1], _model(cfg))
+        rows = lg[len(prompt) - 1:]
+        chosen = jnp.take_along_axis(rows, seq[len(prompt):, None], -1)[:, 0]
+        assert float(jnp.max(jnp.max(rows, -1) - chosen)) < TOL
+
+
+def test_decode_step_logits_match_the_reference_and_skip_freed_slots():
+    """One decode step through the latent cache on logits, and a freed
+    slot (its table row all scratch) is routed nowhere and not counted."""
+    cfg = LongcatConfig.tiny(first_expert=2, held_experts=4)
+    params = _params(cfg)
+    model = _model(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(8), (13,), 0, 256)
+    pool = init_latent_pool(cfg, 9, 4)
+    tables = jnp.asarray([[1, 2, 3, 4], [0, 0, 0, 0]], jnp.int32)
+    stats = []
+    step = jax.jit(functools.partial(latent_decode_step, cfg=cfg))
+    for pos in range(13):  # token by token: every row goes through decode
+        tok = jnp.asarray([tokens[pos], 7], jnp.int32)
+        logits, pool, st = step(
+            params, tok, jnp.asarray([pos, 0], jnp.int32), tables, pool)
+        stats.append(np.asarray(st))
+    want = reference.logits(params, tokens, model)
+    assert float(jnp.max(jnp.abs(logits[0] - want[-1]))) < TOL
+    # only the live slot's 2 layers x 3 picks are counted
+    assert all(s[0] + s[2] <= 6 for s in stats)
+
+
+# ------------------------------------------------ (d) the kernel's arm
+
+LENGTHS = {"empty-and-one": [0, 1, 0, 5], "block-edge": [4, 8, 3, 12],
+           "many-blocks": [24, 17, 0, 23]}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(LENGTHS))
+def test_latent_arm_matches_the_gather_path(case, dtype):
+    """The same pool, tables and lengths through the kernel's latent arm
+    (Pallas interpreter) and through a gather of the whole table: H heads
+    against one shared row a token, values the row's leading columns, the
+    caller's scale.  Lengths 0 (zeros by contract), 1, a block's edge and
+    many blocks; pages no slot may read hold NaN."""
+    A, NB, bs, W, kr, H, MB = 3, 40, 4, 128, 32, 4, 6
+    lengths = LENGTHS[case]
+    rng = np.random.default_rng(7)
+    free = list(range(1, NB))
+    rng.shuffle(free)
+    tables = np.zeros((4, MB), np.int32)
+    live = set()
+    for s, n in enumerate(lengths):
+        for p in range(-(-n // bs)):
+            tables[s, p] = free.pop()
+            live.add(int(tables[s, p]))
+        tables[s, -(-n // bs):] = free[-1]  # never to be read
+    pool = np.array(jax.random.normal(jax.random.PRNGKey(1),
+                                      (A, NB, bs, W)), np.float32)
+    pool[:, [b for b in range(NB) if b not in live]] = np.nan
+    pool = jnp.asarray(pool, dtype)
+    q = jax.random.normal(jax.random.PRNGKey(2), (4, H, W), dtype)
+    lens = jnp.asarray(lengths, jnp.int32)
+    scale = 24 ** -0.5
+    got = latent_paged_attention(q, pool, jnp.asarray(tables), lens,
+                                 layer=1, value_width=kr, scale=scale,
+                                 pages_per_block=2)
+    rows = jnp.nan_to_num(pool[1][jnp.asarray(tables)].reshape(4, MB * bs, W))
+    scores = jnp.einsum("bhw,btw->bht", q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    mask = jnp.arange(MB * bs)[None, None, :] < lens[:, None, None]
+    probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), -1).astype(dtype)
+    want = jnp.einsum("bht,btk->bhk", probs, rows[..., :kr],
+                      preferred_element_type=jnp.float32)
+    want = jnp.where(lens[:, None, None] > 0, want, 0).astype(dtype)
+    assert got.shape == (4, H, kr) and got.dtype == dtype
+    assert not bool(jnp.any(jnp.isnan(got)))
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                 - want.astype(jnp.float32)))) < tol
+
+
+def test_decode_attention_path_reads_the_pool(monkeypatch):
+    cfg = LongcatConfig.tiny()
+    tiny = init_latent_pool(cfg, 4, 8)
+    real = {"kv": jnp.zeros((2, 3, 16, 640), jnp.bfloat16)}
+    dense = {"k": jnp.zeros((1, 3, 16, 8, 128), jnp.bfloat16),
+             "v": jnp.zeros((1, 3, 16, 8, 128), jnp.bfloat16)}
+    assert decode_attention_path(real) == "gather"  # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert decode_attention_path(real) == "latent_kernel"
+    assert decode_attention_path(dense) == "paged_kernel"
+    assert decode_attention_path(tiny) == "gather"  # 8 rows a page
+    assert decode_attention_path(real, spec_tokens=2) == "gather"
+    assert decode_attention_path(real, mesh=object()) == "gather"
+
+
+# --------------------------------------------------- (e) the control
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_the_control_reads_not_correct(seed):
+    """The control of the cell's ``correct``: the reference with every
+    weight product's operands rounded to float8_e4m3fn, in the program's
+    place, against the program in bfloat16 (as the configuration states).
+    Readings at this size (seeds 1-5, logits of magnitude 0.6): the median
+    over positions of the logit error is 0.003 sound and 0.02-0.05 control.
+    The median, not the largest: at toy widths the stream is the embedding
+    (0.02) plus what the zero-compute experts return (their weight times a
+    normed vector), so ONE pick that rounding flips moves one position's
+    logits by 0.2-0.5, sound or not.  At the published widths the dense
+    blocks' outputs carry the stream and a flipped pick (weight 6/768) is
+    lost in it: the cell judges every returned token (PERF.md section 6)."""
+    cfg = LongcatConfig.tiny(first_expert=2, held_experts=4,
+                             dtype=jnp.bfloat16)
+    params = longcat_init(jax.random.PRNGKey(seed), cfg)
+    model = _model(LongcatConfig.tiny(first_expert=2, held_experts=4))
+    tokens = jax.random.randint(jax.random.PRNGKey(seed + 10), (1, 64), 0,
+                                256)
+    want = reference.logits(params, tokens[0], model)
+
+    def readings(got):
+        """Over positions, the median and the largest logit error."""
+        err = jnp.max(jnp.abs(got - want), axis=-1)
+        return float(jnp.median(err)), float(jnp.max(err))
+
+    sound = readings(longcat_apply(params, tokens, cfg)[0])
+    wrong = readings(reference.logits(
+        params, tokens[0], dict(model, control_dtype="float8_e4m3fn")))
+    assert sound[0] < 0.008 < wrong[0], (sound, wrong)
+    assert wrong[0] > 3 * sound[0]
+
+
+# ------------------------------------------- (f) what is not supplied
+
+def test_unsupported_options_raise():
+    cfg = LongcatConfig.tiny()
+    kw = dict(tokenizer=_Ids(), batch_slots=2, max_len=32, block_size=8)
+    with pytest.raises(ValueError, match="kv_dtype"):
+        LLMEngine(cfg, kv_cache_dtype="int8", **kw)
+    with pytest.raises(NotImplementedError, match="verify"):
+        LLMEngine(cfg, spec_tokens=2, **kw)
+    with pytest.raises(NotImplementedError, match="mesh"):
+        LLMEngine(cfg, mesh=object(), **kw)
+    eng = LLMEngine(cfg, **kw)
+    with pytest.raises(NotImplementedError, match="handoff"):
+        eng.submit([1, 2, 3], prefill_only=True)
+    with pytest.raises(NotImplementedError, match="handoff"):
+        eng.export_kv(0)
+    with pytest.raises(NotImplementedError, match="handoff"):
+        eng.adopt_prefilled({})
+    with pytest.raises(NotImplementedError):
+        longcat_apply(None, jnp.zeros((1, 2), jnp.int32), cfg, mesh=object())
+    # a configuration nobody registered is refused by name
+    from ray_tpu.models.moe import MoEConfig
+    with pytest.raises(TypeError, match="MoEConfig"):
+        LLMEngine(MoEConfig.tiny_moe(), **kw)
+    with pytest.raises(ValueError, match="longcat_flash_tiny"):
+        preset("no-such-model")
+
+
+def test_presets_resolve_by_name_for_every_served_model():
+    from ray_tpu.llm.serving import _build_engine
+    from ray_tpu.models.llama import LlamaConfig
+
+    assert preset("tiny") == LlamaConfig.tiny()
+    assert preset("llama3_8b") == LlamaConfig.llama3_8b()
+    assert preset("longcat_flash_tiny") == LongcatConfig.tiny()
+    assert preset("longcat_flash").num_experts == 512
+    eng = _build_engine({"model": "longcat_flash_tiny", "batch_slots": 2,
+                         "max_len": 32, "block_size": 8}, 1)
+    assert eng.model.name == "longcat_flash"
+    assert eng.cfg.param_dtype == jnp.float32  # a tiny preset stays as it is
+    assert _build_engine({"batch_slots": 2}, 1).model.name == "llama"
+
+
+def test_a_replica_admits_as_many_requests_as_its_engine_has_slots():
+    from ray_tpu.llm import build_llm_deployment
+
+    def caps(kw):
+        c = build_llm_deployment(kw).deployment.config
+        return c.max_ongoing_requests, c.max_queued_requests
+
+    assert caps(None) == caps({"batch_slots": 32}) == (32, 64)
+    assert caps({"batch_slots": 8}) == (32, 64)
+    assert caps({"batch_slots": 128, "max_len": 3584}) == (128, 256)
