@@ -109,6 +109,9 @@ class Request:
     t_admit: float = 0.0
     t_decode: float = 0.0
     trace_ctx: Optional[tracing.SpanContext] = None
+    # recorded, not yet handed to ``on_token``: a request's first token
+    # waits here for the tokens of its first window (``LLMEngine._tell``)
+    held: List[int] = dataclasses.field(default_factory=list)
 
     def __post_init__(self):
         if self.n_prompt < 0:
@@ -253,6 +256,15 @@ class _BlockManager:
              f"phantom {everything - expect}")
 
 
+@dataclasses.dataclass
+class _Window:
+    """A dispatched decode window whose tokens the host has not fetched."""
+    out_d: Any  # [k, B (+ the model's counters)] int32, on its way over
+    k: int
+    active: List[int]  # the slots it decodes for
+    t_arm: float  # the arm clock when its preparation began
+
+
 class LLMEngine:
     def __init__(self, cfg, params=None, *,
                  tokenizer: Optional[Any] = None, batch_slots: int = 8,
@@ -329,6 +341,9 @@ class LLMEngine:
             (*model.counters, "decode_steps",
              *(f"prefill_{n}" for n in model.counters), "prefill_calls")
             if model.counters else (), 0)
+        # every model: windows dispatched, and how many of them were
+        # launched before the emit of the window before (step())
+        self.counters.update(decode_windows=0, windows_carried=0)
         self._stack_counted = jax.jit(lambda toks, counts: jnp.concatenate(
             [jnp.stack(toks), jnp.stack(counts)], axis=1))
         # prompt-lookup speculative decoding (vLLM's ngram method,
@@ -409,12 +424,16 @@ class LLMEngine:
         self._tables = np.zeros((self.B, self.MB), np.int32)
         # device mirrors of the decode inputs, kept resident across
         # windows: re-uploading unchanged tables/temps/token/cur costs a
-        # dispatch each through a high-latency link.  Any host-side slot
-        # mutation (admit/retire/preempt/block growth) sets the flag.
+        # dispatch each through a high-latency link.  A changed table row
+        # (admit/retire/preempt/block growth) sets the flag; a request
+        # entering a slot also drops (tok_d, cur_d)
+        # (_refresh_device_mirrors).
         self._dev: Optional[Tuple[Any, Any]] = None  # (tok_d, cur_d)
         self._tables_d = None
         self._temps_d = None
         self._dev_dirty = True
+        # the window dispatched by the last step() and not yet fetched
+        self._inflight: Optional[_Window] = None
         # per-token hook for streaming consumers: on_token(request_id, tok)
         self.on_token: Optional[Any] = None
         # a model's prefill counters, summed on the device (one shape,
@@ -491,7 +510,9 @@ class LLMEngine:
         removed outright — releasing any chunk-prefill block pins it
         accumulated — and an active one is marked ``done`` so the next
         ``step()`` retires it through the ordinary path (slot cleared,
-        blocks released, device mirrors refreshed).  Returns ``True``
+        blocks released, device mirrors refreshed); what a window in
+        flight decodes for it meanwhile is dropped at that step's commit,
+        and its blocks stay held until then.  Returns ``True``
         when the request was found; the retire still emits its (partial)
         ``GenerationOutput``, which an abandoning caller simply drops.
 
@@ -537,7 +558,50 @@ class LLMEngine:
 
     def step(self) -> List[GenerationOutput]:
         """Admit queued requests into free slots (prefix-cached prefill),
-        run ONE decode step for all active slots, retire finished.
+        take ONE decode window's tokens for all active slots, retire
+        finished.
+
+        A one-deep pipeline of decode windows: the window whose tokens
+        this step hands out was as a rule launched by the step before,
+        and this step launches the next one from the device-resident
+        ``(tok_d, cur_d, key_d, pool)`` as soon as it has fetched and
+        committed this one, before any per-token Python.  The order:
+
+        1. ``engine.admit`` / ``engine.first_tokens``: prefills are
+           dispatched BEHIND a window in flight (``self.pool`` is chained,
+           the device orders them), the first tokens sync as ever;
+        2. the window: the one in flight, or with none in flight one
+           launched now (``engine.prepare_window`` +
+           ``engine.dispatch_window[carried=0]``);
+        3. ``engine.fetch_window`` (the one host sync), then
+           ``engine.emit[part=commit]``: each slot's column cut at its
+           stop token / ``max_tokens`` / ``max_len`` and the host state
+           (``out_tokens``, ``_cur_len``, ``_next_token``, ``done``)
+           advanced in bulk, a slot at a time and not a token at a time;
+        4. the carried window: ``engine.prepare_window`` +
+           ``engine.dispatch_window[carried=1]`` for the slots that go on
+           (this step's admissions among them); the step returns with it
+           running;
+        5. ``engine.emit[part=notify]`` (``on_token``, the arm clock) and
+           ``engine.retire`` (``tokenizer.decode``, request spans), under
+           the carried window, as is all the caller does between steps.
+           A request's first token goes to ``on_token`` with the tokens
+           of its first window, one step after its admission (``_tell``).
+
+        A window is not carried when no slot goes on (so an engine whose
+        ``has_unfinished()`` is false has nothing in flight) or when
+        speculation is on (``spec_tokens``: the verify arm drafts from the
+        last host tokens).  The un-carried order is the same code with
+        the launch left to the next step.
+        ``stats()["counters"]["windows_carried"]`` of ``["decode_windows"]``
+        says how often it engages.
+
+        A slot that is done or empty is not in a launch: its row of the
+        device's block tables is zero (``_refresh_device_mirrors``), so
+        the decode program takes it for an empty slot: it reads nothing
+        for it and its write goes to the scratch block, never into blocks
+        that ``retire`` releases after the launch or that the prefix
+        cache holds.
 
         The step is tiled by ``tracing.annotate`` phases (``engine.admit``,
         ``engine.first_tokens``, ``engine.verify``,
@@ -562,8 +626,9 @@ class LLMEngine:
             with tracing.annotate("engine.admit", kind="adopt") as ann:
                 ann.set_metadata(n=self._place_adopted())
 
-        # 1. admit — prefills dispatch back-to-back; the first tokens of
-        # ALL admissions are sampled and fetched in ONE host sync
+        # 1. admit — prefills dispatch back-to-back (behind the window in
+        # flight, if any); the first tokens of ALL admissions are sampled
+        # and fetched in ONE host sync
         admitted: List[Tuple[int, Any]] = []
         budget = self.prefill_chunk or None  # tokens of prefill this step
         for i in range(self.B):
@@ -608,92 +673,148 @@ class LLMEngine:
                         prompt_tokens=len(req.prompt_tokens),
                         cached_tokens=req.cached_prefix_len)
                     req.t_decode = now
-                    self._record_token(i, req, int(tok))
+                    self._record_token(i, req, int(tok), hold=True)
                 # the step's device temporaries die inside the phase that
                 # made them, not at the frame's exit under no phase:
                 # freeing a device buffer gives up the interpreter lock,
                 # and what the thread then waits belongs to the phase
                 del admitted, rows, lg, k, first
 
+        # 2. the window this step hands out: the one the step before left
+        # in flight, or one launched now
+        window, self._inflight = self._inflight, None
+        if window is None:
+            window = self._launch_window(carried=False)
+        if window is not None:
+            # 3. its tokens, and the host state they leave
+            taken = self._fetch_and_commit(window)
+            # 4. the next window, before any per-token work on this one
+            if self._carries():
+                self._inflight = self._launch_window(carried=True)
+            # 5. what the launch did not wait for
+            self._notify(window, taken)
+
+        with tracing.annotate("engine.retire") as ann:
+            out = self._retire()
+            ann.set_metadata(n=len(out))
+        return out
+
+    def _carries(self) -> bool:
+        """Whether a window may be launched ahead of the emit of the one
+        before: not with speculation on, whose verify arm drafts from the
+        host's last tokens."""
+        return self.G == 0
+
+    def _launch_window(self, carried: bool) -> Optional[_Window]:
+        """Dispatch one decode window (``window_k`` chained steps and the
+        small program that stacks their tokens) for the slots that are not
+        done, and return it un-fetched; ``None`` when no slot goes on or
+        the verify arm produced this step's tokens instead.  ``carried``:
+        the window before this one has been committed but not emitted."""
         active = [i for i in range(self.B) if self._slots[i] is not None
                   and not self._slots[i].done]
         if active and self.G:
             with tracing.annotate("engine.verify"):
                 if self._try_speculate(active):
-                    active = []  # this step's tokens came from the verify
-        if active:
-            with tracing.annotate("engine.prepare_window"):
-                # arm timing starts BEFORE block growth / mirror refresh /
-                # uploads so the window arm carries the same per-step host
-                # costs the verify arm does (symmetric bandit comparison)
-                t_arm = self._arm_clock()
-                # ensure every active slot has blocks for the whole
-                # window; preempt the youngest request if the pool is
-                # exhausted
-                active = self._ensure_decode_blocks(active, horizon=self.K)
-                if active:
-                    # adaptive window: never decode past what the
-                    # longest-running active request can still accept
-                    window_k = self._window_arity(active)
-                    live = int(self._cur_len[active].sum())  # host mirror
-                    self._refresh_device_mirrors()
-                    if self._dev is None:
-                        tok_d = jnp.asarray(self._next_token)
-                        cur_d = jnp.asarray(self._cur_len)
-                    else:
-                        tok_d, cur_d = self._dev
-                    key_d = self._key
-        if active:
-            with tracing.annotate(
-                    "engine.dispatch_window", k=window_k, active=len(active),
-                    live_tokens=live, attn=self.attn):
-                toks, counts = [], []
-                for _ in range(window_k):  # device-chained: no host sync
-                    tok_d, cur_d, key_d, self.pool, *extra = self._decode1(
-                        self.params, tok_d, cur_d, self._tables_d,
-                        self.pool, key_d, self._temps_d)
-                    toks.append(tok_d)
-                    counts += extra
-                self._key = key_d
-                self._dev = (tok_d, cur_d)
-            with tracing.annotate("engine.fetch_window") as ann:
-                # ONE host sync for the whole window_k * B window (a
-                # model's counters ride in the same array)
-                if counts:
-                    window = np.asarray(self._stack_counted(toks, counts))
-                    ann.set_metadata(k=window_k, active=len(active),
-                                     **self._count(
-                                         window[:, self.B:].sum(axis=0),
-                                         steps=window_k))
-                    window = window[:, :self.B]
-                else:
-                    window = np.asarray(self._stack(*toks))
-            with tracing.annotate("engine.emit") as ann:
-                if self.G:
-                    self._spec_streak = 0
-                    # per-ARITY EMA: short windows have different sync
-                    # amortization (and their own _stack compiles), so
-                    # each arity gets its own sample stream — the verify
-                    # gate compares against the arity it would displace
-                    self._observe_arm(("window", window_k), window_k,
-                                      self._arm_clock() - t_arm)
-                emitted = 0
-                for step in range(window_k):
-                    for i in active:
-                        req = self._slots[i]
-                        if req is None or req.done:
-                            continue  # stopped mid-window: drop the tail
-                        self._cur_len[i] += 1
-                        self._record_token(i, req, int(window[step, i]))
-                        emitted += 1
-                ann.set_metadata(tokens=emitted)
-                del toks, counts, window  # as above: freed inside the phase
+                    return None  # this step's tokens came from the verify
+        if not active:
+            return None
+        with tracing.annotate("engine.prepare_window"):
+            # arm timing starts BEFORE block growth / mirror refresh /
+            # uploads so the window arm carries the same per-step host
+            # costs the verify arm does (symmetric bandit comparison)
+            t_arm = self._arm_clock()
+            # ensure every active slot has blocks for the whole window;
+            # preempt the youngest request if the pool is exhausted
+            active = self._ensure_decode_blocks(active, horizon=self.K)
+            if not active:
+                return None
+            # adaptive window: never decode past what the
+            # longest-running active request can still accept
+            window_k = self._window_arity(active)
+            live = int(self._cur_len[active].sum())  # host mirror
+            self._refresh_device_mirrors()
+            tok_d, cur_d = self._dev
+            key_d = self._key
+        with tracing.annotate(
+                "engine.dispatch_window", k=window_k, active=len(active),
+                live_tokens=live, attn=self.attn, carried=int(carried)):
+            toks, counts = [], []
+            for _ in range(window_k):  # device-chained: no host sync
+                tok_d, cur_d, key_d, self.pool, *extra = self._decode1(
+                    self.params, tok_d, cur_d, self._tables_d,
+                    self.pool, key_d, self._temps_d)
+                toks.append(tok_d)
+                counts += extra
+            self._key = key_d
+            self._dev = (tok_d, cur_d)
+            # stacked behind the last step at once (a model's counters
+            # ride in the same array) and sent on its way to the host, so
+            # that the fetch has nothing left to dispatch
+            out_d = self._stack_counted(toks, counts) if counts \
+                else self._stack(*toks)
+            out_d.copy_to_host_async()
+            self.counters["decode_windows"] += 1
+            self.counters["windows_carried"] += int(carried)
+            del toks, counts, extra  # freed inside the phase, as above
+        return _Window(out_d, window_k, active, t_arm)
 
-        # 3. retire
-        with tracing.annotate("engine.retire") as ann:
-            out = self._retire()
-            ann.set_metadata(n=len(out))
-        return out
+    def _fetch_and_commit(self, w: _Window
+                          ) -> List[Tuple[Request, List[int]]]:
+        """The window's one host sync, then the commit: the host state
+        that ``_record_token``, token by token, would leave, advanced a
+        slot at a time.  Returns ``[(request, tokens kept)]`` for
+        ``_notify``."""
+        with tracing.annotate("engine.fetch_window") as ann:
+            window = np.asarray(w.out_d)
+            if self.model.counters:  # they ride behind the B tokens
+                ann.set_metadata(k=w.k, active=len(w.active),
+                                 **self._count(
+                                     window[:, self.B:].sum(axis=0),
+                                     steps=w.k))
+            w.out_d = None  # freed inside the phase, as above
+        with tracing.annotate("engine.emit", part="commit") as ann:
+            taken = []
+            for i in w.active:
+                req = self._slots[i]
+                if req is None or req.done:
+                    continue  # aborted while the window ran: drop it all
+                sp = req.sampling
+                room = min(sp.max_tokens - req.num_generated,
+                           self.max_len - 1 - len(req.prompt_tokens)
+                           - len(req.out_tokens))
+                toks = window[:max(0, min(w.k, room)), i].tolist()
+                used = len(toks)  # positions the slot's cache now holds
+                if sp.stop_token_id is not None \
+                        and sp.stop_token_id in toks:
+                    # stopped mid-window: drop the stop token and the tail
+                    toks = toks[:toks.index(sp.stop_token_id)]
+                    used = len(toks) + 1
+                    req.done = True
+                elif used >= room:
+                    req.done = True
+                self._cur_len[i] += used
+                if toks:
+                    req.out_tokens.extend(toks)
+                    self._next_token[i] = toks[-1]
+                taken.append((req, toks))
+            ann.set_metadata(tokens=sum(len(t) for _, t in taken))
+        return taken
+
+    def _notify(self, w: _Window, taken) -> None:
+        """What of a window's emit need not precede the next launch: the
+        arm clock and the per-token hook."""
+        with tracing.annotate("engine.emit", part="notify"):
+            if self.G:
+                self._spec_streak = 0
+                # per-ARITY EMA: short windows have different sync
+                # amortization (and their own _stack compiles), so
+                # each arity gets its own sample stream — the verify
+                # gate compares against the arity it would displace
+                self._observe_arm(("window", w.k), w.k,
+                                  self._arm_clock() - w.t_arm)
+            for req, toks in taken:
+                self._tell(req, toks)
 
     def _place_adopted(self) -> int:
         """Move adopted requests from the adopt queue into free slots;
@@ -713,6 +834,7 @@ class LLMEngine:
             self._tables[i] = 0
             self._tables[i, :len(req.blocks)] = req.blocks
             self._dev_dirty = True
+            self._dev = None  # a request entered: its token, position, temp
             req.t_admit = req.t_decode = now
             self._request_span("engine.queue_wait", req, req.t_queued, now)
             placed += 1
@@ -1090,6 +1212,7 @@ class LLMEngine:
         self._tables[i] = 0
         self._tables[i, :len(req.blocks)] = req.blocks
         self._dev_dirty = True
+        self._dev = None  # a request entered: its token, position, temp
         # device array; caller batch-samples all admissions in one sync
         return ("full", logits, len(suffix))
 
@@ -1432,7 +1555,10 @@ class LLMEngine:
 
     # -- internals ----------------------------------------------------------
 
-    def _record_token(self, i: int, req: Request, tok: int):
+    def _record_token(self, i: int, req: Request, tok: int,
+                      hold: bool = False):
+        """One sampled token into the request's state.  ``hold``: a first
+        token, kept back from ``on_token`` while the request goes on."""
         sp = req.sampling
         if sp.stop_token_id is not None and tok == sp.stop_token_id:
             req.done = True
@@ -1444,15 +1570,33 @@ class LLMEngine:
             # decode replica generates everything after it
             req.done = True
             return
-        if self.on_token is not None:
-            try:
-                self.on_token(req.request_id, tok)
-            except Exception:  # noqa: BLE001 - consumer hook must not kill decode
-                pass
         if (req.num_generated >= sp.max_tokens
                 or len(req.prompt_tokens) + len(req.out_tokens)
                 >= self.max_len - 1):
             req.done = True
+        if hold and not req.done:
+            req.held.append(tok)
+        else:
+            self._tell(req, [tok])
+
+    def _tell(self, req: Request, toks: List[int]):
+        """Hand tokens to the per-token hook, behind what the request held
+        back.  A request's first token is handed out with the tokens of
+        its first window, as it was when admission and that window shared
+        a step.  On its own it could leave a window sooner (the step that
+        samples it fetches a window the request is not in), but no token
+        after it would: the stream's time from first to last token would
+        grow by a window, and so would every reading of the gap between
+        tokens taken from it.  The window it waits is the one its prefill
+        queued behind (ROADMAP A3 gives it back to the first token)."""
+        held, req.held = req.held, []
+        if self.on_token is None:
+            return
+        for tok in held + toks:
+            try:
+                self.on_token(req.request_id, tok)
+            except Exception:  # noqa: BLE001 - consumer hook must not kill decode
+                pass
 
     def _count(self, sums, steps: int = 0, kind: str = "") -> Dict[str, int]:
         """Add fetched counter sums (``ServedModel.counters``' order) to
@@ -1467,18 +1611,42 @@ class LLMEngine:
         return added
 
     def _refresh_device_mirrors(self):
-        """Re-upload the tables/temps device mirrors iff a host-side slot
-        mutation (admit/retire/preempt/table growth) dirtied them — ONE
-        invariant for both the decode window and the verify path (temps
-        is B floats, noise next to the [B, MB] tables).  Dirty also
-        invalidates the tok/cur pair: the slot set changed."""
+        """Bring the device mirrors of the decode inputs up to the host's,
+        each only where the host changed it — ONE invariant for both the
+        decode window and the verify path:
+
+        * the block tables, when a row changed (admit / retire / preempt /
+          table growth set ``_dev_dirty``).  First the row of every slot
+          whose request is done is zeroed, as an empty slot's is: whatever
+          is launched next — a carried window runs before ``retire`` —
+          reads nothing for that slot and writes its position to the
+          scratch block, never into blocks about to be released;
+        * ``(tok_d, cur_d)`` and the temperatures, when a request entered
+          a slot or the verify arm advanced the host (``_dev`` is None).
+          A table that grew does NOT invalidate them: after a window's
+          commit the host's ``_next_token`` / ``_cur_len`` of a slot that
+          goes on are what the window's last step left on the device, so
+          the chained pair is kept, and an upload of the host's vectors
+          ([B]-shaped, whatever the number of admissions) patches the
+          entering slots in without touching the others' values.
+
+        Uploads are ``jnp.array`` (always a copy, never a view of the
+        numpy buffer): the host mirrors are written by the next step's
+        admissions while a window that reads the device arrays is in
+        flight."""
         import jax.numpy as jnp
 
+        for i, req in enumerate(self._slots):
+            if req is not None and req.done and self._tables[i, 0]:
+                self._tables[i] = 0
+                self._dev_dirty = True
         if self._dev_dirty or self._tables_d is None:
-            self._tables_d = jnp.asarray(self._tables)
-            self._temps_d = jnp.asarray(self._temp_vec())
-            self._dev = None
+            self._tables_d = jnp.array(self._tables)
             self._dev_dirty = False
+        if self._dev is None:
+            self._temps_d = jnp.array(self._temp_vec())
+            self._dev = (jnp.array(self._next_token),
+                         jnp.array(self._cur_len))
 
     def _temp_vec(self, sl: slice = slice(None)) -> np.ndarray:
         temps = np.ones(self.B, np.float32)

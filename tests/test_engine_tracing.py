@@ -91,6 +91,7 @@ def _largest_hole(outer, children):
     ("window", {}),
     ("speculative", {"spec_tokens": 3}),
     ("chunked", {"prefill_chunk": 16}),
+    ("uncarried", {}),
 ])
 def test_engine_step_is_tiled_by_its_phases(tiny, tmp_path, case, kwargs):
     from ray_tpu.llm import LLMEngine
@@ -98,6 +99,8 @@ def test_engine_step_is_tiled_by_its_phases(tiny, tmp_path, case, kwargs):
     cfg, params = tiny
     eng = LLMEngine(cfg, params, batch_slots=2, max_len=128,
                     decode_window=4, **kwargs)
+    if case == "uncarried":
+        eng._carries = lambda: False  # every launch left to the next step
     sp = SamplingParams(temperature=0.0, max_tokens=22)
     # repetitive prompts so the speculative arm drafts; a 40-token one so
     # the chunked case prefills in chunks
@@ -126,6 +129,7 @@ def test_engine_step_is_tiled_by_its_phases(tiny, tmp_path, case, kwargs):
             verifying.pop()
     eng._window_arity = recording_arity
     eng._try_speculate = flagged_speculate
+    before = dict(eng.stats()["counters"])
     with _Profile(tmp_path) as prof:
         outs = eng.generate(prompts(20), sp)
     assert all(len(o.token_ids) == 22 for o in outs)
@@ -175,12 +179,269 @@ def test_engine_step_is_tiled_by_its_phases(tiny, tmp_path, case, kwargs):
     for e in windows:
         assert 1 <= e[3]["k"] <= 4 and 1 <= e[3]["active"] <= 2
         assert e[3]["attn"] == eng.attn == "gather"  # CPU backend
+    # the positions its slots hold as each window is dispatched, carried
+    # or not: the commit has brought the host mirror up to date
     assert [e[3]["live_tokens"] for e in windows] == held
-    emitted = sum(e[3]["tokens"] for e in prof.named("engine.emit"))
+    emits = prof.named("engine.emit")
+    commits = [e for e in emits if e[3]["part"] == "commit"]
+    notifies = [e for e in emits if e[3]["part"] == "notify"]
+    assert len(commits) == len(notifies) == len(emits) // 2
+    emitted = sum(e[3]["tokens"] for e in commits)
     assert sum(e[3]["n"] for e in prof.named("engine.first_tokens")) == 3
     if case != "speculative":
         assert emitted == 3 * 22 - 3  # all but the three first tokens
     assert sum(e[3]["n"] for e in prof.named("engine.retire")) == 3
+    # every window is fetched and committed before it is emitted, in the
+    # step that hands its tokens out; a carried window (n+1) is dispatched
+    # between the commit and the notify of the window before it (n)
+    carried = [e for e in windows if e[3]["carried"]]
+    counters = eng.stats()["counters"]
+    assert counters["decode_windows"] - before["decode_windows"] \
+        == len(windows)
+    assert counters["windows_carried"] - before["windows_carried"] \
+        == len(carried)
+    if case in ("speculative", "uncarried"):
+        assert not carried and counters["windows_carried"] == 0
+    else:
+        assert len(carried) >= 0.6 * len(windows) >= 6
+    for e in carried:
+        step = next(s for s in steps if s[1] <= e[1] and e[2] <= s[2])
+        (commit,) = [c for c in commits
+                     if step[1] <= c[1] and c[2] <= step[2]]
+        (notify,) = [n for n in notifies
+                     if step[1] <= n[1] and n[2] <= step[2]]
+        assert commit[2] <= e[1] and e[2] <= notify[1]
+    for step in steps:
+        names = [e[0] + (f"[{e[3]['part']}]" if e[0] == "engine.emit"
+                         else f"[{e[3]['carried']}]"
+                         if e[0] == "engine.dispatch_window" else "")
+                 for e in thread if e[0] != "engine.step"
+                 and step[1] <= e[1] and e[2] <= step[2]
+                 and e[0] not in ("engine.admit", "engine.first_tokens",
+                                  "engine.verify")]
+        assert names in (
+            # nothing in flight, none launched (a verify step, a drain)
+            ["engine.retire"],
+            # nothing in flight: launch, fetch, commit, [launch], notify
+            ["engine.prepare_window", "engine.dispatch_window[0]",
+             "engine.fetch_window", "engine.emit[commit]",
+             "engine.emit[notify]", "engine.retire"],
+            ["engine.prepare_window", "engine.dispatch_window[0]",
+             "engine.fetch_window", "engine.emit[commit]",
+             "engine.prepare_window", "engine.dispatch_window[1]",
+             "engine.emit[notify]", "engine.retire"],
+            # one in flight: fetch, commit, [launch], notify
+            ["engine.fetch_window", "engine.emit[commit]",
+             "engine.prepare_window", "engine.dispatch_window[1]",
+             "engine.emit[notify]", "engine.retire"],
+            ["engine.fetch_window", "engine.emit[commit]",
+             "engine.emit[notify]", "engine.retire"]), names
+
+
+# ------------------------------------------------- the carried window
+
+def _engine_pair(model, **kwargs):
+    """Two engines on the same weights: one as it is (a window is launched
+    ahead of the emit of the one before), one whose every launch is left to
+    the next step, which is the order the engine had before windows were
+    carried."""
+    from ray_tpu.llm import LLMEngine
+
+    cfg, params, extra = model
+    kw = dict(batch_slots=2, max_len=96, block_size=4, decode_window=4,
+              **extra)
+    carried = LLMEngine(cfg, params, **{**kw, **kwargs})
+    kwargs.pop("spec_tokens", None)  # compared with the plain engine
+    plain = LLMEngine(cfg, params, **{**kw, **kwargs})
+    plain._carries = lambda: False
+    return carried, plain
+
+
+def _run_schedule(eng, arrivals, aborts=None):
+    """Drive ``eng`` a step at a time: ``arrivals[step]`` are submitted
+    and ``aborts[step]`` (indices into the submitted) aborted before that
+    step.  The block accounting is audited after every step.  Returns the
+    requests' tokens, in the order submitted."""
+    ids, outs, step = [], {}, 0
+    while step <= max(arrivals) or eng.has_unfinished():
+        for prompt, sp in arrivals.get(step, ()):
+            ids.append(eng.submit(prompt, sp))
+        for j in (aborts or {}).get(step, ()):
+            assert eng.abort(ids[j])
+        for out in eng.step():
+            assert out.error is None
+            outs[out.request_id] = out.token_ids
+        eng.blocks.assert_integrity()
+        assert (eng._inflight is not None) <= eng.has_unfinished()
+        step += 1
+        assert step < 200
+    assert eng._inflight is None
+    return [outs[i] for i in ids]
+
+
+def _greedy(max_tokens, stop=None):
+    return SamplingParams(temperature=0.0, max_tokens=max_tokens,
+                          stop_token_id=stop)
+
+
+P = [[3, 4, 5, 6, 7], [9, 8, 7, 6], [11, 12, 13, 14, 15, 16], [20, 21, 22]]
+LONG = [30] + [7, 8] * 20  # 41 tokens: three chunks of 16
+
+
+def _schedules():
+    """name -> (engine kwargs, arrivals, aborts, what the engine's
+    counters must show)."""
+    return {
+        # requests join and leave while others decode; the fourth waits
+        # for a slot
+        "staggered": ({}, {0: [(P[0], _greedy(14))], 2: [(P[1], _greedy(9))],
+                           3: [(P[2], _greedy(11)), (P[3], _greedy(6))]},
+                      None, {}),
+        # 1 first token + 4 + 2: the budget ends inside the second window
+        # while the other slot goes on
+        "max_tokens_mid_window": ({}, {0: [(P[0], _greedy(7)),
+                                           (P[1], _greedy(18))]}, None, {}),
+        # the pool holds 6 blocks of 4: the younger request is preempted
+        # and recomputed
+        "preemption": ({"num_blocks": 7},
+                       {0: [(P[0][:4], _greedy(10)), (P[1], _greedy(10))]},
+                       None, {"preemptions": 1}),
+        # the first request is aborted while its third window is in
+        # flight; a queued one takes its slot
+        "abort_in_flight": ({}, {0: [(P[0], _greedy(30)),
+                                     (P[1], _greedy(13))],
+                                 1: [(P[2], _greedy(9))]}, {3: [0]}, {}),
+        # a 41-token prompt prefilled 16 tokens a step behind the windows
+        # of a request that is decoding
+        "chunked_prefill": ({"prefill_chunk": 16},
+                            {0: [(P[0], _greedy(21))],
+                             1: [(LONG, _greedy(10))]}, None, {"chunks": 2}),
+        # a draft model: nothing is carried, and the answers are the
+        # plain engine's
+        "speculative": ({"spec_tokens": 3},
+                        {0: [([5, 4, 5, 4, 5, 4, 5, 4], _greedy(14))],
+                         1: [([6] + [7, 8] * 6, _greedy(12))]}, None,
+                        {"carried": 0}),
+    }
+
+
+@pytest.fixture(scope="module")
+def models(tiny):
+    from ray_tpu.models.longcat import LongcatConfig
+
+    return {"llama": (*tiny, {}),
+            "longcat": (LongcatConfig.tiny(), None, {"seed": 5})}
+
+
+@pytest.mark.parametrize("model,case", [
+    *[("llama", c) for c in _schedules()], ("longcat", "staggered"),
+    ("llama", "stop_mid_window")])
+def test_carried_windows_give_the_uncarried_orders_tokens(models, model,
+                                                          case):
+    """Greedy outputs are token for token those of the engine that
+    launches every window only after it has emitted the one before."""
+    if case == "stop_mid_window":
+        # the token a request produces sixth (second of its second
+        # window) becomes its stop token, if it is the first of its kind
+        probe, _ = _engine_pair(models[model])
+        (free,) = _run_schedule(probe, {0: [(P[0], _greedy(14))]})
+        at = next(i for i in (5, 6, 9, 10) if free[i] not in free[:i])
+        kwargs, aborts, want = {}, None, {"tokens": [free[:at], 18]}
+        arrivals = {0: [(P[0], _greedy(14, stop=free[at])),
+                        (P[1], _greedy(18))]}
+    else:
+        kwargs, arrivals, aborts, want = _schedules()[case]
+    carried, plain = _engine_pair(models[model], **kwargs)
+    got = _run_schedule(carried, arrivals, aborts)
+    ref = _run_schedule(plain, arrivals, aborts)
+    assert got == ref
+    asked = [sp.max_tokens for step in sorted(arrivals)
+             for _, sp in arrivals[step]]
+    for j, (toks, n) in enumerate(zip(got, want.get("tokens", asked))):
+        if aborts and any(j in js for js in aborts.values()):
+            assert 0 < len(toks) < n  # what it had when it was aborted
+        else:
+            assert toks == n if isinstance(n, list) else len(toks) == n
+    c, p = carried.stats()["counters"], plain.stats()["counters"]
+    assert p["windows_carried"] == 0 and p["decode_windows"] > 0
+    if "carried" in want:
+        assert c["windows_carried"] == want["carried"]
+        assert carried.spec_stats["verify_steps"] > 0
+    else:
+        assert 0 < c["windows_carried"] <= c["decode_windows"]
+        # only the first window after a drain is not carried
+        assert c["windows_carried"] >= c["decode_windows"] - 3
+    for eng in (carried, plain):
+        assert eng.blocks.stats["preemptions"] >= want.get("preemptions", 0)
+        assert eng.prefill_stats["chunks"] >= want.get("chunks", 0)
+        assert eng.blocks.available() == eng.num_blocks - 1
+
+
+@pytest.mark.parametrize("order", ["carried", "uncarried"])
+def test_first_token_is_handed_out_with_its_first_window(tiny, order):
+    """``on_token`` gets a request's first token together with the tokens
+    of its first window, in either order of launch and emit: one step
+    after the admission when windows are carried, in the admission's step
+    when they are not.  A request that ends on its first token is told at
+    once."""
+    # a third slot, for a request that is already decoding
+    pair = _engine_pair((*tiny, {}), batch_slots=3)
+    eng = pair[order == "uncarried"]
+    told = {}
+    eng.on_token = lambda rid, tok: told.setdefault(rid, []).append(tok)
+    eng.submit(P[2], _greedy(30))
+    eng.step()  # its second window is in flight when the others come
+    assert (eng._inflight is not None) == (order == "carried")
+    long_, short = eng.submit(P[0], _greedy(10)), eng.submit(P[1], _greedy(1))
+    per_step, outs = [], {}
+    while long_ not in outs:
+        seen = len(told.get(long_, []))
+        for out in eng.step():
+            outs[out.request_id] = out.token_ids
+        per_step.append(len(told.get(long_, [])) - seen)
+        if len(per_step) == 1:
+            assert told.get(short) == outs[short] and len(outs[short]) == 1
+    # 10 tokens = the first + windows of 4, 4 and 1
+    assert per_step == ([0, 5, 4, 1] if order == "carried" else [5, 4, 1])
+    assert told[long_] == outs[long_] and len(outs[long_]) == 10
+    assert not any(r.held for r in eng._slots if r is not None)
+
+
+def test_a_carried_window_writes_only_its_own_slots_blocks(tiny):
+    """A window launched before ``retire`` writes into the blocks of the
+    slots it decodes for and into the scratch block, nowhere else: not
+    into the blocks of a request that is done (released right after the
+    launch), not into a registered prefix block the cache holds."""
+    import numpy as np
+
+    eng, _ = _engine_pair((*tiny, {}))
+    launch, checked = eng._launch_window, []
+
+    def watched(carried):
+        before = ({k: np.asarray(v) for k, v in eng.pool.items()}
+                  if carried else None)
+        done = [i for i, r in enumerate(eng._slots)
+                if r is not None and r.done]
+        w = launch(carried)
+        if carried and w is not None:
+            own = {0} | {b for i in w.active
+                         for b in eng._slots[i].blocks}
+            rest = [b for b in range(eng.num_blocks) if b not in own]
+            for k, v in eng.pool.items():  # waits for the window
+                assert np.array_equal(before[k][:, rest],
+                                      np.asarray(v)[:, rest])
+            checked.append((len(done), len(eng.blocks.lru)))
+        return w
+    eng._launch_window = watched
+    shared = list(range(40, 48))  # two full blocks: registered
+    _run_schedule(eng, {0: [(shared + [1], _greedy(7)),
+                            (P[1], _greedy(20))],
+                        4: [(shared + [2], _greedy(6))]})
+    assert eng.blocks.stats["prefix_blocks_reused"] >= 2
+    # some carried window ran beside a slot that was done and not yet
+    # retired, and some while the cache held registered blocks
+    assert any(done for done, _ in checked)
+    assert any(cached for _, cached in checked)
 
 
 @pytest.fixture
