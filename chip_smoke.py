@@ -16,7 +16,7 @@ only once no process of the previous is alive:
 
 1. kernels + train: ``flash_attention`` forward and backward against
    ``reference_attention`` at three shapes, then 2 warm-up + 5 steps of the
-   702M Llama-shaped configuration (``bench.py``'s ``b16_s1024_full``)
+   702M Llama-shaped configuration (``TRAIN_MODEL``, batch 16 x seq 1024)
    through ``JaxTrainer`` — with ``--chips 4`` on one chip, on four as
    ``fsdp``, and on four as ``fsdp_tp``;
 2. serve: Llama-2-7B (bf16) behind ``serve`` over HTTP — one warm-up,
@@ -41,9 +41,10 @@ import time
 import urllib.error
 import urllib.request
 
-# ``bench.py``'s b16_s1024_full: the one configuration with an on-chip
-# record (634-641 ms/step on one v5e through an earlier rig — an anchor
-# to read the printed step time against, not a bound)
+# 702M parameters (hidden 1536, 16 layers, 12 heads, MLP 6144, vocab
+# 32000) at batch 16 x seq 1024: small enough to reach its first step in
+# seconds on one chip, and the same on four.  The printed step time is a
+# sign of life, not a record: the benchmark is ``cells/``.
 TRAIN_MODEL = dict(vocab_size=32000, hidden_size=1536, num_layers=16,
                    num_heads=12, num_kv_heads=12, mlp_dim=6144,
                    max_seq_len=1024)

@@ -5,7 +5,7 @@ The bench harness parses the LAST line of a run's captured output
 ``print(json.dumps(...))`` endings broke that contract twice over —
 unflushed-stream interleave let stderr warning chatter land after the
 record, and any failure before the final print exited with a traceback
-instead of a record.  ``MULTICHIP_*.json`` shipped without a top-level
+instead of a record.  A multichip record shipped without a top-level
 parsed metric for five rounds because of exactly this class.
 
 ``ray_tpu._private.bench_emit`` centralizes the fix
@@ -77,9 +77,8 @@ class BenchEmissionChecker(Checker):
             "intermediate records")
 
     def applies_to(self, relpath: str) -> bool:
-        return relpath in ("bench.py", "__graft_entry__.py") or (
-            relpath.startswith("benchmarks/")
-            and relpath.endswith(".py"))
+        return (relpath.startswith("benchmarks/")
+                and relpath.endswith(".py"))
 
     def check(self, pf: ParsedFile) -> Iterable[Finding]:
         out: List[Finding] = []

@@ -175,8 +175,7 @@ class Checker:
 
     def applies_to(self, relpath: str) -> bool:
         return (relpath.startswith("ray_tpu/")
-                and not relpath.startswith("ray_tpu/_private/analysis/")
-                ) or relpath == "bench.py"
+                and not relpath.startswith("ray_tpu/_private/analysis/"))
 
     def check(self, pf: ParsedFile) -> Iterable[Finding]:
         raise NotImplementedError
@@ -236,8 +235,7 @@ def get_checkers(rules: Optional[Sequence[str]] = None) -> List[Checker]:
 _SKIP_DIRS = {"__pycache__", ".git", ".pytest_cache", "build", "dist"}
 
 #: default scan set, relative to the repo root
-DEFAULT_PATHS = ("ray_tpu", "tests", "bench.py", "benchmarks",
-                 "__graft_entry__.py")
+DEFAULT_PATHS = ("ray_tpu", "tests", "benchmarks")
 
 
 @dataclasses.dataclass

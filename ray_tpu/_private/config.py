@@ -37,7 +37,6 @@ _FLAG_DEFS: Dict[str, Any] = {
     # transport retry layer can never double-apply one
     "gcs_reply_cache_size": 4096,
     # --- object store ---
-    "object_store_memory_bytes": 2 * 1024**3,
     # C++ shm arena (ray_tpu/_native/store.cc) — the plasma-equivalent fast
     # path; objects > arena_store_bytes/4 use per-object segments instead
     "use_native_arena_store": True,
@@ -122,7 +121,6 @@ _FLAG_DEFS: Dict[str, Any] = {
     # --- health / failure detection ---
     # (reference gcs_health_check_manager.h:45 timings)
     "health_check_period_s": 5.0,
-    "health_check_timeout_s": 30.0,
     "num_heartbeats_timeout": 6,
     # --- health plane (straggler / silent-degradation detection) ---
     # passive-scoring cadence of the HealthMonitor loop
@@ -173,16 +171,9 @@ _FLAG_DEFS: Dict[str, Any] = {
     "transfer_push_concurrency": 8,
     # --- collective ---
     "collective_op_timeout_s": 120.0,
-    # --- compiled graphs / channels ---
-    "channel_buffer_size_bytes": 4 * 1024**2,
-    "channel_acquire_timeout_s": 60.0,
     # --- data ---
-    "data_target_block_size_bytes": 128 * 1024**2,
-    "data_max_inflight_tasks_per_op": 8,
     # unfused unordered reads stream blocks via generator tasks
     "data_streaming_reads": True,
-    # --- metrics ---
-    "metrics_report_interval_s": 5.0,
 }
 
 

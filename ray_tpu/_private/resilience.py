@@ -5,10 +5,9 @@ Reference: lineage-based retry with explicit retryable-vs-fatal error
 classification is a core primitive of the source system (Moritz et al.,
 OSDI'18 §4.2.3; ``RetryableGrpcClient``, ``src/ray/rpc/retryable_grpc_
 client.h``).  Before this module every subsystem hand-rolled its own
-reconnect loop (``bench.py`` had none at all — one transient PJRT
-``UNAVAILABLE`` zeroed a round's headline MFU number).  All control-path
-retries now share ONE taxonomy, ONE backoff policy, and ONE place to
-inject faults (``ray_tpu.util.fault_injection``):
+reconnect loop, or had none: one transient PJRT ``UNAVAILABLE`` ended
+a run.  All control-path retries now share ONE taxonomy, ONE backoff
+policy, and ONE place to inject faults (``ray_tpu.util.fault_injection``):
 
 - :func:`is_retryable` — the classifier: transport loss (socket/EOF/
   raylet RPC disconnect) and PJRT ``UNAVAILABLE`` are retryable;
@@ -21,7 +20,7 @@ inject faults (``ray_tpu.util.fault_injection``):
   successful in-session measurement.
 
 Import discipline: this module must stay importable from anywhere in the
-tree (bench script, store client, worker, serve), so it imports nothing
+tree (store client, worker, serve, scripts), so it imports nothing
 from ray_tpu at module scope.
 """
 
@@ -86,7 +85,7 @@ def is_retryable(err: BaseException) -> bool:
     if isinstance(err, RetryableTransportError):
         return True
     # raylet / peer RPC loss (lazy import: rpc.py must not be a hard dep
-    # of the bench script's classification path)
+    # of a caller that only classifies)
     try:
         from ray_tpu._private.rpc import RpcConnectionError
 
@@ -177,8 +176,7 @@ def retry_call(
 
     Fatal (unclassified) errors raise immediately; retryable errors raise
     only after ``policy.max_attempts`` tries.  ``on_retry(attempt, err,
-    delay)`` observes each retry (bench uses it to build the structured
-    degradation record).
+    delay)`` observes each retry.
     """
     attempt = 0
     while True:

@@ -355,9 +355,8 @@ class CoreWorker:
 
         Adaptive cadence: the 50 ms tick only while events are flowing.
         An IDLE worker backs off to 500 ms — at 1,000 workers per host the
-        constant tick alone was measured saturating the CPU (the envelope
-        benchmark's 1k-actor section), and idle GC latency is not worth
-        20 wakeups/s per process.
+        constant tick alone is 20,000 wakeups/s, and idle GC latency is
+        not worth 20 wakeups/s per process.
         """
         drain_every = config.ref_event_drain_interval_s
         probe_every = config.borrower_liveness_interval_s
@@ -1597,9 +1596,8 @@ class CoreWorker:
     def submit_actor_task(self, spec: TaskSpec,
                           nested_arg_refs: Optional[list] = None):
         # Fire-and-forget like submit_task: refs are deterministic, so the
-        # caller thread never blocks on a loop round trip per method call
-        # (this alone is ~2x on the 1:1 sync actor-call microbench).  A
-        # get() racing the enqueue falls back to _wait_local_location,
+        # caller thread never blocks on a loop round trip per method call.
+        # A get() racing the enqueue falls back to _wait_local_location,
         # fulfilled by the reply path.  call_soon_threadsafe preserves
         # submission order, so per-caller seq_nos stay monotonic.
         if spec.num_returns == STREAMING_RETURNS:

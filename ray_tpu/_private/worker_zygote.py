@@ -5,9 +5,8 @@ TPU-native equivalent of the reference WorkerPool's prestart capability
 process-start latency).  The reference prestarts whole idle python
 processes; here ONE zygote process pays the interpreter + heavy-import
 cost (jax alone is most of it), then every worker is an ``os.fork()``
-away — milliseconds instead of seconds, which is the difference between
-1,000 actors in minutes vs an hour (round-3 envelope: 2.4 s/worker,
-58 min for 1k actors).
+away — milliseconds instead of seconds a worker, which is what starting
+1,000 actors on one host turns on.
 
 Fork safety: the zygote imports modules but never initializes a jax
 backend, starts an event loop, or spawns threads — children initialize

@@ -22,8 +22,7 @@ In-mesh jit fusion: a method bound with ``.options(jit=True)`` promises a
 jax-traceable body; adjacent jit-marked nodes on the same actor are fused
 at compile time into ONE ``jax.jit`` program, so intermediates between
 them never leave the device (no host staging, no per-node dispatch, XLA
-fuses across node boundaries).  Cross-actor edges still host-stage —
-measured in ``benchmarks/dag_fusion_bench.py``.
+fuses across node boundaries).  Cross-actor edges still host-stage.
 
 The ``jit=True`` contract is jax's: the method must be a pure function
 of its ARGUMENTS.  Actor attributes it reads (``self.w``) are traced

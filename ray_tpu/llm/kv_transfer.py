@@ -14,7 +14,7 @@ pool slices to a **decode replica** over the PR 10 tiered channel plane
 - tier-B writes serialize the KV arrays **zero-copy straight into the
   channel segment** (pickle-5 out-of-band buffers, ONE copy of the block
   data, no host-pickle staging — the ``COPY_STATS`` write-copy counter
-  proves the 1.0x ratio, as in ``benchmarks/channel_bench.py``);
+  proves the 1.0x ratio, ``tests/test_llm_disagg.py``);
 - the decode side lands frames through the alias-guarded ``device_put``
   path (``serialization.device_rebuild_guard``): shipped block views
   never alias the reusable segment OR the live pool (the PR 5/10 aliasing

@@ -190,15 +190,11 @@ def _embed_lookup(params, tokens, cfg: LlamaConfig, *, mesh, rules=None):
     *is* the canonical activation layout — without the operand pins,
     XLA propagates the table's model-dim sharding into the output and
     the very next activation constraint forces an involuntary full
-    rematerialization (the multichip bench's per-round warning tail).
-    ``RAY_TPU_LEGACY_SHARDING=1`` restores the unpinned legacy gather
-    for the fixed-vs-legacy bench A/B.
+    rematerialization (``tests/test_sharding_discipline.py`` holds the
+    compiled step to zero such warnings).
     """
-    from ray_tpu.parallel.sharding import legacy_sharding_enabled
-
-    if mesh is None or legacy_sharding_enabled():
-        x = params["embed"][tokens].astype(cfg.dtype)
-        return _constrain(x, mesh, "batch", "seq", None, rules=rules)
+    if mesh is None:
+        return params["embed"][tokens].astype(cfg.dtype)
     table = _constrain(params["embed"], mesh, "vocab", None, rules=rules)
     toks = _constrain(tokens, mesh, "batch", "seq", rules=rules)
     x = table[toks].astype(cfg.dtype)

@@ -25,8 +25,6 @@ from ray_tpu.parallel.pipeline import (  # noqa: F401
 from ray_tpu.parallel.sharding import (  # noqa: F401
     LogicalAxisRules,
     DEFAULT_RULES,
-    ENV_LEGACY_SHARDING,
-    legacy_sharding_enabled,
     logical_to_pspec,
     spec_tree_to_shardings,
     shard_tree,
@@ -36,9 +34,4 @@ from ray_tpu.parallel.sharding import (  # noqa: F401
 from ray_tpu.parallel.xla_warnings import (  # noqa: F401
     count_sharding_warnings,
     sharding_warning_capture,
-)
-from ray_tpu.parallel.overlap import (  # noqa: F401
-    OVERLAP_TPU_FLAGS,
-    ensure_collective_overlap,
-    overlap_active,
 )

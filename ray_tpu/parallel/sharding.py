@@ -11,25 +11,10 @@ with one declarative mechanism.
 
 from __future__ import annotations
 
-import os
 from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-#: A/B escape hatch for the multichip layout-discipline bench: ``1``
-#: restores the pre-discipline constraint set (no gather-operand
-#: constraints, DEFAULT_RULES-only ``_constrain``) so a round can
-#: measure fixed-vs-legacy on identical hardware.  Read at TRACE time —
-#: set it before the trainer's first step, not mid-run.
-ENV_LEGACY_SHARDING = "RAY_TPU_LEGACY_SHARDING"
-
-
-def legacy_sharding_enabled() -> bool:
-    """True when the legacy (pre-layout-discipline) constraint set is
-    requested via :data:`ENV_LEGACY_SHARDING`."""
-    return os.environ.get(ENV_LEGACY_SHARDING, "").strip().lower() in (
-        "1", "true", "yes")
 
 # A logical axis maps to one mesh axis, a tuple of mesh axes, or None
 # (replicated).

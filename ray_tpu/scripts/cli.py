@@ -551,7 +551,7 @@ def main(argv=None):
                                     "2 internal error)")
     p.add_argument("paths", nargs="*",
                    help="files/dirs to scan, relative to --root "
-                        "(default: ray_tpu tests bench.py)")
+                        "(default: ray_tpu tests benchmarks)")
     p.add_argument("--root", default=None,
                    help="repo root (default: the tree containing the "
                         "ray_tpu package)")

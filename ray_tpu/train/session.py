@@ -52,8 +52,8 @@ class StepLedger:
     current trace, and — while a profiler session runs
     (``train.profile()``) — ``train.step`` / ``train.<bucket>``
     annotations in the profiler's trace, on the device's clock.
-    Standalone-constructible (``StepLedger(group_name="bench")``) —
-    bench.py uses it without a session.
+    Standalone-constructible (``StepLedger(group_name=...)``): it
+    needs no session.
     """
 
     BUCKETS = ("data_wait", "h2d", "compute", "collective_wait",

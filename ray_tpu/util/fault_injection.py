@@ -9,10 +9,10 @@ call — via the API::
     with fi.armed("gcs_store.call", nth=2, exc=ConnectionError("boom")):
         ...  # the 2nd store RPC in this process raises
 
-or, for subprocesses (bench, spawned workers), via the environment::
+or, for subprocesses (spawned workers, crucibles), via the environment::
 
-    RAY_TPU_FAULT_INJECT="bench.backend_init:1:2:unavailable"
-    #                      site              :nth:count:kind[:arg]
+    RAY_TPU_FAULT_INJECT="gcs_store.call:1:2:unavailable"
+    #                      site          :nth:count:kind[:arg]
 
 Spec grammar: ``site:nth[:count[:kind[:arg...]]][@start+duration]`` —
 calls ``nth .. nth+count-1`` to the site trigger the ``kind`` (see
@@ -37,7 +37,6 @@ Sites currently wired (see docs/fault_tolerance.md):
 ==========================  =================================================
 site                        guards
 ==========================  =================================================
-``bench.backend_init``      ``jax.devices()`` in bench.py
 ``gcs_store.call``          every ``ExternalStoreClient`` RPC attempt
 ``gcs_store.wal_append``    the file-store WAL write (torn-write tests)
 ``worker.lease``            the owner's ``lease_worker`` raylet RPC

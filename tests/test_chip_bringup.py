@@ -74,8 +74,7 @@ def test_spawned_worker_compiles_into_the_drivers_cache(ray_start):
 
 def test_one_place_decides_the_compile_cache():
     hits = []
-    paths = [os.path.join(REPO, "bench.py"),
-             os.path.join(REPO, "chip_smoke.py")]
+    paths = [os.path.join(REPO, "chip_smoke.py")]
     for root, _, files in os.walk(os.path.join(REPO, "ray_tpu")):
         paths += [os.path.join(root, f) for f in files if f.endswith(".py")]
     for path in paths:
@@ -157,15 +156,3 @@ def test_tpu_leases_bind_workers_to_disjoint_chips(no_cluster):
     # holds a TPU lease, cannot get a TPU: an error, never the CPU
     with pytest.raises(Exception, match="Unable to initialize backend 'tpu'"):
         ray_tpu.get(touch.remote(), timeout=120)
-
-
-def test_unknown_device_kind_has_no_peak(monkeypatch):
-    sys.path.insert(0, REPO)
-    import bench
-
-    class Dev:
-        device_kind = "TPU v99"
-
-    monkeypatch.setattr(bench.jax, "devices", lambda: [Dev()])
-    with pytest.raises(KeyError, match="TPU v99"):
-        bench.peak_flops_per_chip()

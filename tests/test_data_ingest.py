@@ -13,7 +13,6 @@ below total block-fetch time).
 """
 
 import gc
-import importlib.util
 import os
 import signal
 import threading
@@ -28,8 +27,6 @@ from ray_tpu.data.block import BlockMetadata, batch_to_block
 from ray_tpu.data.context import DataContext
 from ray_tpu.data.iterator import DataIterator, _ShuffleBuffer
 from ray_tpu.data.operators import OutputSplitter, PhysicalOperator, RefBundle
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _bundles_from_blocks(n_blocks: int, rows: int, pad_cols: int = 0):
@@ -494,15 +491,50 @@ def test_node_death_mid_lookahead_recovers_via_lineage(no_cluster):
         cluster.shutdown()
 
 
-# -- overlap smoke bench (CI gate) --------------------------------------------
+# -- the pipelined iterator overlaps a slow source with the consumer's step ---
 
 
-def _load_ingest_bench():
-    spec = importlib.util.spec_from_file_location(
-        "ingest_bench", os.path.join(_REPO, "benchmarks", "ingest_bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def _ingest_once(bundles, *, pipelined, rows, block_delay_s, step_delay_s):
+    """One pass over ``bundles`` through a DataIterator whose source
+    sleeps ``block_delay_s`` a bundle (a slow upstream) and whose consumer
+    sleeps ``step_delay_s`` a batch (a training step); forced serial =
+    lookahead and prefetch off.  Returns (wall seconds, ingest ledger)."""
+    def source():
+        for b in bundles:
+            time.sleep(block_delay_s)
+            yield b
+
+    ctx = DataContext.get_current()
+    saved = ctx.iterator_lookahead_bytes
+    ctx.iterator_lookahead_bytes = saved if pipelined else 0
+    try:
+        it = DataIterator(source)
+        t0 = time.perf_counter()
+        n = 0
+        for _batch in it.iter_batches(
+                batch_size=rows, prefetch_batches=2 if pipelined else 0):
+            time.sleep(step_delay_s)
+            n += 1
+        assert n > 0
+        return time.perf_counter() - t0, it.ingest_stats.to_dict()
+    finally:
+        ctx.iterator_lookahead_bytes = saved
+
+
+def _compare_ingest(*, blocks, rows, block_delay_s, step_delay_s):
+    rng = np.random.default_rng(0)
+    bundles = []
+    for _ in range(blocks):
+        block = batch_to_block({"x": rng.standard_normal((rows, 8)),
+                                "y": rng.integers(0, 10, rows)})
+        bundles.append(RefBundle(
+            [(ray_tpu.put(block), BlockMetadata.for_block(block))]))
+    kw = dict(rows=rows, block_delay_s=block_delay_s,
+              step_delay_s=step_delay_s)
+    serial_wall, serial = _ingest_once(bundles, pipelined=False, **kw)
+    pipe_wall, pipe = _ingest_once(bundles, pipelined=True, **kw)
+    return {"speedup": serial_wall / pipe_wall,
+            "serial_ingest": serial, "pipelined_ingest": pipe}
 
 
 def test_pipelined_ingest_beats_forced_serial(ray_start):
@@ -510,11 +542,10 @@ def test_pipelined_ingest_beats_forced_serial(ray_start):
     iterator sustains >= 1.5x the forced-serial throughput, and the stats
     ledger proves the overlap (consumer-blocked strictly below total
     block-fetch time)."""
-    bench = _load_ingest_bench()
     result = None
     for attempt in range(3):  # pipelining is timing-sensitive under load
-        result = bench.run_compare(blocks=12, rows=256,
-                                   block_delay_s=0.04, step_delay_s=0.04)
+        result = _compare_ingest(blocks=12, rows=256,
+                                 block_delay_s=0.04, step_delay_s=0.04)
         if result["speedup"] >= 1.5:
             break
     assert result["speedup"] >= 1.5, result

@@ -158,7 +158,7 @@ class TestShipperRoundTrip:
                 got = landed[0]
             # the acceptance gate: ZERO host-pickle staging copies on
             # the tier-B path — payload bytes move into the segment
-            # exactly once (channel_bench's no-double-copy counter)
+            # exactly once (the no-double-copy counter)
             ratio = COPY_STATS["bytes_copied"] / max(
                 1, COPY_STATS["payload_bytes"])
             assert ratio < 1.05, COPY_STATS
@@ -394,43 +394,6 @@ def test_two_stage_sampled_stream_surfaces_death(serve_shutdown):
                         ray_tpu.kill(rep)
                         break
     assert two.stats["reprefills"] == 0
-
-
-# ---------------------------------------------------------------------------
-# open-loop bench math (the gate record's pure pieces)
-# ---------------------------------------------------------------------------
-
-
-def test_openloop_workload_and_summary_math():
-    import argparse
-
-    from benchmarks.serving_bench import (_openloop_summary,
-                                          _openloop_workload)
-
-    args = argparse.Namespace(duration=10.0, rate=8.0, long_every=4,
-                              max_len=256, max_tokens=64, prompt_len=64)
-    reqs = _openloop_workload(args)
-    assert reqs and all(at < 10.0 for at, _k, _b in reqs)
-    kinds = [k for _at, k, _b in reqs]
-    assert kinds.count("long") == len(reqs) // 4
-    # longs are the head-of-line antagonist; shorts stream a small budget
-    for _at, kind, body in reqs:
-        if kind == "long":
-            assert len(body["prompt"]) >= 64 and body["max_tokens"] == 4
-        else:
-            assert len(body["prompt"]) == 16 and body["max_tokens"] == 16
-    samples = [
-        {"t": 0.0, "kind": "short", "latency_s": 0.1, "tokens": 16,
-         "outcome": "ok"},
-        {"t": 1.0, "kind": "short", "latency_s": 0.9, "tokens": 16,
-         "outcome": "ok"},
-        {"t": 2.0, "kind": "long", "latency_s": 0.5, "tokens": 4,
-         "outcome": "error"},
-    ]
-    s = _openloop_summary(samples, wall=2.0)
-    assert s["offered"] == 3 and s["served"] == 2 and s["errors"] == 1
-    assert s["tokens"] == 32 and s["tokens_per_s"] == 16.0
-    assert s["p99_ms"] == 900.0 and s["short_p99_ms"] == 900.0
 
 
 # ---------------------------------------------------------------------------

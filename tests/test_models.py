@@ -151,8 +151,8 @@ class TestShardedTraining:
 
 
 class TestTrainerLevers:
-    """Round-5 MFU levers: correctness on CPU (the chip side is
-    benchmarks/mfu_sweep.py; not measured on record)."""
+    """The trainer's levers (accumulation, remat policies): correctness
+    on CPU; what each is worth on the chip is not measured."""
 
     def test_grad_accumulation_matches_full_batch(self):
         """accum_steps=k over the SAME effective batch must produce the

@@ -544,7 +544,7 @@ def decoder_layer(x, mesh, rules):
     # trainers elsewhere legitimately build NamedShardings
     r = lint_tree(tmp_path, {"ray_tpu/models/bad.py": "",
                              "ray_tpu/parallel/impl.py": bad,
-                             "bench.py": bad},
+                             "benchmarks/crucible.py": bad},
                   rules=["sharding-discipline"])
     assert not r.findings, r.findings
 
@@ -573,7 +573,7 @@ from ray_tpu._private.bench_emit import emit_final_record, emit_record_line
 
 def main():
     emit_record_line({"config": "intermediate"})
-    print("MULTICHIP_TIMINGS " + json.dumps({"x": 1}))  # prefixed: legal
+    print("TIMINGS " + json.dumps({"x": 1}))  # prefixed: legal
     emit_final_record({"metric": "m", "value": 1})
 
 

@@ -446,74 +446,3 @@ class TestCpuMeshMultiProcessSmoke:
             (m["loss"], ref_loss)
         assert np.isclose(m["csum"], ref_csum, rtol=1e-4, atol=1e-2), \
             (m["csum"], ref_csum)
-
-
-# ---------------------------------------------------------------------------
-# bench multichip record (the MULTICHIP_*.json metric source)
-# ---------------------------------------------------------------------------
-
-
-class TestBenchMultichip:
-    def test_run_multichip_emits_numeric_metric(self):
-        import os
-        import sys
-
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        import bench
-
-        from ray_tpu.train import session as session_mod
-
-        rec = bench.run_multichip(preset="fsdp_tp")
-        # the bench's in-process train session must not leak out
-        assert session_mod._session is None
-        assert isinstance(rec["value"], (int, float))
-        assert rec["value"] > 0, rec
-        d = rec["detail"]
-        assert d["scope"] == "multichip_trainer_path"
-        assert d["preset"] == "fsdp_tp"
-        assert d["mesh"].get("tp") == 2
-        assert d["tokens_per_s"] > 0
-        assert d["devices"] > 1
-        # layout discipline: the record counts SPMD resharding warnings
-        # over the whole trainer-path run, and there are none
-        assert d["xla_sharding_warnings"] == 0, d
-        # the multichip record carries the same step_time_breakdown
-        # block as the single-chip record (unified assembly path)
-        bd = d["step_time_breakdown"]
-        assert "error" not in bd, bd
-        assert bd["coverage"] > 0.5, bd
-        assert set(bd["buckets_s"]) <= {
-            "data_wait", "h2d", "compute", "collective_wait",
-            "channel_wait", "checkpoint_snapshot", "checkpoint_persist",
-            "weight_publish", "other"}
-        # in-bench legacy-vs-fixed A/B: the fixed layout compiles clean
-        # and does not lose tokens/s.  The record's own `ok` keeps the
-        # strict fixed>=legacy gate; under suite load a wall-clock tie
-        # can wobble a few percent, so the TEST allows that margin —
-        # the layout claim it guards is the warning count, which is
-        # exact.
-        ab = d["sharding_ab"]
-        assert ab["fixed_warnings"] == 0, ab
-        assert ab["legacy_warnings"] >= 1, ab  # the A/B is not vacuous
-        assert ab["tokens_per_s_ratio"] >= 0.95, ab
-
-    def test_run_multichip_backend_loss_degrades_to_record(self, monkeypatch):
-        """The round-5 outage at the multichip path's jax.devices()
-        touchpoint: the record degrades structurally, never a traceback."""
-        import os
-        import sys
-
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        import bench
-
-        def dead_devices(*a, **k):
-            raise RuntimeError(
-                "Unable to initialize backend 'tpu': UNKNOWN: TPU "
-                "initialization failed: No jellyfish device found.")
-
-        monkeypatch.setattr(bench.jax, "devices", dead_devices)
-        rec = bench.run_multichip()
-        assert rec["value"] == 0.0
-        assert "backend unavailable" in rec["detail"]["error"]

@@ -8,9 +8,10 @@ Three layers:
    the sharded Llama train step on the 8-device CPU mesh for every
    ``MESH_PRESETS`` entry AND the dryrun's multi-axis / hybrid meshes,
    asserting no "involuntary full rematerialization" / last-resort
-   replicate line.  The same subprocess compiles the LEGACY constraint
-   set (``RAY_TPU_LEGACY_SHARDING=1``) on the hybrid mesh and must see
-   warnings there — proof the capture isn't vacuously quiet.
+   replicate line.  The same subprocess compiles the step once more on
+   the hybrid mesh with a mis-pinned embedding gather of its own (no
+   operand constraints) and must see warnings there — proof the capture
+   isn't vacuously quiet.
 2. **Warning-capture units** — marker counting and the fd-level
    capture actually seeing C-level fd-2 writes.
 3. **Donation** — the train step really donates the state buffers
@@ -23,7 +24,6 @@ import os
 import subprocess
 import sys
 
-import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -36,10 +36,10 @@ import sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=8")
-os.environ.pop("RAY_TPU_LEGACY_SHARDING", None)
 
 import jax
 
+from ray_tpu.models import llama
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.training import default_optimizer, make_llama_trainer
 from ray_tpu.parallel import (
@@ -49,7 +49,6 @@ from ray_tpu.parallel import (
     create_mesh,
     resolve_mesh_config,
 )
-from ray_tpu.parallel.sharding import ENV_LEGACY_SHARDING
 from ray_tpu.parallel.xla_warnings import sharding_warning_capture
 
 
@@ -82,11 +81,17 @@ for name, mesh in meshes.items():
     if lines:
         out["lines"][name] = lines[:2]
 
-# legacy arm on the hybrid mesh: the capture must SEE the resharding
-# the old constraint set provokes, or the zeros above prove nothing
-os.environ[ENV_LEGACY_SHARDING] = "1"
-out["legacy_hybrid"], _ = compile_count(meshes["hybrid_2slice"])
-os.environ.pop(ENV_LEGACY_SHARDING, None)
+# control on the hybrid mesh: a gather whose operands are not pinned (the
+# table's model-dim sharding flows into its output, then the batch
+# constraint) reshards; the capture must SEE that, or the zeros above
+# prove nothing
+def unpinned_lookup(params, tokens, cfg, *, mesh, rules=None):
+    x = params["embed"][tokens].astype(cfg.dtype)
+    return llama._constrain(x, mesh, "batch", "seq", None, rules=rules)
+
+
+llama._embed_lookup = unpinned_lookup
+out["unpinned_hybrid"], _ = compile_count(meshes["hybrid_2slice"])
 
 print("GOLDEN " + json.dumps(out))
 '''
@@ -123,9 +128,9 @@ class TestGoldenShardingGate:
                                                "hybrid_2slice"}
 
     def test_legacy_constraints_still_warn(self, golden_result):
-        """The capture is not vacuous: the pre-discipline constraint
-        set reshards on the hybrid mesh and the counter sees it."""
-        assert golden_result["legacy_hybrid"] >= 1
+        """The capture is not vacuous: a gather without the operand
+        pins reshards on the hybrid mesh and the counter sees it."""
+        assert golden_result["unpinned_hybrid"] >= 1
 
 
 class TestWarningCaptureUnits:
@@ -163,19 +168,6 @@ class TestWarningCaptureUnits:
         assert inner["text"] == "inner\n"
         assert "outer-a" in outer["text"] and "outer-b" in outer["text"]
         assert "inner" not in outer["text"]
-
-    def test_legacy_env_gate_parsing(self, monkeypatch):
-        from ray_tpu.parallel.sharding import (
-            ENV_LEGACY_SHARDING,
-            legacy_sharding_enabled,
-        )
-
-        monkeypatch.delenv(ENV_LEGACY_SHARDING, raising=False)
-        assert not legacy_sharding_enabled()
-        for val, want in (("1", True), ("true", True), ("YES", True),
-                          ("0", False), ("", False), ("no", False)):
-            monkeypatch.setenv(ENV_LEGACY_SHARDING, val)
-            assert legacy_sharding_enabled() is want, val
 
 
 class TestDonation:
@@ -232,33 +224,3 @@ class TestDonation:
             tr.step(state, batch)
         assert any("donated buffers were not usable" in str(x.message)
                    for x in w), [str(x.message) for x in w]
-
-
-class TestLayoutParity:
-    def test_fixed_and_legacy_losses_match(self, monkeypatch):
-        """The discipline changes layouts, never numerics: same mesh,
-        same params, same batch -> bit-for-bit equal loss."""
-        import jax
-
-        from ray_tpu.models.llama import LlamaConfig, llama_init, llama_loss
-        from ray_tpu.parallel import MeshConfig, create_mesh
-        from ray_tpu.parallel.sharding import ENV_LEGACY_SHARDING
-
-        mesh = create_mesh(MeshConfig(dp=2, fsdp=2, tp=2))
-        cfg = LlamaConfig.tiny(num_heads=4, num_kv_heads=4, num_layers=2)
-        params = llama_init(jax.random.PRNGKey(0), cfg)
-        batch = {"tokens": jax.random.randint(
-            jax.random.PRNGKey(1), (8, 9), 0, cfg.vocab_size)}
-
-        def loss():
-            with mesh:
-                return float(jax.jit(
-                    lambda p, b: llama_loss(p, b, cfg, mesh=mesh))(
-                        params, batch))
-
-        monkeypatch.delenv(ENV_LEGACY_SHARDING, raising=False)
-        fixed = loss()
-        monkeypatch.setenv(ENV_LEGACY_SHARDING, "1")
-        legacy = loss()
-        assert fixed == legacy
-        assert np.isfinite(fixed)

@@ -514,50 +514,3 @@ class TestStepLedger:
         snap = metrics.collect_local()
         hist = snap["train_step_bucket_s"]["histogram"]
         assert any(h["tags"].get("group") == "span-check" for h in hist)
-
-
-def test_bench_step_time_breakdown_contract():
-    """Acceptance: the bench record's step_time_breakdown bucket sum is
-    within 10% of the measured step wall, and the instrumentation
-    overhead with tracing off is <2% of the bench step."""
-    import jax
-
-    import bench
-    from ray_tpu.models.llama import LlamaConfig
-    from ray_tpu.models.training import (default_optimizer,
-                                         make_llama_trainer)
-    from ray_tpu.parallel import MeshConfig, create_mesh
-
-    cfg = LlamaConfig.tiny()
-    mesh = create_mesh(MeshConfig(dp=-1))
-    tr = make_llama_trainer(
-        cfg, mesh, optimizer=default_optimizer(warmup=1, decay_steps=100))
-    state = tr.init_state(jax.random.PRNGKey(0))
-    tokens = jax.random.randint(
-        jax.random.PRNGKey(1), (8, 129), 0, cfg.vocab_size)
-    b = tr.shard_batch({"tokens": tokens})
-    for _ in range(2):  # compile + settle
-        state, m = tr.step(state, b)
-        float(m["loss"])
-
-    # overhead is a minimum-statistic: retry a couple of times so a
-    # background-load spike cannot fail a genuinely-<2% instrumentation
-    best = None
-    for _ in range(3):
-        state, bd = bench.measure_step_breakdown(tr, state, b,
-                                                 steps=5, runs=3)
-        if best is None or bd["tracing_off_overhead_pct"] \
-                < best["tracing_off_overhead_pct"]:
-            best = bd
-        if best["tracing_off_overhead_pct"] < 2.0:
-            break
-    assert best["steps"] >= 5
-    assert set(best["buckets_s"]) >= {"compute", "other"}
-    # bucket sum within 10% of measured step wall
-    assert best["bucket_sum_s"] == pytest.approx(
-        best["step_wall_s"], rel=0.10), best
-    assert 0.9 <= best["coverage"] <= 1.1, best
-    # tracing-off overhead <2% on the bench step
-    assert best["tracing_off_overhead_pct"] < 2.0, best
-    # fractions sum to ~1 (the dashboard panel contract)
-    assert sum(best["fractions"].values()) == pytest.approx(1.0, rel=0.10)
