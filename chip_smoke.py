@@ -61,7 +61,7 @@ STREAM_PROMPT = (
     "bank while seven bright lanterns sway above the old stone bridge")
 
 # (name, batch, seq, q heads, kv heads, head dim): the train shape; a GQA
-# shape (the n_rep reduction in the dK/dV grid); a length that is not a
+# shape (the n_rep reduction in the backward grid); a length that is not a
 # multiple of the block (the pad-and-mask path)
 KERNEL_SHAPES = [("mha_b16_s1024", 16, 1024, 12, 12, 128),
                  ("gqa_32q_8kv", 2, 1024, 32, 8, 128),
@@ -308,9 +308,9 @@ class Smoke:
             return m
         self.check(m["platform"] == "tpu" and m["device_count"] == chips,
                    f"{tag} ran on {chips} tpu device(s)")
-        # forward, dQ and dK/dV kernels: interpret mode or the jnp
+        # the forward and the backward kernel: interpret mode or the jnp
         # reference would leave no custom call in the compiled step
-        self.check(m["mosaic_flash_calls"] >= 3,
+        self.check(m["mosaic_flash_calls"] >= 2,
                    f"{tag} compiled step holds the Mosaic flash kernels "
                    f"({m['mosaic_flash_calls']} custom calls)")
         used = [b for b in m["bytes_in_use"] if b]

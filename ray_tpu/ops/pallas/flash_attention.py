@@ -1,21 +1,37 @@
-"""Flash attention (forward + backward) in Pallas for TPU.
+"""Flash attention (forward + backward) in Pallas for TPU: two kernels.
 
 Forward: blockwise online-softmax attention.  For each (batch*head, q-block)
 grid cell the kernel streams K/V blocks through VMEM, keeping running
 max/normalizer in VMEM scratch that persists across the innermost (k-block)
 grid dimension — the TPU grid executes sequentially per core, so scratch is
-the accumulator carry.  QK^T and PV ride the MXU with fp32 accumulation;
-causal blocks fully above the diagonal are skipped via ``pl.when``; the
+the accumulator carry.  QK^T and PV ride the MXU with fp32 accumulation; the
 log-sum-exp is written out for the backward pass.
 
-Backward: the standard two-kernel flash decomposition with recomputed
-probabilities P = exp(S - lse):
-  - dQ kernel, grid (b*h, nq, nk): accumulates dQ over K blocks;
-  - dK/dV kernel, grid (b*kv_h, nk, n_rep*nq): accumulates dK/dV over all
-    q-heads mapped to the kv head (GQA) and all Q blocks — the reduction
-    over the grouped q-heads lives in the sequential grid, so no cross-cell
-    races.
-Both use D = rowsum(dO * O) precomputed on the VPU outside the kernels.
+Backward: one kernel, grid (b*kv_h, n_rep, nq, nk).  For a (Q block, K block)
+pair it forms P = exp(S - lse) and dP = dO V^T once and accumulates all of
+dV += P^T dO, dK += dS^T Q and dQ += dS K in fp32 VMEM scratch, each cast to
+the operands' dtype once, when its reduction ends.  dQ reduces over the
+innermost (k-block) dimension in a block-sized scratch; dK/dV reduce over
+the Q blocks and the q-heads grouped on the kv head (GQA), so they are kept
+whole, [sk, d] each, across the three inner dimensions: 2 * sk * d * 4 bytes
+(4 MiB at 4096 x 128, 32 MiB at 32768), which is why the call sets its own
+``vmem_limit_bytes`` from the shapes.  The scores are held transposed,
+[keys, queries]: lse and D = rowsum(dO * O) (precomputed outside, on the
+VPU) broadcast along sublanes as they are stored, and only dQ's product
+contracts over its operands' leading dimension.
+
+Work the causal mask does not ask for is not executed, in both kernels
+(``_visit_block``): a block wholly above the diagonal is skipped, and its
+K/V block not fetched (the index map stays on the last block needed); a
+block wholly below it is one pass with no mask built; the block the
+diagonal starts in is cut into strips of queries (two forward, four
+backward, of a 1024 block), each run against the keys up to its own end
+only, so 3/4 and 5/8 of the block's products are executed; a block that
+something else cuts (the pad of the last K block, a diagonal through
+unequal blocks when sq != sk) runs whole under its mask.  The strips of a
+block share one basic block: tiles in regions of their own ran at half
+the MXU's rate on a v5e, and ``lax.cond`` around a mask cost more than
+the mask.
 
 Sequences are padded to the block size and pad K positions masked, so any
 length works.  GQA is handled by index-mapping q-heads onto kv heads — no
@@ -32,6 +48,78 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+_NT = (((1,), (1,)), ((), ()))  # A B^T
+_NN = (((1,), (0,)), ((), ()))  # A B
+_TN = (((0,), (0,)), ((), ()))  # A^T B
+
+
+def _strip(block, parts):
+    """Rows of the strips a block on the diagonal is cut into: the block
+    in up to ``parts``, each a whole number of 128-lane vector tiles."""
+    for n in range(parts, 1, -1):
+        if block % (n * 128) == 0:
+            return block // n
+    return block
+
+
+def _masked(scores, causal, k_padded, r0, c0, q_axis, seq_k):
+    """``scores`` of queries from r0 (along ``q_axis``) against keys from
+    c0, with what the mask forbids set to _NEG_INF."""
+    qpos = r0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, q_axis)
+    kpos = c0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1 - q_axis)
+    mask = None
+    if causal:
+        mask = qpos >= kpos
+    if k_padded:  # pad K positions contribute nothing
+        pad = kpos < seq_k
+        mask = pad if mask is None else jnp.logical_and(mask, pad)
+    return jnp.where(mask, scores, _NEG_INF)
+
+
+def _visit_block(tile_fn, *, causal, k_padded, qi, ki, last_k, block_q,
+                 block_k, strip, seq_k):
+    """Run ``tile_fn(i0, rows, cols, mask)``, queries i0.. of block qi
+    against the first ``cols`` keys of block ki, over what the block needs:
+
+    - nothing, above the diagonal;
+    - the whole block with no mask built (``mask`` None), below it;
+    - on it (equal blocks: where it starts), strip by strip, each strip's
+      queries against the keys up to them, all in one basic block;
+    - the whole block masked where something else cuts through it: the
+      pad of the last K block, a diagonal through unequal blocks.
+
+    ``mask(scores, q_axis)`` returns the scores masked."""
+    r0, c0 = qi * block_q, ki * block_k
+    whole = functools.partial(tile_fn, 0, block_q, block_k)
+
+    def mask(i0):
+        return lambda scores, q_axis: _masked(
+            scores, causal, k_padded, r0 + i0, c0, q_axis, seq_k)
+
+    if not causal and not k_padded:
+        whole(None)
+        return
+    run, cut = True, False  # any of it asked for; anything cuts through it
+    if causal:
+        run = r0 + block_q - 1 >= c0
+        cut = r0 < c0 + block_k - 1
+    if k_padded:
+        cut = jnp.logical_or(cut, last_k)
+    pl.when(jnp.logical_and(run, jnp.logical_not(cut)))(
+        functools.partial(whole, None))
+    strips = causal and block_q == block_k and strip < block_q
+    if strips:
+        on_diag = r0 == c0  # equal blocks: no other block is crossed
+
+        @pl.when(on_diag)
+        def _diagonal():
+            for i0 in range(0, block_q, strip):
+                tile_fn(i0, strip, i0 + strip, mask(i0))
+
+        cut = jnp.logical_and(cut, jnp.logical_not(on_diag))
+    if k_padded or not strips:
+        pl.when(jnp.logical_and(run, cut))(
+            functools.partial(whole, mask(0)))
 
 
 # ---------------------------------------------------------------------------
@@ -40,7 +128,7 @@ _NEG_INF = -1e30
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale,
-    causal, block_q, block_k, num_kblocks, seq_k
+    causal, k_padded, block_q, block_k, seq_k
 ):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -51,44 +139,36 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _compute():
-        q = q_ref[0]  # [block_q, d]
-        k = k_ref[0]  # [block_k, d]
+    def tile(i0, rows, cols, mask):
+        qs, ks = pl.ds(i0, rows), pl.ds(0, cols)
+        q = q_ref[0, qs, :]  # [rows, d]
+        k = k_ref[0, ks, :]  # [cols, d]
         logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        qpos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        kpos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = kpos < seq_k  # pad K positions contribute nothing
-        if causal:
-            mask = jnp.logical_and(mask, qpos >= kpos)
-        logits = jnp.where(mask, logits, _NEG_INF)
-        m_prev = m_scr[:]
+            q, k, _NT, preferred_element_type=jnp.float32) * scale
+        if mask is not None:
+            logits = mask(logits, 0)
+        m_prev = m_scr[qs, :]
         m_blk = jnp.max(logits, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_blk)
         p = jnp.exp(logits - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0]
+        l_scr[qs, :] = l_scr[qs, :] * alpha + jnp.sum(
+            p, axis=-1, keepdims=True)
+        v = v_ref[0, ks, :]
         pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = m_new
+            p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
+        acc_scr[qs, :] = acc_scr[qs, :] * alpha + pv
+        m_scr[qs, :] = m_new
 
-    if causal:
-        # Skip k-blocks strictly above the causal diagonal.
-        pl.when(ki * block_k <= qi * block_q + block_q - 1)(_compute)
-    else:
-        _compute()
+    last_k = ki == pl.num_programs(2) - 1
+    # two strips: the chain QK^T -> softmax -> PV of a strip does not
+    # overlap, so finer strips cost more in latency than they skip
+    _visit_block(
+        tile, causal=causal, k_padded=k_padded, qi=qi, ki=ki, last_k=last_k,
+        block_q=block_q, block_k=block_k, strip=_strip(block_q, 2),
+        seq_k=seq_k)
 
-    @pl.when(ki == num_kblocks - 1)
+    @pl.when(last_k)
     def _finalize():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
@@ -111,6 +191,15 @@ def _fold_heads(x):
     return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
 
+def _last_k_block(causal, qi, ki, block_q, block_k):
+    """The K block to hold at grid step (qi, ki): ki, but no further than
+    the last one a causal Q block needs, so that a skipped step fetches
+    nothing (an unchanged block index is not copied again)."""
+    if not causal:
+        return ki
+    return jnp.minimum(ki, (qi * block_q + block_q - 1) // block_k)
+
+
 def _flash_fwd_impl(q, k, v, *, causal, block_q, block_k, interpret):
     b, sq, h, d = q.shape
     sk, kv_h = k.shape[1], k.shape[2]
@@ -129,14 +218,15 @@ def _flash_fwd_impl(q, k, v, *, causal, block_q, block_k, interpret):
         return (bh, qi, 0)
 
     def kv_map(bh, qi, ki):
-        return ((bh // h) * kv_h + (bh % h) // n_rep, ki, 0)
+        return ((bh // h) * kv_h + (bh % h) // n_rep,
+                _last_k_block(causal, qi, ki, block_q, block_k), 0)
 
     def lse_map(bh, qi, ki):
         return (bh, 0, qi)
 
     kernel = functools.partial(
-        _fwd_kernel, scale=d ** -0.5, causal=causal, block_q=block_q,
-        block_k=block_k, num_kblocks=nk, seq_k=sk,
+        _fwd_kernel, scale=d ** -0.5, causal=causal, k_padded=sk_p != sk,
+        block_q=block_q, block_k=block_k, seq_k=sk,
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -162,111 +252,81 @@ def _flash_fwd_impl(q, k, v, *, causal, block_q, block_k, interpret):
         interpret=interpret,
     )(qt, kt, vt)
     out = out.reshape(b, h, sq_p, d).transpose(0, 2, 1, 3)[:, :sq]
-    return out, lse  # lse stays padded/folded for the backward kernels
+    return out, lse  # lse stays padded/folded for the backward kernel
 
 
 # ---------------------------------------------------------------------------
 # Backward
 # ---------------------------------------------------------------------------
 
-def _recompute_p(q, k, lse, *, scale, causal, block_q, block_k, qi, ki,
-                 seq_k):
-    """P block = exp(S - lse), with pad/causal masking. fp32 [bq, bk]."""
-    s_blk = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    qpos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    kpos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-    mask = kpos < seq_k
-    if causal:
-        mask = jnp.logical_and(mask, qpos >= kpos)
-    s_blk = jnp.where(mask, s_blk, _NEG_INF)
-    return jnp.exp(s_blk - lse[:, None])
-
-
-def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, dq_scr, *,
-    scale, causal, block_q, block_k, num_kblocks, seq_k
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, dk_ref, dv_ref,
+    dq_scr, dk_scr, dv_scr, *, scale, causal, k_padded, block_q, block_k,
+    seq_k
 ):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    rep, qi, ki = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    first_q = jnp.logical_and(rep == 0, qi == 0)
+    last_q = jnp.logical_and(rep == pl.num_programs(1) - 1,
+                             qi == pl.num_programs(2) - 1)
+    last_k = ki == pl.num_programs(3) - 1
 
-    @pl.when(ki == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    def _compute():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        p = _recompute_p(
-            q, k, lse_ref[0, 0], scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, qi=qi, ki=ki, seq_k=seq_k,
-        )
-        dp = jax.lax.dot_general(  # dO V^T: [bq, bk]
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - dd_ref[0, 0][:, None])
-        dq_scr[:] += scale * jax.lax.dot_general(  # dS K: [bq, d]
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    if causal:
-        pl.when(ki * block_k <= qi * block_q + block_q - 1)(_compute)
-    else:
-        _compute()
-
-    @pl.when(ki == num_kblocks - 1)
-    def _emit():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref,
-    dk_scr, dv_scr, *, scale, causal, block_q, block_k, num_inner, nq, seq_k
-):
-    ki = pl.program_id(1)
-    j = pl.program_id(2)  # j = rep * nq + qi
-    qi = j % nq
-
-    @pl.when(j == 0)
-    def _init():
+    @pl.when(jnp.logical_and(first_q, ki == 0))
+    def _init_kv():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _compute():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        p = _recompute_p(
-            q, k, lse_ref[0, 0], scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, qi=qi, ki=ki, seq_k=seq_k,
-        )
-        dv_scr[:] += jax.lax.dot_general(  # P^T dO: [bk, d]
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - dd_ref[0, 0][:, None])
-        dk_scr[:] += scale * jax.lax.dot_general(  # dS^T Q: [bk, d]
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    @pl.when(ki == 0)
+    def _init_q():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    if causal:
-        pl.when(qi * block_q + block_q - 1 >= ki * block_k)(_compute)
-    else:
-        _compute()
+    def tile(i0, rows, cols, mask):
+        qs, ks = pl.ds(i0, rows), pl.ds(0, cols)
+        q, do = q_ref[0, qs, :], do_ref[0, qs, :]  # [rows, d]
+        k, v = k_ref[0, ks, :], v_ref[0, ks, :]  # [cols, d]
+        # rows of the whole-sequence dK/dV scratch this tile adds to
+        kv = pl.ds(pl.multiple_of(ki * block_k, block_k), cols)
+        # scores transposed, [cols, rows]: lse and D broadcast as stored
+        s_t = jax.lax.dot_general(
+            k, q, _NT, preferred_element_type=jnp.float32) * scale
+        if mask is not None:
+            s_t = mask(s_t, 1)
+        p_t = jnp.exp(s_t - lse_ref[0, :, qs])
+        dv_scr[kv, :] += jax.lax.dot_general(  # P^T dO: [cols, d]
+            p_t.astype(do.dtype), do, _NN,
+            preferred_element_type=jnp.float32)
+        dp_t = jax.lax.dot_general(  # V dO^T: [cols, rows]
+            v, do, _NT, preferred_element_type=jnp.float32)
+        ds_t = (p_t * (dp_t - dd_ref[0, :, qs])).astype(q.dtype)
+        dk_scr[kv, :] += scale * jax.lax.dot_general(  # dS^T Q: [cols, d]
+            ds_t, q, _NN, preferred_element_type=jnp.float32)
+        dq_scr[qs, :] += scale * jax.lax.dot_general(  # dS K: [rows, d]
+            ds_t, k, _TN, preferred_element_type=jnp.float32)
 
-    @pl.when(j == num_inner - 1)
-    def _emit():
+    # four strips: the five products keep the MXU busy, so skipped work
+    # is saved time
+    _visit_block(
+        tile, causal=causal, k_padded=k_padded, qi=qi, ki=ki, last_k=last_k,
+        block_q=block_q, block_k=block_k, strip=_strip(block_q, 4),
+        seq_k=seq_k)
+
+    @pl.when(last_k)
+    def _emit_q():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(last_q, last_k))
+    def _emit_kv():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _bwd_vmem_bytes(block_q, block_k, sk_p, d, itemsize):
+    """What the backward call may use of VMEM: the whole-sequence fp32
+    dK/dV scratch and their output blocks (both buffers), the streamed
+    blocks, and a whole block's fp32 scores and their siblings."""
+    whole = 2 * sk_p * d * (4 + 2 * itemsize)
+    streamed = 2 * (3 * block_q + 2 * block_k) * d * itemsize
+    scores = 6 * block_q * block_k * 4
+    return whole + streamed + scores + block_q * d * 4 + (8 << 20)
 
 
 def _flash_bwd_impl(res, g, *, causal, block_q, block_k, interpret):
@@ -286,29 +346,35 @@ def _flash_bwd_impl(res, g, *, causal, block_q, block_k, interpret):
 
     qt, kt, vt = _fold_heads(qp), _fold_heads(kp), _fold_heads(vp)
     dot, got = _fold_heads(op), _fold_heads(gp)
-    # D = rowsum(dO * O): cheap VPU work, done outside the kernels.
+    # D = rowsum(dO * O): cheap VPU work, done outside the kernel.
     dd = jnp.sum(
         got.astype(jnp.float32) * dot.astype(jnp.float32), axis=-1
     )[:, None, :]  # [b*h, 1, sq_p]
 
-    scale = d ** -0.5
+    # Grid (b*kv_h, n_rep, nq, nk): dQ's reduction is the innermost
+    # dimension; dK/dV's, over grouped q-heads and Q blocks, spans the
+    # three inner ones, all sequential, so no cross-cell races.
+    def head(bkv, rep):
+        return (bkv // kv_h) * h + (bkv % kv_h) * n_rep + rep
 
-    # --- dQ ----------------------------------------------------------------
-    def q_map(bh, qi, ki):
-        return (bh, qi, 0)
+    def q_map(bkv, rep, qi, ki):
+        return (head(bkv, rep), qi, 0)
 
-    def kv_map(bh, qi, ki):
-        return ((bh // h) * kv_h + (bh % h) // n_rep, ki, 0)
+    def kv_map(bkv, rep, qi, ki):
+        return (bkv, _last_k_block(causal, qi, ki, block_q, block_k), 0)
 
-    def lse_map(bh, qi, ki):
-        return (bh, 0, qi)
+    def lse_map(bkv, rep, qi, ki):
+        return (head(bkv, rep), 0, qi)
 
-    dq = pl.pallas_call(
+    def whole_kv_map(bkv, rep, qi, ki):
+        return (bkv, 0, 0)
+
+    dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _dq_kernel, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, num_kblocks=nk, seq_k=sk,
+            _bwd_kernel, scale=d ** -0.5, causal=causal,
+            k_padded=sk_p != sk, block_q=block_q, block_k=block_k, seq_k=sk,
         ),
-        grid=(b * h, nq, nk),
+        grid=(b * kv_h, n_rep, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_map),
             pl.BlockSpec((1, block_k, d), kv_map),
@@ -316,60 +382,28 @@ def _flash_bwd_impl(res, g, *, causal, block_q, block_k, interpret):
             pl.BlockSpec((1, block_q, d), q_map),
             pl.BlockSpec((1, 1, block_q), lse_map),
             pl.BlockSpec((1, 1, block_q), lse_map),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), q_map),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(qt, kt, vt, got, lse, dd)
-    dq = dq.reshape(b, h, sq_p, d).transpose(0, 2, 1, 3)[:, :sq]
-
-    # --- dK/dV -------------------------------------------------------------
-    # Grid (b*kv_h, nk, n_rep*nq): the reduction over grouped q-heads and
-    # q-blocks runs inside the sequential inner grid dimension.
-    num_inner = n_rep * nq
-
-    def q_map2(bkv, ki, j):
-        batch, kvh_idx = bkv // kv_h, bkv % kv_h
-        rep, qi = j // nq, j % nq
-        return (batch * h + kvh_idx * n_rep + rep, qi, 0)
-
-    def kv_map2(bkv, ki, j):
-        return (bkv, ki, 0)
-
-    def lse_map2(bkv, ki, j):
-        batch, kvh_idx = bkv // kv_h, bkv % kv_h
-        rep, qi = j // nq, j % nq
-        return (batch * h + kvh_idx * n_rep + rep, 0, qi)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, num_inner=num_inner, nq=nq, seq_k=sk,
-        ),
-        grid=(b * kv_h, nk, num_inner),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), q_map2),
-            pl.BlockSpec((1, block_k, d), kv_map2),
-            pl.BlockSpec((1, block_k, d), kv_map2),
-            pl.BlockSpec((1, block_q, d), q_map2),
-            pl.BlockSpec((1, 1, block_q), lse_map2),
-            pl.BlockSpec((1, 1, block_q), lse_map2),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), kv_map2),
-            pl.BlockSpec((1, block_k, d), kv_map2),
+            pl.BlockSpec((1, block_q, d), q_map),
+            pl.BlockSpec((1, sk_p, d), whole_kv_map),
+            pl.BlockSpec((1, sk_p, d), whole_kv_map),
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
             jax.ShapeDtypeStruct((b * kv_h, sk_p, d), k.dtype),
             jax.ShapeDtypeStruct((b * kv_h, sk_p, d), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((sk_p, d), jnp.float32),
+            pltpu.VMEM((sk_p, d), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_bwd_vmem_bytes(
+                block_q, block_k, sk_p, d, q.dtype.itemsize)),
         interpret=interpret,
     )(qt, kt, vt, got, lse, dd)
+    dq = dq.reshape(b, h, sq_p, d).transpose(0, 2, 1, 3)[:, :sq]
     dk = dk.reshape(b, kv_h, sk_p, d).transpose(0, 2, 1, 3)[:, :sk]
     dv = dv.reshape(b, kv_h, sk_p, d).transpose(0, 2, 1, 3)[:, :sk]
     return dq, dk, dv

@@ -1,0 +1,76 @@
+"""The flash kernels compiled for a v5e that is described, not attached.
+
+The interpreter cannot see what the chip's compiler refuses: more VMEM
+than a kernel may use (the backward keeps dK/dV whole in scratch and sets
+its own ``vmem_limit_bytes``), a slice off the (8, 128) tiling.  libtpu is
+installed in the sandbox and compiles for a topology by name, in about two
+seconds a shape and at no chip time.  Nothing runs: no result, no time.
+
+Only the worker that is given this file may load libtpu, and only once a
+test has started: the topology is described in a fixture, never at import.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.ops.pallas import flash_attention as fa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    with pytest.MonkeyPatch.context() as env:
+        env.setenv("TPU_LOG_DIR", "disabled")
+        env.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+        env.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip is written to the persistent
+        # cache but cannot be read back without one
+        cache_was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+def _compile_grad(one_chip, b, sq, sk, h, kvh, d, causal):
+    def shape(s, heads):
+        return jax.ShapeDtypeStruct((b, s, heads, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        # interpret=False: the backend here is the CPU, the target is not
+        out = fa._flash(q, k, v, causal, 1024, 1024, False)
+        return out.astype(jnp.float32).sum()
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(sq, h), shape(sk, kvh), shape(sk, kvh)).compile()
+
+
+@pytest.mark.parametrize("b,sq,sk,h,kvh,d,causal", [
+    pytest.param(4, 4096, 4096, 32, 8, 128, True, id="train-1chip-s4096"),
+    pytest.param(1, 32768, 32768, 32, 8, 128, True, id="published-length"),
+    pytest.param(1, 32768, 32768, 8, 8, 128, True, id="published-length-mha"),
+    pytest.param(2, 1000, 1000, 12, 12, 128, True, id="padded"),
+    pytest.param(2, 2048, 4096, 8, 2, 128, False, id="full-unequal"),
+])
+def test_forward_and_backward_compile_as_two_kernels(
+        one_chip, b, sq, sk, h, kvh, d, causal):
+    text = _compile_grad(one_chip, b, sq, sk, h, kvh, d, causal).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_a_sequence_whose_scratch_outgrows_vmem_is_refused_at_lowering(
+        one_chip):
+    """dK/dV whole in float32 at S = 65 536 are 64 MiB beside as much in
+    output blocks: past the v5e's 128 MiB, and the compiler says so."""
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _compile_grad(one_chip, 1, 65536, 65536, 8, 8, 128, True)

@@ -1,5 +1,7 @@
 """Tests for ray_tpu.ops: attention kernels, norms, rope."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -41,6 +43,40 @@ class TestReferenceAttention:
         np.testing.assert_allclose(ours, jaxs, atol=1e-5)
 
 
+def _pallas_calls(jaxpr, path=""):
+    """(path of enclosing primitives, number of results) of every
+    ``pallas_call`` in a jaxpr and the jaxprs nested in it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((path, len(eqn.outvars)))
+        for sub in eqn.params.values():
+            for inner in sub if isinstance(sub, (list, tuple)) else [sub]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    found += _pallas_calls(
+                        inner, f"{path}/{eqn.primitive.name}")
+    return found
+
+
+# (causal, heads, kv heads, sq, sk, block).  Block 32: today's cases, no
+# strips (a block under two vector tiles is not cut).  Block 256 is cut in
+# strips of 128: 768 meets whole blocks below the diagonal, blocks on it
+# and the part of them above it; 700 pads the last block; 100 is shorter
+# than a block; 300 against 600 has unequal lengths.
+_GRAD_CASES = [
+    pytest.param(True, 4, 2, 64, 64, 32, id="s64-block32"),
+    pytest.param(True, 4, 2, 48, 48, 32, id="s48-block32-padded"),
+] + [
+    pytest.param(causal, 4, kvh, sq, sk, 256,
+                 id=f"{'causal' if causal else 'full'}-rep{4 // kvh}-{name}")
+    for causal in (True, False)
+    for kvh in (4, 1)
+    for name, sq, sk in (("multiple", 768, 768), ("padded", 700, 700),
+                         ("short", 100, 100), ("unequal", 300, 600))
+]
+
+
 class TestFlashAttention:
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_reference(self, causal):
@@ -50,19 +86,47 @@ class TestFlashAttention:
         # Interpret mode emulates MXU bf16 matmul precision.
         np.testing.assert_allclose(out, ref, atol=2e-2)
 
-    # s=48 exercises the backward padding path (not a block multiple).
-    @pytest.mark.parametrize("s", [64, 48])
-    def test_grad_matches_reference(self, s):
-        q, k, v = _qkv(s=s)
-        g = jax.grad(
-            lambda *a: flash_attention(*a, block_q=32, block_k=32).sum(),
-            argnums=(0, 1, 2),
-        )(q, k, v)
-        gr = jax.grad(
-            lambda *a: reference_attention(*a).sum(), argnums=(0, 1, 2)
-        )(q, k, v)
-        for a, b in zip(g, gr):
-            np.testing.assert_allclose(a, b, atol=2e-2)
+    @pytest.mark.parametrize("causal,h,kvh,sq,sk,block", _GRAD_CASES)
+    def test_grad_matches_reference(self, causal, h, kvh, sq, sk, block):
+        ks = jax.random.split(jax.random.PRNGKey(0), 4)
+        q = jax.random.normal(ks[0], (2, sq, h, 32))
+        k = jax.random.normal(ks[1], (2, sk, kvh, 32))
+        v = jax.random.normal(ks[2], (2, sk, kvh, 32))
+        # a random cotangent: under loss = sum(out) dO is constant and a
+        # transposed or mis-indexed dO block would go unnoticed
+        w = jax.random.normal(ks[3], (2, sq, h, 32))
+
+        def grads(attn):
+            return jax.grad(
+                lambda *a: (attn(*a, causal=causal) * w).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+
+        g = grads(functools.partial(
+            flash_attention, block_q=block, block_k=block))
+        gr = grads(reference_attention)
+        for name, a, b in zip(("dq", "dk", "dv"), g, gr):
+            np.testing.assert_allclose(a, b, atol=2e-3, err_msg=name)
+
+    def test_one_forward_and_one_backward_kernel_a_layer(self):
+        """Under ``save_attn`` the gradient of a scanned model holds one
+        ``pallas_call`` in the forward layer body (the forward kernel: out
+        and lse) and one in the backward body (the one backward kernel:
+        dq, dk, dv): no forward replay, no second backward kernel."""
+        from ray_tpu.models.llama import LlamaConfig, llama_init, llama_loss
+
+        def kernels(policy):
+            cfg = LlamaConfig.tiny(attention_impl="flash",
+                                   remat_policy=policy)
+            params = llama_init(jax.random.PRNGKey(0), cfg)
+            batch = {"tokens": jnp.zeros((1, 33), jnp.int32)}
+            jaxpr = jax.make_jaxpr(jax.grad(
+                lambda p: llama_loss(p, batch, cfg)))(params)
+            return sorted(_pallas_calls(jaxpr.jaxpr))
+
+        assert kernels("save_attn") == [("/scan", 2), ("/scan/remat2", 3)]
+        # the control: with nothing saved the forward kernel is replayed
+        assert kernels("full") == [
+            ("/scan", 2), ("/scan/remat2", 2), ("/scan/remat2", 3)]
 
 
 class TestRingAttention:
