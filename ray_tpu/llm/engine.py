@@ -18,6 +18,14 @@ batching, paged attention, automatic prefix caching,
   gather + mask); prefill compiles per power-of-2 (suffix, prefix) bucket.
   Host-side scheduling (admit/preempt/retire) is plain numpy — no jit
   boundary crossings beyond the two program calls.
+* **Layer types**: a model whose layers do not all keep the same positions
+  (window and full attention mixed: ``ServedModel.layer_types``) gets a
+  pool, a block manager and a block table PER TYPE.  A window type's table
+  keeps logical indexing; an entry wholly behind the window is the scratch
+  block and its block is back in that pool's free list, while the request
+  decodes (``_release_behind_window``) and from admission on for a prompt
+  longer than the window.  A model without the field is one type, and
+  everything below is what it was.
 * **Preemption**: out of blocks mid-decode → the youngest request is
   rolled back to the queue (its tokens re-prefill later), matching vLLM's
   recompute-preemption policy.
@@ -80,6 +88,11 @@ class Request:
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
     blocks: List[int] = dataclasses.field(default_factory=list)
+    # a model with several layer types: the blocks held in each pool after
+    # the first (``LLMEngine._more``'s order), by logical index as
+    # ``blocks`` is, 0 where a window type's block was never allocated or
+    # has been given back
+    more_blocks: List[List[int]] = dataclasses.field(default_factory=list)
     # chunked prefill: blocks already written for this prompt, refs HELD
     # (pinned against ORDINARY pool pressure; forfeited by
     # _yield_chunk_pins when a starved queue head needs the pool);
@@ -257,6 +270,21 @@ class _BlockManager:
 
 
 @dataclasses.dataclass
+class _LayerPool:
+    """One layer type's share of the cache on the host: its block manager,
+    its ``[B, MB]`` table, and the window behind which its blocks are dead
+    (None: never)."""
+    name: str
+    layers: int
+    window: Optional[int]
+    blocks: _BlockManager
+    tables: np.ndarray
+
+    def held(self) -> int:
+        return self.blocks.num_blocks - 1 - self.blocks.available()
+
+
+@dataclasses.dataclass
 class _Window:
     """A dispatched decode window whose tokens the host has not fetched."""
     out_d: Any  # [k, B (+ the model's counters)] int32, on its way over
@@ -288,6 +316,13 @@ class LLMEngine:
                       not spec_tokens or model.verify_step is not None)
         model.require("parameter specs for an engine with a mesh",
                       mesh is None or model.param_specs is not None)
+        # {type: {"layers", "window"}} of a model whose layers do not all
+        # keep the same positions; None: one type, one pool, one table
+        types = model.layer_types(cfg) if model.layer_types else None
+        model.require("chunked prefill (prefill_chunk) for a model with "
+                      "several layer types: it resumes through prefix "
+                      "hits, which such a model does not take",
+                      not prefill_chunk or types is None)
         self.cfg = cfg
         self.mesh = mesh
         self.tokenizer = tokenizer or default_tokenizer(cfg.vocab_size)
@@ -295,9 +330,21 @@ class LLMEngine:
         self.max_len = max_len or cfg.max_seq_len
         self.bs = block_size
         self.MB = -(-self.max_len // block_size)  # blocks per sequence
+        # multi-step window: K on-device steps chained without any host
+        # sync (token/position/key stay device-resident), sampled tokens
+        # fetched ONCE per window — the host↔device round trip
+        # amortizes over window*slots tokens
+        self.K = max(1, decode_window)
         # default pool = dense-equivalent capacity (callers can shrink it:
         # prefix sharing + short requests usually need far less)
-        self.num_blocks = num_blocks or (self.B * self.MB + 1)
+        if types is None:
+            self.num_blocks = num_blocks or (self.B * self.MB + 1)
+        else:  # an int is every type's; a window type never holds more
+            self.num_blocks = {
+                t: (num_blocks.get(t) if isinstance(num_blocks, dict)
+                    else num_blocks)
+                or self.B * min(self.MB, self._window_blocks(
+                    spec["window"])) + 1 for t, spec in types.items()}
         if params is None:
             params = model.init(jax.random.PRNGKey(seed), cfg)
         self.params = params
@@ -310,12 +357,22 @@ class LLMEngine:
                                     kv_dtype=kv_cache_dtype)
         if mesh is not None:
             self._shard_over_mesh(mesh)
-        self.blocks = _BlockManager(self.num_blocks)
-        # multi-step window: K on-device steps chained without any host
-        # sync (token/position/key stay device-resident), sampled tokens
-        # fetched ONCE per window — the host↔device round trip
-        # amortizes over window*slots tokens
-        self.K = max(1, decode_window)
+        # the host's side of each pool; ``blocks`` / ``_tables`` are the
+        # first's (the only one's, for a model of one layer type), whose
+        # blocks a request holds in ``Request.blocks``; ``_more`` the rest
+        one = {"kv": {"layers": cfg.num_layers, "window": None}}
+        sizes = self.num_blocks if types else {"kv": self.num_blocks}
+        pools = [_LayerPool(t, spec["layers"], spec["window"],
+                            _BlockManager(sizes[t]),
+                            np.zeros((self.B, self.MB), np.int32))
+                 for t, spec in (types or one).items()]
+        if pools[0].window is not None:
+            raise ValueError(f"{model.name}: the first layer type keeps "
+                             f"every position (a slot is live where its "
+                             f"first table holds a block)")
+        self._pools, self._more = pools, pools[1:]
+        self._by_type = types is not None  # programs take tables by type
+        self.blocks = pools[0].blocks
         # the decode step's attention, read off what is in front of us:
         # "paged_kernel" / "latent_kernel" (live blocks read in place) for
         # a dense / latent pool on one TPU device without speculation,
@@ -341,6 +398,8 @@ class LLMEngine:
             (*model.counters, "decode_steps",
              *(f"prefill_{n}" for n in model.counters), "prefill_calls")
             if model.counters else (), 0)
+        if any(p.window for p in pools):
+            self.counters["window_blocks_released"] = 0
         # every model: windows dispatched, and how many of them were
         # launched before the emit of the window before (step())
         self.counters.update(decode_windows=0, windows_carried=0)
@@ -421,7 +480,7 @@ class LLMEngine:
         self._slots: List[Optional[Request]] = [None] * self.B
         self._cur_len = np.zeros(self.B, np.int32)
         self._next_token = np.zeros(self.B, np.int32)
-        self._tables = np.zeros((self.B, self.MB), np.int32)
+        self._tables = pools[0].tables
         # device mirrors of the decode inputs, kept resident across
         # windows: re-uploading unchanged tables/temps/token/cur costs a
         # dispatch each through a high-latency link.  A changed table row
@@ -732,13 +791,13 @@ class LLMEngine:
             # adaptive window: never decode past what the
             # longest-running active request can still accept
             window_k = self._window_arity(active)
-            live = int(self._cur_len[active].sum())  # host mirror
+            live = self._live_tokens(active)  # host mirror
             self._refresh_device_mirrors()
             tok_d, cur_d = self._dev
             key_d = self._key
         with tracing.annotate(
                 "engine.dispatch_window", k=window_k, active=len(active),
-                live_tokens=live, attn=self.attn, carried=int(carried)):
+                attn=self.attn, carried=int(carried), **live):
             toks, counts = [], []
             for _ in range(window_k):  # device-chained: no host sync
                 tok_d, cur_d, key_d, self.pool, *extra = self._decode1(
@@ -758,6 +817,75 @@ class LLMEngine:
             self.counters["windows_carried"] += int(carried)
             del toks, counts, extra  # freed inside the phase, as above
         return _Window(out_d, window_k, active, t_arm)
+
+    def _live_tokens(self, active: List[int]) -> Dict[str, int]:
+        """``engine.dispatch_window``'s ``live_tokens``: the cached
+        positions a decode step attends over, all slots together.  With
+        several layer types it is the mean over the layers, a window type
+        counting at most its window (so that positions x the bytes a
+        position takes over ALL layers is what a step reads), beside each
+        type's own count and the blocks each pool holds."""
+        lens = self._cur_len[active]
+        if not self._by_type:
+            return {"live_tokens": int(lens.sum())}
+        by = {p.name: int((np.minimum(lens, p.window) if p.window
+                           else lens).sum()) for p in self._pools}
+        layers = sum(p.layers for p in self._pools)
+        out = {"live_tokens": round(sum(
+            by[p.name] * p.layers for p in self._pools) / layers)}
+        for p in self._pools:
+            out[f"live_tokens_{p.name}"] = by[p.name]
+            out[f"blocks_held_{p.name}"] = p.held()
+        return out
+
+    def _window_blocks(self, window: Optional[int]) -> int:
+        """The most blocks a slot holds in a pool whose blocks die behind
+        ``window``: the window, a decode window ahead of it, and the block
+        that either end cuts."""
+        if window is None:
+            return self.MB
+        return (window + self.K - 2) // self.bs + 2
+
+    def _release_behind_window(self, i: int, req: Request) -> None:
+        """Give back slot i's blocks that lie wholly behind a layer type's
+        window.  The next step writes position ``cur_len`` and sees no
+        position before ``cur_len + 1 - window``, and no later step sees
+        further back; the window that was in flight has been fetched, and
+        the next launch uploads the tables.  The entry becomes the scratch
+        block, which the kernel never reads for a position before the
+        window."""
+        for p, held in zip(self._more, req.more_blocks):
+            if p.window is None:
+                continue
+            dead = min(max(0, int(self._cur_len[i]) + 1 - p.window)
+                       // self.bs, len(held))
+            first = dead  # the held run ends where the last release did
+            while first and held[first - 1]:
+                first -= 1
+            if first == dead:
+                continue
+            for b in range(first, dead):
+                p.blocks.release(held[b])
+                held[b] = 0
+            p.tables[i, first:dead] = 0
+            self._dev_dirty = True
+            self.counters["window_blocks_released"] += dead - first
+
+    def _release_blocks(self, req: Request) -> None:
+        """Every block the request holds, in every pool."""
+        for bid in req.blocks:
+            self.blocks.release(bid)
+        req.blocks = []
+        for p, held in zip(self._more, req.more_blocks):
+            for bid in held:
+                if bid:
+                    p.blocks.release(bid)
+        req.more_blocks = []
+
+    def _clear_tables(self, i: int) -> None:
+        for p in self._pools:
+            p.tables[i] = 0
+        self._dev_dirty = True
 
     def _fetch_and_commit(self, w: _Window
                           ) -> List[Tuple[Request, List[int]]]:
@@ -797,6 +925,8 @@ class LLMEngine:
                 if toks:
                     req.out_tokens.extend(toks)
                     self._next_token[i] = toks[-1]
+                if self._more and not req.done:
+                    self._release_behind_window(i, req)
                 taken.append((req, toks))
             ann.set_metadata(tokens=sum(len(t) for _, t in taken))
         return taken
@@ -860,14 +990,11 @@ class LLMEngine:
                     # release_export is the abandonment path
                     self._exports[req.request_id] = req
                 else:
-                    for bid in req.blocks:
-                        self.blocks.release(bid)
-                    req.blocks = []
+                    self._release_blocks(req)
                     self._request_span("engine.decode", req, req.t_decode,
                                        now, tokens=len(toks))
                 self._slots[i] = None
-                self._tables[i] = 0
-                self._dev_dirty = True
+                self._clear_tables(i)
         return out
 
     def _admit_stats(self, i: int, res) -> Dict[str, Any]:
@@ -883,6 +1010,9 @@ class LLMEngine:
                  "prompt_tokens": len(req.prompt_tokens),
                  "bucket": _bucket(prefilled, self.max_len)
                  if prefilled else 0}
+        if self._more:  # did a window keep the prefill from K blocks
+            stats["window_skips"] = int(any(
+                p.window and prefilled > p.window for p in self._more))
         if kind == "full":
             stats["cached_tokens"] = req.cached_prefix_len
             stats["queue_wait_ms"] = round(
@@ -1078,9 +1208,16 @@ class LLMEngine:
         from ray_tpu.util.health import device_memory_stats
 
         used = sum(1 for s in self._slots if s is not None)
-        capacity = max(1, self.num_blocks - 1)  # excl. the scratch block
-        available = self.blocks.available()
+        # excl. the scratch blocks; summed over the layer types' pools,
+        # whose blocks differ in bytes (a block x the type's layers)
+        capacity = max(1, sum(p.blocks.num_blocks - 1 for p in self._pools))
+        available = sum(p.blocks.available() for p in self._pools)
+        by_type = {"pools": {p.name: {
+            "total": p.blocks.num_blocks - 1,
+            "available": p.blocks.available(), "held": p.held()}
+            for p in self._pools}} if self._by_type else {}
         return {
+            **by_type,
             "queued": len(self._queue),
             "adopt_queued": len(self._adopt_queue),
             "exports_held": len(self._exports),
@@ -1088,8 +1225,8 @@ class LLMEngine:
             "slots_total": self.B,
             "slot_occupancy": round(used / self.B, 4),
             "blocks_total": capacity,
-            "blocks_free": len(self.blocks.free),
-            "blocks_cached": len(self.blocks.lru),
+            "blocks_free": sum(len(p.blocks.free) for p in self._pools),
+            "blocks_cached": sum(len(p.blocks.lru) for p in self._pools),
             "blocks_available": available,
             "block_pressure": round(1.0 - available / capacity, 4),
             "block_size": self.bs,
@@ -1107,7 +1244,13 @@ class LLMEngine:
     # -- admission / prefill ------------------------------------------------
 
     def _prompt_chain_keys(self, tokens: List[int]) -> List[Any]:
+        """The prompt's full blocks' keys in the prefix cache; none for a
+        model with several layer types, which takes no prefix hits (a hit
+        would need the window type's blocks before it, which are not
+        kept), so that nothing of it is ever registered or looked up."""
         keys = []
+        if self._by_type:
+            return keys
         parent = None
         for b in range(len(tokens) // self.bs):
             parent = (parent, tuple(tokens[b * self.bs:(b + 1) * self.bs]))
@@ -1158,7 +1301,12 @@ class LLMEngine:
         # would spuriously reject a request that admitted fine before
         worst = -(-min(req.n_prompt + req.sampling.max_tokens + 1,
                        self.max_len) // self.bs)
-        if worst >= self.num_blocks:
+        # a further layer type's blocks: up to the first decode's, from the
+        # first one a later step can still see
+        more = [(max(0, n + 1 - p.window) // self.bs if p.window else 0,
+                 need) for p in self._more]
+        if any(min(worst, self._window_blocks(p.window))
+               >= p.blocks.num_blocks for p in self._pools):
             # even an empty pool could never hold this one sequence: fail
             # THIS request (an admit/preempt livelock otherwise) — never
             # the whole batch; one oversized HTTP request must not kill
@@ -1181,7 +1329,9 @@ class LLMEngine:
             # chunk-prefill)
             return self._admit_chunk(i, req, hit_blocks, len(pinned),
                                       cached_len, budget, keys)
-        if self.blocks.available() < need:
+        if self.blocks.available() < need or any(
+                p.blocks.available() < hi - lo
+                for p, (lo, hi) in zip(self._more, more)):
             for bid in hit_blocks[len(pinned):]:
                 self.blocks.release(bid)  # pinned chunk progress stays
             if self._yield_chunk_pins():
@@ -1194,6 +1344,9 @@ class LLMEngine:
 
         new_blocks = [self.blocks.alloc() for _ in range(need)]
         req.blocks = hit_blocks + new_blocks
+        req.more_blocks = [
+            [0] * lo + [p.blocks.alloc() for _ in range(hi - lo)]
+            for p, (lo, hi) in zip(self._more, more)]
         req.chunk_blocks = []  # refs transferred into req.blocks
         req.cached_prefix_len = cached_len
         self._queue.popleft()
@@ -1203,15 +1356,15 @@ class LLMEngine:
                            req.t_admit)
 
         logits = self._run_prefill(suffix, cached_len, req.blocks,
-                                   hit_blocks)
+                                   hit_blocks, req.more_blocks)
         # register freshly-computed full blocks for future prefix hits
-        for b in range(len(hit_blocks), n // self.bs):
-            if (b + 1) * self.bs <= n:
-                self.blocks.register(req.blocks[b], keys[b])
+        # (``keys``: one for each full block of the prompt, or none)
+        for b in range(len(hit_blocks), len(keys)):
+            self.blocks.register(req.blocks[b], keys[b])
         self._cur_len[i] = n
-        self._tables[i] = 0
-        self._tables[i, :len(req.blocks)] = req.blocks
-        self._dev_dirty = True
+        self._clear_tables(i)
+        for p, held in zip(self._pools, [req.blocks] + req.more_blocks):
+            p.tables[i, :len(held)] = held
         self._dev = None  # a request entered: its token, position, temp
         # device array; caller batch-samples all admissions in one sync
         return ("full", logits, len(suffix))
@@ -1236,23 +1389,35 @@ class LLMEngine:
         return False
 
     def _run_prefill(self, suffix: List[int], cached_len: int,
-                     blocks: List[int], hit_blocks: List[int]):
+                     blocks: List[int], hit_blocks: List[int],
+                     more_blocks: List[List[int]] = ()):
         """ONE bucketed b=1 ``prefill_suffix`` dispatch shared by full
         admissions and chunk prefills: pads the suffix to its jit bucket,
         builds the scatter coordinates from ``blocks`` (position p ->
         ``blocks[p // bs]``), gathers the cached prefix, and returns the
-        last-position logits as a device array."""
+        last-position logits as a device array.  A model with several
+        layer types takes the block coordinates by type (``more_blocks``:
+        ``Request.more_blocks``; a position whose block a window type does
+        not hold goes to that pool's scratch block)."""
         import jax.numpy as jnp
 
         S = _bucket(len(suffix), self.max_len)
         pad_tok = list(suffix) + [0] * (S - len(suffix))
         # pool coordinates for each padded suffix lane (pads -> scratch 0)
-        dst_b = np.zeros(S, np.int32)
+        pos = cached_len + np.arange(len(suffix))
         dst_o = np.zeros(S, np.int32)
-        for j in range(len(suffix)):
-            p = cached_len + j
-            dst_b[j] = blocks[p // self.bs]
-            dst_o[j] = p % self.bs
+        dst_o[:len(suffix)] = pos % self.bs
+
+        def coordinates(held):
+            dst = np.zeros(S, np.int32)
+            dst[:len(suffix)] = np.asarray(held, np.int32)[pos // self.bs]
+            return jnp.asarray(dst)
+
+        dst_b = coordinates(blocks)
+        if self._by_type:
+            dst_b = {p.name: coordinates(held) if p is not self._pools[0]
+                     else dst_b
+                     for p, held in zip(self._pools, [blocks, *more_blocks])}
         P = _bucket(len(hit_blocks), self.MB) if hit_blocks else 0
         prefix_ids = np.zeros(P, np.int32)
         prefix_ids[:len(hit_blocks)] = hit_blocks
@@ -1262,7 +1427,7 @@ class LLMEngine:
             self.params, jnp.asarray([pad_tok], jnp.int32),
             jnp.int32(len(suffix)), jnp.int32(cached_len),
             pk, pv, jnp.int32(cached_len),
-            jnp.asarray(dst_b), jnp.asarray(dst_o), self.pool)
+            dst_b, jnp.asarray(dst_o), self.pool)
         for counts in extra:  # fetched with the first tokens
             self._prefill_sum = counts if self._prefill_sum is None \
                 else self._prefill_sum + counts
@@ -1332,21 +1497,21 @@ class LLMEngine:
             last_pos = min(int(self._cur_len[i]) + min(horizon, remaining)
                            - 1, self.max_len - 1)
             blk_idx = last_pos // self.bs
-            while blk_idx >= len(req.blocks):
-                bid = self.blocks.alloc()
-                if bid is None:
-                    # cheapest relief first: a queued prompt's forfeited
-                    # chunk pins cost at most one chunk recompute, vs a
-                    # whole-request re-prefill for a preemption
-                    if self._yield_chunk_pins(include_head=True):
-                        continue
-                    victim = self._preempt_youngest()
-                    if victim is None or victim == i:
-                        break  # self-preempted: slot is back in the queue
-                    continue
-                req.blocks.append(bid)
-                self._tables[i, len(req.blocks) - 1] = bid
-                self._dev_dirty = True
+            for p, held in zip(self._pools, [req.blocks] + req.more_blocks):
+                while blk_idx >= len(held) and self._slots[i] is req:
+                    bid = p.blocks.alloc()
+                    if bid is None:
+                        # cheapest relief first: a queued prompt's forfeited
+                        # chunk pins cost at most one chunk recompute, vs a
+                        # whole-request re-prefill for a preemption
+                        if self._yield_chunk_pins(include_head=True):
+                            continue
+                        if self._preempt_youngest() is None:
+                            break
+                        continue  # self-preempted: slot is back in the queue
+                    held.append(bid)
+                    p.tables[i, len(held) - 1] = bid
+                    self._dev_dirty = True
         return [i for i in active if self._slots[i] is not None
                 and not self._slots[i].done]
 
@@ -1357,9 +1522,7 @@ class LLMEngine:
             return None
         i = max(cand, key=lambda j: self._slots[j].request_id)
         req = self._slots[i]
-        for bid in req.blocks:
-            self.blocks.release(bid)
-        req.blocks = []
+        self._release_blocks(req)
         # roll generated tokens into the prompt: re-prefill resumes exactly
         # (n_prompt keeps outputs and the max_tokens budget intact)
         req.prompt_tokens = req.prompt_tokens + req.out_tokens
@@ -1374,8 +1537,7 @@ class LLMEngine:
         req.t_queued = now
         self._queue.appendleft(req)
         self._slots[i] = None
-        self._tables[i] = 0
-        self._dev_dirty = True
+        self._clear_tables(i)
         self.blocks.stats["preemptions"] += 1
         return i
 
@@ -1638,10 +1800,11 @@ class LLMEngine:
 
         for i, req in enumerate(self._slots):
             if req is not None and req.done and self._tables[i, 0]:
-                self._tables[i] = 0
-                self._dev_dirty = True
+                self._clear_tables(i)
         if self._dev_dirty or self._tables_d is None:
-            self._tables_d = jnp.array(self._tables)
+            self._tables_d = ({p.name: jnp.array(p.tables)
+                               for p in self._pools} if self._by_type
+                              else jnp.array(self._tables))
             self._dev_dirty = False
         if self._dev is None:
             self._temps_d = jnp.array(self._temp_vec())

@@ -23,11 +23,21 @@ configuration's type: ``served_model(cfg)`` returns the model's
 
 and optionally ``verify_step`` (speculation), ``param_specs`` (an engine
 with a mesh), ``handoff`` (``export_kv`` / ``adopt_prefilled`` know its
-pool) and ``counters``: the names of the small integer sums its prefill
+pool), ``layer_types`` (below) and ``counters``: the names of the small integer sums its prefill
 and decode programs return beside the rest (one int32 vector, fetched with
 the window's tokens, summed into ``LLMEngine.stats()["counters"]``).
 What a model leaves out the engine refuses by name at construction or at
 the call, never by a wrong answer.
+
+A model whose layers do not all keep the same positions supplies
+``layer_types(cfg) -> {type: {"layers": n, "window": None | int}}``, the
+type without a window first.  The engine then builds a pool, a block
+manager and a block table for each type: ``init_pool`` takes
+``num_blocks`` by type and returns ``{type: pool}``, ``decode_sample``
+takes ``block_tables`` by type, ``prefill_suffix`` its ``dst_blocks`` by
+type, and a window type's blocks that lie wholly behind the window go back
+to its pool while a request decodes (``docs/llm_serving.md``).  Such a
+model takes no prefix hits and no ``prefill_chunk`` yet.
 
 ``presets`` are the configurations the model offers by name
 (``build_llm_deployment({"model": "<name>"})`` resolves one through
@@ -56,6 +66,7 @@ class ServedModel:
     verify_step: Optional[Callable] = None
     param_specs: Optional[Callable] = None
     handoff: bool = False
+    layer_types: Optional[Callable] = None
     counters: Tuple[str, ...] = ()
 
     def require(self, what: str, have: bool) -> None:
@@ -103,10 +114,28 @@ def _longcat() -> ServedModel:
         counters=("moe_pairs_held", "moe_experts_hit", "moe_zero_picks"))
 
 
+def _smallthinker() -> ServedModel:
+    from ray_tpu.models import smallthinker as st
+
+    return ServedModel(
+        name="smallthinker", init=st.smallthinker_init,
+        init_pool=st.init_pools, prefill_suffix=st.prefill_suffix,
+        gather_prefix=st.gather_prefix, decode_sample=st.decode_sample,
+        decode_attention_path=st.decode_attention_path,
+        presets={"smallthinker_tiny": st.SmallThinkerConfig.tiny,
+                 "smallthinker_21b": st.SmallThinkerConfig},
+        test_presets=("smallthinker_tiny",),
+        layer_types=st.layer_types,
+        # LongCat's names, so that one reader reads both; this model has no
+        # zero-compute expert and reports 0 picks of one
+        counters=("moe_pairs_held", "moe_experts_hit", "moe_zero_picks"))
+
+
 # configuration class -> the function that builds its ServedModel: a model's
 # modules are imported when it is first asked for, so that a process which
 # serves one model loads one model
-_MODELS = {"LlamaConfig": _llama, "LongcatConfig": _longcat}
+_MODELS = {"LlamaConfig": _llama, "LongcatConfig": _longcat,
+           "SmallThinkerConfig": _smallthinker}
 
 
 @functools.lru_cache(maxsize=None)

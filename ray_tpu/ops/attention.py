@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-_WARNED_WINDOW_NO_FLASH = False
 _NEG_INF = -1e30
 
 
@@ -210,9 +209,9 @@ def dot_product_attention(
     impl: 'auto' | 'ref' | 'flash' | 'ring'.  'auto' picks ring when the
     mesh shards sequence (sp>1), Pallas flash on TPU otherwise, and the
     reference path on CPU test meshes.  ``window`` (sliding-window /
-    Mistral-style) is supported by ref and ring; 'auto' avoids the flash
-    kernel when a window is set (the pallas kernel has no window mask
-    yet — a skipped-block windowed variant is the natural follow-up).
+    Mistral-style) is supported by all three; the flash kernel's forward
+    skips the K blocks before the window, its backward has no window yet
+    and raises by name (differentiate 'ref' or 'ring' under a window).
     """
     if impl == "auto":
         if (
@@ -222,27 +221,9 @@ def dot_product_attention(
         ):
             impl = "ring"
         elif jax.default_backend() == "tpu" and q.shape[1] >= 256:
-            if window is None:
-                impl = "flash"
-            else:
-                global _WARNED_WINDOW_NO_FLASH
-                if not _WARNED_WINDOW_NO_FLASH:
-                    _WARNED_WINDOW_NO_FLASH = True
-                    import warnings
-
-                    warnings.warn(
-                        "sliding_window forces reference attention on "
-                        "TPU (the pallas flash kernel has no window "
-                        "mask yet): full [b,h,S,S] logits materialize "
-                        "per layer — expect higher HBM use at long "
-                        "sequence lengths", stacklevel=2)
-                impl = "ref"
+            impl = "flash"
         else:
             impl = "ref"
-    if impl == "flash" and window is not None:
-        raise ValueError(
-            "impl='flash' does not support sliding windows; use 'ref', "
-            "'ring', or 'auto'")
     if impl == "ring":
         assert mesh is not None, "ring attention needs a mesh"
         return ring_attention(
@@ -253,7 +234,7 @@ def dot_product_attention(
         from ray_tpu.ops.pallas.flash_attention import flash_attention
 
         if mesh is None:
-            return flash_attention(q, k, v, causal=causal)
+            return flash_attention(q, k, v, causal=causal, window=window)
         # The pallas_call is opaque to GSPMD: run it per-shard under
         # shard_map, with batch sharded over dp/fsdp and heads over tp
         # (sequence is whole per device since sp==1 on this path).
@@ -266,7 +247,8 @@ def dot_product_attention(
         qspec = P(batch_axes if batch_axes else None, None, head_axis, None)
         kvspec = qspec
         return jax.shard_map(
-            lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=causal),
+            lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=causal,
+                                               window=window),
             mesh=mesh,
             in_specs=(qspec, kvspec, kvspec),
             out_specs=qspec,

@@ -6,9 +6,10 @@ add for the tokens routed to them, and what the absent experts would add is
 somebody else's part of the sum (on one chip of a cut deployment: left out).
 
 ``route_top_k`` is the router (softmax scores, an additive selection bias
-that picks but does not weigh, no renormalisation).  ``held_experts_ffn`` is
-the dispatch: **sorted and grouped**, never a dense all-experts product,
-no capacity and no dropped token.
+that picks but does not weigh, the picked weights renormalised where the
+model says so).  ``held_experts_ffn`` is the dispatch: **sorted and
+grouped**, never a dense all-experts product, no capacity and no dropped
+token.
 
 * every (token, pick) pair gets a key: its expert's index in the held range,
   or a sentinel past it; one stable sort puts the held pairs first, grouped
@@ -16,7 +17,8 @@ no capacity and no dropped token.
 * the held pairs are walked in chunks of ``chunk`` rows by a loop whose trip
   count is ``ceil(held pairs / chunk)``, a run-time value: the work follows
   the pairs that landed here, and no bound on them is ever assumed;
-* a chunk gathers its tokens' rows, runs the three SwiGLU products as
+* a chunk gathers its tokens' rows, runs the expert's three products (gate
+  and up, the ``activation`` of the two, down: SwiGLU unless told) as
   ``jax.lax.ragged_dot`` over the chunk's group sizes (on a TPU a Mosaic
   grouped matmul: an expert's weights are read once for the rows it got),
   weighs each row and scatter-adds it to its token.
@@ -35,20 +37,25 @@ from jax import lax
 from ray_tpu.ops.layers import swiglu
 
 
-def route_top_k(y, w_router, bias, k: int, scale: float):
-    """y ``[T, H]``, w_router ``[H, N]``, bias ``[N]`` -> (idx ``[T, k]``
-    int32, weight ``[T, k]`` float32).
+def route_top_k(y, w_router, bias, k: int, scale: float,
+                renormalise: bool = False):
+    """y ``[T, H]``, w_router ``[H, N]``, bias ``[N]`` or None -> (idx
+    ``[T, k]`` int32, weight ``[T, k]`` float32).
 
     ``p = softmax(y W_r)`` in float32 over all N outputs; the k largest of
-    ``p + bias`` are picked; a pick's weight is its own ``p`` (no bias, not
-    renormalised) times ``scale``.  The product runs at the highest
+    ``p + bias`` are picked; a pick's weight is its own ``p`` (no bias),
+    divided by the sum over the k picked where ``renormalise``
+    (``norm_topk_prob``), times ``scale``.  The product runs at the highest
     precision: a pick is a discrete choice, and N is small."""
     logits = jnp.matmul(y.astype(jnp.float32), w_router.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
     p = jax.nn.softmax(logits, axis=-1)
-    _, idx = lax.top_k(p + bias.astype(jnp.float32), k)
-    weight = jnp.take_along_axis(p, idx, axis=-1) * scale
-    return idx.astype(jnp.int32), weight
+    _, idx = lax.top_k(p if bias is None else p + bias.astype(jnp.float32),
+                       k)
+    weight = jnp.take_along_axis(p, idx, axis=-1)
+    if renormalise:
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), weight * scale
 
 
 def default_chunk(pairs: int) -> int:
@@ -59,14 +66,22 @@ def default_chunk(pairs: int) -> int:
     return int(min(4096, max(256, -(-pairs // 8 // 128) * 128)))
 
 
+def reglu(gate: jnp.ndarray, up: jnp.ndarray) -> jnp.ndarray:
+    """ReGLU activation: relu(gate) * up."""
+    return jnp.maximum(gate, 0) * up
+
+
 def held_experts_ffn(y, idx, weight, w_gate, w_up, w_down, *, first: int,
-                     live=None, chunk: int | None = None):
+                     live=None, chunk: int | None = None,
+                     activation=swiglu):
     """What the held experts add to each token.
 
     y ``[T, H]``; idx / weight ``[T, k]`` from the router (global expert
     ids); w_gate / w_up ``[E, H, F]``, w_down ``[E, F, H]``: the weights of
     experts ``first .. first + E - 1``.  ``live`` ``[T]`` bool: tokens that
     are padding (a freed slot, a bucket's tail) are routed nowhere.
+    ``activation(gate, up)``: what stands between an expert's first two
+    products and its third.
     Returns (out ``[T, H]`` float32, pairs, experts_hit): the weighted sum
     over the held experts a token picked, how many (token, pick) pairs
     landed on held experts, and how many held experts got at least one.
@@ -100,7 +115,7 @@ def held_experts_ffn(y, idx, weight, w_gate, w_up, w_down, *, first: int,
         x = y[rows]
         gate = lax.ragged_dot(x, w_gate.astype(dt), sizes)
         up = lax.ragged_dot(x, w_up.astype(dt), sizes)
-        o = lax.ragged_dot(swiglu(gate, up), w_down.astype(dt), sizes,
+        o = lax.ragged_dot(activation(gate, up), w_down.astype(dt), sizes,
                            preferred_element_type=jnp.float32)
         # a row past the last group belongs to no expert: whatever the
         # grouped product left there is not read

@@ -33,6 +33,14 @@ block share one basic block: tiles in regions of their own ran at half
 the MXU's rate on a v5e, and ``lax.cond`` around a mask cost more than
 the mask.
 
+The forward kernel takes a sliding ``window`` (a query sees the last
+``window`` positions, ``ops.attention.sliding_window_mask``): a K block that
+lies wholly before every query's window is skipped and not fetched, as one
+above the diagonal is (the index map holds the first block needed until the
+grid reaches it), and only a block the window's edge cuts builds its mask.
+Forward only: the backward kernel has no window yet and says so.  With
+``window=None`` nothing of this is traced.
+
 Sequences are padded to the block size and pad K positions masked, so any
 length works.  GQA is handled by index-mapping q-heads onto kv heads — no
 materialized KV expansion.
@@ -62,7 +70,7 @@ def _strip(block, parts):
     return block
 
 
-def _masked(scores, causal, k_padded, r0, c0, q_axis, seq_k):
+def _masked(scores, causal, k_padded, r0, c0, q_axis, seq_k, window=None):
     """``scores`` of queries from r0 (along ``q_axis``) against keys from
     c0, with what the mask forbids set to _NEG_INF."""
     qpos = r0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, q_axis)
@@ -70,6 +78,8 @@ def _masked(scores, causal, k_padded, r0, c0, q_axis, seq_k):
     mask = None
     if causal:
         mask = qpos >= kpos
+    if window is not None:  # causal too: the wrapper insists
+        mask = jnp.logical_and(mask, qpos - kpos < window)
     if k_padded:  # pad K positions contribute nothing
         pad = kpos < seq_k
         mask = pad if mask is None else jnp.logical_and(mask, pad)
@@ -77,16 +87,18 @@ def _masked(scores, causal, k_padded, r0, c0, q_axis, seq_k):
 
 
 def _visit_block(tile_fn, *, causal, k_padded, qi, ki, last_k, block_q,
-                 block_k, strip, seq_k):
+                 block_k, strip, seq_k, window=None):
     """Run ``tile_fn(i0, rows, cols, mask)``, queries i0.. of block qi
     against the first ``cols`` keys of block ki, over what the block needs:
 
-    - nothing, above the diagonal;
-    - the whole block with no mask built (``mask`` None), below it;
+    - nothing, above the diagonal or wholly before the ``window``;
+    - the whole block with no mask built (``mask`` None), below the
+      diagonal and inside every query's window;
     - on it (equal blocks: where it starts), strip by strip, each strip's
       queries against the keys up to them, all in one basic block;
     - the whole block masked where something else cuts through it: the
-      pad of the last K block, a diagonal through unequal blocks.
+      pad of the last K block, a diagonal through unequal blocks, the
+      window's edge.
 
     ``mask(scores, q_axis)`` returns the scores masked."""
     r0, c0 = qi * block_q, ki * block_k
@@ -94,7 +106,7 @@ def _visit_block(tile_fn, *, causal, k_padded, qi, ki, last_k, block_q,
 
     def mask(i0):
         return lambda scores, q_axis: _masked(
-            scores, causal, k_padded, r0 + i0, c0, q_axis, seq_k)
+            scores, causal, k_padded, r0 + i0, c0, q_axis, seq_k, window)
 
     if not causal and not k_padded:
         whole(None)
@@ -103,6 +115,11 @@ def _visit_block(tile_fn, *, causal, k_padded, qi, ki, last_k, block_q,
     if causal:
         run = r0 + block_q - 1 >= c0
         cut = r0 < c0 + block_k - 1
+    if window is not None:
+        # the nearest pair is (first query, last key), the farthest (last
+        # query, first key)
+        run = jnp.logical_and(run, r0 - (c0 + block_k - 1) < window)
+        cut = jnp.logical_or(cut, r0 + block_q - 1 - c0 >= window)
     if k_padded:
         cut = jnp.logical_or(cut, last_k)
     pl.when(jnp.logical_and(run, jnp.logical_not(cut)))(
@@ -117,7 +134,7 @@ def _visit_block(tile_fn, *, causal, k_padded, qi, ki, last_k, block_q,
                 tile_fn(i0, strip, i0 + strip, mask(i0))
 
         cut = jnp.logical_and(cut, jnp.logical_not(on_diag))
-    if k_padded or not strips:
+    if k_padded or not strips or window is not None:
         pl.when(jnp.logical_and(run, cut))(
             functools.partial(whole, mask(0)))
 
@@ -128,7 +145,7 @@ def _visit_block(tile_fn, *, causal, k_padded, qi, ki, last_k, block_q,
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale,
-    causal, k_padded, block_q, block_k, seq_k
+    causal, k_padded, block_q, block_k, seq_k, window=None
 ):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -166,7 +183,7 @@ def _fwd_kernel(
     _visit_block(
         tile, causal=causal, k_padded=k_padded, qi=qi, ki=ki, last_k=last_k,
         block_q=block_q, block_k=block_k, strip=_strip(block_q, 2),
-        seq_k=seq_k)
+        seq_k=seq_k, window=window)
 
     @pl.when(last_k)
     def _finalize():
@@ -191,16 +208,22 @@ def _fold_heads(x):
     return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
 
-def _last_k_block(causal, qi, ki, block_q, block_k):
+def _last_k_block(causal, qi, ki, block_q, block_k, window=None):
     """The K block to hold at grid step (qi, ki): ki, but no further than
-    the last one a causal Q block needs, so that a skipped step fetches
-    nothing (an unchanged block index is not copied again)."""
+    the last one a causal Q block needs and no sooner than the first one
+    its ``window`` reaches, so that a skipped step fetches nothing (an
+    unchanged block index is not copied again)."""
     if not causal:
         return ki
-    return jnp.minimum(ki, (qi * block_q + block_q - 1) // block_k)
+    ki = jnp.minimum(ki, (qi * block_q + block_q - 1) // block_k)
+    if window is not None:
+        ki = jnp.maximum(ki, jnp.maximum(qi * block_q - window + 1, 0)
+                         // block_k)
+    return ki
 
 
-def _flash_fwd_impl(q, k, v, *, causal, block_q, block_k, interpret):
+def _flash_fwd_impl(q, k, v, *, causal, block_q, block_k, interpret,
+                    window=None):
     b, sq, h, d = q.shape
     sk, kv_h = k.shape[1], k.shape[2]
     n_rep = h // kv_h
@@ -219,14 +242,14 @@ def _flash_fwd_impl(q, k, v, *, causal, block_q, block_k, interpret):
 
     def kv_map(bh, qi, ki):
         return ((bh // h) * kv_h + (bh % h) // n_rep,
-                _last_k_block(causal, qi, ki, block_q, block_k), 0)
+                _last_k_block(causal, qi, ki, block_q, block_k, window), 0)
 
     def lse_map(bh, qi, ki):
         return (bh, 0, qi)
 
     kernel = functools.partial(
         _fwd_kernel, scale=d ** -0.5, causal=causal, k_padded=sk_p != sk,
-        block_q=block_q, block_k=block_k, seq_k=sk,
+        block_q=block_q, block_k=block_k, seq_k=sk, window=window,
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -413,16 +436,20 @@ def _flash_bwd_impl(res, g, *, causal, block_q, block_k, interpret):
 # custom_vjp plumbing + public entry
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, block_q, block_k, interpret, window=None):
     out, _ = _flash_fwd_impl(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+        interpret=interpret, window=window,
     )
     return out
 
 
-def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret, window):
+    if window is not None:
+        raise NotImplementedError(
+            "flash_attention's backward kernel has no sliding window yet: "
+            "differentiate reference_attention(window=...) or ring_attention")
     out, lse = _flash_fwd_impl(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
         interpret=interpret,
@@ -436,7 +463,7 @@ def _flash_vjp_fwd(q, k, v, causal, block_q, block_k, interpret):
     return out, (q, k, v, out_res, lse)
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_vjp_bwd(causal, block_q, block_k, interpret, window, res, g):
     return _flash_bwd_impl(
         res, g, causal=causal, block_q=block_q, block_k=block_k,
         interpret=interpret,
@@ -455,12 +482,18 @@ def flash_attention(
     block_q: int = 1024,
     block_k: int = 1024,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Flash attention. q: [b, s, h, d]; k, v: [b, s, kv_h, d].
+
+    ``window``: a query at position p sees the keys in (p - window, p],
+    positions counted from 0 in both operands; causal only, forward only.
 
     Off-TPU this runs the Pallas interpreter (slow; tests use small
     shapes).
     """
+    if window is not None and not causal:
+        raise ValueError("sliding window requires causal attention")
     if jax.default_backend() != "tpu":
         interpret = True
-    return _flash(q, k, v, causal, block_q, block_k, interpret)
+    return _flash(q, k, v, causal, block_q, block_k, interpret, window)

@@ -74,3 +74,22 @@ def test_a_sequence_whose_scratch_outgrows_vmem_is_refused_at_lowering(
     output blocks: past the v5e's 128 MiB, and the compiler says so."""
     with pytest.raises(Exception, match="(?i)vmem"):
         _compile_grad(one_chip, 1, 65536, 65536, 8, 8, 128, True)
+
+
+@pytest.mark.parametrize("sq,h,kvh,window", [
+    pytest.param(14352, 28, 4, 4096, id="smallthinker-prefill-window"),
+    pytest.param(14352, 28, 4, None, id="smallthinker-prefill-full"),
+    pytest.param(8192, 32, 8, 1000, id="window-off-the-block-grid"),
+])
+def test_the_windowed_forward_compiles_as_one_kernel(one_chip, sq, h, kvh,
+                                                     window):
+    """The forward alone, as a prefill runs it (PR 31): the index map that
+    holds the first K block a window reaches, and the edge's mask."""
+    def shape(heads):
+        return jax.ShapeDtypeStruct((1, sq, heads, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    text = jax.jit(lambda q, k, v: fa._flash(
+        q, k, v, True, 1024, 1024, False, window)).lower(
+            shape(h), shape(kvh), shape(kvh)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
