@@ -379,6 +379,10 @@ class LLMEngine:
         # "gather" for the rest
         self.attn = model.decode_attention_path(self.pool, mesh=mesh,
                                                 spec_tokens=spec_tokens)
+        # the decode step's expert layer, read off the same way
+        # (ops/experts.py:expert_path at this engine's B rows a step):
+        # "decode_kernel" / "grouped"; None for a model without experts
+        self.experts = self._expert_path(self.B)
         self._decode1 = jax.jit(
             functools.partial(model.decode_sample, cfg=cfg, attn=self.attn),
             donate_argnums=(4,))
@@ -403,6 +407,8 @@ class LLMEngine:
         # every model: windows dispatched, and how many of them were
         # launched before the emit of the window before (step())
         self.counters.update(decode_windows=0, windows_carried=0)
+        if self.experts:  # of decode_windows: those the kernel ran
+            self.counters["expert_kernel_windows"] = 0
         self._stack_counted = jax.jit(lambda toks, counts: jnp.concatenate(
             [jnp.stack(toks), jnp.stack(counts)], axis=1))
         # prompt-lookup speculative decoding (vLLM's ngram method,
@@ -797,7 +803,8 @@ class LLMEngine:
             key_d = self._key
         with tracing.annotate(
                 "engine.dispatch_window", k=window_k, active=len(active),
-                attn=self.attn, carried=int(carried), **live):
+                attn=self.attn, carried=int(carried), **live,
+                **({"experts": self.experts} if self.experts else {})):
             toks, counts = [], []
             for _ in range(window_k):  # device-chained: no host sync
                 tok_d, cur_d, key_d, self.pool, *extra = self._decode1(
@@ -815,8 +822,17 @@ class LLMEngine:
             out_d.copy_to_host_async()
             self.counters["decode_windows"] += 1
             self.counters["windows_carried"] += int(carried)
+            if self.experts == "decode_kernel":
+                self.counters["expert_kernel_windows"] += 1
             del toks, counts, extra  # freed inside the phase, as above
         return _Window(out_d, window_k, active, t_arm)
+
+    def _expert_path(self, tokens: int) -> Optional[str]:
+        """The expert layer's path in a program of ``tokens`` rows, as the
+        model's ``expert_path`` reads it off the shapes; None for a model
+        without an expert layer."""
+        path = self.model.expert_path
+        return path(self.cfg, tokens) if path else None
 
     def _live_tokens(self, active: List[int]) -> Dict[str, int]:
         """``engine.dispatch_window``'s ``live_tokens``: the cached
@@ -1010,6 +1026,8 @@ class LLMEngine:
                  "prompt_tokens": len(req.prompt_tokens),
                  "bucket": _bucket(prefilled, self.max_len)
                  if prefilled else 0}
+        if self.experts and prefilled:  # the bucket's prefill program's
+            stats["experts"] = self._expert_path(stats["bucket"])
         if self._more:  # did a window keep the prefill from K blocks
             stats["window_skips"] = int(any(
                 p.window and prefilled > p.window for p in self._more))
@@ -1232,6 +1250,7 @@ class LLMEngine:
             "block_size": self.bs,
             "kv_cache_dtype": self.kv_cache_dtype or "native",
             "attn": self.attn,
+            "experts": self.experts,
             "prefix_cache": dict(self.blocks.stats),
             "prefill_chunks": self.prefill_stats["chunks"],
             "spec": dict(self.spec_stats),

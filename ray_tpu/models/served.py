@@ -23,7 +23,9 @@ configuration's type: ``served_model(cfg)`` returns the model's
 
 and optionally ``verify_step`` (speculation), ``param_specs`` (an engine
 with a mesh), ``handoff`` (``export_kv`` / ``adopt_prefilled`` know its
-pool), ``layer_types`` (below) and ``counters``: the names of the small integer sums its prefill
+pool), ``layer_types`` (below), ``expert_path(cfg, tokens) -> str`` (a model
+with an expert layer: which path ``ops/experts.py:expert_path`` gives a
+program of ``tokens`` rows, the engine's ``experts`` label) and ``counters``: the names of the small integer sums its prefill
 and decode programs return beside the rest (one int32 vector, fetched with
 the window's tokens, summed into ``LLMEngine.stats()["counters"]``).
 What a model leaves out the engine refuses by name at construction or at
@@ -67,6 +69,7 @@ class ServedModel:
     param_specs: Optional[Callable] = None
     handoff: bool = False
     layer_types: Optional[Callable] = None
+    expert_path: Optional[Callable] = None
     counters: Tuple[str, ...] = ()
 
     def require(self, what: str, have: bool) -> None:
@@ -74,6 +77,17 @@ class ServedModel:
             raise NotImplementedError(
                 f"{self.name} does not supply {what} yet (see "
                 f"docs/llm_serving.md, 'Which options each model supports')")
+
+
+def _expert_path(cfg, tokens: int) -> str:
+    """``ServedModel.expert_path`` of both expert models: their expert
+    layers hand ``held_experts_ffn`` ``tokens`` rows of ``hidden_size`` in
+    ``cfg.dtype`` and experts ``expert_ffn_dim`` wide."""
+    from ray_tpu.ops import experts
+
+    return experts.expert_path(
+        tokens, cfg.experts_per_token, cfg.hidden_size, cfg.expert_ffn_dim,
+        cfg.dtype)
 
 
 def _llama() -> ServedModel:
@@ -111,6 +125,7 @@ def _longcat() -> ServedModel:
         presets={"longcat_flash_tiny": lc.LongcatConfig.tiny,
                  "longcat_flash": lc.LongcatConfig},
         test_presets=("longcat_flash_tiny",),
+        expert_path=_expert_path,
         counters=("moe_pairs_held", "moe_experts_hit", "moe_zero_picks"))
 
 
@@ -125,7 +140,7 @@ def _smallthinker() -> ServedModel:
         presets={"smallthinker_tiny": st.SmallThinkerConfig.tiny,
                  "smallthinker_21b": st.SmallThinkerConfig},
         test_presets=("smallthinker_tiny",),
-        layer_types=st.layer_types,
+        layer_types=st.layer_types, expert_path=_expert_path,
         # LongCat's names, so that one reader reads both; this model has no
         # zero-compute expert and reports 0 picks of one
         counters=("moe_pairs_held", "moe_experts_hit", "moe_zero_picks"))

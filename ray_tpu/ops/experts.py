@@ -7,25 +7,37 @@ somebody else's part of the sum (on one chip of a cut deployment: left out).
 
 ``route_top_k`` is the router (softmax scores, an additive selection bias
 that picks but does not weigh, the picked weights renormalised where the
-model says so).  ``held_experts_ffn`` is the dispatch: **sorted and
-grouped**, never a dense all-experts product, no capacity and no dropped
-token.
+model says so).  ``held_experts_ffn`` is the dispatch: **sorted**, never a
+dense all-experts product, no capacity and no dropped token.
 
-* every (token, pick) pair gets a key: its expert's index in the held range,
-  or a sentinel past it; one stable sort puts the held pairs first, grouped
-  by expert;
-* the held pairs are walked in chunks of ``chunk`` rows by a loop whose trip
-  count is ``ceil(held pairs / chunk)``, a run-time value: the work follows
-  the pairs that landed here, and no bound on them is ever assumed;
-* a chunk gathers its tokens' rows, runs the expert's three products (gate
-  and up, the ``activation`` of the two, down: SwiGLU unless told) as
-  ``jax.lax.ragged_dot`` over the chunk's group sizes (on a TPU a Mosaic
-  grouped matmul: an expert's weights are read once for the rows it got),
-  weighs each row and scatter-adds it to its token.
+Shared by both of its paths: every (token, pick) pair gets a key, its
+expert's index in the held range or a sentinel past it; one stable sort puts
+the held pairs first, grouped by expert; the experts' counts and offsets,
+and the returned ``pairs`` (pairs on held experts) and ``experts_hit``.
 
-The chunk is the trade between reading an expert's weights again (every
-chunk reads the weights of the experts it holds rows for) and computing on
-padding rows (the last chunk is padded to ``chunk``).
+Then one of two paths, which share no loop because their needs conflict
+(below some 240 rows an expert the weights' bytes bound the layer and the
+smallest tile is right; above it the MXU does and whole tiles are):
+
+* ``"grouped"``: the held pairs are walked in chunks of ``chunk`` rows by a
+  loop whose trip count is ``ceil(held pairs / chunk)``, a run-time value:
+  the work follows the pairs that landed here, and no bound on them is ever
+  assumed.  A chunk gathers its tokens' rows, runs the expert's three
+  products (gate and up, the ``activation`` of the two, down: SwiGLU unless
+  told) as ``jax.lax.ragged_dot`` over the chunk's group sizes (on a TPU a
+  Mosaic grouped matmul), weighs each row and scatter-adds it to its token.
+  The chunk is the trade between reading an expert's weights again (every
+  chunk reads the weights of the experts it holds rows for) and computing
+  on padding rows (the last chunk is padded to ``chunk``).
+* ``"decode_kernel"`` (``ops/pallas/expert_decode.py``): each HIT expert's
+  three weights streamed through VMEM once against its rows in tiles of 16,
+  the activation on float32, the weighted float32 rows added to their
+  tokens inside the kernel; an expert no pair landed on is not read.
+
+``expert_path`` picks, from what can be seen when the program is traced and
+nothing else: the static shapes, the dtype, the backend and its device
+count.  The same function labels the engine's programs
+(``LLMEngine.stats()["experts"]``).
 """
 
 from __future__ import annotations
@@ -71,6 +83,44 @@ def reglu(gate: jnp.ndarray, up: jnp.ndarray) -> jnp.ndarray:
     return jnp.maximum(gate, 0) * up
 
 
+# The most (token, pick) pairs ``expert_path`` hands the decode kernel: ONE
+# rule on the static pair count, measured on a v5e at both served models'
+# widths with every token live (ms an expert layer, the kernel | the grouped
+# path; my chip runs, PR 32, call 1).  SmallThinker (H 2560, F 768, 64
+# experts, 6 picks): 192 pairs (its decode step) 0.97 | 1.87, 768: 1.03 |
+# 2.09, 1536: 1.04 | 2.28, 3072: 1.06 | 2.60, 6144: 1.18 | 6.44, 12 288:
+# 2.22 | 11.07.  LongCat (H 6144, F 2048, 16 of 768 held, 12 picks): 1536
+# pairs (its decode step) 1.53 | 2.29, 3072: 1.71 | 2.25, 6144: 1.76 | 2.87,
+# 12 288: 1.87 | 4.61, 24 576: 2.27 | 6.07.  Up to 3072 pairs the kernel's
+# time is the hit experts' bytes at 84-90% of HBM speed; from 6144 on it
+# grows with the rows (each 16-row tile of an expert loads the weights into
+# the MXU again), which is the grouped path's kind of problem.  The kernel
+# stays ahead there only because the grouped path pays 0.75 us a pair for
+# its scatter-add: that is ROADMAP A3's to cure, in the grouped path.  E did
+# not enter: 16 held experts and 64 read the same.
+DECODE_KERNEL_MAX_PAIRS = 4096
+# y and the result stay whole in VMEM as float32, two stages of weights
+# beside them (ops/pallas/expert_decode.py:vmem_bytes; the v5e has 128 MiB)
+_DECODE_KERNEL_MAX_ROWS_BYTES = 32 << 20
+
+
+def expert_path(T: int, k: int, H: int, F: int, dtype) -> str:
+    """Which path ``held_experts_ffn`` takes for T tokens of k picks over
+    experts of widths H and F: ``"decode_kernel"`` or ``"grouped"``.
+    A function of static shapes, the dtype, the backend and its device
+    count, never of a knob: one TPU device (the kernel is not under
+    ``shard_map``; ``ops/attention.py`` keeps Pallas off the CPU path the
+    same way), widths in whole 128-lane tiles and tokens in whole sublane
+    tiles (Mosaic refuses others), 16- or 32-bit floats, rows that fit
+    VMEM, and at most ``DECODE_KERNEL_MAX_PAIRS`` pairs."""
+    if (jax.default_backend() != "tpu" or jax.device_count() != 1
+            or H % 128 or F % 128 or T % 8
+            or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32)
+            or 2 * T * H * 4 > _DECODE_KERNEL_MAX_ROWS_BYTES):
+        return "grouped"
+    return "decode_kernel" if T * k <= DECODE_KERNEL_MAX_PAIRS else "grouped"
+
+
 def held_experts_ffn(y, idx, weight, w_gate, w_up, w_down, *, first: int,
                      live=None, chunk: int | None = None,
                      activation=swiglu):
@@ -101,6 +151,16 @@ def held_experts_ffn(y, idx, weight, w_gate, w_up, w_down, *, first: int,
     ends = jnp.cumsum(counts)
     starts = ends - counts
     total = ends[-1]
+    hit = jnp.sum(counts > 0, dtype=jnp.int32)
+    if expert_path(T, k, y.shape[1], w_gate.shape[2],
+                   y.dtype) == "decode_kernel":
+        # imported where it is used: a CPU worker never loads Pallas
+        from ray_tpu.ops.pallas.expert_decode import expert_decode_ffn
+
+        out = expert_decode_ffn(
+            y, order // k, weight.reshape(N)[order], starts, counts,
+            w_gate, w_up, w_down, activation=activation)
+        return out, total, hit
     # padded by one chunk so that the last chunk's slice never clamps
     token_of = jnp.pad(order // k, (0, C)).astype(jnp.int32)
     w_sorted = jnp.pad(weight.reshape(N)[order], (0, C))
@@ -124,4 +184,4 @@ def held_experts_ffn(y, idx, weight, w_gate, w_up, w_down, *, first: int,
 
     out = lax.fori_loop(0, (total + C - 1) // C, one_chunk,
                         jnp.zeros((T, y.shape[1]), jnp.float32))
-    return out, total, jnp.sum(counts > 0, dtype=jnp.int32)
+    return out, total, hit

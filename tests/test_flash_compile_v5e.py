@@ -1,4 +1,5 @@
-"""The flash kernels compiled for a v5e that is described, not attached.
+"""The flash kernels, and the decode-shaped expert kernel (PR 32), compiled
+for a v5e that is described, not attached.
 
 The interpreter cannot see what the chip's compiler refuses: more VMEM
 than a kernel may use (the backward keeps dK/dV whole in scratch and sets
@@ -93,3 +94,55 @@ def test_the_windowed_forward_compiles_as_one_kernel(one_chip, sq, h, kvh,
         q, k, v, True, 1024, 1024, False, window)).lower(
             shape(h), shape(kvh), shape(kvh)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+# ------------------------- the expert layer at decode shape (PR 32)
+
+_EXPERT_CELLS = {
+    # T, k, E held, H, F: a decode step of the cell's engine
+    "serve-smallthinker-long-context": (32, 6, 64, 2560, 768),
+    "serve-longcat-long-answers": (128, 12, 16, 6144, 2048),
+}
+
+
+def _compile_expert_layer(one_chip, monkeypatch, T, k, E, H, F):
+    from ray_tpu.ops import experts
+
+    # the backend here is the CPU, the target is not: what the rule and
+    # the kernel's interpret switch ask is answered for the target
+    # (cells/tools/compile_for_v5e.py does the same)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    bf = jnp.bfloat16
+    return jax.jit(lambda y, idx, weight, *ws: experts.held_experts_ffn(
+        y, idx, weight, *ws, first=0, activation=experts.reglu)).lower(
+            shape((T, H), bf), shape((T, k), jnp.int32),
+            shape((T, k), jnp.float32), shape((E, H, F), bf),
+            shape((E, H, F), bf), shape((E, F, H), bf)).compile().as_text()
+
+
+@pytest.mark.parametrize("cell", sorted(_EXPERT_CELLS))
+def test_the_expert_layer_of_a_decode_step_compiles_as_one_kernel(
+        one_chip, monkeypatch, cell):
+    """Both expert cells' decode shapes take ``ops/pallas/expert_decode.py``:
+    ONE Mosaic call an expert layer for its three products and its combine,
+    within the VMEM the call asks for, and no grouped product is left."""
+    text = _compile_expert_layer(one_chip, monkeypatch, *_EXPERT_CELLS[cell])
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ragged" not in text and "scatter" not in text
+
+
+@pytest.mark.parametrize("T", [
+    pytest.param(14352, id="smallthinker-longest-bucket"),
+    pytest.param(1024, id="smallthinker-first-bucket-past-the-rule"),
+])
+def test_a_long_prefill_bucket_keeps_the_grouped_products(
+        one_chip, monkeypatch, T):
+    """Past ``DECODE_KERNEL_MAX_PAIRS`` the expert layer is the grouped
+    path's: three ``ragged_dot`` a chunk and the scatter-add."""
+    text = _compile_expert_layer(one_chip, monkeypatch, T, 6, 64, 2560, 768)
+    assert text.count("ragged-dot") >= 3 and "scatter" in text

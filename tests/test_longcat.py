@@ -365,3 +365,47 @@ def test_a_replica_admits_as_many_requests_as_its_engine_has_slots():
     assert caps(None) == caps({"batch_slots": 32}) == (32, 64)
     assert caps({"batch_slots": 8}) == (32, 64)
     assert caps({"batch_slots": 128, "max_len": 3584}) == (128, 256)
+
+
+def test_engine_on_the_expert_kernel_decodes_what_the_grouped_path_does(
+        monkeypatch):
+    """The decode program forced onto ``ops/pallas/expert_decode.py``
+    (interpreter; a held range, so most pairs are sentinels) returns token
+    for token what the grouped path returns, with the counters the grouped
+    path counts, and ``stats()["experts"]`` says which ran."""
+    from ray_tpu.ops import experts
+
+    cfg = preset("longcat_flash_tiny")
+    cfg = dataclasses.replace(cfg, first_expert=2, held_experts=4)
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (5, 17, 11)]
+    sp = SamplingParams(max_tokens=10, temperature=0.0, stop_token_id=None)
+
+    def run():
+        eng = LLMEngine(cfg, tokenizer=_Ids(), batch_slots=4, max_len=96,
+                        block_size=8, decode_window=4, seed=5)
+        outs = eng.generate(prompts, sp)
+        return eng.stats(), [o.token_ids for o in outs]
+
+    st, want = run()  # the CPU backend: grouped
+    assert st["experts"] == "grouped"
+    assert st["counters"]["expert_kernel_windows"] == 0
+    monkeypatch.setattr(
+        experts, "expert_path",
+        lambda T, *a: "decode_kernel" if T == 4 else "grouped")
+    st2, got = run()
+    assert got == want and st2["experts"] == "decode_kernel"
+    c, c2 = st["counters"], st2["counters"]
+    assert c2["expert_kernel_windows"] == c2["decode_windows"] > 0
+    assert {n: c2[n] for n in c if n.startswith(("moe_", "prefill_"))} \
+        == {n: c[n] for n in c if n.startswith(("moe_", "prefill_"))}
+
+
+def test_a_model_without_experts_has_no_experts_label():
+    from ray_tpu.models.llama import LlamaConfig
+
+    eng = LLMEngine(LlamaConfig.tiny(num_layers=1, dtype=jnp.float32),
+                    tokenizer=_Ids(), batch_slots=2, max_len=32)
+    st = eng.stats()
+    assert st["experts"] is None
+    assert "expert_kernel_windows" not in st["counters"]

@@ -375,3 +375,72 @@ def test_a_model_with_a_window_type_refuses_what_it_does_not_take():
             None, None, eng.pool, cfg=cfg)
     with pytest.raises(ValueError, match="at least one 'full'"):
         st.SmallThinkerConfig.tiny(layer_period=("window",))
+
+
+# ------------------------------ (g) the expert layer's two paths (PR 32)
+
+class _Spans:
+    """Stands in for ``tracing.annotate`` in the engine: keeps each
+    annotation's stats by name (``set_metadata`` included)."""
+
+    def __init__(self):
+        self.seen = []
+
+    def __call__(self, name, **stats):
+        self.seen.append((name, stats))
+        return self
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **more):
+        self.seen[-1][1].update(more)
+
+    def named(self, name):
+        return [s for n, s in self.seen if n == name]
+
+
+def test_engine_on_the_expert_kernel_decodes_what_the_grouped_path_does(
+        monkeypatch):
+    """The decode program forced onto ``ops/pallas/expert_decode.py``
+    (interpreter) returns token for token what the grouped path returns,
+    and the engine says which ran: ``stats()["experts"]``, the
+    ``engine.dispatch_window`` / ``engine.admit`` stat, the counter."""
+    from ray_tpu.llm import engine as engine_mod
+    from ray_tpu.ops import experts
+
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (5, 19, 9)]
+    sp = SamplingParams(max_tokens=12, temperature=0.0, stop_token_id=None)
+
+    def run():
+        spans = _Spans()
+        monkeypatch.setattr(engine_mod.tracing, "annotate", spans)
+        cfg, eng = _engine()
+        outs = eng.generate(prompts, sp)
+        return eng, spans, [o.token_ids for o in outs]
+
+    eng, spans, want = run()  # the CPU backend: grouped
+    assert eng.experts == eng.stats()["experts"] == "grouped"
+    assert eng.stats()["counters"]["expert_kernel_windows"] == 0
+    windows = spans.named("engine.dispatch_window")
+    assert windows and all(s["experts"] == "grouped" for s in windows)
+    # the decode program alone (4 slots a step) takes the kernel
+    monkeypatch.setattr(
+        experts, "expert_path",
+        lambda T, *a: "decode_kernel" if T == 4 else "grouped")
+    eng, spans, got = run()
+    assert got == want
+    st_ = eng.stats()
+    assert eng.experts == st_["experts"] == "decode_kernel"
+    c = st_["counters"]
+    assert c["expert_kernel_windows"] == c["decode_windows"] > 0
+    assert c["moe_pairs_held"] > 0 and c["moe_experts_hit"] > 0
+    windows = spans.named("engine.dispatch_window")
+    assert windows and all(s["experts"] == "decode_kernel" for s in windows)
+    admits = [s for s in spans.named("engine.admit") if s["kind"] == "full"]
+    assert len(admits) == 3
+    assert all(s["experts"] == "grouped" and s["bucket"] > 4 for s in admits)
