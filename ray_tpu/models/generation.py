@@ -24,7 +24,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.attention import sliding_window_mask  # noqa: F401
-from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
+from ray_tpu.ops.layers import (apply_rope, heads_projection, rms_norm,
+                                rope_frequencies, swiglu)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -108,12 +109,11 @@ def _layer_with_cache(x, lp, layer_kv, *, cfg, cos, sin, mask,
     masked attention for a caller that never materializes the merged cache
     (the paged decode kernel); ``layer_kv`` and ``mask`` are then unused."""
     b, s, h = x.shape
-    hd = cfg.resolved_head_dim
     dt = cfg.dtype
     y = rms_norm(x, lp["attn_norm"])
-    q = (y @ lp["wq"].astype(dt)).reshape(b, s, cfg.num_heads, hd)
-    k = (y @ lp["wk"].astype(dt)).reshape(b, s, cfg.num_kv_heads, hd)
-    v = (y @ lp["wv"].astype(dt)).reshape(b, s, cfg.num_kv_heads, hd)
+    q = heads_projection(y, lp["wq"].astype(dt), cfg.num_heads)
+    k = heads_projection(y, lp["wk"].astype(dt), cfg.num_kv_heads)
+    v = heads_projection(y, lp["wv"].astype(dt), cfg.num_kv_heads)
     q = apply_rope(q, cos, sin, positions)
     k = apply_rope(k, cos, sin, positions)
     if attend is not None:
@@ -132,7 +132,17 @@ def _layer_with_cache(x, lp, layer_kv, *, cfg, cos, sin, mask,
 
 def _stacked_layers(params):
     """Iterate stacked layer params [L, ...] without lax.scan (generation
-    caches differ per layer; a python loop keeps it simple and L is static)."""
+    caches differ per layer; a python loop keeps it simple and L is static).
+
+    What ``a[i]`` costs in the compiled program: nothing, where a product
+    reads it.  XLA:TPU makes the layer's slice of the stacked parameter an
+    operand of the product's own fusion (``fusion(%params__layers____wo__,
+    ...)``) and streams the weight from where it lies; the seven weights of
+    ``_layer_with_cache`` are all read so since ``heads_projection`` keeps
+    wq, wk and wv from being transposed first (PR 35; compiled for the v5e
+    in ``tests/test_flash_compile_v5e.py``).  It is a copy only for an
+    operand of a Mosaic call or under a ``lax.scan`` over the steps
+    (``paged_decode_sample``)."""
     L = jax.tree.leaves(params["layers"])[0].shape[0]
     for i in range(L):
         yield i, jax.tree.map(lambda a: a[i], params["layers"])

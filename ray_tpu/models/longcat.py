@@ -68,7 +68,8 @@ import jax.numpy as jnp
 from ray_tpu.models.paged_generation import (decode_attention_path,
                                              sample_token_batch)
 from ray_tpu.ops.experts import held_experts_ffn, route_top_k
-from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
+from ray_tpu.ops.layers import (apply_rope, heads_projection, rms_norm,
+                                rope_frequencies, swiglu)
 
 _LANES = 128
 
@@ -182,14 +183,14 @@ def _mla_project(x, ap, cfg: LongcatConfig, cos, sin, positions):
     """x ``[b, s, H]`` -> q_nope ``[b, s, nh, dn]``, q_pe ``[b, s, nh, dr]``
     (rotated), c_kv ``[b, s, kr]`` (normed and scaled: what the cache
     holds), k_pe ``[b, s, dr]`` (rotated, shared by the heads)."""
-    b, s, H = x.shape
+    H = x.shape[-1]
     dt = cfg.dtype
-    nh, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    nh, dn = cfg.num_heads, cfg.qk_nope_head_dim
     qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
     c_q = rms_norm(x @ ap["w_qa"].astype(dt), ap["q_norm"], cfg.rms_norm_eps)
     if cfg.mla_scale_q_lora:
         c_q = c_q * (H / qr) ** 0.5
-    q = (c_q @ ap["w_qb"].astype(dt)).reshape(b, s, nh, dn + dr)
+    q = heads_projection(c_q, ap["w_qb"].astype(dt), nh)
     kv = x @ ap["w_kva"].astype(dt)
     c_kv = rms_norm(kv[..., :kr], ap["kv_norm"], cfg.rms_norm_eps)
     if cfg.mla_scale_kv_lora:

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 
@@ -69,3 +70,25 @@ def swiglu(gate: jnp.ndarray, up: jnp.ndarray) -> jnp.ndarray:
     """SwiGLU activation: silu(gate) * up."""
     g = gate.astype(jnp.float32)
     return (g * jnp.reciprocal(1.0 + jnp.exp(-g))).astype(gate.dtype) * up
+
+
+def heads_projection(y: jnp.ndarray, w: jnp.ndarray, heads: int) -> jnp.ndarray:
+    """``y [..., hidden] @ w [hidden, heads * hd]`` handed on as
+    ``[..., heads, hd]``: the one place a cached decoder layer projects onto
+    its heads (``generation._layer_with_cache``: wq, wk, wv; ``longcat``'s
+    ``w_qb``).
+
+    The barrier keeps the product apart from the reshape.  Written as
+    ``(y @ w).reshape(...)``, or as an einsum onto ``w.reshape(hidden, heads,
+    hd)``, XLA:TPU folds the two into one convolution with the heads as a
+    window, wants ``w`` with its hidden axis minor for it, and so slices and
+    TRANSPOSES THE WHOLE WEIGHT every step (``slice_bitcast_fusion`` +
+    ``copy`` in the compiled program: 50 MB a layer at Mistral-7B's widths,
+    3.3 ms of a 16.5 ms decode step, PERF.md section 6 PR 35).  Held apart,
+    the product's own fusion streams ``w`` from where it lies, a layer's
+    slice of a stacked parameter included, and the relayout falls on the
+    result, which a decode step's few rows make small.
+    ``tests/test_flash_compile_v5e.py`` holds the compiled program to it.
+    """
+    out = jax.lax.optimization_barrier(y @ w)
+    return out.reshape(*y.shape[:-1], heads, -1)

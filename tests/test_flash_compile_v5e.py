@@ -1,5 +1,6 @@
-"""The flash kernels, and the decode-shaped expert kernel (PR 32), compiled
-for a v5e that is described, not attached.
+"""The flash kernels, the decode-shaped expert kernel (PR 32) and the two
+decode programs whose heads projections read their weights in place (PR 35),
+compiled for a v5e that is described, not attached.
 
 The interpreter cannot see what the chip's compiler refuses: more VMEM
 than a kernel may use (the backward keeps dK/dV whole in scratch and sets
@@ -11,8 +12,12 @@ Only the worker that is given this file may load libtpu, and only once a
 test has started: the topology is described in a fixture, never at import.
 """
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -146,3 +151,178 @@ def test_a_long_prefill_bucket_keeps_the_grouped_products(
     path's: three ``ragged_dot`` a chunk and the scatter-add."""
     text = _compile_expert_layer(one_chip, monkeypatch, T, 6, 64, 2560, 768)
     assert text.count("ragged-dot") >= 3 and "scatter" in text
+
+
+# --------------- the heads projection read in place (PR 35): the decode
+# programs of the two served models whose layers project onto their heads
+# through ``ops/layers.py:heads_projection``
+
+_MIB = 1 << 20
+_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1, "pred": 1}
+
+
+def _entry_results(text):
+    """(name, opcode, dims, bytes) of every array-valued instruction of the
+    compiled module's entry computation."""
+    entry = re.search(r"^ENTRY .*?\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    for line in entry.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(",
+            line)
+        if not m:
+            continue
+        name, dtype, dims, op = m.groups()
+        dims = tuple(int(d) for d in dims.split(",") if d)
+        size = _BYTES.get(dtype, 4)
+        for d in dims:
+            size *= d
+        yield name, op, dims, size
+
+
+def _relayouts(text, at_least=_MIB):
+    """What the parent's decode step did to a weight before multiplying by
+    it: a ``slice*fusion`` through the vector unit and a synchronous
+    ``copy`` (the transposition), results of ``at_least`` bytes."""
+    return [(name, op, dims) for name, op, dims, size in _entry_results(text)
+            if size >= at_least
+            and (op == "copy" or (op == "fusion" and "slice" in name))]
+
+
+def _compile_decode(step, params, pool, B, MB, one_chip):
+    """The text of ``step(params, token, cur_len, block_tables, pool, key,
+    temperature)`` compiled for ``B`` slots of ``MB`` blocks, the pool
+    donated as the engine donates it."""
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    args = (params, i32(B), i32(B), i32(B, MB), pool, key,
+            jax.ShapeDtypeStruct((B,), jnp.float32))
+    args = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), args)
+    return jax.jit(step, donate_argnums=(4,)).lower(
+        *args).compile().as_text()
+
+
+def test_the_dense_decode_step_reads_wq_wk_wv_in_place(one_chip, monkeypatch):
+    """``paged_decode_sample`` at Mistral-7B-v0.3's widths (2 of the cell's
+    22 layers, its 32 slots, block size and ``max_len``, a tenth of its
+    pool, bf16): no instruction of the entry computation slices a weight
+    through the vector unit or copies it into another layout.  On the
+    parent this finds 3 copies a layer (``copy = bf16[4096,4096]{1,0}``,
+    two ``bf16[1024,4096]{1,0}``: wq, wk and wv TRANSPOSED for a product
+    that XLA folded with the reshape behind it), 100.7 MB at two layers
+    and 1 107 MB a step at the cell's 22 beside 797 MB of
+    ``slice_bitcast_fusion*``.
+
+    Not held to zero: ``slice-done``.  At two layers the compiler's memory
+    assignment fetches all six weights ahead into memory space 1 by
+    ``slice-start``/``slice-done``, a DMA in the parameter's own layout that
+    the product then reads there (no vector work, the same bytes once); at
+    22 layers it fetches none.  The parent's ``slice-done`` fed the copies,
+    and with no copy left there is nothing for one to feed."""
+    from ray_tpu.models.llama import LlamaConfig, llama_init
+    from ray_tpu.models.paged_generation import (init_kv_pool,
+                                                 paged_decode_sample)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = LlamaConfig(
+        vocab_size=32768, hidden_size=4096, num_layers=2, num_heads=32,
+        num_kv_heads=8, head_dim=128, mlp_dim=14336, max_seq_len=2560,
+        rope_theta=1e6, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    B, bs, MB = 32, 16, 160
+    params = jax.eval_shape(functools.partial(llama_init, cfg=cfg),
+                            jax.random.PRNGKey(0))
+    pool = jax.eval_shape(lambda: init_kv_pool(cfg, 256, bs))
+    text = _compile_decode(functools.partial(paged_decode_sample, cfg=cfg),
+                           params, pool, B, MB, one_chip)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2  # a layer
+    assert _relayouts(text) == []
+
+
+def test_longcat_decode_step_reads_w_qb_in_place(one_chip, monkeypatch):
+    """LongCat-Flash's decode step at its published widths, one double
+    layer, 16 held experts, the cell's 128 slots: no copy of ``w_qb``
+    (``[q_lora_rank, heads x (nope + rope)]`` = ``[1536, 12288]``, 37.7 MB)
+    in either layout.  The parent had one an attention block,
+    ``copy = bf16[1536,12288]{0,1}`` without a memory space: read, written
+    transposed to HBM and read again.  What stays an attention block, and
+    is not this test's: ``w_kvb`` whole and one of the two halves the
+    absorbed products cut it into (16.8 + 8.4 MB: ROADMAP A2), and the
+    projection's own result ``[128, 1, 12288]`` (3.1 MB), which is where
+    the barrier moves the relayout to."""
+    from ray_tpu.models import longcat
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = longcat.LongcatConfig(
+        vocab_size=16384, num_layers=1, first_expert=80, held_experts=16,
+        max_seq_len=3584, param_dtype=jnp.bfloat16)
+    w_qb = (cfg.q_lora_rank,
+            cfg.num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
+    assert w_qb == (1536, 12288)
+    B, bs, MB = 128, 16, 224
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    params = jax.eval_shape(
+        functools.partial(longcat.longcat_init, cfg=cfg), key)
+    pool = jax.eval_shape(lambda: longcat.init_latent_pool(cfg, 512, bs))
+    text = _compile_decode(
+        functools.partial(longcat.latent_decode_sample, cfg=cfg,
+                          attn="latent_kernel"),
+        params, pool, B, MB, one_chip)
+    assert [m for m in _relayouts(text)
+            if m[2] in (w_qb, w_qb[::-1])] == []
+
+
+@pytest.mark.parametrize("tp", [1, 2], ids=["one-device", "tp2"])
+@pytest.mark.parametrize("s", [1, 64])
+def test_the_layers_q_k_v_are_the_plain_products_bit_for_bit(s, tp):
+    """On the CPU (no libtpu): what ``_layer_with_cache`` hands its
+    attention equals ``apply_rope((y @ w).reshape(b, s, heads, hd))`` bit
+    for bit, at decode and prefill row counts, on one device and with the
+    weights sharded over heads on a 2-way ``tp`` mesh of host devices;
+    float32 parameters cast to the bf16 of the products."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models.generation import _layer_with_cache
+    from ray_tpu.models.llama import LlamaConfig, _layer_init
+    from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies
+
+    cfg = LlamaConfig.tiny(dtype=jnp.bfloat16, head_dim=16)
+    hd, b = cfg.resolved_head_dim, 3
+    lp = _layer_init(jax.random.PRNGKey(1), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(2), (b, s, cfg.hidden_size),
+                          cfg.dtype)
+    cos, sin = rope_frequencies(hd, 128, cfg.rope_theta)
+    positions = 5 + jnp.arange(b)[:, None] + jnp.arange(s)[None]
+    if tp > 1:
+        mesh = Mesh(np.array(jax.devices("cpu")[:tp]), ("tp",))
+        over_heads = NamedSharding(mesh, P(None, "tp"))
+        lp = {k: jax.device_put(w, over_heads) if k in ("wq", "wk", "wv")
+              else w for k, w in lp.items()}
+
+    @jax.jit
+    def layer(x, lp):
+        seen = []
+
+        def attend(q, k, v):
+            seen.extend((q, k, v))
+            return jnp.zeros_like(q)
+
+        _layer_with_cache(x, lp, None, cfg=cfg, cos=cos, sin=sin, mask=None,
+                          positions=positions, attend=attend)
+        return seen
+
+    @jax.jit
+    def plain(x, lp):
+        y = rms_norm(x, lp["attn_norm"])
+        q, k, v = ((y @ lp[w].astype(cfg.dtype)).reshape(b, s, heads, hd)
+                   for w, heads in (("wq", cfg.num_heads),
+                                    ("wk", cfg.num_kv_heads),
+                                    ("wv", cfg.num_kv_heads)))
+        return [apply_rope(q, cos, sin, positions),
+                apply_rope(k, cos, sin, positions), v]
+
+    for got, want in zip(layer(x, lp), plain(x, lp)):
+        assert got.dtype == want.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(jnp.float32)),
+            np.asarray(want.astype(jnp.float32)))
