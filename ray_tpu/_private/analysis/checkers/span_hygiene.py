@@ -1,150 +1,51 @@
 """span-hygiene: a trace span must reach its closing path.
 
-The tracing layer (``ray_tpu/_private/tracing.py``) has two faces: the
-``span()``/``trace()`` context managers (lexical lifetime, always
-closed) and ``start_span()`` (manual lifetime, returns a handle that
-must reach ``.end()`` on every path).  The leak class this rule guards
-— mirroring ``thread-lifecycle`` — is a handle stashed in an attribute
-with no closing path: the span stays in the process's open-span table
-forever, its subtree never renders closed in the timeline, and the
-bounded-table eviction silently drops OTHER spans to make room.
+The tracing layer's host spans (``ray_tpu/_private/tracing.py``) are the
+``span()``/``trace()`` context managers: lexical lifetime, always closed,
+provided they are entered.  They are single-use generators, so the leak
+this rule guards, mirroring ``thread-lifecycle``, is one stored instead of
+``with``-entered: it never runs, the span it stands for is never recorded,
+and nothing submitted "inside" it parents to it.
 
-Flagged:
-
-* ``self._span = tracing.start_span(...)`` with no ``self._span.end()``
-  (or ``.close()``) anywhere in the enclosing class;
-* ``s = tracing.start_span(...)`` with no ``s.end()`` in the enclosing
-  function (returning the handle hands lifetime to the caller: allowed);
-* ``... = tracing.span(...)`` / ``tracing.trace(...)`` stored anywhere —
-  the context managers are single-use generators; stashing one instead
-  of ``with``-entering it can never close correctly.
+Flagged: ``... = tracing.span(...)`` / ``tracing.trace(...)`` assigned
+anywhere (a local, an attribute).
 """
 
 from __future__ import annotations
 
 import ast
-from typing import Iterable, List, Optional, Tuple
+from typing import Iterable, List
 
 from ray_tpu._private.analysis.core import (
     Checker, Finding, ParsedFile, register)
 
 _CM_NAMES = ("span", "trace")
-_MANUAL = "start_span"
-_CLOSERS = ("end", "close", "__exit__")
 
 
-def _span_call_kind(call: ast.Call) -> Optional[str]:
-    """"cm" for span()/trace(), "manual" for start_span(); None else.
-    Matches ``tracing.<name>(...)`` and bare ``<name>(...)`` (imported
-    directly)."""
+def _is_span_cm(call: ast.Call) -> bool:
+    """``tracing.span(...)`` / ``tracing.trace(...)``.  Bare ``span()`` /
+    ``trace()`` are too common as user names: only the
+    ``tracing.``-qualified forms are claimed by this rule."""
     f = call.func
-    name = None
-    if isinstance(f, ast.Attribute) and isinstance(f.value, ast.Name) \
-            and f.value.id == "tracing":
-        name = f.attr
-    elif isinstance(f, ast.Name):
-        name = f.id
-    if name == _MANUAL:
-        return "manual"
-    if name in _CM_NAMES and isinstance(f, ast.Attribute):
-        # bare span()/trace() are too common as user names; only the
-        # tracing.-qualified CM forms are claimed by this rule
-        return "cm"
-    return None
-
-
-def _assign_target(pf: ParsedFile,
-                   call: ast.Call) -> Optional[Tuple[str, str]]:
-    """("self", attr) / ("local", name) the handle is bound to, following
-    one level of assignment; anything fancier counts as unbound."""
-    parent = pf.parent(call)
-    if isinstance(parent, ast.Assign) and len(parent.targets) == 1:
-        tgt = parent.targets[0]
-        if isinstance(tgt, ast.Name):
-            return ("local", tgt.id)
-        if isinstance(tgt, ast.Attribute) and \
-                isinstance(tgt.value, ast.Name) and tgt.value.id == "self":
-            return ("self", tgt.attr)
-    return None
-
-
-def _is_with_item(pf: ParsedFile, call: ast.Call) -> bool:
-    parent = pf.parent(call)
-    return isinstance(parent, ast.withitem)
-
-
-def _scope_closes(scope: ast.AST, kind: str, name: str) -> bool:
-    """True when the scope calls ``<handle>.end()``-style closers, or
-    (locals) returns/yields the handle — lifetime handed to the caller."""
-    for n in ast.walk(scope):
-        if isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute) \
-                and n.func.attr in _CLOSERS:
-            v = n.func.value
-            if kind == "local" and isinstance(v, ast.Name) and v.id == name:
-                return True
-            if kind == "self" and isinstance(v, ast.Attribute) \
-                    and v.attr == name and isinstance(v.value, ast.Name) \
-                    and v.value.id == "self":
-                return True
-        if kind == "local" and isinstance(n, (ast.Return, ast.Yield)) \
-                and isinstance(getattr(n, "value", None), ast.Name) \
-                and n.value.id == name:
-            return True
-    return False
+    return (isinstance(f, ast.Attribute) and isinstance(f.value, ast.Name)
+            and f.value.id == "tracing" and f.attr in _CM_NAMES)
 
 
 @register
 class SpanHygieneChecker(Checker):
     rule = "span-hygiene"
-    description = ("trace spans must close: start_span() handles need an "
-                   ".end() path; span()/trace() context managers must be "
-                   "with-entered, never stashed")
-    hint = ("use `with tracing.span(...):` for lexical lifetimes; for a "
-            "stashed start_span() handle add an .end()/.close() path in "
-            "the same class (stop()/close()/finally)")
+    description = ("trace spans must close: span()/trace() context "
+                   "managers must be with-entered, never stashed")
+    hint = "use `with tracing.span(...):` (or `tracing.trace(...)`)"
 
     def check(self, pf: ParsedFile) -> Iterable[Finding]:
         out: List[Finding] = []
         for node in ast.walk(pf.tree):
-            if not isinstance(node, ast.Call):
-                continue
-            k = _span_call_kind(node)
-            if k is None:
-                continue
-            if k == "cm":
-                if _is_with_item(pf, node):
-                    continue
-                if _assign_target(pf, node) is not None or \
-                        isinstance(pf.parent(node), ast.Assign):
-                    out.append(self.finding(
-                        pf, node,
-                        "tracing.span()/trace() is a single-use context "
-                        "manager — stashing it instead of `with`-entering "
-                        "it can never close the span"))
-                continue
-            # manual start_span(): needs a closing path for its binding
-            if _is_with_item(pf, node):
-                continue  # `with start_span(...)` is not the API, but
-                # entering/exiting would still close — out of scope here
-            bound = _assign_target(pf, node)
-            if bound is None:
-                parent = pf.parent(node)
-                if isinstance(parent, (ast.Return, ast.Yield)):
-                    continue  # handle returned: caller owns the lifetime
+            if isinstance(node, ast.Call) and _is_span_cm(node) \
+                    and isinstance(pf.parent(node), ast.Assign):
                 out.append(self.finding(
                     pf, node,
-                    "start_span() handle is dropped — the span can never "
-                    "reach .end() and leaks in the open-span table"))
-                continue
-            kind, name = bound
-            scope = (pf.enclosing_class(node) if kind == "self"
-                     else pf.enclosing_function(node)) or pf.tree
-            if not _scope_closes(scope, kind, name):
-                where = "class" if kind == "self" else "function"
-                out.append(self.finding(
-                    pf, node,
-                    f"start_span() handle bound to "
-                    f"{'self.' if kind == 'self' else ''}{name} has no "
-                    f".end()/.close() path in the enclosing {where} — "
-                    f"the span leaks open"))
+                    "tracing.span()/trace() is a single-use context "
+                    "manager — stashing it instead of `with`-entering "
+                    "it can never close the span"))
         return out

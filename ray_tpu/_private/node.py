@@ -50,7 +50,17 @@ def ensure_compile_cache_env() -> str:
     it is ONE fixed directory in the checkout — the path is part of the
     cache key, so nothing derives it from a pid, a clock or a session
     directory.  JAX reads the variable at import: call this before the
-    first ``import jax`` of a process that compiles."""
+    first ``import jax`` of a process that compiles.
+
+    The cache's key takes the programs' metadata in (JAX's default leaves
+    it out): a program's name scopes (``tracing.scope``) are metadata, and
+    an executable loaded under a key that ignores them carries whatever
+    scopes the process that compiled it had.  Seen on the v5e (PERF.md
+    section 6, PR 37): the dense prefill programs, the same HLO as the
+    parent commit's but for their scopes, came out of a warm cache without
+    a single scope in the profiler's trace."""
+    os.environ.setdefault("JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY",
+                          "true")
     return os.environ.setdefault(
         "JAX_COMPILATION_CACHE_DIR",
         os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(

@@ -46,10 +46,16 @@ of the ``serve-chat-steady`` cell with tracing on and switched off read the
 same median time per token (56.995 and 56.990 ms; the runs of either side
 spread 0.3 ms).
 
-Span-hygiene (enforced by the ``span-hygiene`` raylint rule): prefer the
-``span()`` context manager.  ``start_span()`` returns a handle that MUST
-reach ``.end()`` on every path; stashing it in an attribute without a
-closing path leaks an open span.
+A third face, for code that JAX traces: :func:`scope` is a name scope
+INSIDE a device program (``engine.decode`` / ``attn.proj``: the program,
+then the part of the model).  It is metadata of the compiled instructions,
+so it has no run-time path and nothing to switch off; the profiler's trace
+carries it on every device instruction (``docs/observability.md`` has the
+vocabulary, ``cells/parts.py`` the readers).
+
+Span-hygiene (enforced by the ``span-hygiene`` raylint rule): ``span()``
+and ``trace()`` are context managers and must be entered with ``with``;
+one stashed in an attribute is never entered and never closes.
 """
 
 from __future__ import annotations
@@ -61,7 +67,7 @@ import os
 import sys
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import deque
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
 ENV_ENABLED = "RAY_TPU_TRACING"
@@ -130,11 +136,11 @@ _current: contextvars.ContextVar[Optional[SpanContext]] = \
 
 _buffer_lock = threading.Lock()
 _finished: deque = deque(maxlen=_buffer_cap())
-# manually-opened spans (start_span) + the lazy process root, by span_id;
-# published with their current duration and ``open: True`` so a trace is
-# never missing an ancestor just because it has not closed yet
-_open: "OrderedDict[str, Dict[str, Any]]" = OrderedDict()
+# the lazy process root: the one span that never closes, published with its
+# current duration and ``open: True`` so a trace is never missing its
+# ancestor
 _root_ctx: Optional[SpanContext] = None
+_root_entry: Optional[Dict[str, Any]] = None
 _publisher_started = False
 
 # pluggable duration sinks: the train step ledger registers here so
@@ -214,13 +220,13 @@ def _process_kind() -> str:
 def _ensure_root() -> SpanContext:
     """The lazy per-process root span: work submitted outside any scope
     (a bare driver script) still forms one connected tree per process."""
-    global _root_ctx
+    global _root_ctx, _root_entry
     if _root_ctx is not None:
         return _root_ctx
     with _buffer_lock:
         if _root_ctx is None:
             ctx = SpanContext(new_trace_id(), new_span_id(), None)
-            _open[ctx.span_id] = {
+            _root_entry = {
                 "name": f"{_process_kind()}-root", "kind": "root",
                 "trace_id": ctx.trace_id, "span_id": ctx.span_id,
                 "parent_span_id": None, "start": time.time(), "end": None,
@@ -281,6 +287,46 @@ def annotate(name: str, **stats):
     return profiler.TraceAnnotation(name, **stats)
 
 
+# The vocabulary of the device programs' name scopes, two levels
+# (docs/observability.md has the table; tests hold the models to it).
+PROGRAM_SCOPES = ("engine.decode", "engine.prefill", "engine.verify",
+                  "train.step")
+PART_SCOPES = ("embed", "attn.proj", "attn.cache", "attn.core", "attn.out",
+               "ffn", "router", "experts", "experts.combine", "head",
+               "sample", "loss", "optimizer")
+
+
+def scope(name: str):
+    """A name scope INSIDE a device program, for code that JAX traces::
+
+        with tracing.scope("attn.proj"):
+            q = heads_projection(y, wq, heads)
+
+    A thin face of ``jax.named_scope``: every instruction traced under it
+    carries ``.../<name>/...`` in its ``op_name``, which rides into the
+    compiled program and from there into the profiler's trace (XProf's
+    "Framework Name Scope" rows), on the device's own clock.  It is
+    metadata: the compiled program's schedule does not see it, nothing
+    runs when the program does, and so (unlike :func:`annotate`) there is
+    nothing for ``RAY_TPU_TRACING`` to switch off.  Two levels, one
+    vocabulary for every model (``docs/observability.md``): the program
+    (``engine.decode``, ``engine.prefill``, ``engine.verify``,
+    ``train.step``), put once where the program is built, then the part
+    (``attn.proj``, ``experts``, ``head``, ...)."""
+    jax = sys.modules.get("jax")
+    return jax.named_scope(name) if jax is not None else _NO_ANNOTATION
+
+
+def scoped(name: str, fn: Callable, *args, **kwargs):
+    """``fn(*args, **kwargs)`` traced under :func:`scope` ``name``.  For a
+    program scope: ``jax.jit(functools.partial(tracing.scoped,
+    "engine.decode", decode_sample, cfg=cfg))`` puts the whole program
+    under it and leaves the program's module name what a jitted partial's
+    is."""
+    with scope(name):
+        return fn(*args, **kwargs)
+
+
 def _scalar_stats(attrs: Optional[Dict[str, Any]]) -> Dict[str, Any]:
     """The attrs an annotation can carry: the trace encodes stats as
     ``name#k=v,k=v#`` text, so containers stay in the host record only."""
@@ -307,54 +353,6 @@ def record_span(name: str, start: float, end: float,
     with _buffer_lock:
         _finished.append(entry)
     _ensure_publisher()
-
-
-class Span:
-    """A manually-managed span (``start_span``).  Must reach :meth:`end`
-    on every path — the ``span-hygiene`` lint rule flags handles stashed
-    in attributes without a closing path."""
-
-    __slots__ = ("name", "kind", "ctx", "start", "attrs", "_ended")
-
-    def __init__(self, name: str, kind: str, ctx: SpanContext,
-                 attrs: Optional[Dict[str, Any]] = None):
-        self.name = name
-        self.kind = kind
-        self.ctx = ctx
-        self.attrs = attrs
-        self.start = time.time()
-        self._ended = False
-        with _buffer_lock:
-            _open[ctx.span_id] = {
-                "name": name, "kind": kind, "trace_id": ctx.trace_id,
-                "span_id": ctx.span_id,
-                "parent_span_id": ctx.parent_span_id,
-                "start": self.start, "end": None, "pid": os.getpid(),
-            }
-            while len(_open) > _buffer_cap():  # leak backstop
-                _open.popitem(last=False)
-
-    def end(self) -> None:
-        if self._ended:
-            return
-        self._ended = True
-        with _buffer_lock:
-            _open.pop(self.ctx.span_id, None)
-        record_span(self.name, self.start, time.time(), self.ctx,
-                    kind=self.kind, attrs=self.attrs)
-
-
-def start_span(name: str, *, kind: str = "",
-               parent: Optional[SpanContext] = None,
-               attrs: Optional[Dict[str, Any]] = None) -> Optional[Span]:
-    """Open a span with a non-lexical lifetime.  Returns None when
-    tracing is disabled (callers guard with ``if s is not None``, or use
-    :func:`span` which handles it)."""
-    if not is_enabled():
-        return None
-    ctx = (parent or current_or_root()).child()
-    _ensure_publisher()
-    return Span(name, kind, ctx, attrs)
 
 
 @contextlib.contextmanager
@@ -443,26 +441,21 @@ def task_scope(trace_ctx: Optional[Dict[str, Any]]) -> Iterator[None]:
 
 
 def local_spans(include_open: bool = True) -> List[Dict[str, Any]]:
-    """Snapshot of this process's span buffer (finished + open)."""
-    now = time.time()
+    """Snapshot of this process's span buffer: the finished spans and,
+    with ``include_open``, the process root (the one span left open)."""
     with _buffer_lock:
         out = [dict(e) for e in _finished]
-        if include_open:
-            for e in _open.values():
-                d = dict(e)
-                d["end"] = now
-                d["open"] = True
-                out.append(d)
+        if include_open and _root_entry is not None:
+            out.append(dict(_root_entry, end=time.time(), open=True))
     return out
 
 
 def clear_local() -> None:
     """Drop buffered spans (test isolation)."""
-    global _root_ctx
+    global _root_ctx, _root_entry
     with _buffer_lock:
         _finished.clear()
-        _open.clear()
-        _root_ctx = None
+        _root_ctx = _root_entry = None
 
 
 def publish_kv() -> None:

@@ -383,14 +383,20 @@ class LLMEngine:
         # (ops/experts.py:expert_path at this engine's B rows a step):
         # "decode_kernel" / "grouped"; None for a model without experts
         self.experts = self._expert_path(self.B)
+        # each of the three model programs under its name scope (the
+        # outer level of docs/observability.md's vocabulary; the parts are
+        # the models'): still a jitted partial, so the module keeps the
+        # name the trace readers and the compile cache know it by
         self._decode1 = jax.jit(
-            functools.partial(model.decode_sample, cfg=cfg, attn=self.attn),
+            functools.partial(tracing.scoped, "engine.decode",
+                              model.decode_sample, cfg=cfg, attn=self.attn),
             donate_argnums=(4,))
         self._stack = jax.jit(lambda *ts: jnp.stack(ts))
         from ray_tpu.models.paged_generation import sample_token_batch
 
         self._prefill = jax.jit(
-            functools.partial(model.prefill_suffix, cfg=cfg),
+            functools.partial(tracing.scoped, "engine.prefill",
+                              model.prefill_suffix, cfg=cfg),
             donate_argnums=(9,))  # the pool (avoid a full second copy)
         self._sample = jax.jit(sample_token_batch)
         # the small integer sums a model's programs return beside the rest
@@ -405,8 +411,11 @@ class LLMEngine:
         if any(p.window for p in pools):
             self.counters["window_blocks_released"] = 0
         # every model: windows dispatched, and how many of them were
-        # launched before the emit of the window before (step())
-        self.counters.update(decode_windows=0, windows_carried=0)
+        # launched before the emit of the window before (step()); prompt
+        # tokens prefilled (suffixes' true lengths) and the buckets they
+        # were padded to (the bucket rule's waste without a trace)
+        self.counters.update(decode_windows=0, windows_carried=0,
+                             prefill_tokens=0, prefill_padded_tokens=0)
         if self.experts:  # of decode_windows: those the kernel ran
             self.counters["expert_kernel_windows"] = 0
         self._stack_counted = jax.jit(lambda toks, counts: jnp.concatenate(
@@ -456,7 +465,8 @@ class LLMEngine:
         self.reset_spec_state()
         if self.G:
             self._verify = jax.jit(
-                functools.partial(model.verify_step, cfg=cfg),
+                functools.partial(tracing.scoped, "engine.verify",
+                                  model.verify_step, cfg=cfg),
                 donate_argnums=(4,))
 
         # chunked prefill (vLLM's feature TPU-natively): cap the prompt
@@ -1024,6 +1034,9 @@ class LLMEngine:
         req = self._slots[i] if kind == "full" else self._queue[0]
         stats = {"kind": kind, "rid": req.request_id,
                  "prompt_tokens": len(req.prompt_tokens),
+                 # the suffix's true length (a prefix hit or a chunk
+                 # prefills less than the prompt) and what it was padded to
+                 "prefilled_tokens": prefilled,
                  "bucket": _bucket(prefilled, self.max_len)
                  if prefilled else 0}
         if self.experts and prefilled:  # the bucket's prefill program's
@@ -1421,6 +1434,8 @@ class LLMEngine:
         import jax.numpy as jnp
 
         S = _bucket(len(suffix), self.max_len)
+        self.counters["prefill_tokens"] += len(suffix)
+        self.counters["prefill_padded_tokens"] += S
         pad_tok = list(suffix) + [0] * (S - len(suffix))
         # pool coordinates for each padded suffix lane (pads -> scratch 0)
         pos = cached_len + np.arange(len(suffix))

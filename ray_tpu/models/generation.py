@@ -22,6 +22,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu._private import tracing
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.attention import sliding_window_mask  # noqa: F401
 from ray_tpu.ops.layers import (apply_rope, heads_projection, rms_norm,
@@ -107,27 +108,37 @@ def _layer_with_cache(x, lp, layer_kv, *, cfg, cos, sin, mask,
     per-token-head scales — routed through the scale-folded attend).
     ``attend(q, k, v) -> [b, s, H, hd]`` replaces both the merge and the
     masked attention for a caller that never materializes the merged cache
-    (the paged decode kernel); ``layer_kv`` and ``mask`` are then unused."""
+    (the paged decode kernel); ``layer_kv`` and ``mask`` are then unused.
+
+    The layer's parts carry the name scopes of ``docs/observability.md``
+    (``attn.proj``, ``attn.cache``, ``attn.core``, ``attn.out``, ``ffn``):
+    a profiler trace's device time is cut by them."""
     b, s, h = x.shape
     dt = cfg.dtype
-    y = rms_norm(x, lp["attn_norm"])
-    q = heads_projection(y, lp["wq"].astype(dt), cfg.num_heads)
-    k = heads_projection(y, lp["wk"].astype(dt), cfg.num_kv_heads)
-    v = heads_projection(y, lp["wv"].astype(dt), cfg.num_kv_heads)
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
+    with tracing.scope("attn.proj"):
+        y = rms_norm(x, lp["attn_norm"])
+        q = heads_projection(y, lp["wq"].astype(dt), cfg.num_heads)
+        k = heads_projection(y, lp["wk"].astype(dt), cfg.num_kv_heads)
+        v = heads_projection(y, lp["wv"].astype(dt), cfg.num_kv_heads)
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
     if attend is not None:
-        attn = attend(q, k, v)
+        attn = attend(q, k, v)  # opens attn.cache and attn.core itself
     else:
-        merged = layer_kv(k, v)  # merge with cache; full keys/vals
-        if len(merged) == 4:
-            attn = _gqa_attend_quant(q, *merged, mask)
-        else:
-            attn = _gqa_attend(q, merged[0], merged[1], mask)
-    x = x + (attn.reshape(b, s, -1) @ lp["wo"].astype(dt))
-    y = rms_norm(x, lp["mlp_norm"])
-    act = swiglu(y @ lp["w_gate"].astype(dt), y @ lp["w_up"].astype(dt))
-    return x + act @ lp["w_down"].astype(dt), (k, v)
+        with tracing.scope("attn.cache"):
+            merged = layer_kv(k, v)  # merge with cache; full keys/vals
+        with tracing.scope("attn.core"):
+            if len(merged) == 4:
+                attn = _gqa_attend_quant(q, *merged, mask)
+            else:
+                attn = _gqa_attend(q, merged[0], merged[1], mask)
+    with tracing.scope("attn.out"):
+        x = x + (attn.reshape(b, s, -1) @ lp["wo"].astype(dt))
+    with tracing.scope("ffn"):
+        y = rms_norm(x, lp["mlp_norm"])
+        act = swiglu(y @ lp["w_gate"].astype(dt), y @ lp["w_up"].astype(dt))
+        x = x + act @ lp["w_down"].astype(dt)
+    return x, (k, v)
 
 
 def _stacked_layers(params):

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu._private import tracing
 from ray_tpu.ops.attention import dot_product_attention
 from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
 
@@ -205,39 +206,43 @@ def _decoder_layer(x, lp, *, cfg: LlamaConfig, cos, sin, mesh, rules=None):
     b, s, h = x.shape
     hd = cfg.resolved_head_dim
     dt = cfg.dtype
-    # Attention block.
-    y = rms_norm(x, lp["attn_norm"])
-    q = jnp.einsum("bsh,hq->bsq", y, lp["wq"].astype(dt),
-                   preferred_element_type=jnp.float32).astype(dt)
-    k = jnp.einsum("bsh,hq->bsq", y, lp["wk"].astype(dt),
-                   preferred_element_type=jnp.float32).astype(dt)
-    v = jnp.einsum("bsh,hq->bsq", y, lp["wv"].astype(dt),
-                   preferred_element_type=jnp.float32).astype(dt)
-    q = q.reshape(b, s, cfg.num_heads, hd)
-    k = k.reshape(b, s, cfg.num_kv_heads, hd)
-    v = v.reshape(b, s, cfg.num_kv_heads, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    q = _constrain(q, mesh, "batch", "seq", "heads", None, rules=rules)
-    attn = dot_product_attention(
-        q, k, v, causal=True, impl=cfg.attention_impl, mesh=mesh,
-        window=cfg.sliding_window
-    )
-    attn = checkpoint_name(attn, "attn_out")
-    attn = attn.reshape(b, s, cfg.num_heads * hd)
-    x = x + jnp.einsum("bsq,qh->bsh", attn, lp["wo"].astype(dt),
+    # Attention block; the name scopes are docs/observability.md's parts.
+    with tracing.scope("attn.proj"):
+        y = rms_norm(x, lp["attn_norm"])
+        q = jnp.einsum("bsh,hq->bsq", y, lp["wq"].astype(dt),
                        preferred_element_type=jnp.float32).astype(dt)
-    x = _constrain(x, mesh, "batch", "seq", None, rules=rules)
+        k = jnp.einsum("bsh,hq->bsq", y, lp["wk"].astype(dt),
+                       preferred_element_type=jnp.float32).astype(dt)
+        v = jnp.einsum("bsh,hq->bsq", y, lp["wv"].astype(dt),
+                       preferred_element_type=jnp.float32).astype(dt)
+        q = q.reshape(b, s, cfg.num_heads, hd)
+        k = k.reshape(b, s, cfg.num_kv_heads, hd)
+        v = v.reshape(b, s, cfg.num_kv_heads, hd)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        q = _constrain(q, mesh, "batch", "seq", "heads", None, rules=rules)
+    with tracing.scope("attn.core"):
+        attn = dot_product_attention(
+            q, k, v, causal=True, impl=cfg.attention_impl, mesh=mesh,
+            window=cfg.sliding_window
+        )
+        attn = checkpoint_name(attn, "attn_out")
+    with tracing.scope("attn.out"):
+        attn = attn.reshape(b, s, cfg.num_heads * hd)
+        x = x + jnp.einsum("bsq,qh->bsh", attn, lp["wo"].astype(dt),
+                           preferred_element_type=jnp.float32).astype(dt)
+        x = _constrain(x, mesh, "batch", "seq", None, rules=rules)
     # MLP block.
-    y = rms_norm(x, lp["mlp_norm"])
-    gate = jnp.einsum("bsh,hm->bsm", y, lp["w_gate"].astype(dt),
-                      preferred_element_type=jnp.float32).astype(dt)
-    up = jnp.einsum("bsh,hm->bsm", y, lp["w_up"].astype(dt),
-                    preferred_element_type=jnp.float32).astype(dt)
-    act = checkpoint_name(swiglu(gate, up), "mlp_act")
-    x = x + jnp.einsum("bsm,mh->bsh", act, lp["w_down"].astype(dt),
-                       preferred_element_type=jnp.float32).astype(dt)
-    return _constrain(x, mesh, "batch", "seq", None, rules=rules)
+    with tracing.scope("ffn"):
+        y = rms_norm(x, lp["mlp_norm"])
+        gate = jnp.einsum("bsh,hm->bsm", y, lp["w_gate"].astype(dt),
+                          preferred_element_type=jnp.float32).astype(dt)
+        up = jnp.einsum("bsh,hm->bsm", y, lp["w_up"].astype(dt),
+                        preferred_element_type=jnp.float32).astype(dt)
+        act = checkpoint_name(swiglu(gate, up), "mlp_act")
+        x = x + jnp.einsum("bsm,mh->bsh", act, lp["w_down"].astype(dt),
+                           preferred_element_type=jnp.float32).astype(dt)
+        return _constrain(x, mesh, "batch", "seq", None, rules=rules)
 
 
 def llama_apply(
@@ -257,7 +262,8 @@ def llama_apply(
     """
     s = tokens.shape[1]
     cos, sin = rope_frequencies(cfg.resolved_head_dim, s, cfg.rope_theta)
-    x = _embed_lookup(params, tokens, cfg, mesh=mesh, rules=rules)
+    with tracing.scope("embed"):
+        x = _embed_lookup(params, tokens, cfg, mesh=mesh, rules=rules)
 
     layer_fn = functools.partial(_decoder_layer, cfg=cfg, cos=cos, sin=sin,
                                  mesh=mesh, rules=rules)
@@ -332,13 +338,14 @@ def llama_apply(
     else:
         for lp in params["layers"]:
             x = layer_fn(x, lp)
-    x = rms_norm(x, params["final_norm"])
-    head = (
-        params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    ).astype(cfg.dtype)
-    logits = jnp.einsum("bsh,hv->bsv", x, head,
-                        preferred_element_type=jnp.float32)
-    return _constrain(logits, mesh, "batch", "seq", None, rules=rules)
+    with tracing.scope("head"):
+        x = rms_norm(x, params["final_norm"])
+        head = (
+            params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        ).astype(cfg.dtype)
+        logits = jnp.einsum("bsh,hv->bsv", x, head,
+                            preferred_element_type=jnp.float32)
+        return _constrain(logits, mesh, "batch", "seq", None, rules=rules)
 
 
 def llama_loss(
@@ -354,10 +361,12 @@ def llama_loss(
     tokens = batch["tokens"]
     logits = llama_apply(params, tokens[:, :-1], cfg, mesh=mesh, rules=rules)
     targets = tokens[:, 1:]
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    mask = batch.get("mask")
-    if mask is not None:
-        mask = mask[:, 1:].astype(jnp.float32)
-        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    return jnp.mean(nll)
+    with tracing.scope("loss"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None],
+                                   axis=-1)[..., 0]
+        mask = batch.get("mask")
+        if mask is not None:
+            mask = mask[:, 1:].astype(jnp.float32)
+            return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        return jnp.mean(nll)

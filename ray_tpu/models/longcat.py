@@ -65,8 +65,9 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu._private import tracing
 from ray_tpu.models.paged_generation import (decode_attention_path,
-                                             sample_token_batch)
+                                             embed_tokens, sample_next)
 from ray_tpu.ops.experts import held_experts_ffn, route_top_k
 from ray_tpu.ops.layers import (apply_rope, heads_projection, rms_norm,
                                 rope_frequencies, swiglu)
@@ -187,17 +188,20 @@ def _mla_project(x, ap, cfg: LongcatConfig, cos, sin, positions):
     dt = cfg.dtype
     nh, dn = cfg.num_heads, cfg.qk_nope_head_dim
     qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
-    c_q = rms_norm(x @ ap["w_qa"].astype(dt), ap["q_norm"], cfg.rms_norm_eps)
-    if cfg.mla_scale_q_lora:
-        c_q = c_q * (H / qr) ** 0.5
-    q = heads_projection(c_q, ap["w_qb"].astype(dt), nh)
-    kv = x @ ap["w_kva"].astype(dt)
-    c_kv = rms_norm(kv[..., :kr], ap["kv_norm"], cfg.rms_norm_eps)
-    if cfg.mla_scale_kv_lora:
-        c_kv = c_kv * (H / kr) ** 0.5
-    q_pe = apply_rope(q[..., dn:], cos, sin, positions)
-    k_pe = apply_rope(kv[..., kr:][:, :, None], cos, sin, positions)[:, :, 0]
-    return q[..., :dn], q_pe, c_kv, k_pe
+    with tracing.scope("attn.proj"):
+        c_q = rms_norm(x @ ap["w_qa"].astype(dt), ap["q_norm"],
+                       cfg.rms_norm_eps)
+        if cfg.mla_scale_q_lora:
+            c_q = c_q * (H / qr) ** 0.5
+        q = heads_projection(c_q, ap["w_qb"].astype(dt), nh)
+        kv = x @ ap["w_kva"].astype(dt)
+        c_kv = rms_norm(kv[..., :kr], ap["kv_norm"], cfg.rms_norm_eps)
+        if cfg.mla_scale_kv_lora:
+            c_kv = c_kv * (H / kr) ** 0.5
+        q_pe = apply_rope(q[..., dn:], cos, sin, positions)
+        k_pe = apply_rope(kv[..., kr:][:, :, None], cos, sin,
+                          positions)[:, :, 0]
+        return q[..., :dn], q_pe, c_kv, k_pe
 
 
 def _softmax_scale(cfg: LongcatConfig) -> float:
@@ -215,7 +219,8 @@ def _mla_plain(q_nope, q_pe, c_kv, k_pe, mask, ap, cfg: LongcatConfig):
     b, s, nh, dn = q_nope.shape
     t = c_kv.shape[1]
     dt, dv = cfg.dtype, cfg.v_head_dim
-    kvb = (c_kv @ ap["w_kvb"].astype(dt)).reshape(b, t, nh, dn + dv)
+    with tracing.scope("attn.proj"):  # the cached rows' up-projection
+        kvb = (c_kv @ ap["w_kvb"].astype(dt)).reshape(b, t, nh, dn + dv)
 
     def heads(args):
         qn, qr, kn, v = args  # [b, s|t, g, d]: one group of heads
@@ -231,14 +236,16 @@ def _mla_plain(q_nope, q_pe, c_kv, k_pe, mask, ap, cfg: LongcatConfig):
 
     parts = (q_nope, q_pe, kvb[..., :dn], kvb[..., dn:])
     g = _HEAD_GROUP
-    if nh <= g or nh % g:
-        out = heads(parts)
-    else:  # one group of heads after another
-        split = lambda a: jnp.moveaxis(  # noqa: E731
-            a.reshape(*a.shape[:2], nh // g, g, a.shape[-1]), 2, 0)
-        out = jax.lax.map(heads, tuple(split(a) for a in parts))
-        out = jnp.moveaxis(out, 0, 2).reshape(b, s, nh, dv)
-    return out.reshape(b, s, nh * dv) @ ap["w_o"].astype(dt)
+    with tracing.scope("attn.core"):
+        if nh <= g or nh % g:
+            out = heads(parts)
+        else:  # one group of heads after another
+            split = lambda a: jnp.moveaxis(  # noqa: E731
+                a.reshape(*a.shape[:2], nh // g, g, a.shape[-1]), 2, 0)
+            out = jax.lax.map(heads, tuple(split(a) for a in parts))
+            out = jnp.moveaxis(out, 0, 2).reshape(b, s, nh, dv)
+    with tracing.scope("attn.out"):
+        return out.reshape(b, s, nh * dv) @ ap["w_o"].astype(dt)
 
 
 def _mla_absorbed(q_nope, q_pe, ap, cfg: LongcatConfig, attend_rows):
@@ -249,15 +256,18 @@ def _mla_absorbed(q_nope, q_pe, ap, cfg: LongcatConfig, attend_rows):
     b, nh, dn = q_nope.shape
     dt, kr, dv = cfg.dtype, cfg.kv_lora_rank, cfg.v_head_dim
     w_kvb = ap["w_kvb"].astype(dt).reshape(kr, nh, dn + dv)
-    q_lat = jnp.einsum("bhd,khd->bhk", q_nope, w_kvb[..., :dn],
-                       preferred_element_type=jnp.float32).astype(dt)
-    pad = cfg.latent_width - kr - q_pe.shape[-1]
-    q = jnp.concatenate(
-        [q_lat, q_pe, jnp.zeros((b, nh, pad), dt)], axis=-1)
-    o_lat = attend_rows(q)
-    out = jnp.einsum("bhk,khd->bhd", o_lat, w_kvb[..., dn:],
-                     preferred_element_type=jnp.float32).astype(dt)
-    return out.reshape(b, nh * dv) @ ap["w_o"].astype(dt)
+    with tracing.scope("attn.proj"):  # the query into the latent space
+        q_lat = jnp.einsum("bhd,khd->bhk", q_nope, w_kvb[..., :dn],
+                           preferred_element_type=jnp.float32).astype(dt)
+        pad = cfg.latent_width - kr - q_pe.shape[-1]
+        q = jnp.concatenate(
+            [q_lat, q_pe, jnp.zeros((b, nh, pad), dt)], axis=-1)
+    with tracing.scope("attn.core"):
+        o_lat = attend_rows(q)
+    with tracing.scope("attn.out"):  # out of it again, then W_o
+        out = jnp.einsum("bhk,khd->bhd", o_lat, w_kvb[..., dn:],
+                         preferred_element_type=jnp.float32).astype(dt)
+        return out.reshape(b, nh * dv) @ ap["w_o"].astype(dt)
 
 
 def _pack_rows(c_kv, k_pe, cfg: LongcatConfig):
@@ -280,20 +290,20 @@ def _moe(y, router, ep, cfg: LongcatConfig, live):
     experts hit, zero-compute picks; of live tokens only)."""
     b, s, H = y.shape
     yf, lf = y.reshape(b * s, H), live.reshape(b * s)
-    with jax.named_scope("moe_route"):
+    with tracing.scope("router"):
         idx, weight = route_top_k(
             yf, router["w"], router["bias"], cfg.experts_per_token,
             cfg.routed_scaling_factor)
-    with jax.named_scope("moe_experts"):
+    with tracing.scope("experts"):
         out, pairs, hit = held_experts_ffn(
             yf, idx, weight, ep["w_gate"], ep["w_up"], ep["w_down"],
             first=cfg.first_expert, live=lf)
         zero = idx >= cfg.num_experts  # identity experts: counted in full
         out = out + (jnp.sum(jnp.where(zero, weight, 0.0), -1)[:, None]
                      * yf.astype(jnp.float32))
-    picks = jnp.sum(zero & lf[:, None], dtype=jnp.int32)
-    return (out.astype(cfg.dtype).reshape(b, s, H),
-            jnp.stack([pairs, hit, picks]).astype(jnp.int32))
+        picks = jnp.sum(zero & lf[:, None], dtype=jnp.int32)
+        return (out.astype(cfg.dtype).reshape(b, s, H),
+                jnp.stack([pairs, hit, picks]).astype(jnp.int32))
 
 
 def _double_layer(h, lp, cfg: LongcatConfig, attend, live):
@@ -302,15 +312,21 @@ def _double_layer(h, lp, cfg: LongcatConfig, attend, live):
     caller's), called for block 0 then block 1."""
     eps = cfg.rms_norm_eps
     at, ff = lp["attn"], lp["ffn"]
-    with jax.named_scope("mla"):
-        h1 = h + attend(rms_norm(h, at[0]["norm"], eps), at[0])
-    y = rms_norm(h1, ff[0]["norm"], eps)
+    def attention(h, ap):  # attend opens attn.cache / .core / .out itself
+        with tracing.scope("attn.proj"):
+            xn = rms_norm(h, ap["norm"], eps)
+        o = attend(xn, ap)
+        with tracing.scope("attn.out"):
+            return h + o
+
+    h1 = attention(h, at[0])
+    with tracing.scope("ffn"):
+        y = rms_norm(h1, ff[0]["norm"], eps)
     s, stats = _moe(y, lp["router"], lp["experts"], cfg, live)
-    with jax.named_scope("dense_ffn"):
+    with tracing.scope("ffn"):
         h2 = h1 + _ffn(y, ff[0], cfg)
-    with jax.named_scope("mla"):
-        h3 = h2 + attend(rms_norm(h2, at[1]["norm"], eps), at[1])
-    with jax.named_scope("dense_ffn"):
+    h3 = attention(h2, at[1])
+    with tracing.scope("ffn"):
         out = h3 + _ffn(rms_norm(h3, ff[1]["norm"], eps), ff[1], cfg) + s
     return out, stats
 
@@ -324,9 +340,11 @@ def _layers(params, x, cfg: LongcatConfig, attend, live):
 
 
 def _lm_head(params, cfg: LongcatConfig, x):
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    return jnp.einsum("bsh,hv->bsv", x, params["lm_head"].astype(cfg.dtype),
-                      preferred_element_type=jnp.float32)
+    with tracing.scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        return jnp.einsum("bsh,hv->bsv", x,
+                          params["lm_head"].astype(cfg.dtype),
+                          preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------- programs
@@ -348,7 +366,7 @@ def longcat_apply(params, tokens, cfg: LongcatConfig, *, mesh=None,
         return _mla_plain(*_mla_project(xn, ap, cfg, cos, sin, None), mask,
                           ap, cfg)
 
-    x, stats = _layers(params, params["embed"][tokens].astype(cfg.dtype),
+    x, stats = _layers(params, embed_tokens(params, tokens, cfg.dtype),
                        cfg, attend, live)
     logits = _lm_head(params, cfg, x)
     return (logits, stats) if return_stats else logits
@@ -401,20 +419,24 @@ def latent_prefill_suffix(params, tokens, length, start_pos, prefix_ckv,
         nonlocal kv, a
         q_nope, q_pe, c_kv, k_pe = _mla_project(xn, ap, cfg, cos, sin,
                                                 positions)
-        # pad lanes land in the scratch block
-        kv = kv.at[a, dst_blocks, dst_offsets].set(
-            _pack_rows(c_kv[0], k_pe[0], cfg))
-        c_all = jnp.concatenate([prefix_ckv[a][None].astype(dt), c_kv], 1)
-        pe_all = jnp.concatenate([prefix_kpe[a][None].astype(dt), k_pe], 1)
+        with tracing.scope("attn.cache"):
+            # pad lanes land in the scratch block
+            kv = kv.at[a, dst_blocks, dst_offsets].set(
+                _pack_rows(c_kv[0], k_pe[0], cfg))
+            c_all = jnp.concatenate(
+                [prefix_ckv[a][None].astype(dt), c_kv], 1)
+            pe_all = jnp.concatenate(
+                [prefix_kpe[a][None].astype(dt), k_pe], 1)
         a += 1
         return _mla_plain(q_nope, q_pe, c_all, pe_all, mask, ap, cfg)
 
-    x, stats = _layers(params, params["embed"][tokens].astype(dt), cfg,
-                       attend, live)
+    x, stats = _layers(params, embed_tokens(params, tokens, cfg.dtype),
+                       cfg, attend, live)
     logits = _lm_head(params, cfg, x)
-    last = jnp.take_along_axis(
-        logits, (length - 1)[None, None, None].astype(jnp.int32),
-        axis=1)[:, 0]
+    with tracing.scope("head"):
+        last = jnp.take_along_axis(
+            logits, (length - 1)[None, None, None].astype(jnp.int32),
+            axis=1)[:, 0]
     return last, {"kv": kv}, stats
 
 
@@ -431,16 +453,18 @@ def latent_decode_step(params, token, cur_len, block_tables, pool,
     MB = block_tables.shape[1]
     bs = pool["kv"].shape[2]
     dt, kr = cfg.dtype, cfg.kv_lora_rank
-    cos, sin = rope_frequencies(cfg.qk_rope_head_dim, MB * bs,
-                                cfg.rope_theta)
+    with tracing.scope("attn.proj"):  # the rotary table
+        cos, sin = rope_frequencies(cfg.qk_rope_head_dim, MB * bs,
+                                    cfg.rope_theta)
     positions = cur_len[:, None]
     idx = jnp.arange(MB * bs)
     mask = idx[None, None, :] <= cur_len[:, None, None]
-    rows = jnp.arange(b)
-    blk = block_tables[rows, cur_len // bs]
-    off = cur_len % bs
-    live = block_tables[:, 0] != 0
-    lengths = jnp.where(live, cur_len + 1, 0)
+    with tracing.scope("attn.cache"):  # where the step's rows go
+        rows = jnp.arange(b)
+        blk = block_tables[rows, cur_len // bs]
+        off = cur_len % bs
+        live = block_tables[:, 0] != 0
+        lengths = jnp.where(live, cur_len + 1, 0)
     scale = _softmax_scale(cfg)
     kv = pool["kv"]
     a = 0
@@ -449,8 +473,10 @@ def latent_decode_step(params, token, cur_len, block_tables, pool,
         nonlocal kv, a
         q_nope, q_pe, c_kv, k_pe = _mla_project(xn, ap, cfg, cos, sin,
                                                 positions)
-        # the new row first, so that the token attends to itself
-        kv = kv.at[a, blk, off].set(_pack_rows(c_kv[:, 0], k_pe[:, 0], cfg))
+        with tracing.scope("attn.cache"):
+            # the new row first, so that the token attends to itself
+            kv = kv.at[a, blk, off].set(
+                _pack_rows(c_kv[:, 0], k_pe[:, 0], cfg))
         block = a
         a += 1
 
@@ -471,12 +497,15 @@ def latent_decode_step(params, token, cur_len, block_tables, pool,
             return jnp.einsum("bht,btk->bhk", probs, g[..., :kr],
                               preferred_element_type=jnp.float32).astype(dt)
 
+        with tracing.scope("attn.proj"):
+            q_nope, q_pe = q_nope[:, 0], q_pe[:, 0]
         return _mla_absorbed(
-            q_nope[:, 0], q_pe[:, 0], ap, cfg,
+            q_nope, q_pe, ap, cfg,
             kernel if attn == "latent_kernel" else gather)[:, None]
 
-    x, stats = _layers(params, params["embed"][token][:, None].astype(dt),
-                       cfg, attend, live[:, None])
+    x, stats = _layers(params,
+                       embed_tokens(params, token, cfg.dtype)[:, None], cfg,
+                       attend, live[:, None])
     return _lm_head(params, cfg, x)[:, 0], {"kv": kv}, stats
 
 
@@ -489,6 +518,5 @@ def latent_decode_sample(params, token, cur_len, block_tables, pool, key,
     safe_cur = jnp.minimum(cur_len, ML - 1)
     logits, pool, stats = latent_decode_step(
         params, token, safe_cur, block_tables, pool, cfg=cfg, attn=attn)
-    key, sub = jax.random.split(key)
-    nxt = sample_token_batch(logits, sub, temps)
+    nxt, key = sample_next(logits, key, temps)
     return nxt, cur_len + 1, key, pool, stats

@@ -41,6 +41,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ray_tpu._private import tracing
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.generation import (_layer_with_cache, _stacked_layers,
                                         sliding_window_mask)
@@ -163,11 +164,19 @@ def decode_attention_path(pool, *, mesh=None, spec_tokens: int = 0) -> str:
 
 
 def _lm_head(params, cfg, x):
-    x = rms_norm(x, params["final_norm"])
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(cfg.dtype)
-    return jnp.einsum("bsh,hv->bsv", x, head,
-                      preferred_element_type=jnp.float32)
+    with tracing.scope("head"):
+        x = rms_norm(x, params["final_norm"])
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"]).astype(cfg.dtype)
+        return jnp.einsum("bsh,hv->bsv", x, head,
+                          preferred_element_type=jnp.float32)
+
+
+def embed_tokens(params, tokens, dt):
+    """The embedding rows of ``tokens`` in ``dt``: every served model's
+    first step, under the name scope ``embed``."""
+    with tracing.scope("embed"):
+        return params["embed"][tokens].astype(dt)
 
 
 def paged_decode_step(params, token, cur_len, block_tables, pool,
@@ -192,20 +201,22 @@ def paged_decode_step(params, token, cur_len, block_tables, pool,
     bs = pool["k"].shape[2]
     hd = cfg.resolved_head_dim
     dt = cfg.dtype
-    cos, sin = rope_frequencies(hd, MB * bs, cfg.rope_theta)
+    with tracing.scope("attn.proj"):  # the rotary table
+        cos, sin = rope_frequencies(hd, MB * bs, cfg.rope_theta)
     positions = cur_len[:, None]
-    x = params["embed"][token][:, None].astype(dt)
+    x = embed_tokens(params, token, dt)[:, None]
     # logical position j visible iff j <= cur_len (own slot included)
     idx = jnp.arange(MB * bs)
     mask = idx[None, None, :] <= cur_len[:, None, None]
     if cfg.sliding_window is not None:
         mask &= sliding_window_mask(cur_len[:, None, None],
                                     idx[None, None, :], cfg.sliding_window)
-    rows = jnp.arange(b)
-    blk = block_tables[rows, cur_len // bs]  # [b] target block per seq
-    off = cur_len % bs
-    # the kernel's view of a slot: positions 0..cur_len, or nothing
-    lengths = jnp.where(block_tables[:, 0] != 0, cur_len + 1, 0)
+    with tracing.scope("attn.cache"):  # where the step's rows go
+        rows = jnp.arange(b)
+        blk = block_tables[rows, cur_len // bs]  # [b] target block per seq
+        off = cur_len % bs
+        # the kernel's view of a slot: positions 0..cur_len, or nothing
+        lengths = jnp.where(block_tables[:, 0] != 0, cur_len + 1, 0)
 
     for i, lp in _stacked_layers(params):
         def merge(k, v, i=i):
@@ -225,10 +236,12 @@ def paged_decode_step(params, token, cur_len, block_tables, pool,
             # (every CPU worker, the driver) never loads Pallas
             from ray_tpu.ops.pallas.paged_attention import paged_attention
 
-            pool = _store_kv(pool, i, blk, off, k[:, 0], v[:, 0])
-            return paged_attention(
-                q[:, 0], pool["k"], pool["v"], block_tables, lengths,
-                layer=i, window=cfg.sliding_window)[:, None]
+            with tracing.scope("attn.cache"):
+                pool = _store_kv(pool, i, blk, off, k[:, 0], v[:, 0])
+            with tracing.scope("attn.core"):
+                return paged_attention(
+                    q[:, 0], pool["k"], pool["v"], block_tables, lengths,
+                    layer=i, window=cfg.sliding_window)[:, None]
 
         x, _ = _layer_with_cache(
             x, lp, merge, cfg=cfg, cos=cos, sin=sin, mask=mask,
@@ -255,7 +268,7 @@ def prefill_suffix(params, tokens, length, start_pos, prefix_k, prefix_v,
     dt = cfg.dtype
     cos, sin = rope_frequencies(hd, P + S, cfg.rope_theta)
     positions = start_pos + jnp.arange(S)[None, :]  # [1, S] absolute
-    x = params["embed"][tokens].astype(dt)
+    x = embed_tokens(params, tokens, dt)
     sfx = jnp.arange(S)
     # keys = [prefix (P) | suffix (S)]; query i sees prefix j < prefix_len
     # and suffix j' <= i (within true suffix length)
@@ -288,9 +301,10 @@ def prefill_suffix(params, tokens, length, start_pos, prefix_k, prefix_v,
         x, _ = _layer_with_cache(x, lp, merge, cfg=cfg, cos=cos, sin=sin,
                                  mask=mask, positions=positions)
     logits = _lm_head(params, cfg, x)
-    last = jnp.take_along_axis(
-        logits, (length - 1)[None, None, None].astype(jnp.int32),
-        axis=1)[:, 0]
+    with tracing.scope("head"):
+        last = jnp.take_along_axis(
+            logits, (length - 1)[None, None, None].astype(jnp.int32),
+            axis=1)[:, 0]
     return last, pool
 
 
@@ -320,7 +334,7 @@ def paged_verify_step(params, tokens, cur_len, block_tables, pool,
     cos, sin = rope_frequencies(hd, ML, cfg.rope_theta)
     positions = cur_len[:, None] + jnp.arange(S)[None, :]  # [b, S]
     safe_pos = jnp.minimum(positions, ML - 1)
-    x = params["embed"][tokens].astype(dt)
+    x = embed_tokens(params, tokens, dt)
     idx = jnp.arange(ML)
     # query at global position p sees pool slots <= p (its own included);
     # earlier same-chunk tokens are visible because each layer stores the
@@ -372,9 +386,17 @@ def paged_decode_sample(params, token, cur_len, block_tables, pool, key,
     safe_cur = jnp.minimum(cur_len, ML - 1)
     logits, pool = paged_decode_step(params, token, safe_cur, block_tables,
                                      pool, cfg=cfg, attn=attn)
-    key, sub = jax.random.split(key)
-    nxt = sample_token_batch(logits, sub, temps)
+    nxt, key = sample_next(logits, key, temps)
     return nxt, cur_len + 1, key, pool
+
+
+def sample_next(logits, key, temps):
+    """What every model's ``decode_sample`` ends in: the key split, one
+    half spent on ``sample_token_batch``.  Returns (token, the key to
+    carry)."""
+    with tracing.scope("sample"):
+        key, sub = jax.random.split(key)
+        return sample_token_batch(logits, sub, temps), key
 
 
 def sample_token_batch(logits, key, temps):

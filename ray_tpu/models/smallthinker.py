@@ -53,6 +53,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu._private import tracing
 from ray_tpu.models import paged_generation as pg
 from ray_tpu.ops.attention import dot_product_attention, sliding_window_mask
 from ray_tpu.ops.experts import held_experts_ffn, reglu, route_top_k
@@ -172,11 +173,11 @@ def _layer(x, lp, kind: str, cfg: SmallThinkerConfig, cos, sin, positions,
     dt, eps = cfg.dtype, cfg.rms_norm_eps
     nh, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     ap, ep = lp["attn"], lp["experts"]
-    with jax.named_scope("moe_route"):  # of the layer's input, as it came
+    with tracing.scope("router"):  # of the layer's input, as it came
         idx, weight = route_top_k(
             x.reshape(b * s, H), lp["router"]["w"], None,
             cfg.experts_per_token, 1.0, renormalise=True)
-    with jax.named_scope("attention"):
+    with tracing.scope("attn.proj"):
         xn = rms_norm(x, ap["norm"], eps)
         q = (xn @ ap["w_q"].astype(dt)).reshape(b, s, nh, hd)
         k = (xn @ ap["w_k"].astype(dt)).reshape(b, s, kvh, hd)
@@ -184,18 +185,19 @@ def _layer(x, lp, kind: str, cfg: SmallThinkerConfig, cos, sin, positions,
         if kind == WINDOW:  # a full layer has no position embedding
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
-        o = attend(q, k, v).reshape(b, s, nh * hd)
-        h = x + o @ ap["w_o"].astype(dt)
-    with jax.named_scope("moe_experts"):
+    o = attend(q, k, v)  # opens attn.cache and attn.core itself
+    with tracing.scope("attn.out"):
+        h = x + o.reshape(b, s, nh * hd) @ ap["w_o"].astype(dt)
+    with tracing.scope("experts"):
         y = rms_norm(h, lp["ffn_norm"], eps).reshape(b * s, H)
         out, pairs, hit = held_experts_ffn(
             y, idx, weight, ep["w_gate"], ep["w_up"], ep["w_down"],
             first=cfg.first_expert, live=live.reshape(b * s),
             activation=reglu)
         out = h.astype(jnp.float32) + out.reshape(b, s, H)
-    zero = jnp.zeros((), jnp.int32)
-    return (out.astype(dt),
-            jnp.stack([pairs, hit, zero]).astype(jnp.int32))
+        zero = jnp.zeros((), jnp.int32)
+        return (out.astype(dt),
+                jnp.stack([pairs, hit, zero]).astype(jnp.int32))
 
 
 def _layers(params, x, cfg: SmallThinkerConfig, cos, sin, positions,
@@ -216,9 +218,11 @@ def _window(kind: str, cfg: SmallThinkerConfig) -> Optional[int]:
 
 
 def _lm_head(params, cfg: SmallThinkerConfig, x):
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    return jnp.einsum("bsh,hv->bsv", x, params["lm_head"].astype(cfg.dtype),
-                      preferred_element_type=jnp.float32)
+    with tracing.scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        return jnp.einsum("bsh,hv->bsv", x,
+                          params["lm_head"].astype(cfg.dtype),
+                          preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------- programs
@@ -233,10 +237,11 @@ def smallthinker_apply(params, tokens, cfg: SmallThinkerConfig, *,
     cos, sin = rope_frequencies(cfg.head_dim, s, cfg.rope_theta)
 
     def attend(kind, a, q, k, v):
-        return dot_product_attention(q, k, v, causal=True,
-                                     window=_window(kind, cfg))
+        with tracing.scope("attn.core"):
+            return dot_product_attention(q, k, v, causal=True,
+                                         window=_window(kind, cfg))
 
-    x, stats = _layers(params, params["embed"][tokens].astype(cfg.dtype),
+    x, stats = _layers(params, pg.embed_tokens(params, tokens, cfg.dtype),
                        cfg, cos, sin, None, attend, jnp.ones((b, s), bool))
     logits = _lm_head(params, cfg, x)
     return (logits, stats) if return_stats else logits
@@ -286,24 +291,26 @@ def prefill_suffix(params, tokens, length, start_pos, prefix_k, prefix_v,
             "smallthinker prefills a prompt whole: no cached prefix "
             "(docs/llm_serving.md)")
     _, S = tokens.shape
-    dt = cfg.dtype
     cos, sin = rope_frequencies(cfg.head_dim, S, cfg.rope_theta)
     live = (jnp.arange(S) < length)[None, :]
     pool = {t: dict(p) for t, p in pool.items()}
 
     def attend(kind, a, q, k, v):
         p = pool[kind]  # pad lanes and unseen keys land in the scratch block
-        p["k"] = p["k"].at[a, dst_blocks[kind], dst_offsets].set(k[0])
-        p["v"] = p["v"].at[a, dst_blocks[kind], dst_offsets].set(v[0])
+        with tracing.scope("attn.cache"):
+            p["k"] = p["k"].at[a, dst_blocks[kind], dst_offsets].set(k[0])
+            p["v"] = p["v"].at[a, dst_blocks[kind], dst_offsets].set(v[0])
         # the pad tail lies after every true position: causal hides it
-        return dot_product_attention(q, k, v, causal=True,
-                                     window=_window(kind, cfg))
+        with tracing.scope("attn.core"):
+            return dot_product_attention(q, k, v, causal=True,
+                                         window=_window(kind, cfg))
 
-    x, stats = _layers(params, params["embed"][tokens].astype(dt), cfg,
-                       cos, sin, None, attend, live)
+    x, stats = _layers(params, pg.embed_tokens(params, tokens, cfg.dtype),
+                       cfg, cos, sin, None, attend, live)
     # the head for the last true position only: [S, vocab] float32 logits
     # of a 14k prompt would be 8.7 GB
-    last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
+    with tracing.scope("head"):
+        last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
     return _lm_head(params, cfg, last)[:, 0], pool, stats
 
 
@@ -320,22 +327,30 @@ def decode_step(params, token, cur_len, block_tables, pool,
     bs = pool[FULL]["k"].shape[2]
     dt, hd = cfg.dtype, cfg.head_dim
     kvh, rep = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads
-    cos, sin = rope_frequencies(hd, MB * bs, cfg.rope_theta)
+    with tracing.scope("attn.proj"):  # the rotary table
+        cos, sin = rope_frequencies(hd, MB * bs, cfg.rope_theta)
     positions = cur_len[:, None]
-    rows = jnp.arange(b)
-    off = cur_len % bs
-    blk = {t: tab[rows, cur_len // bs] for t, tab in block_tables.items()}
-    live = block_tables[FULL][:, 0] != 0
-    lengths = jnp.where(live, cur_len + 1, 0)
+    with tracing.scope("attn.cache"):  # where the step's rows go
+        rows = jnp.arange(b)
+        off = cur_len % bs
+        blk = {t: tab[rows, cur_len // bs]
+               for t, tab in block_tables.items()}
+        live = block_tables[FULL][:, 0] != 0
+        lengths = jnp.where(live, cur_len + 1, 0)
     idx = jnp.arange(MB * bs)
     seen = idx[None, :] <= cur_len[:, None]  # [b, MB * bs]
     pool = {t: dict(p) for t, p in pool.items()}
 
     def attend(kind, a, q, k, v):
         p, tab = pool[kind], block_tables[kind]
-        # the new keys and values first, so that the token attends to itself
-        p["k"] = p["k"].at[a, blk[kind], off].set(k[:, 0])
-        p["v"] = p["v"].at[a, blk[kind], off].set(v[:, 0])
+        with tracing.scope("attn.cache"):
+            # the new keys and values first: the token attends to itself
+            p["k"] = p["k"].at[a, blk[kind], off].set(k[:, 0])
+            p["v"] = p["v"].at[a, blk[kind], off].set(v[:, 0])
+        with tracing.scope("attn.core"):
+            return core(kind, a, q, p, tab)
+
+    def core(kind, a, q, p, tab):
         if attn == "paged_kernel":
             from ray_tpu.ops.pallas.paged_attention import paged_attention
 
@@ -356,7 +371,8 @@ def decode_step(params, token, cur_len, block_tables, pool,
                           preferred_element_type=jnp.float32).astype(
                               dt).reshape(b, 1, kvh * rep, hd)
 
-    x, stats = _layers(params, params["embed"][token][:, None].astype(dt),
+    x, stats = _layers(params,
+                       pg.embed_tokens(params, token, cfg.dtype)[:, None],
                        cfg, cos, sin, positions, attend, live[:, None])
     return _lm_head(params, cfg, x)[:, 0], pool, stats
 
@@ -369,6 +385,5 @@ def decode_sample(params, token, cur_len, block_tables, pool, key, temps,
     safe_cur = jnp.minimum(cur_len, ML - 1)
     logits, pool, stats = decode_step(params, token, safe_cur, block_tables,
                                       pool, cfg=cfg, attn=attn)
-    key, sub = jax.random.split(key)
-    nxt = pg.sample_token_batch(logits, sub, temps)
+    nxt, key = pg.sample_next(logits, key, temps)
     return nxt, cur_len + 1, key, pool, stats

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu._private import tracing
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
     LogicalAxisRules,
@@ -137,6 +138,12 @@ class ShardedTrainer:
         }
 
     def _train_step(self, state, batch):
+        # the whole program under the name scope ``train.step``: the
+        # profiler's trace carries it on every device instruction
+        # (docs/observability.md); the module stays ``jit__train_step``
+        return tracing.scoped("train.step", self._step_body, state, batch)
+
+    def _step_body(self, state, batch):
         if self.accum_steps > 1:
             a = self.accum_steps
             for x in jax.tree.leaves(batch):
@@ -166,11 +173,12 @@ class ShardedTrainer:
             loss, grads = jax.value_and_grad(self._loss_fn)(
                 state["params"], batch
             )
-        updates, opt_state = self.optimizer.update(
-            grads, state["opt_state"], state["params"]
-        )
-        params = optax.apply_updates(state["params"], updates)
-        gnorm = optax.global_norm(grads)
+        with tracing.scope("optimizer"):
+            updates, opt_state = self.optimizer.update(
+                grads, state["opt_state"], state["params"]
+            )
+            params = optax.apply_updates(state["params"], updates)
+            gnorm = optax.global_norm(grads)
         new_state = {
             "params": params,
             "opt_state": opt_state,

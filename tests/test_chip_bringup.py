@@ -57,6 +57,49 @@ def test_compile_cache_default_is_one_fixed_path_in_the_checkout(monkeypatch):
     assert ensure_compile_cache_env() == os.path.join(REPO, ".jax_cache")
 
 
+def test_the_cache_key_takes_the_programs_metadata_in(monkeypatch):
+    """A program's name scopes (``tracing.scope``) are metadata: under
+    JAX's default key a warm cache hands back an executable with whatever
+    scopes its compiler had (PERF.md section 6, PR 37).  Two programs that
+    differ in a scope alone get two keys once the variable is set, one
+    without it."""
+    import subprocess
+    import sys
+
+    from ray_tpu._private.node import ensure_compile_cache_env
+
+    var = "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY"
+    monkeypatch.delenv(var, raising=False)
+    ensure_compile_cache_env()
+    assert os.environ[var] == "true"
+    monkeypatch.setenv(var, "false")  # a choice from outside wins
+    ensure_compile_cache_env()
+    assert os.environ[var] == "false"
+    probe = (
+        "import jax, jax.numpy as jnp\n"
+        "from jax._src import cache_key\n"
+        "from ray_tpu._private import tracing\n"
+        "def f(x):\n"
+        "    return x * 2\n"
+        "def g(x):\n"
+        "    with tracing.scope('ffn'):\n"
+        "        return x * 2\n"
+        "import numpy as np\n"
+        "x, dev, keys = jnp.ones(4), jax.devices(), []\n"
+        "for fn in (f, g):\n"
+        "    fn.__name__ = fn.__qualname__ = 'same'\n"
+        "    low = jax.jit(fn).lower(x)\n"
+        "    keys.append(cache_key.get(low.compiler_ir(), np.array(dev[:1]),"
+        " jax._src.compiler.get_compile_options(1, 1), dev[0].client))\n"
+        "print(len(set(keys)))\n")
+    for value, want in (("true", "2"), ("false", "1")):
+        out = subprocess.run(
+            [sys.executable, "-c", probe], capture_output=True, text=True,
+            env={**os.environ, var: value, "JAX_PLATFORMS": "cpu",
+                 "PYTHONPATH": REPO})
+        assert out.stdout.strip() == want, (value, out.stdout, out.stderr)
+
+
 def test_spawned_worker_compiles_into_the_drivers_cache(ray_start):
     @ray_tpu.remote
     def where():

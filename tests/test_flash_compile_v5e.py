@@ -1,6 +1,7 @@
-"""The flash kernels, the decode-shaped expert kernel (PR 32) and the two
-decode programs whose heads projections read their weights in place (PR 35),
-compiled for a v5e that is described, not attached.
+"""The flash kernels, the decode-shaped expert kernel (PR 32), the two
+decode programs whose heads projections read their weights in place (PR 35)
+and the three served models' decode programs' name scopes after XLA:TPU's
+fusion (PR 37), compiled for a v5e that is described, not attached.
 
 The interpreter cannot see what the chip's compiler refuses: more VMEM
 than a kernel may use (the backward keeps dK/dV whole in scratch and sets
@@ -188,13 +189,50 @@ def _relayouts(text, at_least=_MIB):
             and (op == "copy" or (op == "fusion" and "slice" in name))]
 
 
+def _unscoped(text):
+    """(instructions counted, those without a part of the vocabulary) among
+    what a profiler's trace times: the ``fusion``, ``convolution`` and
+    Mosaic-call instructions of the entry computation and of the loop
+    bodies.  A fusion carries its root's ``op_name``; XLA's own
+    instructions (a ``copy``, a ``ConcatBitcast`` of prefetched slices)
+    carry none and are not a model's part."""
+    from ray_tpu._private import tracing
+
+    # {computation: its lines}; the entry computation under "ENTRY"
+    comps = {m.group(1) or m.group(2): m.group(3) for m in re.finditer(
+        r"^(?:(ENTRY) )?%([\w.\-]+) [^\n]*\{\n(.*?)^\}", text,
+        re.S | re.M)}
+    bodies = re.findall(r"body=%([\w.\-]+)", text)
+    lines = "\n".join(comps[c] for c in ["ENTRY", *bodies])
+    counted, bare = 0, []
+    for line in lines.splitlines():
+        m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = .*? "
+                     r"(fusion|convolution|custom-call)\(", line)
+        if not m or (m.group(2) == "custom-call"
+                     and "tpu_custom_call" not in line):
+            continue
+        counted += 1
+        name = re.search(r'op_name="([^"]*)"', line)
+        words = re.split(r"[/()]", name.group(1)) if name else []
+        if "engine.decode" not in words or not any(
+                w in tracing.PART_SCOPES for w in words):
+            bare.append((m.group(1), name.group(1) if name else None))
+    return counted, bare
+
+
 def _compile_decode(step, params, pool, B, MB, one_chip):
     """The text of ``step(params, token, cur_len, block_tables, pool, key,
-    temperature)`` compiled for ``B`` slots of ``MB`` blocks, the pool
-    donated as the engine donates it."""
+    temperature)`` under the engine's program scope, compiled for ``B``
+    slots of ``MB`` blocks (``block_tables`` by type where ``pool`` is),
+    the pool donated as the engine donates it."""
+    from ray_tpu._private import tracing
+
+    step = functools.partial(tracing.scoped, "engine.decode", step)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    args = (params, i32(B), i32(B), i32(B, MB), pool, key,
+    tables = ({t: i32(B, MB) for t in pool} if "k" not in pool
+              and "kv" not in pool else i32(B, MB))
+    args = (params, i32(B), i32(B), tables, pool, key,
             jax.ShapeDtypeStruct((B,), jnp.float32))
     args = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
         s.shape, s.dtype, sharding=one_chip), args)
@@ -236,6 +274,9 @@ def test_the_dense_decode_step_reads_wq_wk_wv_in_place(one_chip, monkeypatch):
                            params, pool, B, MB, one_chip)
     assert text.count('custom_call_target="tpu_custom_call"') == 2  # a layer
     assert _relayouts(text) == []
+    # PR 37: what the trace will time names its part (docs/observability.md)
+    counted, bare = _unscoped(text)
+    assert counted >= 50 and len(bare) <= 0.05 * counted, bare
 
 
 def test_longcat_decode_step_reads_w_qb_in_place(one_chip, monkeypatch):
@@ -270,6 +311,34 @@ def test_longcat_decode_step_reads_w_qb_in_place(one_chip, monkeypatch):
         params, pool, B, MB, one_chip)
     assert [m for m in _relayouts(text)
             if m[2] in (w_qb, w_qb[::-1])] == []
+    counted, bare = _unscoped(text)  # PR 37, as for the dense step
+    assert counted >= 80 and len(bare) <= 0.05 * counted, bare
+
+
+def test_smallthinker_decode_step_names_its_parts(one_chip, monkeypatch):
+    """SmallThinker's decode step as its cell runs it (one period of four
+    layers, every expert, the whole vocabulary, 32 slots of 897 blocks):
+    after XLA:TPU's fusion at least 95% of the instructions a trace will
+    time carry ``engine.decode`` and a part of the vocabulary, and the
+    paged kernel and the expert kernel are there, a call a layer each."""
+    from ray_tpu.models import smallthinker as st
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = st.SmallThinkerConfig(num_layers=4, max_seq_len=14352,
+                                param_dtype=jnp.bfloat16)
+    B, bs, MB = 32, 16, 897
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    params = jax.eval_shape(
+        functools.partial(st.smallthinker_init, cfg=cfg), key)
+    pool = jax.eval_shape(
+        lambda: st.init_pools(cfg, {"full": 512, "window": 512}, bs))
+    text = _compile_decode(
+        functools.partial(st.decode_sample, cfg=cfg, attn="paged_kernel"),
+        params, pool, B, MB, one_chip)
+    assert text.count('custom_call_target="tpu_custom_call"') == 8
+    counted, bare = _unscoped(text)
+    assert counted >= 120 and len(bare) <= 0.05 * counted, bare
 
 
 @pytest.mark.parametrize("tp", [1, 2], ids=["one-device", "tp2"])

@@ -98,17 +98,15 @@ def test_span_hygiene_fixtures(tmp_path):
 
 class Loop:
     def begin(self):
-        self._span = tracing.start_span("loop")  # stashed, never ended
-
-    def tick(self):
-        pass
+        self._span = tracing.span("loop")  # stashed, never entered
 
 def leak_cm():
     s = tracing.span("work")  # CM stashed instead of with-entered
     return s
 
-def drop_handle():
-    tracing.start_span("orphan")  # handle dropped on the floor
+def leak_trace():
+    t = tracing.trace("request")
+    return t
 """
     r = lint_tree(tmp_path, {"ray_tpu/bad.py": bad},
                   rules=["span-hygiene"])
@@ -116,30 +114,19 @@ def drop_handle():
 
     good = """from ray_tpu._private import tracing
 
-class Loop:
-    def begin(self):
-        self._span = tracing.start_span("loop")
-
-    def stop(self):
-        if self._span is not None:
-            self._span.end()
-
 def lexical():
     with tracing.span("work"):
         pass
-    with tracing.trace("request"):
-        pass
+    with tracing.trace("request") as ctx:
+        return ctx
 
-def handoff():
-    s = tracing.start_span("phase")
-    return s  # caller owns the lifetime
+def in_a_program(x):
+    with tracing.scope("attn.proj"):  # a name scope, not a span
+        return x
 
-def local_closed():
-    s = tracing.start_span("phase")
-    try:
-        pass
-    finally:
-        s.end()
+def span(name):  # a user's own function of that name
+    s = span(name)
+    return s
 """
     r = lint_tree(tmp_path, {"ray_tpu/bad.py": good},
                   rules=["span-hygiene"])
