@@ -24,6 +24,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu._private import tracing
 from ray_tpu.ops.attention import dot_product_attention
+from ray_tpu.ops.head_loss import head_loss, head_loss_chunks
 from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
 
 
@@ -245,21 +246,10 @@ def _decoder_layer(x, lp, *, cfg: LlamaConfig, cos, sin, mesh, rules=None):
         return _constrain(x, mesh, "batch", "seq", None, rules=rules)
 
 
-def llama_apply(
-    params: Dict[str, Any],
-    tokens: jnp.ndarray,
-    cfg: LlamaConfig,
-    *,
-    mesh=None,
-    rules=None,
-) -> jnp.ndarray:
-    """Forward pass: tokens [b, s] int32 → logits [b, s, vocab] (fp32).
-
-    ``rules`` is the logical-axis rule table the surrounding trainer
-    shards params with (None = ``DEFAULT_RULES``): activations are
-    constrained through the SAME table, so layouts stay consistent end
-    to end — the named-sharding discipline.
-    """
+def _llama_hidden(params, tokens, cfg: LlamaConfig, *, mesh, rules):
+    """tokens [b, s] → (final-normed hidden states [b, s, h], head [h, v]),
+    both in ``cfg.dtype``: all of the forward but the vocabulary product,
+    for :func:`llama_apply` and :func:`llama_loss`."""
     s = tokens.shape[1]
     cos, sin = rope_frequencies(cfg.resolved_head_dim, s, cfg.rope_theta)
     with tracing.scope("embed"):
@@ -343,9 +333,45 @@ def llama_apply(
         head = (
             params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         ).astype(cfg.dtype)
+        return x, head
+
+
+def llama_apply(
+    params: Dict[str, Any],
+    tokens: jnp.ndarray,
+    cfg: LlamaConfig,
+    *,
+    mesh=None,
+    rules=None,
+) -> jnp.ndarray:
+    """Forward pass: tokens [b, s] int32 → logits [b, s, vocab] (fp32).
+
+    ``rules`` is the logical-axis rule table the surrounding trainer
+    shards params with (None = ``DEFAULT_RULES``): activations are
+    constrained through the SAME table, so layouts stay consistent end
+    to end — the named-sharding discipline.
+    """
+    x, head = _llama_hidden(params, tokens, cfg, mesh=mesh, rules=rules)
+    with tracing.scope("head"):
         logits = jnp.einsum("bsh,hv->bsv", x, head,
                             preferred_element_type=jnp.float32)
         return _constrain(logits, mesh, "batch", "seq", None, rules=rules)
+
+
+def _head_chunks(b: int, s: int, cfg: LlamaConfig, mesh, rules) -> int:
+    """Sequence chunks of the loss's head, from static shapes: the batch
+    rows a device holds decide a chunk's size, and a mesh that shards the
+    sequence axis leaves it whole (each device holds a piece already)."""
+    if mesh is not None:
+        from jax.sharding import NamedSharding
+
+        from ray_tpu.parallel.sharding import logical_to_pspec
+
+        b, held = NamedSharding(mesh, logical_to_pspec(
+            ("batch", "seq"), rules, mesh=mesh)).shard_shape((b, s))
+        if held < s:
+            return 1
+    return head_loss_chunks(b, s, cfg.vocab_size)
 
 
 def llama_loss(
@@ -357,16 +383,24 @@ def llama_loss(
     rules=None,
 ) -> jnp.ndarray:
     """Next-token cross-entropy; batch has 'tokens' [b,s] and optional
-    'mask' [b,s] (1 = contribute to loss)."""
+    'mask' [b,s] (1 = contribute to loss).
+
+    The head and the loss are one function with its own backward rule
+    (``ops/head_loss.py``): no ``[b, s, vocab]`` float32 array is kept,
+    or, past ``CHUNK_LOGITS_BYTES``, formed."""
     tokens = batch["tokens"]
-    logits = llama_apply(params, tokens[:, :-1], cfg, mesh=mesh, rules=rules)
+    x, head = _llama_hidden(params, tokens[:, :-1], cfg, mesh=mesh,
+                            rules=rules)
     targets = tokens[:, 1:]
     with tracing.scope("loss"):
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None],
-                                   axis=-1)[..., 0]
         mask = batch.get("mask")
         if mask is not None:
             mask = mask[:, 1:].astype(jnp.float32)
-            return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-        return jnp.mean(nll)
+            weights = mask / jnp.maximum(jnp.sum(mask), 1.0)
+        else:
+            weights = jnp.full(targets.shape, 1.0 / targets.size,
+                               jnp.float32)
+    return head_loss(
+        x, head, targets, weights,
+        _head_chunks(*targets.shape, cfg, mesh, rules),
+        lambda a: _constrain(a, mesh, "batch", "seq", None, rules=rules))

@@ -1,7 +1,8 @@
 """The flash kernels, the decode-shaped expert kernel (PR 32), the two
 decode programs whose heads projections read their weights in place (PR 35)
-and the three served models' decode programs' name scopes after XLA:TPU's
-fusion (PR 37), compiled for a v5e that is described, not attached.
+the three served models' decode programs' name scopes after XLA:TPU's
+fusion (PR 37) and the train cell's step with its head and loss as one
+function (PR 38), compiled for a v5e that is described, not attached.
 
 The interpreter cannot see what the chip's compiler refuses: more VMEM
 than a kernel may use (the backward keeps dK/dV whole in scratch and sets
@@ -339,6 +340,103 @@ def test_smallthinker_decode_step_names_its_parts(one_chip, monkeypatch):
     assert text.count('custom_call_target="tpu_custom_call"') == 8
     counted, bare = _unscoped(text)
     assert counted >= 120 and len(bare) <= 0.05 * counted, bare
+
+
+# ------------------- the train cell's head and loss as one function (PR 38)
+
+@pytest.fixture(scope="module")
+def train_step(one_chip):
+    """The text of ``train-1chip-s4096``'s step (the cell's configuration
+    and traffic files: Mistral-7B-v0.3's widths, 2 layers, 4 x 4096 tokens,
+    AdamW), compiled as ``cells/tools/compile_for_v5e.py`` compiles it."""
+    import json
+    import os
+
+    from jax.sharding import Mesh
+
+    from cells import families
+    from ray_tpu.parallel.mesh import MESH_AXES
+
+    cells = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "cells")
+    with open(os.path.join(
+            cells, "configs", "mistral-7b-v0.3-L2-train.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(cells, "traffic", "train-b4-s4096.json")) as f:
+        traffic = json.load(f)
+    fam = families.load(config["family"])
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(
+        (1,) * len(MESH_AXES)), MESH_AXES)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        tr = fam.make_trainer(fam.config(config["model"]), mesh,
+                              traffic["optimizer"])
+        state = jax.eval_shape(tr._state_init, jax.random.PRNGKey(0))
+        state = jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            state, tr.state_shardings)
+        batch = {"tokens": jax.ShapeDtypeStruct(
+            (traffic["batch"], traffic["seq"] + 1), jnp.int32,
+            sharding=tr.batch_sharding)}
+        with mesh:
+            return tr._jit_step.lower(state, batch).compile().as_text()
+
+
+def _part(line):
+    """The part of an instruction's ``op_name``, as the trace's reader
+    (``cells/parts.py``) finds it."""
+    from cells import parts
+
+    name = re.search(r'op_name="([^"]*)"', line)
+    return parts.scopes_of(name.group(1))[1] if name else None
+
+
+def test_the_train_step_holds_three_products_of_the_heads_shape(train_step):
+    """Logits (a sequence chunk at a time, in the rule's loop), dX and dW:
+    three, each bf16 x bf16 into float32 as the parent's compiled step ran
+    them (its cotangent a float32 operand at the default precision: one
+    bf16 pass, PERF.md section 6, PR 38).  The parent held four: XLA ran
+    the forward product again in the backward (``fusion.284.remat``)
+    rather than keep 2.1 GB of logits."""
+    products = [line for line in train_step.splitlines()
+                if " convolution(" in line and _part(line) == "head"]
+    assert len(products) == 3, products
+    results = sorted(re.search(r"= (\w+\[[\d,]*\])", p).group(1)
+                     for p in products)
+    assert results == ["f32[4,128,32768]", "f32[4,4096,4096]",
+                       "f32[4096,32768,1]"], results
+    twins = [line.split(" = ")[0].strip() for line in train_step.splitlines()
+             if re.match(r"\s*(?:ROOT )?%[\w.\-]*\.remat[\w.\-]* = ", line)
+             and _part(line) in ("head", "loss")]
+    assert twins == []
+
+
+def test_the_train_step_forms_no_float32_logits_of_the_whole_batch(
+        train_step):
+    """No ``[tokens, vocab]`` float32 array in any computation: a chunk's
+    ``f32[4,128,32768]`` (64 MiB) is the largest, and what the backward
+    reads is the cotangent in the products' operand type."""
+    for shape in ("f32[4,4096,32768]", "f32[16384,32768]",
+                  "f32[4,4096,1,32768]"):
+        assert shape not in train_step, shape
+    assert "f32[4,128,32768]" in train_step
+    assert "bf16[4,4096,32768]" in train_step
+
+
+def test_every_instruction_of_the_rule_is_head_or_loss(train_step):
+    """What a trace will time inside the rule's loop (fusions, the product)
+    carries ``head`` or ``loss``, so ``step_head_loss_ms.train`` covers it
+    and ``(unscoped)`` does not grow (the two backward products are held
+    to ``head`` by the test of the three products)."""
+    bodies = dict(re.findall(
+        r"^%([\w.\-]+) [^\n]*\{\n(.*?)^\}", train_step, re.S | re.M))
+    timed = [[line for line in bodies[b].splitlines()
+              if re.search(r" (fusion|convolution)\(", line)]
+             for b in re.findall(r"body=%([\w.\-]+)", train_step)]
+    (timed,) = [t for t in timed if any(_part(line) == "head" for line in t)]
+    assert len(timed) >= 3
+    assert [line[:160] for line in timed
+            if _part(line) not in ("head", "loss")] == []
 
 
 @pytest.mark.parametrize("tp", [1, 2], ids=["one-device", "tp2"])
