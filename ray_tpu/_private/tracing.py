@@ -294,6 +294,12 @@ PROGRAM_SCOPES = ("engine.decode", "engine.prefill", "engine.verify",
 PART_SCOPES = ("embed", "attn.proj", "attn.cache", "attn.core", "attn.out",
                "ffn", "router", "experts", "experts.combine", "head",
                "sample", "loss", "optimizer")
+# A third level, inside ``attn.core``, where a model's token mixer is not
+# attention alone (models/phi4flash.py): the convolution in front of a
+# state-space scan, the scan (prefill) or its one-position update (decode),
+# the gated memory unit, the differential combination and its norm.  A
+# reader that knows two levels still finds ``attn.core``.
+DETAIL_SCOPES = ("ssm.conv", "ssm.scan", "ssm.update", "gmu", "diff")
 
 
 def scope(name: str):

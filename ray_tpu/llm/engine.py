@@ -26,6 +26,14 @@ batching, paged attention, automatic prefix caching,
   decodes (``_release_behind_window``) and from admission on for a prompt
   longer than the window.  A model without the field is one type, and
   everything below is what it was.
+* **State types**: a layer type may keep, for a request, ONE record of
+  fixed size instead of blocks of positions (recurrent state:
+  ``ServedModel.layer_types``' ``"state"``).  Its pool's axis 1 is the
+  record (record 0 the scratch one, which idle slots update), its table
+  one entry a slot; a record is allocated at admission, given back at
+  retire and at preemption, never grown, never published to the prefix
+  cache.  A type also says how many layers READ its pool (``"readers"``)
+  where they are not the layers that store it.
 * **Preemption**: out of blocks mid-decode → the youngest request is
   rolled back to the queue (its tokens re-prefill later), matching vLLM's
   recompute-preemption policy.
@@ -273,12 +281,16 @@ class _BlockManager:
 class _LayerPool:
     """One layer type's share of the cache on the host: its block manager,
     its ``[B, MB]`` table, and the window behind which its blocks are dead
-    (None: never)."""
+    (None: never).  ``readers``: the layers that read the pool in a decode
+    step (``layers`` store it).  A ``state`` type's manager hands out
+    records, one a request, and its table is ``[B, 1]``."""
     name: str
     layers: int
     window: Optional[int]
     blocks: _BlockManager
     tables: np.ndarray
+    readers: int
+    state: bool = False
 
     def held(self) -> int:
         return self.blocks.num_blocks - 1 - self.blocks.available()
@@ -339,12 +351,16 @@ class LLMEngine:
         # prefix sharing + short requests usually need far less)
         if types is None:
             self.num_blocks = num_blocks or (self.B * self.MB + 1)
-        else:  # an int is every type's; a window type never holds more
-            self.num_blocks = {
-                t: (num_blocks.get(t) if isinstance(num_blocks, dict)
-                    else num_blocks)
-                or self.B * min(self.MB, self._window_blocks(
-                    spec["window"])) + 1 for t, spec in types.items()}
+        else:  # an int is every type's of positions; a window type never
+            # holds more than its window, a state type a record a slot
+            asked = num_blocks if isinstance(num_blocks, dict) else {
+                t: num_blocks for t, spec in types.items()
+                if not spec.get("state")}
+            most = {t: 1 if spec.get("state") else min(
+                self.MB, self._window_blocks(spec["window"]))
+                for t, spec in types.items()}
+            self.num_blocks = {t: asked.get(t) or self.B * most[t] + 1
+                               for t in types}
         if params is None:
             params = model.init(jax.random.PRNGKey(seed), cfg)
         self.params = params
@@ -364,13 +380,18 @@ class LLMEngine:
         sizes = self.num_blocks if types else {"kv": self.num_blocks}
         pools = [_LayerPool(t, spec["layers"], spec["window"],
                             _BlockManager(sizes[t]),
-                            np.zeros((self.B, self.MB), np.int32))
+                            np.zeros((self.B, 1 if spec.get("state")
+                                      else self.MB), np.int32),
+                            spec.get("readers", spec["layers"]),
+                            bool(spec.get("state")))
                  for t, spec in (types or one).items()]
-        if pools[0].window is not None:
+        if pools[0].window is not None or pools[0].state:
             raise ValueError(f"{model.name}: the first layer type keeps "
                              f"every position (a slot is live where its "
                              f"first table holds a block)")
         self._pools, self._more = pools, pools[1:]
+        # the pools of positions: what "blocks" counts in stats()
+        self._kv_pools = [p for p in pools if not p.state]
         self._by_type = types is not None  # programs take tables by type
         self.blocks = pools[0].blocks
         # the decode step's attention, read off what is in front of us:
@@ -722,7 +743,8 @@ class LLMEngine:
                 if budget is not None and budget <= 0:
                     break  # spent: further walks would only defer
         if admitted:
-            with tracing.annotate("engine.first_tokens", n=len(admitted)):
+            with tracing.annotate("engine.first_tokens",
+                                  n=len(admitted)) as ann:
                 self._key, k = jax.random.split(self._key)
                 # padded to a power of two (the last row again, greedy):
                 # a number of admissions not seen before would lower three
@@ -737,7 +759,10 @@ class LLMEngine:
                 if self._prefill_sum is not None:
                     first, counts = jax.device_get(
                         (first, self._prefill_sum))
-                    self._count(counts, self._prefill_calls, "prefill_")
+                    ann.set_metadata(**{
+                        "prefill_" + name: v for name, v in self._count(
+                            counts, self._prefill_calls,
+                            "prefill_").items()})
                     self._prefill_sum, self._prefill_calls = None, 0
                 first = np.asarray(first)[:len(admitted)]
                 now = time.time()
@@ -847,21 +872,27 @@ class LLMEngine:
     def _live_tokens(self, active: List[int]) -> Dict[str, int]:
         """``engine.dispatch_window``'s ``live_tokens``: the cached
         positions a decode step attends over, all slots together.  With
-        several layer types it is the mean over the layers, a window type
-        counting at most its window (so that positions x the bytes a
-        position takes over ALL layers is what a step reads), beside each
-        type's own count and the blocks each pool holds."""
+        several layer types it is the mean over the layers that READ a
+        pool of positions, a window type counting at most its window (so
+        that positions x the bytes a position takes in those layers is
+        what a step reads), beside each type's own count and the blocks
+        each pool holds; for a state type the records a step reads and
+        writes (one an active slot) and the records held."""
         lens = self._cur_len[active]
         if not self._by_type:
             return {"live_tokens": int(lens.sum())}
         by = {p.name: int((np.minimum(lens, p.window) if p.window
-                           else lens).sum()) for p in self._pools}
-        layers = sum(p.layers for p in self._pools)
+                           else lens).sum()) for p in self._kv_pools}
+        layers = sum(p.readers for p in self._kv_pools)
         out = {"live_tokens": round(sum(
-            by[p.name] * p.layers for p in self._pools) / layers)}
-        for p in self._pools:
+            by[p.name] * p.readers for p in self._kv_pools) / layers)}
+        for p in self._kv_pools:
             out[f"live_tokens_{p.name}"] = by[p.name]
             out[f"blocks_held_{p.name}"] = p.held()
+        for p in self._pools:
+            if p.state:  # a record a slot is read and written, whole
+                out[f"live_tokens_{p.name}"] = len(active)
+                out[f"{p.name}_records_held"] = p.held()
         return out
 
     def _window_blocks(self, window: Optional[int]) -> int:
@@ -871,6 +902,11 @@ class LLMEngine:
         if window is None:
             return self.MB
         return (window + self.K - 2) // self.bs + 2
+
+    def _most_held(self, p: _LayerPool, worst: int) -> int:
+        """The most blocks (records) of pool ``p`` one sequence of up to
+        ``worst`` blocks ever holds."""
+        return 1 if p.state else min(worst, self._window_blocks(p.window))
 
     def _release_behind_window(self, i: int, req: Request) -> None:
         """Give back slot i's blocks that lie wholly behind a layer type's
@@ -1239,10 +1275,12 @@ class LLMEngine:
         from ray_tpu.util.health import device_memory_stats
 
         used = sum(1 for s in self._slots if s is not None)
-        # excl. the scratch blocks; summed over the layer types' pools,
-        # whose blocks differ in bytes (a block x the type's layers)
-        capacity = max(1, sum(p.blocks.num_blocks - 1 for p in self._pools))
-        available = sum(p.blocks.available() for p in self._pools)
+        # excl. the scratch blocks; summed over the layer types' pools of
+        # positions, whose blocks differ in bytes (a block x the type's
+        # layers); a state type's records are under "pools" alone
+        kv = self._kv_pools
+        capacity = max(1, sum(p.blocks.num_blocks - 1 for p in kv))
+        available = sum(p.blocks.available() for p in kv)
         by_type = {"pools": {p.name: {
             "total": p.blocks.num_blocks - 1,
             "available": p.blocks.available(), "held": p.held()}
@@ -1256,8 +1294,8 @@ class LLMEngine:
             "slots_total": self.B,
             "slot_occupancy": round(used / self.B, 4),
             "blocks_total": capacity,
-            "blocks_free": sum(len(p.blocks.free) for p in self._pools),
-            "blocks_cached": sum(len(p.blocks.lru) for p in self._pools),
+            "blocks_free": sum(len(p.blocks.free) for p in kv),
+            "blocks_cached": sum(len(p.blocks.lru) for p in kv),
             "blocks_available": available,
             "block_pressure": round(1.0 - available / capacity, 4),
             "block_size": self.bs,
@@ -1334,11 +1372,11 @@ class LLMEngine:
         worst = -(-min(req.n_prompt + req.sampling.max_tokens + 1,
                        self.max_len) // self.bs)
         # a further layer type's blocks: up to the first decode's, from the
-        # first one a later step can still see
+        # first one a later step can still see; a state type's one record
         more = [(max(0, n + 1 - p.window) // self.bs if p.window else 0,
-                 need) for p in self._more]
-        if any(min(worst, self._window_blocks(p.window))
-               >= p.blocks.num_blocks for p in self._pools):
+                 1 if p.state else need) for p in self._more]
+        if any(self._most_held(p, worst) >= p.blocks.num_blocks
+               for p in self._pools):
             # even an empty pool could never hold this one sequence: fail
             # THIS request (an admit/preempt livelock otherwise) — never
             # the whole batch; one oversized HTTP request must not kill
@@ -1430,7 +1468,8 @@ class LLMEngine:
         last-position logits as a device array.  A model with several
         layer types takes the block coordinates by type (``more_blocks``:
         ``Request.more_blocks``; a position whose block a window type does
-        not hold goes to that pool's scratch block)."""
+        not hold goes to that pool's scratch block; a state type's entry
+        is ``[1]``, the request's record)."""
         import jax.numpy as jnp
 
         S = _bucket(len(suffix), self.max_len)
@@ -1448,9 +1487,10 @@ class LLMEngine:
             return jnp.asarray(dst)
 
         dst_b = coordinates(blocks)
-        if self._by_type:
-            dst_b = {p.name: coordinates(held) if p is not self._pools[0]
-                     else dst_b
+        if self._by_type:  # a state type's: the request's record
+            dst_b = {p.name: dst_b if p is self._pools[0]
+                     else jnp.asarray(held, jnp.int32) if p.state
+                     else coordinates(held)
                      for p, held in zip(self._pools, [blocks, *more_blocks])}
         P = _bucket(len(hit_blocks), self.MB) if hit_blocks else 0
         prefix_ids = np.zeros(P, np.int32)
@@ -1532,6 +1572,8 @@ class LLMEngine:
                            - 1, self.max_len - 1)
             blk_idx = last_pos // self.bs
             for p, held in zip(self._pools, [req.blocks] + req.more_blocks):
+                if p.state:
+                    continue  # a record does not grow with the position
                 while blk_idx >= len(held) and self._slots[i] is req:
                     bid = p.blocks.alloc()
                     if bid is None:

@@ -39,7 +39,20 @@ manager and a block table for each type: ``init_pool`` takes
 takes ``block_tables`` by type, ``prefill_suffix`` its ``dst_blocks`` by
 type, and a window type's blocks that lie wholly behind the window go back
 to its pool while a request decodes (``docs/llm_serving.md``).  Such a
-model takes no prefix hits and no ``prefill_chunk`` yet.
+model takes no prefix hits and no ``prefill_chunk`` yet.  Two further keys
+of a type, both optional:
+
+* ``"readers": m``: the layers that READ the type's pool in a decode step,
+  where they are not the ``n`` that store it (a cache that later layers
+  attend over with queries of their own);
+* ``"state": True``: a **state type**.  Axis 1 of its pool is a RECORD of
+  fixed size, one a request (record 0 the scratch one), not a block of
+  positions: it is allocated at admission, given back at retire and at
+  preemption, never grown and never published to the prefix cache.  Its
+  table is ``[B, 1]`` (a slot's record; 0 for an idle slot),
+  ``prefill_suffix``'s ``dst_blocks[type]`` is ``[1]`` (the request's
+  record, which the prefill writes from a zero state), and ``num_blocks``
+  of the type is records + 1.
 
 ``presets`` are the configurations the model offers by name
 (``build_llm_deployment({"model": "<name>"})`` resolves one through
@@ -146,11 +159,28 @@ def _smallthinker() -> ServedModel:
         counters=("moe_pairs_held", "moe_experts_hit", "moe_zero_picks"))
 
 
+def _phi4flash() -> ServedModel:
+    from ray_tpu.models import phi4flash as pf
+
+    return ServedModel(
+        name="phi4flash", init=pf.phi4flash_init, init_pool=pf.init_pools,
+        prefill_suffix=pf.prefill_suffix, gather_prefix=pf.gather_prefix,
+        decode_sample=pf.decode_sample,
+        decode_attention_path=pf.decode_attention_path,
+        presets={"phi4flash_tiny": pf.Phi4FlashConfig.tiny,
+                 "phi4_mini_flash": pf.Phi4FlashConfig},
+        test_presets=("phi4flash_tiny",), layer_types=pf.layer_types,
+        # positions the self-decoder and the cross-decoder computed: a
+        # prefill's read "prefill_positions" / "prefill_cross_positions"
+        counters=("positions", "cross_positions"))
+
+
 # configuration class -> the function that builds its ServedModel: a model's
 # modules are imported when it is first asked for, so that a process which
 # serves one model loads one model
 _MODELS = {"LlamaConfig": _llama, "LongcatConfig": _longcat,
-           "SmallThinkerConfig": _smallthinker}
+           "SmallThinkerConfig": _smallthinker,
+           "Phi4FlashConfig": _phi4flash}
 
 
 @functools.lru_cache(maxsize=None)

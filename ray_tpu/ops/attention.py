@@ -46,18 +46,21 @@ def reference_attention(
     positions_q: Optional[jnp.ndarray] = None,
     positions_k: Optional[jnp.ndarray] = None,
     window: Optional[int] = None,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Plain softmax attention, fp32 accumulation.
 
     q: [b, sq, h, d]; k, v: [b, sk, kv_h, d] with h % kv_h == 0.
     ``window``: sliding-window (Mistral-style) — query p attends keys in
-    (p - window, p].  Requires causal.
+    (p - window, p].  Requires causal.  ``scale``: the scores' factor,
+    ``d ** -0.5`` unless given.
     """
     b, sq, h, d = q.shape
     kv_h = k.shape[2]
     k = _repeat_kv(k, h // kv_h)
     v = _repeat_kv(v, h // kv_h)
-    scale = d ** -0.5
+    if scale is None:
+        scale = d ** -0.5
     if window is not None and not causal:
         raise ValueError("sliding window requires causal attention")
     logits = jnp.einsum(
@@ -203,6 +206,7 @@ def dot_product_attention(
     mesh: Optional[Mesh] = None,
     sp_axis: str = "sp",
     window: Optional[int] = None,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Dispatching attention entry point used by the model layer.
 
@@ -212,7 +216,13 @@ def dot_product_attention(
     Mistral-style) is supported by all three; the flash kernel's forward
     skips the K blocks before the window, its backward has no window yet
     and raises by name (differentiate 'ref' or 'ring' under a window).
+    ``scale`` (the scores' factor where it is not ``head_dim ** -0.5``) is
+    taken by 'ref' and by the flash forward on one device, forward only.
     """
+    if scale is not None and mesh is not None:
+        raise NotImplementedError(
+            "dot_product_attention takes a scale of its own on one device "
+            "only (the ring and the sharded flash call have none)")
     if impl == "auto":
         if (
             mesh is not None
@@ -234,7 +244,8 @@ def dot_product_attention(
         from ray_tpu.ops.pallas.flash_attention import flash_attention
 
         if mesh is None:
-            return flash_attention(q, k, v, causal=causal, window=window)
+            return flash_attention(q, k, v, causal=causal, window=window,
+                                   scale=scale)
         # The pallas_call is opaque to GSPMD: run it per-shard under
         # shard_map, with batch sharded over dp/fsdp and heads over tp
         # (sequence is whole per device since sp==1 on this path).
@@ -254,4 +265,5 @@ def dot_product_attention(
             out_specs=qspec,
             check_vma=False,
         )(q, k, v)
-    return reference_attention(q, k, v, causal=causal, window=window)
+    return reference_attention(q, k, v, causal=causal, window=window,
+                               scale=scale)

@@ -223,7 +223,7 @@ def _last_k_block(causal, qi, ki, block_q, block_k, window=None):
 
 
 def _flash_fwd_impl(q, k, v, *, causal, block_q, block_k, interpret,
-                    window=None):
+                    window=None, scale=None):
     b, sq, h, d = q.shape
     sk, kv_h = k.shape[1], k.shape[2]
     n_rep = h // kv_h
@@ -248,7 +248,8 @@ def _flash_fwd_impl(q, k, v, *, causal, block_q, block_k, interpret,
         return (bh, 0, qi)
 
     kernel = functools.partial(
-        _fwd_kernel, scale=d ** -0.5, causal=causal, k_padded=sk_p != sk,
+        _fwd_kernel, scale=d ** -0.5 if scale is None else float(scale),
+        causal=causal, k_padded=sk_p != sk,
         block_q=block_q, block_k=block_k, seq_k=sk, window=window,
     )
     out, lse = pl.pallas_call(
@@ -483,11 +484,14 @@ def flash_attention(
     block_k: int = 1024,
     interpret: bool = False,
     window: int | None = None,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """Flash attention. q: [b, s, h, d]; k, v: [b, s, kv_h, d].
 
     ``window``: a query at position p sees the keys in (p - window, p],
     positions counted from 0 in both operands; causal only, forward only.
+    ``scale``: the scores' factor where it is not ``d ** -0.5`` (a query
+    zero-padded to twice its head's width); forward only.
 
     Off-TPU this runs the Pallas interpreter (slow; tests use small
     shapes).
@@ -496,4 +500,8 @@ def flash_attention(
         raise ValueError("sliding window requires causal attention")
     if jax.default_backend() != "tpu":
         interpret = True
+    if scale is not None:  # forward only: outside the custom_vjp
+        return _flash_fwd_impl(
+            q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+            interpret=interpret, window=window, scale=scale)[0]
     return _flash(q, k, v, causal, block_q, block_k, interpret, window)

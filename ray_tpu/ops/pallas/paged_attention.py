@@ -178,6 +178,8 @@ _BLOCK_ROWS = 1024
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths, *, layer,
                     window: int | None = None,
+                    scale: float | None = None,
+                    kv_heads: int | None = None,
                     pages_per_block: int | None = None,
                     interpret: bool | None = None):
     """The dense arm: attention of one query token a slot over its paged
@@ -189,7 +191,12 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *, layer,
     lengths ``[b]`` int32, the number of cached positions the token sees
     (its own included), 0 for a slot that holds nothing.  ``window``: only
     the last ``window`` positions are visible (``ops.attention.
-    sliding_window_mask``).  Returns ``[b, H, hd]`` in q's dtype.
+    sliding_window_mask``).  ``scale``: the scores' factor, ``hd ** -0.5``
+    unless given.  ``kv_heads``: the pools come as pages already,
+    ``[L, NB, bs * kv_heads, hd]`` (a number of KV heads that is no
+    multiple of a tile's 8 sublanes has no unpadded 5-D layout on the chip,
+    and XLA copies such a pool to reshape it).  Returns ``[b, H, hd]`` in
+    q's dtype.
 
     Off-TPU this runs the Pallas interpreter (slow; tests use small
     shapes).
@@ -198,6 +205,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *, layer,
         interpret = jax.default_backend() != "tpu"
     return _paged_attention(q, k_pool, v_pool, block_tables, lengths,
                             jnp.asarray(layer, jnp.int32), window=window,
+                            scale=scale, kv_heads=kv_heads,
                             pages_per_block=pages_per_block,
                             interpret=interpret)
 
@@ -270,17 +278,22 @@ def _call(kernel, q, pools, block_tables, lengths, layer, *, kv_heads,
       block_tables.reshape(-1).astype(jnp.int32), q, group, tok, *pools)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("window", "pages_per_block", "interpret"))
+@functools.partial(jax.jit, static_argnames=(
+    "window", "scale", "kv_heads", "pages_per_block", "interpret"))
 def _paged_attention(q, k_pool, v_pool, block_tables, lengths, layer, *,
-                     window, pages_per_block, interpret):
+                     window, scale, kv_heads, pages_per_block, interpret):
     hd = q.shape[2]
-    L, NB, bs, KVH, _ = k_pool.shape
-    # a page as a matrix of (token, kv head) rows: a view, not a copy
-    k_pages = k_pool.reshape(L, NB, bs * KVH, hd)
-    v_pages = v_pool.reshape(L, NB, bs * KVH, hd)
-    kernel = functools.partial(_kernel, window=window, block_size=bs,
-                               scale=float(hd) ** -0.5)
+    if kv_heads is None:
+        L, NB, bs, KVH, _ = k_pool.shape
+        # a page as a matrix of (token, kv head) rows: a view, not a copy
+        k_pages = k_pool.reshape(L, NB, bs * KVH, hd)
+        v_pages = v_pool.reshape(L, NB, bs * KVH, hd)
+    else:
+        KVH, bs = kv_heads, k_pool.shape[2] // kv_heads
+        k_pages, v_pages = k_pool, v_pool
+    kernel = functools.partial(
+        _kernel, window=window, block_size=bs,
+        scale=float(hd) ** -0.5 if scale is None else float(scale))
     return _call(kernel, q, (k_pages, v_pages), block_tables, lengths, layer,
                  kv_heads=KVH, out_width=hd,
                  pages_per_block=pages_per_block, interpret=interpret)
