@@ -479,6 +479,31 @@ def init_pools(cfg: Phi4FlashConfig, num_blocks: Dict[str, int],
     return pools
 
 
+def store_slabs(pages, layer, blocks, offsets, slabs):
+    """A token's keys (or values) into a pool stored as pages, ONE update a
+    token: pages ``[L, NB, bs * g, W]``, slabs ``[n, g, W]``, token ``i``
+    to rows ``offsets[i] * g .. + g`` of page ``blocks[i]`` of ``layer``.
+
+    XLA:TPU's scatter takes a window of whole trailing dimensions; a window
+    inside the page's rows it unrolls into a loop of small fusions, and
+    single rows (``[n, g]`` updates of ``[W]``) cost ~70 ns each against
+    ``g * W`` bytes of work.  So the page is viewed ``[bs, g / r, r, W]``,
+    ``r`` the rows a 32-bit sublane packs (2 in bf16): the same bytes in
+    the same order on the chip (a packed sublane is contiguous and a page's
+    sublanes are in order), a bitcast both ways, and the slab is the
+    window.  The coordinates are the engine's and in bounds by
+    construction; idle slots and pad lanes share the scratch block, so
+    they are not unique."""
+    L, NB, rows, W = pages.shape
+    n, g, _ = slabs.shape
+    r = max(1, 4 // pages.dtype.itemsize)
+    r = r if g % r == 0 else 1
+    view = pages.reshape(L, NB, rows // g, g // r, r, W)
+    view = view.at[layer, blocks, offsets].set(
+        slabs.reshape(n, g // r, r, W), mode="promise_in_bounds")
+    return view.reshape(pages.shape)
+
+
 def decode_attention_path(pool, *, mesh=None, spec_tokens: int = 0) -> str:
     """``paged_generation.decode_attention_path``'s rule for the two pools
     of positions, whose blocks are pages already: the paged kernel on one
@@ -524,8 +549,6 @@ def prefill_suffix(params, tokens, length, start_pos, prefix_k, prefix_v,
     _, S = tokens.shape
     pool = {t: dict(p) for t, p in pool.items()}
     rec = dst_blocks[STATE][0]
-    g = cfg.num_kv_heads // 2
-    rows = dst_offsets[:, None] * g + jnp.arange(g)  # [S, pairs] in a page
     kept = {}
 
     def ssm_layer(a, x, lp, publish):
@@ -539,9 +562,9 @@ def prefill_suffix(params, tokens, length, start_pos, prefix_k, prefix_v,
     def attend(kind, a, q, k, v):
         p = pool[kind]  # pad lanes and unseen keys land in the scratch block
         with tracing.scope("attn.cache"):
-            blocks = dst_blocks[kind][:, None]
-            p["k"] = p["k"].at[a, blocks, rows].set(k[0])
-            p["v"] = p["v"].at[a, blocks, rows].set(v[0])
+            for name, slabs in (("k", k[0]), ("v", v[0])):
+                p[name] = store_slabs(p[name], a, dst_blocks[kind],
+                                      dst_offsets, slabs)
         if kind == FULL:
             kept["kv"] = (k, v)
         # the pad tail lies after every true position: causal hides it
@@ -577,8 +600,8 @@ def decode_step(params, token, cur_len, block_tables, pool,
     R = pool[STATE]["ssm"].shape[1]
     with tracing.scope("attn.cache"):  # where the step's rows go
         rows = jnp.arange(b)
-        off = (cur_len % bs)[:, None] * g + jnp.arange(g)  # [b, pairs]
-        blk = {t: block_tables[t][rows, cur_len // bs][:, None]
+        off = cur_len % bs
+        blk = {t: block_tables[t][rows, cur_len // bs]
                for t in (FULL, WINDOW)}
         live = block_tables[FULL][:, 0] != 0
         lengths = jnp.where(live, cur_len + 1, 0)
@@ -642,8 +665,8 @@ def decode_step(params, token, cur_len, block_tables, pool,
         p = pool[kind]
         with tracing.scope("attn.cache"):
             # the new keys and values first: the token attends to itself
-            p["k"] = p["k"].at[a, blk[kind], off].set(k[:, 0])
-            p["v"] = p["v"].at[a, blk[kind], off].set(v[:, 0])
+            for name, slabs in (("k", k[:, 0]), ("v", v[:, 0])):
+                p[name] = store_slabs(p[name], a, blk[kind], off, slabs)
         with tracing.scope("attn.core"):
             return read(kind, a, q)
 

@@ -1,8 +1,10 @@
 """The flash kernels, the decode-shaped expert kernel (PR 32), the two
 decode programs whose heads projections read their weights in place (PR 35)
 the three served models' decode programs' name scopes after XLA:TPU's
-fusion (PR 37) and the train cell's step with its head and loss as one
-function (PR 38), compiled for a v5e that is described, not attached.
+fusion (PR 37), the train cell's step with its head and loss as one
+function (PR 38) and Phi-4-mini-flash's decode step writing a token's
+slab into its page as one update (PR 40), compiled for a v5e that is
+described, not attached.
 
 The interpreter cannot see what the chip's compiler refuses: more VMEM
 than a kernel may use (the backward keeps dK/dV whole in scratch and sets
@@ -221,8 +223,8 @@ def _unscoped(text):
     return counted, bare
 
 
-def _compile_decode(step, params, pool, B, MB, one_chip):
-    """The text of ``step(params, token, cur_len, block_tables, pool, key,
+def _compiled_decode(step, params, pool, B, MB, one_chip):
+    """``step(params, token, cur_len, block_tables, pool, key,
     temperature)`` under the engine's program scope, compiled for ``B``
     slots of ``MB`` blocks (``block_tables`` by type where ``pool`` is),
     the pool donated as the engine donates it."""
@@ -231,14 +233,18 @@ def _compile_decode(step, params, pool, B, MB, one_chip):
     step = functools.partial(tracing.scoped, "engine.decode", step)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    tables = ({t: i32(B, MB) for t in pool} if "k" not in pool
-              and "kv" not in pool else i32(B, MB))
+    tables = ({t: i32(B, 1 if "ssm" in pool[t] else MB) for t in pool}
+              if "k" not in pool and "kv" not in pool else i32(B, MB))
     args = (params, i32(B), i32(B), tables, pool, key,
             jax.ShapeDtypeStruct((B,), jnp.float32))
     args = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
         s.shape, s.dtype, sharding=one_chip), args)
-    return jax.jit(step, donate_argnums=(4,)).lower(
-        *args).compile().as_text()
+    return jax.jit(step, donate_argnums=(4,)).lower(*args).compile()
+
+
+def _compile_decode(*args):
+    """The text of ``_compiled_decode``'s program."""
+    return _compiled_decode(*args).as_text()
 
 
 def test_the_dense_decode_step_reads_wq_wk_wv_in_place(one_chip, monkeypatch):
@@ -340,6 +346,59 @@ def test_smallthinker_decode_step_names_its_parts(one_chip, monkeypatch):
     assert text.count('custom_call_target="tpu_custom_call"') == 8
     counted, bare = _unscoped(text)
     assert counted >= 120 and len(bare) <= 0.05 * counted, bare
+
+
+def _scatters(text):
+    """(the result's dims, the number of updates) of every ``scatter`` in
+    the compiled module: an update is one index vector, so the updates'
+    dims that are no window's."""
+    out = []
+    for m in re.finditer(
+            r"= \w+\[([\d,]*)\]\S* scatter\(%[\w.\-]+, %[\w.\-]+, "
+            r"%([\w.\-]+)\), update_window_dims=\{([\d,]*)\}", text):
+        dims, updates, window = m.groups()
+        shape = re.search(
+            r"%" + re.escape(updates) + r" = \w+\[([\d,]*)\]", text).group(1)
+        window = {int(d) for d in window.split(",") if d}
+        out.append((tuple(int(d) for d in dims.split(",") if d), int(np.prod(
+            [int(d) for i, d in enumerate(shape.split(",")) if d
+             and i not in window]))))
+    return out
+
+
+def test_phi4flash_decode_step_writes_slabs(one_chip, monkeypatch):
+    """Phi-4-mini-flash's decode step as its cell runs it (all 32 layers,
+    64 slots of 257 blocks, the cell's pools): each of the nine storing
+    layers' keys and values reaches its page in ONE scatter of 64 updates,
+    a token's ``[10, 128]`` slab as the window ``[5, 2, 128]`` of the page
+    viewed ``[16, 5, 2, 128]``: the same bytes in the same order, so a
+    ``bitcast`` and no copy (temporaries 0.040 GB, XLA's own prefetches of
+    weights; a layer of the window pool is 0.089).  The parent's step
+    wrote 640 single rows a scatter, ~70 ns each on the chip (1.03 of a
+    step's 19 ms), and a pool held ``[.., 16, 10, 128]`` was copied whole
+    every layer (PR 39: 4.19 GB)."""
+    from ray_tpu.models import phi4flash as pf
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = pf.Phi4FlashConfig(max_seq_len=4112, param_dtype=jnp.bfloat16)
+    B, bs, MB = 64, 16, 257
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    params = jax.eval_shape(
+        functools.partial(pf.phi4flash_init, cfg=cfg), key)
+    pool = jax.eval_shape(lambda: pf.init_pools(
+        cfg, {"full": 8320, "window": 2180, "state": 65}, bs))
+    compiled = _compiled_decode(
+        functools.partial(pf.decode_sample, cfg=cfg, attn="paged_kernel"),
+        params, pool, B, MB, one_chip)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 16  # reads
+    scatters = _scatters(text)
+    assert all(n <= B for _, n in scatters), scatters
+    # keys and values of the full layer and the eight window layers (XLA
+    # folds the leading dimensions as it likes; the window is the slab)
+    assert [n for dims, n in scatters if dims[-3:] == (5, 2, 128)] == 18 * [B]
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
 
 
 # ------------------- the train cell's head and loss as one function (PR 38)

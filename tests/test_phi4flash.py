@@ -11,7 +11,8 @@ these tests and for the cell's ``correct``.
 (a) the building blocks of ``ops/ssm.py`` against loops written out, and the
     ``scale`` the attention ops gained;
 (b) the forward, and prefill then decode through the three pools, on logits
-    against the reference's full forward pass;
+    against the reference's full forward pass; the pages' write, a slab a
+    token (PR 40), against a row-by-row write, bit for bit;
 (c) a record's life: the same prompt in two buckets leaves the same record,
     a reused slot and a preempted request answer as the reference does, the
     books of every pool and of the state type are back at zero;
@@ -355,6 +356,102 @@ def test_bfloat16_in_place_of_float32_fails_the_tolerances():
     low = pf.phi4flash_apply(params, tokens,
                              dataclasses.replace(cfg, dtype=jnp.bfloat16))
     assert float(jnp.max(jnp.abs(low[0] - want))) > 100 * TOL
+
+
+def _rows_one_by_one(pages, layer, blocks, offsets, slabs):
+    """``store_slabs``' contract as the write it replaced: a token's ``g``
+    rows of a page, one ``set`` each, in the tokens' order."""
+    n, g, _ = slabs.shape
+    for i in range(n):
+        for j in range(g):
+            pages = pages.at[layer, blocks[i], offsets[i] * g + j].set(
+                slabs[i, j])
+    return pages
+
+
+def _write_case(case, cfg, params, pool):
+    """(program, arguments, blocks of the full and the window pool that
+    must have changed) of one case of the test below: pools of 12 blocks of
+    4 positions, block 0 the scratch one."""
+    i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+    bs, w = 4, cfg.sliding_window
+    if case.startswith("prefill"):
+        S, n = (16, 11) if case == "prefill-pad-tail" else (32, 30)
+        held = np.arange(1, 1 + S // bs, dtype=np.int32)
+        pos = np.arange(S)
+        dst = np.where(pos < n, held[pos // bs], 0)
+        # as ``_run_prefill``: a position whose block the window type does
+        # not hold (no later step can see it) has the scratch block's
+        behind = pos // bs < (n - w) // bs
+        window = np.where(behind, 0, dst)
+        assert (case == "prefill-window-scratch") == bool(behind.any())
+        tokens = jax.random.randint(jax.random.PRNGKey(21), (1, S), 0, 256)
+        empty = jnp.zeros((1, 0, cfg.num_kv_heads // 2, 2 * cfg.head_dim),
+                          cfg.dtype)
+        return (functools.partial(pf.prefill_suffix, cfg=cfg),
+                (params, tokens, i32(n), i32(0), empty, empty, i32(0),
+                 {"full": i32(dst), "window": i32(window),
+                  "state": i32([2])}, i32(np.where(pos < n, pos % bs, 0)),
+                 pool),
+                (dst[dst > 0], window[window > 0]))
+    # a decode step of four slots; in the second case slots 2 and 3 hold
+    # nothing and both write position 0 of the scratch block
+    cur = np.asarray([9, 6, 13, 2], np.int32)
+    tables = np.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [6, 7, 8, 9],
+                         [10, 0, 0, 0]], np.int32)
+    records = np.asarray([[1], [2], [3], [4]], np.int32)
+    if case == "decode-idle-slots":
+        cur[2:], tables[2:], records[2:] = 0, 0, 0
+    written = tables[np.arange(4), cur // bs]
+    return (functools.partial(pf.decode_step, cfg=cfg, attn="gather"),
+            (params, i32([5, 6, 7, 8]), i32(cur),
+             {"full": i32(tables), "window": i32(tables),
+              "state": i32(records)}, pool),
+            (written[written > 0],) * 2)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", [
+    "decode-step", "prefill-pad-tail", "prefill-window-scratch",
+    "decode-idle-slots"])
+def test_a_tokens_slab_lands_where_its_rows_did(case, dtype, monkeypatch):
+    """PR 40: ``store_slabs`` writes a token's ``[pairs, 2 hd]`` slab as ONE
+    update through a view of the page (``[bs, pairs / r, r, 2 hd]``, ``r``
+    rows a packed sublane: 1 in float32, 2 in bfloat16).  The three pools
+    after a program with it equal, bit for bit, the pools the same program
+    leaves with the row-by-row write: every block, the scratch one too
+    (idle slots and pad lanes race there, and on the CPU both forms settle
+    a race the same way: the last update stays)."""
+    cfg = pf.Phi4FlashConfig.tiny(dtype=jnp.dtype(dtype))
+    params = pf.phi4flash_init(jax.random.PRNGKey(11), cfg)
+    blank = pf.init_pools(cfg, {"full": 12, "window": 12, "state": 5}, 4)
+    leaves, tree = jax.tree.flatten(blank)
+    # pools that hold something everywhere: a stray write shows
+    before = jax.tree.unflatten(tree, [
+        jax.random.normal(jax.random.PRNGKey(30 + i), x.shape, x.dtype)
+        for i, x in enumerate(leaves)])
+    program, args, written = _write_case(case, cfg, params, before)
+
+    def pools_after():
+        # a function new to jit, so traced with the write then in force
+        return jax.jit(lambda *a: program(*a))(*args)[1]
+
+    got = pools_after()
+    monkeypatch.setattr(pf, "store_slabs", _rows_one_by_one)
+    want = pools_after()
+    for t in ("full", "window", "state"):
+        for name in got[t]:
+            assert got[t][name].dtype == before[t][name].dtype
+            np.testing.assert_array_equal(
+                np.asarray(got[t][name].astype(jnp.float32)),
+                np.asarray(want[t][name].astype(jnp.float32)),
+                err_msg=f"{t}.{name}")
+    for t, blocks in zip(("full", "window"), written):
+        for name in ("k", "v"):  # the case wrote where it says it did
+            changed = np.any(np.asarray(got[t][name] != before[t][name]),
+                             axis=(0, 2, 3))
+            assert set(np.flatnonzero(changed)) - {0} == set(
+                blocks.tolist()), (t, name)
 
 
 # --------------------------------------------------- (c) a record's life
