@@ -439,6 +439,12 @@ class LLMEngine:
                              prefill_tokens=0, prefill_padded_tokens=0)
         if self.experts:  # of decode_windows: those the kernel ran
             self.counters["expert_kernel_windows"] = 0
+        # a model whose prefill has more than one attention path
+        # (ServedModel.prefill_attention_path): prefill programs run, by
+        # path, and the last one's (engine.admit's "attention")
+        self.prefill_attention = collections.Counter() \
+            if model.prefill_attention_path else None
+        self._prefill_path = None
         self._stack_counted = jax.jit(lambda toks, counts: jnp.concatenate(
             [jnp.stack(toks), jnp.stack(counts)], axis=1))
         # prompt-lookup speculative decoding (vLLM's ngram method,
@@ -1077,6 +1083,8 @@ class LLMEngine:
                  if prefilled else 0}
         if self.experts and prefilled:  # the bucket's prefill program's
             stats["experts"] = self._expert_path(stats["bucket"])
+        if self._prefill_path and prefilled:
+            stats["attention"] = self._prefill_path
         if self._more:  # did a window keep the prefill from K blocks
             stats["window_skips"] = int(any(
                 p.window and prefilled > p.window for p in self._more))
@@ -1302,6 +1310,8 @@ class LLMEngine:
             "kv_cache_dtype": self.kv_cache_dtype or "native",
             "attn": self.attn,
             "experts": self.experts,
+            **({"prefill_attention": dict(self.prefill_attention)}
+               if self.prefill_attention is not None else {}),
             "prefix_cache": dict(self.blocks.stats),
             "prefill_chunks": self.prefill_stats["chunks"],
             "spec": dict(self.spec_stats),
@@ -1493,6 +1503,10 @@ class LLMEngine:
                      else coordinates(held)
                      for p, held in zip(self._pools, [blocks, *more_blocks])}
         P = _bucket(len(hit_blocks), self.MB) if hit_blocks else 0
+        if self.prefill_attention is not None:
+            self._prefill_path = self.model.prefill_attention_path(
+                self.cfg, S, P * self.bs)
+            self.prefill_attention[self._prefill_path] += 1
         prefix_ids = np.zeros(P, np.int32)
         prefix_ids[:len(hit_blocks)] = hit_blocks
         pk, pv = self.model.gather_prefix(self.pool, jnp.asarray(prefix_ids),

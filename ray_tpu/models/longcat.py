@@ -12,8 +12,18 @@ apart from the dense decoder in ``models/llama.py``:
   ``c_kv`` (``kv_lora_rank`` wide, normed and scaled) beside a rotated
   ``k_pe`` (``qk_rope_head_dim`` wide) that all heads share.  **The cache
   holds that row** and nothing per head.  Prefill up-projects the rows it
-  attends over (the cached prefix's too) and runs the plain form; decode
-  absorbs ``W_kvb``: ``q_nope W_kvb,k^T`` is scored against ``c_kv``
+  attends over (the cached prefix's too) and runs the non-absorbed form:
+  keys ``[k_nope | k_pe]`` of ``qk_nope_head_dim + qk_rope_head_dim``
+  beside values of ``v_head_dim``.  A prompt with no cached prefix, on one
+  TPU device and 256 tokens or more, attends through the flash kernel
+  (``ops/pallas/flash_attention.py``, which takes values narrower than the
+  keys): all heads in one call and no score matrix in HBM.  Every other
+  prefill keeps the plain path, float32 scores a group of ``_HEAD_GROUP``
+  heads at a time (``prefill_attention_path``): a prefix hit, because the
+  prefix's rows come padded to a bucket of blocks and the mask that hides
+  the pad is not causal, which is the only mask the kernel builds; a short
+  prompt and the CPU, by ``dot_product_attention``'s own rule.
+  Decode absorbs ``W_kvb``: ``q_nope W_kvb,k^T`` is scored against ``c_kv``
   itself, the probabilities weight ``c_kv``, and ``W_kvb,v`` then ``W_o``
   follow.
 * **The shortcut-connected double layer.**  A layer is two attention blocks
@@ -68,6 +78,7 @@ import jax.numpy as jnp
 from ray_tpu._private import tracing
 from ray_tpu.models.paged_generation import (decode_attention_path,
                                              embed_tokens, sample_next)
+from ray_tpu.ops.attention import attention_impl, dot_product_attention
 from ray_tpu.ops.experts import held_experts_ffn, route_top_k
 from ray_tpu.ops.layers import (apply_rope, heads_projection, rms_norm,
                                 rope_frequencies, swiglu)
@@ -208,14 +219,35 @@ def _softmax_scale(cfg: LongcatConfig) -> float:
     return float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
 
 
+def prefill_attention_path(seq: int, prefix: int, impl: str = "auto") -> str:
+    """Which form ``seq`` queries attend through after ``prefix`` cached rows
+    (the padded counts a program is traced at): ``"flash"`` | ``"plain"``.
+    Flash where the keys are exactly the queries' positions (no prefix: the
+    mask is then the causal one for every live query, which is the only mask
+    the kernel builds) and ``dot_product_attention``'s own rule
+    (``attention_impl``) picks the kernel: one TPU device, 256 queries or
+    more.  ``impl="flash"`` stands in for that rule (the tests' interpreter,
+    a compile for the chip from the CPU); a prefix keeps the plain form
+    whatever it says."""
+    if prefix == 0 and (impl == "flash" or impl == "auto"
+                        and attention_impl(seq) == "flash"):
+        return "flash"
+    return "plain"
+
+
 # heads a score matrix is made for at a time where it is large: a 2048-token
 # prefill's float32 scores are 16 MB a head, 1 GB for all 64 at once
 _HEAD_GROUP = 16
 
 
-def _mla_plain(q_nope, q_pe, c_kv, k_pe, mask, ap, cfg: LongcatConfig):
+def _mla_plain(q_nope, q_pe, c_kv, k_pe, mask, ap, cfg: LongcatConfig,
+               impl: str = "auto"):
     """The non-absorbed form over rows ``c_kv [b, t, kr]`` / ``k_pe
-    [b, t, dr]`` (up-projected here); mask ``[b, s, t]``."""
+    [b, t, dr]`` (up-projected here); mask ``[b, s, t]``.  With ``t == s``
+    the callers' mask is causal for every live query, and the flash kernel
+    takes it from there where ``prefill_attention_path`` says so: all heads
+    in one call, keys ``[k_nope | k_pe]`` beside narrower values, no score
+    matrix in HBM."""
     b, s, nh, dn = q_nope.shape
     t = c_kv.shape[1]
     dt, dv = cfg.dtype, cfg.v_head_dim
@@ -237,7 +269,14 @@ def _mla_plain(q_nope, q_pe, c_kv, k_pe, mask, ap, cfg: LongcatConfig):
     parts = (q_nope, q_pe, kvb[..., :dn], kvb[..., dn:])
     g = _HEAD_GROUP
     with tracing.scope("attn.core"):
-        if nh <= g or nh % g:
+        if prefill_attention_path(s, t - s, impl) == "flash":
+            pe = jnp.broadcast_to(k_pe[:, :, None],
+                                  (b, t, nh, k_pe.shape[-1]))
+            out = dot_product_attention(
+                jnp.concatenate([q_nope, q_pe], -1),
+                jnp.concatenate([kvb[..., :dn], pe], -1), kvb[..., dn:],
+                causal=True, impl="flash", scale=_softmax_scale(cfg))
+        elif nh <= g or nh % g:
             out = heads(parts)
         else:  # one group of heads after another
             split = lambda a: jnp.moveaxis(  # noqa: E731
@@ -394,12 +433,14 @@ def gather_latent_prefix(pool, blocks, cfg: LongcatConfig):
 
 def latent_prefill_suffix(params, tokens, length, start_pos, prefix_ckv,
                           prefix_kpe, prefix_len, dst_blocks, dst_offsets,
-                          pool, cfg: LongcatConfig):
+                          pool, cfg: LongcatConfig, attn_impl: str = "auto"):
     """b=1 prefill of a prompt *suffix* against a cached prefix: the
     contract of ``paged_generation.prefill_suffix`` with latent rows for
     keys and values (``gather_latent_prefix``'s pair).  The prefix's rows
-    are up-projected with the suffix's and the plain form runs over both.
-    Returns ``(logits_at_last [1, vocab], pool, stats int32[3])``."""
+    are up-projected with the suffix's and the plain form runs over both;
+    a prompt with no cached prefix (``P == 0``) attends through the flash
+    kernel where ``prefill_attention_path`` says so (``attn_impl``: its
+    ``impl``).  Returns ``(logits_at_last [1, vocab], pool, stats int32[3])``."""
     _, S = tokens.shape
     P = prefix_ckv.shape[1]
     dt = cfg.dtype
@@ -428,7 +469,8 @@ def latent_prefill_suffix(params, tokens, length, start_pos, prefix_ckv,
             pe_all = jnp.concatenate(
                 [prefix_kpe[a][None].astype(dt), k_pe], 1)
         a += 1
-        return _mla_plain(q_nope, q_pe, c_all, pe_all, mask, ap, cfg)
+        return _mla_plain(q_nope, q_pe, c_all, pe_all, mask, ap, cfg,
+                          attn_impl)
 
     x, stats = _layers(params, embed_tokens(params, tokens, cfg.dtype),
                        cfg, attend, live)

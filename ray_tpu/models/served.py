@@ -25,9 +25,14 @@ and optionally ``verify_step`` (speculation), ``param_specs`` (an engine
 with a mesh), ``handoff`` (``export_kv`` / ``adopt_prefilled`` know its
 pool), ``layer_types`` (below), ``expert_path(cfg, tokens) -> str`` (a model
 with an expert layer: which path ``ops/experts.py:expert_path`` gives a
-program of ``tokens`` rows, the engine's ``experts`` label) and ``counters``: the names of the small integer sums its prefill
-and decode programs return beside the rest (one int32 vector, fetched with
-the window's tokens, summed into ``LLMEngine.stats()["counters"]``).
+program of ``tokens`` rows, the engine's ``experts`` label),
+``prefill_attention_path(cfg, bucket, prefix) -> str`` (a model whose
+prefill has more than one attention path: which one the program of a
+``bucket``-token suffix after ``prefix`` padded cached positions runs, the
+engine's ``attention`` label) and ``counters``: the names of the small
+integer sums its prefill and decode programs return beside the rest (one
+int32 vector, fetched with the window's tokens, summed into
+``LLMEngine.stats()["counters"]``).
 What a model leaves out the engine refuses by name at construction or at
 the call, never by a wrong answer.
 
@@ -83,6 +88,7 @@ class ServedModel:
     handoff: bool = False
     layer_types: Optional[Callable] = None
     expert_path: Optional[Callable] = None
+    prefill_attention_path: Optional[Callable] = None
     counters: Tuple[str, ...] = ()
 
     def require(self, what: str, have: bool) -> None:
@@ -139,6 +145,8 @@ def _longcat() -> ServedModel:
                  "longcat_flash": lc.LongcatConfig},
         test_presets=("longcat_flash_tiny",),
         expert_path=_expert_path,
+        prefill_attention_path=lambda cfg, bucket, prefix:
+            lc.prefill_attention_path(bucket, prefix),
         counters=("moe_pairs_held", "moe_experts_hit", "moe_zero_picks"))
 
 

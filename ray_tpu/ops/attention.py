@@ -50,7 +50,8 @@ def reference_attention(
 ) -> jnp.ndarray:
     """Plain softmax attention, fp32 accumulation.
 
-    q: [b, sq, h, d]; k, v: [b, sk, kv_h, d] with h % kv_h == 0.
+    q: [b, sq, h, d]; k: [b, sk, kv_h, d]; v: [b, sk, kv_h, dv] (``dv``
+    need not be ``d``: the output is ``[b, sq, h, dv]``) with h % kv_h == 0.
     ``window``: sliding-window (Mistral-style) — query p attends keys in
     (p - window, p].  Requires causal.  ``scale``: the scores' factor,
     ``d ** -0.5`` unless given.
@@ -196,6 +197,21 @@ def ring_attention(
     )(q, k, v)
 
 
+def attention_impl(seq_q: int, mesh: Optional[Mesh] = None,
+                   sp_axis: str = "sp") -> str:
+    """What ``dot_product_attention``'s ``impl="auto"`` runs for ``seq_q``
+    queries: 'ring' when the mesh shards the sequence (sp > 1), the Pallas
+    flash kernel on a TPU from 256 queries on, the reference elsewhere (the
+    CPU's test meshes).  Read by callers that have a path of their own to
+    fall back to (``models/longcat.py``)."""
+    if (mesh is not None and sp_axis in mesh.axis_names
+            and mesh.shape[sp_axis] > 1):
+        return "ring"
+    if jax.default_backend() == "tpu" and seq_q >= 256:
+        return "flash"
+    return "ref"
+
+
 def dot_product_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -210,30 +226,25 @@ def dot_product_attention(
 ) -> jnp.ndarray:
     """Dispatching attention entry point used by the model layer.
 
-    impl: 'auto' | 'ref' | 'flash' | 'ring'.  'auto' picks ring when the
-    mesh shards sequence (sp>1), Pallas flash on TPU otherwise, and the
-    reference path on CPU test meshes.  ``window`` (sliding-window /
-    Mistral-style) is supported by all three; the flash kernel's forward
-    skips the K blocks before the window, its backward has no window yet
-    and raises by name (differentiate 'ref' or 'ring' under a window).
-    ``scale`` (the scores' factor where it is not ``head_dim ** -0.5``) is
-    taken by 'ref' and by the flash forward on one device, forward only.
+    impl: 'auto' | 'ref' | 'flash' | 'ring'.  'auto' is ``attention_impl``:
+    ring when the mesh shards sequence (sp>1), Pallas flash on TPU
+    otherwise, and the reference path on CPU test meshes.  ``window``
+    (sliding-window / Mistral-style) is supported by all three; the flash
+    kernel's forward skips the K blocks before the window, its backward has
+    no window yet and raises by name (differentiate 'ref' or 'ring' under a
+    window).  ``scale`` (the scores' factor where it is not
+    ``head_dim ** -0.5``) and values of another width than the keys
+    (``v: [b, s, kv_h, dv]``, the output ``dv`` wide) are taken by 'ref' and
+    by the flash forward on one device, forward only.
     """
-    if scale is not None and mesh is not None:
+    if mesh is not None and (scale is not None
+                             or v.shape[-1] != q.shape[-1]):
         raise NotImplementedError(
-            "dot_product_attention takes a scale of its own on one device "
-            "only (the ring and the sharded flash call have none)")
+            "dot_product_attention takes a scale of its own, or values of "
+            "another width than the keys, on one device only (the ring and "
+            "the sharded flash call have neither)")
     if impl == "auto":
-        if (
-            mesh is not None
-            and sp_axis in mesh.axis_names
-            and mesh.shape[sp_axis] > 1
-        ):
-            impl = "ring"
-        elif jax.default_backend() == "tpu" and q.shape[1] >= 256:
-            impl = "flash"
-        else:
-            impl = "ref"
+        impl = attention_impl(q.shape[1], mesh, sp_axis)
     if impl == "ring":
         assert mesh is not None, "ring attention needs a mesh"
         return ring_attention(

@@ -41,6 +41,17 @@ grid reaches it), and only a block the window's edge cuts builds its mask.
 Forward only: the backward kernel has no window yet and says so.  With
 ``window=None`` nothing of this is traced.
 
+The forward takes values of another width than the keys: q, k
+``[.., d]`` beside v ``[.., dv]`` give ``[.., dv]`` (latent attention's
+non-absorbed form: keys of the nope and rope widths together, 192, beside
+128-wide values).  The width of v's block, of the output's and of the
+accumulator is read off v; with ``dv == d`` the call is what it was.  The
+default scale stays ``d ** -0.5`` of the keys' width.  Forward only, as a
+``scale`` of the caller's own is: the backward kernel has one width, and a
+gradient through either raises by name.  These forward-only calls go
+through one jitted function, so that a program's calls at the same shapes
+share one traced and lowered kernel.
+
 Sequences are padded to the block size and pad K positions masked, so any
 length works.  GQA is handled by index-mapping q-heads onto kv heads — no
 materialized KV expansion.
@@ -226,6 +237,7 @@ def _flash_fwd_impl(q, k, v, *, causal, block_q, block_k, interpret,
                     window=None, scale=None):
     b, sq, h, d = q.shape
     sk, kv_h = k.shape[1], k.shape[2]
+    dv = v.shape[-1]  # the values' (and the output's) width: need not be d
     n_rep = h // kv_h
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
@@ -258,24 +270,24 @@ def _flash_fwd_impl(q, k, v, *, causal, block_q, block_k, interpret,
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_map),
             pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), q_map),
+            pl.BlockSpec((1, block_q, dv), q_map),
             pl.BlockSpec((1, 1, block_q), lse_map),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, sq_p, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, sq_p), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
     )(qt, kt, vt)
-    out = out.reshape(b, h, sq_p, d).transpose(0, 2, 1, 3)[:, :sq]
+    out = out.reshape(b, h, sq_p, dv).transpose(0, 2, 1, 3)[:, :sq]
     return out, lse  # lse stays padded/folded for the backward kernel
 
 
@@ -473,6 +485,35 @@ def _flash_vjp_bwd(causal, block_q, block_k, interpret, window, res, g):
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
+_NO_BACKWARD = (
+    "flash_attention's backward kernel takes q, k and v of one width and "
+    "the default scale: a call with values narrower or wider than the keys, "
+    "or with a scale of its own, is forward only (differentiate "
+    "reference_attention)")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_forward_only(q, k, v, causal, block_q, block_k, interpret, window,
+                        scale):
+    """The forward where there is no backward for it: a ``scale``, unequal
+    widths."""
+    return _flash_fwd_impl(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=interpret, window=window, scale=scale)[0]
+
+
+def _no_backward(*args):
+    raise NotImplementedError(_NO_BACKWARD)
+
+
+_flash_forward_only.defvjp(_no_backward, _no_backward)
+# ONE jitted function: the calls of a program at the same shapes (a model's
+# attention blocks) share one traced and lowered kernel, as
+# ``paged_attention.py``'s layers do (lowering a Pallas kernel is Python
+# work no compile cache saves)
+_flash_forward_only = jax.jit(_flash_forward_only,
+                              static_argnums=(3, 4, 5, 6, 7, 8))
+
 
 def flash_attention(
     q: jnp.ndarray,
@@ -486,12 +527,16 @@ def flash_attention(
     window: int | None = None,
     scale: float | None = None,
 ) -> jnp.ndarray:
-    """Flash attention. q: [b, s, h, d]; k, v: [b, s, kv_h, d].
+    """Flash attention. q: [b, s, h, d]; k: [b, s, kv_h, d]; v: [b, s, kv_h,
+    dv] -> [b, s, h, dv].
 
     ``window``: a query at position p sees the keys in (p - window, p],
     positions counted from 0 in both operands; causal only, forward only.
     ``scale``: the scores' factor where it is not ``d ** -0.5`` (a query
     zero-padded to twice its head's width); forward only.
+    ``dv != d`` (latent attention's non-absorbed form: keys of the nope and
+    rope widths together, values of their own): forward only; the default
+    scale stays that of the keys' width.
 
     Off-TPU this runs the Pallas interpreter (slow; tests use small
     shapes).
@@ -500,8 +545,7 @@ def flash_attention(
         raise ValueError("sliding window requires causal attention")
     if jax.default_backend() != "tpu":
         interpret = True
-    if scale is not None:  # forward only: outside the custom_vjp
-        return _flash_fwd_impl(
-            q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-            interpret=interpret, window=window, scale=scale)[0]
+    if scale is not None or v.shape[-1] != q.shape[-1]:  # forward only
+        return _flash_forward_only(
+            q, k, v, causal, block_q, block_k, interpret, window, scale)
     return _flash(q, k, v, causal, block_q, block_k, interpret, window)

@@ -3,8 +3,9 @@ decode programs whose heads projections read their weights in place (PR 35)
 the three served models' decode programs' name scopes after XLA:TPU's
 fusion (PR 37), the train cell's step with its head and loss as one
 function (PR 38) and Phi-4-mini-flash's decode step writing a token's
-slab into its page as one update (PR 40), compiled for a v5e that is
-described, not attached.
+slab into its page as one update (PR 40) and LongCat-Flash's uncached
+prefill through the flash forward at 192-wide keys beside 128-wide values
+(PR 43), compiled for a v5e that is described, not attached.
 
 The interpreter cannot see what the chip's compiler refuses: more VMEM
 than a kernel may use (the backward keeps dK/dV whole in scratch and sets
@@ -49,6 +50,14 @@ def one_chip():
             yield SingleDeviceSharding(topo.devices[0])
         finally:
             jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+def _mosaic_calls(text):
+    """The operands' shapes of every Mosaic call in a compiled module, as
+    its ``operand_layout_constraints`` give them."""
+    return [re.findall(r"(\w+\[[\d,]*\])", m) for m in re.findall(
+        r'custom_call_target="tpu_custom_call", operand_layout_constraints='
+        r"\{((?:\w+\[[\d,]*\]\{[\d,]*\}(?:, )?)+)\}", text)]
 
 
 def _compile_grad(one_chip, b, sq, sk, h, kvh, d, causal):
@@ -103,6 +112,78 @@ def test_the_windowed_forward_compiles_as_one_kernel(one_chip, sq, h, kvh,
         q, k, v, True, 1024, 1024, False, window)).lower(
             shape(h), shape(kvh), shape(kvh)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # equal widths: the operands are the parent's (PR 43), the sequence
+    # padded to whole blocks and the heads folded into the batch
+    padded = -(-sq // 1024) * 1024
+    assert _mosaic_calls(text) == [[f"bf16[{h},{padded},128]"]
+                                   + 2 * [f"bf16[{kvh},{padded},128]"]]
+
+
+# ------------------ values narrower than the keys (PR 43): latent
+# attention's non-absorbed form, LongCat-Flash's uncached prefill
+
+@pytest.mark.parametrize("s", [2048, 1024, 256])
+def test_the_forward_compiles_at_192_wide_keys_beside_128_wide_values(
+        one_chip, monkeypatch, s):
+    """LongCat-Flash's widths (64 heads, keys ``qk_nope + qk_rope`` = 192,
+    values 128, its own scale), bf16, the longest bucket, the one that is a
+    single block and the shortest the rule gives the kernel: one Mosaic
+    call whose key operand is 192 wide as it stands (a block's last
+    dimension equal to the array's), the output 128 wide."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def shape(d):
+        return jax.ShapeDtypeStruct((1, s, 64, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    text = jax.jit(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, scale=192 ** -0.5)).lower(
+            shape(192), shape(192), shape(128)).compile().as_text()
+    assert _mosaic_calls(text) == [
+        2 * [f"bf16[64,{s},192]"] + [f"bf16[64,{s},128]"]]
+    assert re.search(rf"= \(bf16\[64,{s},128\][^=]*custom-call\(", text)
+
+
+def test_longcat_uncached_prefill_attends_through_the_flash_kernel(
+        one_chip, monkeypatch):
+    """LongCat-Flash's 1024-token prefill at the cell's widths (its four
+    double layers, 16 held experts): eight flash calls, one an attention
+    block, all eight one function of the lowered module (traced and lowered
+    to Mosaic once), and no score matrix: on the parent the program held
+    ``f32[16,1024,1024]`` scores and ``pred[..,1024,1024]`` masks, a group
+    of 16 heads at a time, each a pass through HBM."""
+    from ray_tpu.models import longcat
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = longcat.LongcatConfig(
+        vocab_size=16384, num_layers=4, first_expert=80, held_experts=16,
+        max_seq_len=3584, param_dtype=jnp.bfloat16)
+    S = 1024
+    assert longcat.prefill_attention_path(S, 0) == "flash"
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    params = jax.eval_shape(
+        functools.partial(longcat.longcat_init, cfg=cfg), key)
+    pool = jax.eval_shape(lambda: longcat.init_latent_pool(cfg, 512, 16))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    empty = lambda w: jax.ShapeDtypeStruct(  # noqa: E731
+        (2 * cfg.num_layers, 0, w), cfg.dtype)
+    args = (params, i32(1, S), i32(), i32(), empty(cfg.kv_lora_rank),
+            empty(cfg.qk_rope_head_dim), i32(), i32(S), i32(S), pool)
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), args)
+    lowered = jax.jit(
+        functools.partial(longcat.latent_prefill_suffix, cfg=cfg),
+        donate_argnums=(9,)).lower(*args)
+    module = lowered.as_text()
+    assert module.count("func.func private @_flash_forward_only") == 1
+    assert module.count("call @_flash_forward_only") == 8
+    text = lowered.compile().as_text()
+    flash = [c for c in _mosaic_calls(text)
+             if c == 2 * ["bf16[64,1024,192]"] + ["bf16[64,1024,128]"]]
+    assert len(flash) == 8
+    assert "f32[16,1024,1024]" not in text
+    assert not re.search(r"pred\[[\d,]*1024,1024\]", text)
 
 
 # ------------------------- the expert layer at decode shape (PR 32)
@@ -448,6 +529,17 @@ def _part(line):
 
     name = re.search(r'op_name="([^"]*)"', line)
     return parts.scopes_of(name.group(1))[1] if name else None
+
+
+def test_the_train_step_holds_the_parents_flash_calls(train_step):
+    """Equal widths: the forward and the backward kernel of the scanned
+    layer, with the operands the parent's step handed them (PR 43 made the
+    values' width a parameter of the forward; at ``dv == d`` nothing of
+    the call may move)."""
+    q, kv, lse = "bf16[128,4096,128]", "bf16[32,4096,128]", \
+        "f32[128,1,4096]"
+    assert sorted(_mosaic_calls(train_step), key=len) == [
+        [q, kv, kv], [q, kv, kv, q, lse, lse]]
 
 
 def test_the_train_step_holds_three_products_of_the_heads_shape(train_step):

@@ -14,7 +14,10 @@ these tests and for the cell's ``correct``.
 (d) the kernel's latent arm against the gather path;
 (e) the control (weight products rounded to an 8-bit float) reads not
     correct;
-(f) what the model does not supply raises.
+(f) what the model does not supply raises;
+(g) a prompt with no cached prefix through the flash kernel (interpreter)
+    against the plain path and the reference, and the engine's label of
+    which ran.
 
 Tolerances: float32 on both sides, different orders of summation (the
 absorbed form against the plain one, grouped products against a loop over
@@ -33,9 +36,11 @@ import pytest
 from cells.families import longcat_flash_reference as reference
 from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.models.generation import SamplingParams
-from ray_tpu.models.longcat import (LongcatConfig, _moe, init_latent_pool,
-                                    latent_decode_step, longcat_apply,
-                                    longcat_init)
+from ray_tpu.models.longcat import (LongcatConfig, _moe,
+                                    gather_latent_prefix, init_latent_pool,
+                                    latent_decode_step,
+                                    latent_prefill_suffix, longcat_apply,
+                                    longcat_init, prefill_attention_path)
 from ray_tpu.models.paged_generation import decode_attention_path
 from ray_tpu.models.served import preset, served_model
 from ray_tpu.ops.pallas.paged_attention import latent_paged_attention
@@ -409,3 +414,160 @@ def test_a_model_without_experts_has_no_experts_label():
     st = eng.stats()
     assert st["experts"] is None
     assert "expert_kernel_windows" not in st["counters"]
+
+
+# ------------------------------------- (g) the prefill's attention paths
+
+def _kernels(jaxpr):
+    """The ``pallas_call``s of a jaxpr, inner jaxprs included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _kernels(sub)
+    return n
+
+
+def _prefill(cfg, params, tokens, length, prefix_blocks, impl):
+    """``latent_prefill_suffix`` of ``tokens [S]`` (``length`` live) behind
+    ``prefix_blocks`` cached blocks of 8, as the engine calls it, on a pool
+    whose rows are random (a prefix's are read): a function of nothing but
+    its arguments, to run or to trace."""
+    S, bs = len(tokens), 8
+    pool = init_latent_pool(cfg, 24, bs)
+    pool = {"kv": jax.random.normal(jax.random.PRNGKey(8), pool["kv"].shape)}
+    cached = len(prefix_blocks) * bs
+    pos = cached + np.arange(S)
+    live = np.arange(S) < length
+    dst_b = np.where(live, 8 + pos // bs, 0).astype(np.int32)
+    dst_o = np.where(live, pos % bs, 0).astype(np.int32)
+    prefix = gather_latent_prefix(
+        pool, jnp.asarray(prefix_blocks, jnp.int32), cfg)
+    return functools.partial(
+        latent_prefill_suffix, cfg=cfg, attn_impl=impl), (
+        params, jnp.asarray([tokens], jnp.int32), jnp.int32(length),
+        jnp.int32(cached), *prefix, jnp.int32(cached), jnp.asarray(dst_b),
+        jnp.asarray(dst_o), pool)
+
+
+@pytest.mark.parametrize("length", [64, 50], ids=["whole", "pad-lanes"])
+def test_an_uncached_prompt_through_the_flash_kernel(length):
+    """No cached prefix: the flash path (forced; the interpreter) gives the
+    last position's logits and the cache rows of the plain path, and both
+    stay within the limit of the float32 reference.  Pad lanes at the
+    bucket's tail attend differently on the two paths and are read by
+    nobody: they land in the scratch block."""
+    cfg = LongcatConfig.tiny()
+    params = _params(cfg)
+    tokens = np.random.default_rng(6).integers(0, 256, 64).tolist()
+    out = {}
+    for impl in ("flash", "ref"):
+        fn, args = _prefill(cfg, params, tokens, length, [], impl)
+        assert _kernels(jax.make_jaxpr(fn)(*args).jaxpr) == (
+            2 * cfg.num_layers if impl == "flash" else 0)
+        out[impl] = fn(*args)
+    want = reference.logits(params, jnp.asarray(tokens[:length]),
+                            _model(cfg))[-1]
+    for impl, (logits, pool, stats) in out.items():
+        assert float(jnp.max(jnp.abs(logits[0] - want))) < TOL, impl
+    flash, plain = out["flash"], out["ref"]
+    assert float(jnp.max(jnp.abs(flash[0] - plain[0]))) < TOL
+    # every block but the scratch one: the rows the prompt wrote
+    assert float(jnp.max(jnp.abs(
+        flash[1]["kv"][:, 1:] - plain[1]["kv"][:, 1:]))) < TOL
+    assert np.array_equal(flash[2], plain[2])
+
+
+def test_a_prefix_hit_keeps_the_plain_path():
+    """Behind cached blocks the program is the plain one whatever the rule
+    for the kernel says: the prefix's mask is not causal."""
+    cfg = LongcatConfig.tiny()
+    params = _params(cfg)
+    tokens = np.random.default_rng(6).integers(0, 256, 64).tolist()
+    texts = []
+    for impl in ("flash", "ref"):
+        fn, args = _prefill(cfg, params, tokens, 50, [3, 4, 0, 0], impl)
+        jaxpr = jax.make_jaxpr(fn)(*args)
+        assert _kernels(jaxpr.jaxpr) == 0
+        texts.append(str(jaxpr))
+    assert texts[0] == texts[1]
+    assert prefill_attention_path(64, 32, "flash") == "plain"
+    assert prefill_attention_path(64, 0, "flash") == "flash"
+    # the rule itself, on this backend: dot_product_attention's
+    assert prefill_attention_path(1024, 0) == "plain"
+
+
+class _Spans:
+    """Stands in for ``tracing.annotate`` in the engine: keeps each
+    annotation's stats by name (``set_metadata`` included)."""
+
+    def __init__(self):
+        self.seen = []
+
+    def __call__(self, name, **stats):
+        self.seen.append((name, stats))
+        return self
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **more):
+        self.seen[-1][1].update(more)
+
+    def named(self, name):
+        return [s for n, s in self.seen if n == name]
+
+
+def test_the_engine_says_which_attention_a_prefill_ran(monkeypatch):
+    """``engine.admit`` carries ``attention`` and ``stats()`` counts the
+    prefills by path: flash for an uncached prompt of a bucket the rule
+    gives the kernel (here: 32 tokens on, the interpreter), plain for a
+    prefix hit and for a short prompt; greedy tokens are the plain
+    engine's.  A model with one path reports neither key."""
+    from ray_tpu.llm import engine as engine_mod
+    from ray_tpu.models import longcat
+
+    cfg = dataclasses.replace(preset("longcat_flash_tiny"), first_expert=2,
+                              held_experts=4)
+    rng = np.random.default_rng(3)
+    shared = rng.integers(0, 256, 24).tolist()
+    prompts = [shared + rng.integers(0, 256, 16).tolist(),
+               shared + rng.integers(0, 256, 9).tolist(),
+               rng.integers(0, 256, 5).tolist()]
+    sp = SamplingParams(max_tokens=6, temperature=0.0, stop_token_id=None)
+
+    def run():
+        spans = _Spans()
+        monkeypatch.setattr(engine_mod.tracing, "annotate", spans)
+        eng = LLMEngine(cfg, tokenizer=_Ids(), batch_slots=4, max_len=96,
+                        block_size=8, seed=5)
+        outs = eng.generate(prompts[:1], sp) + eng.generate(prompts[1:], sp)
+        admits = [s for s in spans.named("engine.admit")
+                  if s["kind"] == "full"]
+        return eng.stats(), admits, [o.token_ids for o in outs]
+
+    st, admits, want = run()  # the CPU: dot_product_attention's rule
+    assert st["prefill_attention"] == {"plain": 3}
+    assert [s["attention"] for s in admits] == ["plain"] * 3
+    monkeypatch.setattr(longcat, "attention_impl",
+                        lambda seq: "flash" if seq >= 32 else "ref")
+    st, admits, got = run()
+    assert got == want
+    assert st["prefill_attention"] == {"flash": 1, "plain": 2}
+    assert [(s["bucket"], s["cached_tokens"], s["attention"])
+            for s in admits] == [(64, 0, "flash"), (16, 24, "plain"),
+                                 (8, 0, "plain")]
+
+    from ray_tpu.models.llama import LlamaConfig
+
+    spans = _Spans()
+    monkeypatch.setattr(engine_mod.tracing, "annotate", spans)
+    eng = LLMEngine(LlamaConfig.tiny(num_layers=1, dtype=jnp.float32),
+                    tokenizer=_Ids(), batch_slots=2, max_len=32)
+    eng.generate(prompts[2:], sp)
+    assert "prefill_attention" not in eng.stats()
+    admits = spans.named("engine.admit")
+    assert admits and not any("attention" in s for s in admits)

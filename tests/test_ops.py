@@ -107,6 +107,72 @@ class TestFlashAttention:
         for name, a, b in zip(("dq", "dk", "dv"), g, gr):
             np.testing.assert_allclose(a, b, atol=2e-3, err_msg=name)
 
+    # (d, dv, heads, kv heads, length, block, scale): values narrower and
+    # wider than the keys (latent attention's non-absorbed form is 192
+    # beside 128), causal; 300 of block 256 pads the last block and cuts
+    # the diagonal block in strips, 100 is shorter than a block
+    @pytest.mark.parametrize("d,dv,h,kvh,s,block,scale", [
+        pytest.param(48, 32, 4, 4, 256, 128, None, id="narrower-values"),
+        pytest.param(32, 64, 4, 4, 256, 128, None, id="wider-values"),
+        pytest.param(48, 32, 4, 4, 300, 256, None, id="narrower-padded"),
+        pytest.param(32, 64, 4, 1, 300, 256, None, id="wider-padded-gqa"),
+        pytest.param(48, 32, 4, 2, 100, 256, None, id="narrower-short-gqa"),
+        pytest.param(48, 32, 4, 2, 300, 256, 0.3, id="narrower-own-scale"),
+        pytest.param(32, 64, 4, 4, 256, 128, 0.3, id="wider-own-scale"),
+    ])
+    def test_unequal_widths_match_reference(self, d, dv, h, kvh, s, block,
+                                            scale):
+        ks = jax.random.split(jax.random.PRNGKey(1), 3)
+        q = jax.random.normal(ks[0], (2, s, h, d))
+        k = jax.random.normal(ks[1], (2, s, kvh, d))
+        v = jax.random.normal(ks[2], (2, s, kvh, dv))
+        ref = reference_attention(q, k, v, causal=True, scale=scale)
+        out = flash_attention(q, k, v, causal=True, block_q=block,
+                              block_k=block, scale=scale)
+        assert out.shape == (2, s, h, dv)
+        np.testing.assert_allclose(out, ref, atol=2e-2)
+        # the default scale is that of the keys' width: a kernel that
+        # took the values' would be off by far more than the tolerance
+        if scale is None:
+            other = reference_attention(q, k, v, causal=True,
+                                        scale=dv ** -0.5)
+            assert float(jnp.abs(other - ref).max()) > 0.05
+
+    @pytest.mark.parametrize("dv,scale", [(32, None), (64, None),
+                                          (48, 0.3)],
+                             ids=["narrower", "wider", "own-scale"])
+    def test_forward_only_calls_refuse_a_gradient_by_name(self, dv, scale):
+        ks = jax.random.split(jax.random.PRNGKey(1), 3)
+        q = jax.random.normal(ks[0], (1, 64, 2, 48))
+        k = jax.random.normal(ks[1], (1, 64, 2, 48))
+        v = jax.random.normal(ks[2], (1, 64, 2, dv))
+        with pytest.raises(NotImplementedError, match="forward only"):
+            jax.grad(lambda q: flash_attention(
+                q, k, v, block_q=32, block_k=32, scale=scale).sum())(q)
+
+    def test_forward_only_calls_share_one_lowered_kernel(self):
+        """Two calls of a program at the same shapes are one function of
+        its module, called twice (a model's attention blocks: lowering the
+        kernel is Python work no compile cache saves)."""
+        q = jnp.zeros((1, 64, 2, 48))
+        v = jnp.zeros((1, 64, 2, 32))
+
+        def two(q, v):
+            o = flash_attention(q, q, v, block_q=32, block_k=32)
+            return flash_attention(q + o.sum(), q, v, block_q=32, block_k=32)
+
+        text = jax.jit(two).lower(q, v).as_text()
+        assert text.count("call @_flash_forward_only") == 2
+        assert text.count("func.func private @_flash_forward_only") == 1
+
+    def test_sharded_call_refuses_unequal_widths(self):
+        from ray_tpu.ops.attention import dot_product_attention
+
+        mesh = create_mesh(MeshConfig(dp=8, fsdp=1, tp=1, sp=1))
+        q = jnp.zeros((8, 64, 2, 48))
+        with pytest.raises(NotImplementedError, match="one device only"):
+            dot_product_attention(q, q, q[..., :32], impl="flash", mesh=mesh)
+
     def test_one_forward_and_one_backward_kernel_a_layer(self):
         """Under ``save_attn`` the gradient of a scanned model holds one
         ``pallas_call`` in the forward layer body (the forward kernel: out
