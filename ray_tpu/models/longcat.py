@@ -25,7 +25,8 @@ apart from the dense decoder in ``models/llama.py``:
   prompt and the CPU, by ``dot_product_attention``'s own rule.
   Decode absorbs ``W_kvb``: ``q_nope W_kvb,k^T`` is scored against ``c_kv``
   itself, the probabilities weight ``c_kv``, and ``W_kvb,v`` then ``W_o``
-  follow.
+  follow; it reads the two halves as leaves of their own (``w_uk``,
+  ``w_uv``: ``absorbed_pair``), laid out for those two products.
 * **The shortcut-connected double layer.**  A layer is two attention blocks
   and two dense SwiGLU blocks; the expert branch starts after the first
   attention and joins after the second dense block::
@@ -142,6 +143,25 @@ class LongcatConfig:
 
 # ------------------------------------------------------------------ params
 
+def absorbed_pair(w_kvb, cfg: LongcatConfig):
+    """``w_kvb [kr, nh * (dn + dv)]`` (the published ``kv_b_proj``) ->
+    ``(w_uk [nh, dn, kr], w_uv [nh, kr, dv])``: its keys' half and its
+    values' half with the heads leading, each laid out as the decode
+    step's absorbed product streams it (``_mla_absorbed``).  A slice and a
+    transposition, no arithmetic: every element of ``w_kvb`` is in exactly
+    one of the two, bit for bit.
+
+    The pair is DERIVED, not trained: ``longcat_init`` makes it here from
+    the ``w_kvb`` it has just drawn, and whoever else writes ``w_kvb`` (a
+    checkpoint loader after reading ``kv_b_proj``, an update of the
+    weights) calls this again, or the decode step keeps multiplying by the
+    old matrix while the prefill uses the new one."""
+    kr, nh, dn = cfg.kv_lora_rank, cfg.num_heads, cfg.qk_nope_head_dim
+    w = w_kvb.reshape(kr, nh, -1)
+    return (jnp.transpose(w[..., :dn], (1, 2, 0)),
+            jnp.transpose(w[..., dn:], (1, 0, 2)))
+
+
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def longcat_init(key: jax.Array, cfg: LongcatConfig) -> Dict[str, Any]:
     """Seeded parameters.  ``layers`` is a list of L double layers, each
@@ -151,7 +171,19 @@ def longcat_init(key: jax.Array, cfg: LongcatConfig) -> Dict[str, Any]:
     its consumer (a Mosaic call's operand, a weight two blocks share), and
     a decode step that copies its weights reads them twice.  ONE program: a
     10 GB tree made leaf by leaf is a hundred dispatches whose time moves
-    with the host."""
+    with the host.
+
+    An attention block holds ``kv_b_proj`` as THREE leaves: ``w_kvb [kr,
+    nh * (dn + dv)]``, the published matrix, which the prefill
+    (``_mla_plain``: ``latent_prefill_suffix``, ``longcat_apply``) and the
+    benchmark's reference read and through which alone a gradient of
+    ``longcat_apply`` flows; and ``w_uk [nh, dn, kr]`` / ``w_uv [nh, kr,
+    dv]``, its two halves as the decode step's absorbed products read them
+    (``_mla_absorbed``, which never touches ``w_kvb``), made from it here
+    by ``absorbed_pair`` and by nobody else.  The second copy costs
+    ``kr * nh * (dn + dv)`` parameters a block: 16.8 MB in bf16 at the
+    published widths, 134 MB over the serving cell's eight blocks (1.3% of
+    its weights)."""
     L, H, nh = cfg.num_layers, cfg.hidden_size, cfg.num_heads
     qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -167,10 +199,12 @@ def longcat_init(key: jax.Array, cfg: LongcatConfig) -> Dict[str, Any]:
         return jnp.ones(shape, pd)
 
     def attention():
+        w_kvb = w(kr, nh * (dn + dv))
+        w_uk, w_uv = absorbed_pair(w_kvb, cfg)
         return {"norm": ones(H), "w_qa": w(H, qr), "q_norm": ones(qr),
                 "w_qb": w(qr, nh * (dn + dr)), "w_kva": w(H, kr + dr),
-                "kv_norm": ones(kr), "w_kvb": w(kr, nh * (dn + dv)),
-                "w_o": w(nh * dv, H)}
+                "kv_norm": ones(kr), "w_kvb": w_kvb, "w_uk": w_uk,
+                "w_uv": w_uv, "w_o": w(nh * dv, H)}
 
     def dense():
         return {"norm": ones(H), "w_gate": w(H, F), "w_up": w(H, F),
@@ -291,20 +325,37 @@ def _mla_absorbed(q_nope, q_pe, ap, cfg: LongcatConfig, attend_rows):
     """One query token a slot, ``W_kvb`` absorbed.  q_nope ``[b, nh, dn]``,
     q_pe ``[b, nh, dr]``; ``attend_rows(q [b, nh, W]) -> [b, nh, kr]``
     scores the query against the cached rows and returns the weighted
-    ``c_kv``."""
+    ``c_kv``.
+
+    Both products are batched over the heads and read the block's derived
+    pair (``absorbed_pair``), never ``w_kvb``: as strided halves of that one
+    leaf, laid out for the prefill's ``c_kv @ w_kvb``, XLA:TPU fetched the
+    whole matrix transposed into fast memory in every block of every step.
+    The way in is spelt with the heads leading on both sides and each swap
+    held apart from the product by a barrier: left to itself XLA multiplies
+    with the slots minor (``[nh, kr, b]``) and transposes the result back
+    for the kernel, which takes ``[b, nh, W]``; held apart, the product
+    emits ``[nh, b, kr]`` and the swap is a permutation of whole rows.
+    Either alone buys nothing (the pair under the plain spelling 0.04 ms of
+    a 14.6 ms step, the spelling over ``w_kvb``'s halves none), together
+    0.56 ms: PERF.md section 6, PR 45;
+    ``tests/test_flash_compile_v5e.py`` holds the compiled program to it."""
     b, nh, dn = q_nope.shape
     dt, kr, dv = cfg.dtype, cfg.kv_lora_rank, cfg.v_head_dim
-    w_kvb = ap["w_kvb"].astype(dt).reshape(kr, nh, dn + dv)
+    barrier = jax.lax.optimization_barrier
     with tracing.scope("attn.proj"):  # the query into the latent space
-        q_lat = jnp.einsum("bhd,khd->bhk", q_nope, w_kvb[..., :dn],
+        q_lat = jnp.einsum("hbd,hdk->hbk",
+                           barrier(jnp.swapaxes(q_nope, 0, 1)),
+                           ap["w_uk"].astype(dt),
                            preferred_element_type=jnp.float32).astype(dt)
+        q_lat = jnp.swapaxes(barrier(q_lat), 0, 1)
         pad = cfg.latent_width - kr - q_pe.shape[-1]
         q = jnp.concatenate(
             [q_lat, q_pe, jnp.zeros((b, nh, pad), dt)], axis=-1)
     with tracing.scope("attn.core"):
         o_lat = attend_rows(q)
     with tracing.scope("attn.out"):  # out of it again, then W_o
-        out = jnp.einsum("bhk,khd->bhd", o_lat, w_kvb[..., dn:],
+        out = jnp.einsum("bhk,hkd->bhd", o_lat, ap["w_uv"].astype(dt),
                          preferred_element_type=jnp.float32).astype(dt)
         return out.reshape(b, nh * dv) @ ap["w_o"].astype(dt)
 

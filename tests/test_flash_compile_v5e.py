@@ -3,9 +3,10 @@ decode programs whose heads projections read their weights in place (PR 35)
 the three served models' decode programs' name scopes after XLA:TPU's
 fusion (PR 37), the train cell's step with its head and loss as one
 function (PR 38) and Phi-4-mini-flash's decode step writing a token's
-slab into its page as one update (PR 40) and LongCat-Flash's uncached
+slab into its page as one update (PR 40), LongCat-Flash's uncached
 prefill through the flash forward at 192-wide keys beside 128-wide values
-(PR 43), compiled for a v5e that is described, not attached.
+(PR 43) and its decode step reading the absorbed pair in place (PR 45),
+compiled for a v5e that is described, not attached.
 
 The interpreter cannot see what the chip's compiler refuses: more VMEM
 than a kernel may use (the backward keeps dK/dV whole in scratch and sets
@@ -246,11 +247,16 @@ _MIB = 1 << 20
 _BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1, "pred": 1}
 
 
+def _entry(text):
+    """The lines of a compiled module's entry computation."""
+    return re.search(r"^ENTRY .*?\{\n(.*?)^\}", text,
+                     re.S | re.M).group(1).splitlines()
+
+
 def _entry_results(text):
     """(name, opcode, dims, bytes) of every array-valued instruction of the
     compiled module's entry computation."""
-    entry = re.search(r"^ENTRY .*?\{\n(.*?)^\}", text, re.S | re.M).group(1)
-    for line in entry.splitlines():
+    for line in _entry(text):
         m = re.match(
             r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(",
             line)
@@ -267,10 +273,51 @@ def _entry_results(text):
 def _relayouts(text, at_least=_MIB):
     """What the parent's decode step did to a weight before multiplying by
     it: a ``slice*fusion`` through the vector unit and a synchronous
-    ``copy`` (the transposition), results of ``at_least`` bytes."""
-    return [(name, op, dims) for name, op, dims, size in _entry_results(text)
-            if size >= at_least
-            and (op == "copy" or (op == "fusion" and "slice" in name))]
+    ``copy`` (the transposition), results of ``at_least`` bytes; and
+    (PR 45) an asynchronous ``copy-start`` whose destination is laid out
+    otherwise than its source, which is the same transposition under
+    another name (a ``copy-start`` that keeps the layout is XLA's prefetch
+    into fast memory and moves the bytes the product would read anyway)."""
+    found = [(name, op, dims) for name, op, dims, size in _entry_results(text)
+             if size >= at_least
+             and (op == "copy" or (op == "fusion" and "slice" in name))]
+    for line in _entry(text):  # (destination, source, context) = copy-start(
+        m = re.match(r"\s*%?([\w.\-]+) = \((\w+)\[([\d,]*)\]\{([\d,]*)[:}]\S* "
+                     r"\w+\[[\d,]*\]\{([\d,]*)[:}].* copy-start\(", line)
+        if m and m.group(4) != m.group(5):
+            dims = tuple(int(d) for d in m.group(3).split(","))
+            if _BYTES.get(m.group(2), 4) * np.prod(dims) >= at_least:
+                found.append((m.group(1), "copy-start", dims))
+    return found
+
+
+# what hands an array on as it is, or moves it without an instruction's work
+_PASSES_ON = ("bitcast", "get-tuple-element", "copy-start", "copy-done",
+              "slice-start", "slice-done")
+
+
+def _origin(text, name):
+    """(opcode, ``op_name``) of what an entry instruction's first operand
+    is, looked up through ``_PASSES_ON``: ``("parameter",
+    "args[0]['layers'][0]...")`` for a weight, ``("fusion",
+    ".../attn.proj/.../dot_general")`` for a product's result."""
+    lines = {m.group(1): m.group(2) for m in (re.match(
+        r"\s*(?:ROOT )?%?([\w.\-]+) = (.*)", line) for line in _entry(text))
+        if m}
+
+    def instruction(name):
+        """(opcode, first operand's name, the line's right-hand side)."""
+        op, operand = re.search(r" ([\w\-]+)\(%?([\w.\-]*)",
+                                lines[name]).groups()
+        return op, operand, lines[name]
+
+    op, name, _ = instruction(name)
+    while True:
+        op, operand, rhs = instruction(name)
+        if op not in _PASSES_ON:
+            label = re.search(r'op_name="([^"]*)"', rhs)
+            return op, label.group(1) if label else ""
+        name = operand
 
 
 def _unscoped(text):
@@ -367,40 +414,78 @@ def test_the_dense_decode_step_reads_wq_wk_wv_in_place(one_chip, monkeypatch):
     assert counted >= 50 and len(bare) <= 0.05 * counted, bare
 
 
-def test_longcat_decode_step_reads_w_qb_in_place(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def longcat_decode(one_chip):
     """LongCat-Flash's decode step at its published widths, one double
-    layer, 16 held experts, the cell's 128 slots: no copy of ``w_qb``
-    (``[q_lora_rank, heads x (nope + rope)]`` = ``[1536, 12288]``, 37.7 MB)
-    in either layout.  The parent had one an attention block,
-    ``copy = bf16[1536,12288]{0,1}`` without a memory space: read, written
-    transposed to HBM and read again.  What stays an attention block, and
-    is not this test's: ``w_kvb`` whole and one of the two halves the
-    absorbed products cut it into (16.8 + 8.4 MB: ROADMAP A2), and the
-    projection's own result ``[128, 1, 12288]`` (3.1 MB), which is where
-    the barrier moves the relayout to."""
+    layer, 16 held experts, the cell's 128 slots: (its compiled text, the
+    configuration)."""
     from ray_tpu.models import longcat
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(jax, "device_count", lambda: 1)
     cfg = longcat.LongcatConfig(
         vocab_size=16384, num_layers=1, first_expert=80, held_experts=16,
         max_seq_len=3584, param_dtype=jnp.bfloat16)
-    w_qb = (cfg.q_lora_rank,
-            cfg.num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
-    assert w_qb == (1536, 12288)
     B, bs, MB = 128, 16, 224
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     params = jax.eval_shape(
         functools.partial(longcat.longcat_init, cfg=cfg), key)
     pool = jax.eval_shape(lambda: longcat.init_latent_pool(cfg, 512, bs))
-    text = _compile_decode(
-        functools.partial(longcat.latent_decode_sample, cfg=cfg,
-                          attn="latent_kernel"),
-        params, pool, B, MB, one_chip)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        mp.setattr(jax, "device_count", lambda: 1)
+        text = _compile_decode(
+            functools.partial(longcat.latent_decode_sample, cfg=cfg,
+                              attn="latent_kernel"),
+            params, pool, B, MB, one_chip)
+    return text, cfg
+
+
+def test_longcat_decode_step_reads_w_qb_in_place(longcat_decode):
+    """No copy of ``w_qb`` (``[q_lora_rank, heads x (nope + rope)]`` =
+    ``[1536, 12288]``, 37.7 MB) in either layout.  The parent of PR 35 had
+    one an attention block, ``copy = bf16[1536,12288]{0,1}`` without a
+    memory space: read, written transposed to HBM and read again.  What
+    stays an attention block, and is not this test's: the projection's own
+    result ``[128, 1, 12288]`` (3.1 MB), which is where the barrier moves
+    the relayout to.  (Until PR 45 also ``w_kvb`` whole: the test below.)"""
+    text, cfg = longcat_decode
+    w_qb = (cfg.q_lora_rank,
+            cfg.num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
+    assert w_qb == (1536, 12288)
     assert [m for m in _relayouts(text)
             if m[2] in (w_qb, w_qb[::-1])] == []
     counted, bare = _unscoped(text)  # PR 37, as for the dense step
     assert counted >= 80 and len(bare) <= 0.05 * counted, bare
+
+
+def test_longcat_decode_step_reads_the_absorbed_pair_in_place(longcat_decode):
+    """The same program: no relayout of 1 MiB or more of ANY parameter, and
+    none with the dimensions of ``w_kvb`` ``(512, 16384)``, of one of its
+    halves or of a transposed half but the one of a product's own result.
+    Until PR 45 every attention block had ``copy = bf16[512,16384]{0,1...
+    S(1)}`` of the parameter ``w_kvb``: the whole matrix (16.8 MB) fetched
+    transposed into fast memory for the two absorbed products, which read
+    strided halves of it.  They read ``w_uk`` / ``w_uv`` now, each where it
+    lies.
+
+    What stays, by its origin: the way in's RESULT ``q_lat`` ``[heads,
+    slots, kr]`` permuted to the kernel's ``[slots, heads, kr]`` (8.4 MB:
+    with 128 slots it has a half's dimensions, which is how PR 35 came to
+    read the parent's as "one of the halves"; the parent transposed it out
+    of ``[heads, kr, slots]``), and the swapped ``q_nope`` (2.1 MB)."""
+    text, cfg = longcat_decode
+    kr, nh = cfg.kv_lora_rank, cfg.num_heads
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    found = [(m, _origin(text, m[0])) for m in _relayouts(text)]
+    assert [f for f in found if f[1][0] == "parameter"] == []
+    alike = [f for f in found if sorted(f[0][2]) in (
+        sorted((kr, nh * (dn + dv))), sorted((kr, nh, dn)),
+        sorted((kr, nh, dv)))]
+    assert len(alike) == 2, alike  # one an attention block
+    assert all(op == "fusion" and "attn.proj/hbd,hdk->hbk" in label
+               for _, (op, label) in alike), alike
+    # the products read the parameters themselves (or XLA's own fetch of
+    # one in the parameter's layout): nothing of w_kvb is an operand
+    assert "w_uk" in text and "w_uv" in text and "w_kvb" not in text
 
 
 def test_smallthinker_decode_step_names_its_parts(one_chip, monkeypatch):

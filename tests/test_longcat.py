@@ -17,7 +17,12 @@ these tests and for the cell's ``correct``.
 (f) what the model does not supply raises;
 (g) a prompt with no cached prefix through the flash kernel (interpreter)
     against the plain path and the reference, and the engine's label of
-    which ran.
+    which ran;
+(h) the absorbed pair (PR 45: ``w_uk`` and ``w_uv``, the two halves of
+    ``w_kvb`` as the decode products read them): equal to the halves
+    element for element, a decode step over them the step over slices of
+    ``w_kvb`` (bit for bit from the size at which the CPU runs both through
+    one routine), and re-derived after ``w_kvb`` is replaced.
 
 Tolerances: float32 on both sides, different orders of summation (the
 absorbed form against the plain one, grouped products against a loop over
@@ -36,7 +41,8 @@ import pytest
 from cells.families import longcat_flash_reference as reference
 from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.models.generation import SamplingParams
-from ray_tpu.models.longcat import (LongcatConfig, _moe,
+from ray_tpu.models import longcat
+from ray_tpu.models.longcat import (LongcatConfig, _moe, absorbed_pair,
                                     gather_latent_prefix, init_latent_pool,
                                     latent_decode_step,
                                     latent_prefill_suffix, longcat_apply,
@@ -571,3 +577,125 @@ def test_the_engine_says_which_attention_a_prefill_ran(monkeypatch):
     assert "prefill_attention" not in eng.stats()
     admits = spans.named("engine.admit")
     assert admits and not any("attention" in s for s in admits)
+
+
+# ------------------------------------------------- (h) the absorbed pair
+
+def _blocks(params):
+    return [ap for lp in params["layers"] for ap in lp["attn"]]
+
+
+def test_the_absorbed_pair_is_the_two_halves_of_w_kvb():
+    """``longcat_init``'s ``w_uk`` / ``w_uv`` are what ``absorbed_pair``
+    makes of ``w_kvb``, and that is a slice and a transposition: every
+    element of the matrix is in exactly one of the two, unchanged."""
+    cfg = LongcatConfig.tiny()
+    kr, nh = cfg.kv_lora_rank, cfg.num_heads
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    blocks = _blocks(longcat_init(jax.random.PRNGKey(3), cfg))
+    assert len(blocks) == 2 * cfg.num_layers
+    for ap in blocks:
+        assert ap["w_kvb"].shape == (kr, nh * (dn + dv))
+        w_uk, w_uv = absorbed_pair(ap["w_kvb"], cfg)
+        np.testing.assert_array_equal(ap["w_uk"], w_uk)
+        np.testing.assert_array_equal(ap["w_uv"], w_uv)
+        w = np.asarray(ap["w_kvb"]).reshape(kr, nh, dn + dv)
+        assert w_uk.shape == (nh, dn, kr) and w_uv.shape == (nh, kr, dv)
+        np.testing.assert_array_equal(
+            np.asarray(w_uk), w[..., :dn].transpose(1, 2, 0))
+        np.testing.assert_array_equal(
+            np.asarray(w_uv), w[..., dn:].transpose(1, 0, 2))
+
+
+def _absorbed_over_w_kvb(q_nope, q_pe, ap, cfg, attend_rows):
+    """``_mla_absorbed`` as it was until PR 45: both products over strided
+    halves of the one leaf."""
+    b, nh, dn = q_nope.shape
+    dt, kr, dv = cfg.dtype, cfg.kv_lora_rank, cfg.v_head_dim
+    w_kvb = ap["w_kvb"].astype(dt).reshape(kr, nh, dn + dv)
+    q_lat = jnp.einsum("bhd,khd->bhk", q_nope, w_kvb[..., :dn],
+                       preferred_element_type=jnp.float32).astype(dt)
+    pad = cfg.latent_width - kr - q_pe.shape[-1]
+    o_lat = attend_rows(jnp.concatenate(
+        [q_lat, q_pe, jnp.zeros((b, nh, pad), dt)], axis=-1))
+    out = jnp.einsum("bhk,khd->bhd", o_lat, w_kvb[..., dn:],
+                     preferred_element_type=jnp.float32).astype(dt)
+    return out.reshape(b, nh * dv) @ ap["w_o"].astype(dt)
+
+
+def _decode(params, cfg, tokens, attn, block_size=4):
+    """``tokens`` through ``latent_decode_step`` one at a time beside a
+    freed slot: the last step's logits for the live slot."""
+    n = len(tokens)
+    pool = init_latent_pool(cfg, 1 + -(-n // block_size), block_size)
+    tables = jnp.zeros((2, -(-n // block_size)), jnp.int32).at[0].set(
+        jnp.arange(1, 1 + -(-n // block_size)))
+    step = jax.jit(functools.partial(latent_decode_step, cfg=cfg, attn=attn))
+    for pos in range(n):
+        logits, pool, _ = step(
+            params, jnp.asarray([tokens[pos], 7], jnp.int32),
+            jnp.asarray([pos, 0], jnp.int32), tables, pool)
+    return logits[0]
+
+
+@pytest.mark.parametrize("attn", ["gather", "latent_kernel"])
+def test_a_decode_step_over_the_pair_is_the_step_over_w_kvb(
+        attn, monkeypatch):
+    """Float32, through the gathered path and through the latent kernel
+    (interpreter; pages of 16 rows): the same logits to float32's rounding,
+    far inside ``TOL``.  Not to the last bit at this size: the products are
+    the parent's multiplications, but for two slots and 16-wide heads
+    XLA:CPU unrolls a dot itself and adds along the operands' memory
+    order, which the pair's layout changes (the test below has the size
+    at which it does not)."""
+    cfg = LongcatConfig.tiny(first_expert=2, held_experts=4)
+    params = _params(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(8), (21,), 0, 256)
+    got = _decode(params, cfg, tokens, attn, block_size=16)
+    monkeypatch.setattr(longcat, "_mla_absorbed", _absorbed_over_w_kvb)
+    want = _decode(params, cfg, tokens, attn, block_size=16)
+    assert got.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-6  # logits of ~0.6
+    ref = reference.logits(params, tokens, _model(cfg))[-1]
+    assert float(jnp.max(jnp.abs(got - ref))) < TOL
+
+
+def test_the_absorbed_products_are_the_ones_over_w_kvb_bit_for_bit():
+    """``_mla_absorbed`` against the spelling over ``w_kvb``'s halves, 64
+    slots of 4 heads 64 wide over a latent space of 128: from here on
+    XLA:CPU hands both spellings to its one matrix product routine, and
+    the two products and ``W_o`` after them agree to the last bit."""
+    cfg = LongcatConfig.tiny(qk_nope_head_dim=64, v_head_dim=64,
+                             kv_lora_rank=128)
+    ap = _blocks(longcat_init(jax.random.PRNGKey(1), cfg))[0]
+    q_nope, q_pe = (jax.random.normal(jax.random.PRNGKey(k), (64, 4, d))
+                    for k, d in ((2, 64), (3, cfg.qk_rope_head_dim)))
+
+    def through(absorbed):
+        return jax.jit(lambda *a: absorbed(
+            *a, cfg, lambda q: jnp.tanh(q[..., :128])))(q_nope, q_pe, ap)
+
+    got, want = through(longcat._mla_absorbed), through(_absorbed_over_w_kvb)
+    assert got.shape == (64, cfg.hidden_size) and got.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(want))) > 0.01
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_a_replaced_w_kvb_decodes_once_the_pair_is_derived_again():
+    """What a checkpoint loader does: read ``kv_b_proj`` into ``w_kvb``,
+    then ``absorbed_pair``.  The decode step follows the new matrix (the
+    reference reads ``w_kvb`` alone); with the pair left stale it does
+    not."""
+    cfg = LongcatConfig.tiny(first_expert=2, held_experts=4)
+    params = _params(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(8), (13,), 0, 256)
+    for i, ap in enumerate(_blocks(params)):
+        ap["w_kvb"] = 0.02 * jax.random.normal(
+            jax.random.PRNGKey(40 + i), ap["w_kvb"].shape)
+    want = reference.logits(params, tokens, _model(cfg))[-1]
+    stale = _decode(params, cfg, tokens, "gather")
+    assert float(jnp.max(jnp.abs(stale - want))) > 100 * TOL
+    for ap in _blocks(params):
+        ap["w_uk"], ap["w_uv"] = absorbed_pair(ap["w_kvb"], cfg)
+    got = _decode(params, cfg, tokens, "gather")
+    assert float(jnp.max(jnp.abs(got - want))) < TOL
