@@ -224,7 +224,7 @@ def _build_app(profile: Profile):
             self._work_ms = work_ms
             if mode == "engine":
                 from ray_tpu.llm.engine import LLMEngine
-                from ray_tpu.models.generation import SamplingParams
+                from ray_tpu.llm import SamplingParams
                 from ray_tpu.models.llama import LlamaConfig
 
                 cfg = LlamaConfig.tiny(num_heads=4, num_kv_heads=4,

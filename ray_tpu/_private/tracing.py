@@ -289,6 +289,9 @@ def annotate(name: str, **stats):
 
 # The vocabulary of the device programs' name scopes, two levels
 # (docs/observability.md has the table; tests hold the models to it).
+# ``engine.verify`` is a reserved word: no program emits it since PR 46, but
+# ``cells/parts.py:PROGRAMS`` holds it and ``tests/test_device_scopes.py``
+# holds the two tuples equal (ROADMAP B2 queues dropping it from both).
 PROGRAM_SCOPES = ("engine.decode", "engine.prefill", "engine.verify",
                   "train.step")
 PART_SCOPES = ("embed", "attn.proj", "attn.cache", "attn.core", "attn.out",
@@ -316,9 +319,9 @@ def scope(name: str):
     runs when the program does, and so (unlike :func:`annotate`) there is
     nothing for ``RAY_TPU_TRACING`` to switch off.  Two levels, one
     vocabulary for every model (``docs/observability.md``): the program
-    (``engine.decode``, ``engine.prefill``, ``engine.verify``,
-    ``train.step``), put once where the program is built, then the part
-    (``attn.proj``, ``experts``, ``head``, ...)."""
+    (``engine.decode``, ``engine.prefill``, ``train.step``), put once where
+    the program is built, then the part (``attn.proj``, ``experts``,
+    ``head``, ...)."""
     jax = sys.modules.get("jax")
     return jax.named_scope(name) if jax is not None else _NO_ANNOTATION
 

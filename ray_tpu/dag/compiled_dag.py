@@ -393,10 +393,21 @@ def _exec_iterations(instance, spec, read_channels, tasks, coll_pool):
 
         stopping = False
         for t in tasks:
-            try:
-                if t.get("fused") is not None:
+            if t.get("fused") is not None:
+                # outside the handler below, as an unfused task's write is:
+                # a fused task deals with its subtasks' errors itself, and
+                # what it still raises is a write to a closed channel
+                # (teardown, a dead peer), which must end the loop.  Caught
+                # here it would be written nowhere (a fused task has no
+                # out_channel of its own) and the loop would read the
+                # closed channel again, for ever, a core a loop.
+                try:
                     _exec_fused(instance, t, resolve, local)
-                    continue
+                except _StopSignal:
+                    stopping = True
+                    break
+                continue
+            try:
                 args = [resolve(a) for a in t["args"]]
                 kwargs = {k: resolve(v) for k, v in t["kwargs"].items()}
                 vals = list(args) + list(kwargs.values())

@@ -17,7 +17,7 @@ from ray_tpu.llm.serving import (
     build_llm_deployment,
     disaggregated_handle,
 )
-from ray_tpu.models.generation import SamplingParams
+from ray_tpu.models.paged_generation import SamplingParams
 
 __all__ = [
     "ByteTokenizer", "GenerationOutput", "KVBlockShipper",

@@ -19,7 +19,7 @@ class LLMPredictor:
     def __init__(self, engine_kwargs: Optional[Dict[str, Any]] = None,
                  prompt_column: str = "prompt", output_column: str = "generated",
                  sampling: Optional[Dict[str, Any]] = None):
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.models.paged_generation import SamplingParams
         from ray_tpu.models.llama import LlamaConfig
         from ray_tpu.llm.engine import LLMEngine
 

@@ -54,7 +54,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from ray_tpu._private import tracing
-from ray_tpu.models.generation import SamplingParams
+from ray_tpu.models.paged_generation import SamplingParams
 
 
 class ByteTokenizer:
@@ -302,7 +302,6 @@ class _Window:
     out_d: Any  # [k, B (+ the model's counters)] int32, on its way over
     k: int
     active: List[int]  # the slots it decodes for
-    t_arm: float  # the arm clock when its preparation began
 
 
 class LLMEngine:
@@ -312,9 +311,7 @@ class LLMEngine:
                  num_blocks: Optional[int] = None, decode_window: int = 16,
                  seed: int = 0, mesh=None,
                  kv_cache_dtype: Optional[str] = None,
-                 spec_tokens: int = 0, spec_ngram: int = 2,
-                 spec_lookup_window: int = 512, prefill_chunk: int = 0,
-                 arm_clock=None):
+                 prefill_chunk: int = 0):
         import jax
         import jax.numpy as jnp
 
@@ -322,10 +319,8 @@ class LLMEngine:
 
         # the programs come from the model whose configuration this is
         # (models/served.py): init, pool, suffix prefill, decode-and-sample,
-        # prefix gather, and optionally verify and parameter specs
+        # prefix gather, and optionally parameter specs
         self.model = model = served_model(cfg)
-        model.require("speculative decoding's verify step (spec_tokens)",
-                      not spec_tokens or model.verify_step is not None)
         model.require("parameter specs for an engine with a mesh",
                       mesh is None or model.param_specs is not None)
         # {type: {"layers", "window"}} of a model whose layers do not all
@@ -396,10 +391,8 @@ class LLMEngine:
         self.blocks = pools[0].blocks
         # the decode step's attention, read off what is in front of us:
         # "paged_kernel" / "latent_kernel" (live blocks read in place) for
-        # a dense / latent pool on one TPU device without speculation,
-        # "gather" for the rest
-        self.attn = model.decode_attention_path(self.pool, mesh=mesh,
-                                                spec_tokens=spec_tokens)
+        # a dense / latent pool on one TPU device, "gather" for the rest
+        self.attn = model.decode_attention_path(self.pool, mesh=mesh)
         # the decode step's expert layer, read off the same way
         # (ops/experts.py:expert_path at this engine's B rows a step):
         # "decode_kernel" / "grouped"; None for a model without experts
@@ -447,55 +440,6 @@ class LLMEngine:
         self._prefill_path = None
         self._stack_counted = jax.jit(lambda toks, counts: jnp.concatenate(
             [jnp.stack(toks), jnp.stack(counts)], axis=1))
-        # prompt-lookup speculative decoding (vLLM's ngram method,
-        # TPU-native): host drafts from each request's own history, one
-        # batched paged_verify_step forward checks pending + G drafts,
-        # greedy acceptance keeps the longest matching prefix + a bonus
-        # token — up to G+1 tokens per host sync, token-EXACT vs plain
-        # greedy decode.  Only fully-greedy batches speculate.
-        #
-        # Economics: a verify pass yields up to G+1 tokens per FORWARD
-        # (one weights read) where the decode window pays one forward
-        # per token — on a weights-bound chip speculation wins whenever
-        # acceptance is decent, even with G+1 < decode_window.  Where the
-        # host sync dominates, the window amortizes syncs better: there,
-        # size spec_tokens so G+1 is comparable to decode_window, or
-        # leave speculation off.
-        self.G = max(0, int(spec_tokens))
-        if self.G and int(spec_ngram) < 1:
-            raise ValueError(f"spec_ngram must be >= 1, got {spec_ngram}")
-        self.spec_ngram = int(spec_ngram)
-        # drafting scans the LAST spec_lookup_window history tokens per
-        # step (O(window) host work per slot per step).  Long-document
-        # extraction that copies from the EARLY body of a huge prompt
-        # needs a larger window — raise it and pay the linear scan
-        if self.G and int(spec_lookup_window) < 1:
-            raise ValueError("spec_lookup_window must be >= 1")
-        self.spec_lookup_window = int(spec_lookup_window)
-        self.spec_stats = {"proposed": 0, "accepted": 0, "verify_steps": 0,
-                           "backoffs": 0, "dry_rests": 0}
-        # the bandit's clock: every arm timing (window + verify) reads
-        # THIS callable, so tests inject a deterministic tick counter
-        # and the win-arm decision becomes a pure function of the
-        # workload — wall-clock stalls on a loaded box can't flip it
-        self._arm_clock = arm_clock if arm_clock is not None \
-            else time.perf_counter
-        self._arm_seen: set = set()  # compiles persist across resets
-        # dynamic disable (vLLM-style): a verify pass that mispredicts
-        # yields ~1 token per host sync vs decode_window per sync, so a
-        # low-acceptance workload must fall back to the plain window
-        # (acceptance EMA + rest), and a two-arm throughput bandit TIMES
-        # both paths (EMA host-observed PER-SLOT tokens/s) because
-        # acceptance alone can't tell whether a verify beats the window
-        # — that depends on link latency vs forward time.  All state
-        # initialized by reset_spec_state (the one place defaults live).
-        self.reset_spec_state()
-        if self.G:
-            self._verify = jax.jit(
-                functools.partial(tracing.scoped, "engine.verify",
-                                  model.verify_step, cfg=cfg),
-                donate_argnums=(4,))
-
         # chunked prefill (vLLM's feature TPU-natively): cap the prompt
         # tokens prefilled per step so a long prompt can't stall the
         # decode batch.  Chunks are block-aligned; their full blocks
@@ -684,17 +628,16 @@ class LLMEngine:
            ``engine.dispatch_window[carried=1]`` for the slots that go on
            (this step's admissions among them); the step returns with it
            running;
-        5. ``engine.emit[part=notify]`` (``on_token``, the arm clock) and
+        5. ``engine.emit[part=notify]`` (``on_token``) and
            ``engine.retire`` (``tokenizer.decode``, request spans), under
            the carried window, as is all the caller does between steps.
            A request's first token goes to ``on_token`` with the tokens
            of its first window, one step after its admission (``_tell``).
 
         A window is not carried when no slot goes on (so an engine whose
-        ``has_unfinished()`` is false has nothing in flight) or when
-        speculation is on (``spec_tokens``: the verify arm drafts from the
-        last host tokens).  The un-carried order is the same code with
-        the launch left to the next step.
+        ``has_unfinished()`` is false has nothing in flight).  The
+        un-carried order (``_carries``) is the same code with the launch
+        left to the next step.
         ``stats()["counters"]["windows_carried"]`` of ``["decode_windows"]``
         says how often it engages.
 
@@ -706,8 +649,8 @@ class LLMEngine:
         cache holds.
 
         The step is tiled by ``tracing.annotate`` phases (``engine.admit``,
-        ``engine.first_tokens``, ``engine.verify``,
-        ``engine.prepare_window``, ``engine.dispatch_window``,
+        ``engine.first_tokens``, ``engine.prepare_window``,
+        ``engine.dispatch_window``,
         ``engine.fetch_window``, ``engine.emit``, ``engine.retire``) under
         one ``engine.step``: in a profiler trace they say what the host
         was doing in every idle gap of the device.  One annotation per
@@ -779,7 +722,7 @@ class LLMEngine:
                         prompt_tokens=len(req.prompt_tokens),
                         cached_tokens=req.cached_prefix_len)
                     req.t_decode = now
-                    self._record_token(i, req, int(tok), hold=True)
+                    self._record_token(i, req, int(tok))
                 # the step's device temporaries die inside the phase that
                 # made them, not at the frame's exit under no phase:
                 # freeing a device buffer gives up the interpreter lock,
@@ -798,7 +741,7 @@ class LLMEngine:
             if self._carries():
                 self._inflight = self._launch_window(carried=True)
             # 5. what the launch did not wait for
-            self._notify(window, taken)
+            self._notify(taken)
 
         with tracing.annotate("engine.retire") as ann:
             out = self._retire()
@@ -806,33 +749,26 @@ class LLMEngine:
         return out
 
     def _carries(self) -> bool:
-        """Whether a window may be launched ahead of the emit of the one
-        before: not with speculation on, whose verify arm drafts from the
-        host's last tokens."""
-        return self.G == 0
+        """Whether a window is launched ahead of the emit of the one
+        before.  Always: this is the seam a test patches
+        (``eng._carries = lambda: False``) to get the un-carried order,
+        the reference order the carried one's tokens are held to."""
+        return True
 
     def _launch_window(self, carried: bool) -> Optional[_Window]:
         """Dispatch one decode window (``window_k`` chained steps and the
         small program that stacks their tokens) for the slots that are not
-        done, and return it un-fetched; ``None`` when no slot goes on or
-        the verify arm produced this step's tokens instead.  ``carried``:
-        the window before this one has been committed but not emitted."""
+        done, and return it un-fetched; ``None`` when no slot goes on.
+        ``carried``: the window before this one has been committed but not
+        emitted."""
         active = [i for i in range(self.B) if self._slots[i] is not None
                   and not self._slots[i].done]
-        if active and self.G:
-            with tracing.annotate("engine.verify"):
-                if self._try_speculate(active):
-                    return None  # this step's tokens came from the verify
         if not active:
             return None
         with tracing.annotate("engine.prepare_window"):
-            # arm timing starts BEFORE block growth / mirror refresh /
-            # uploads so the window arm carries the same per-step host
-            # costs the verify arm does (symmetric bandit comparison)
-            t_arm = self._arm_clock()
             # ensure every active slot has blocks for the whole window;
             # preempt the youngest request if the pool is exhausted
-            active = self._ensure_decode_blocks(active, horizon=self.K)
+            active = self._ensure_decode_blocks(active)
             if not active:
                 return None
             # adaptive window: never decode past what the
@@ -866,7 +802,7 @@ class LLMEngine:
             if self.experts == "decode_kernel":
                 self.counters["expert_kernel_windows"] += 1
             del toks, counts, extra  # freed inside the phase, as above
-        return _Window(out_d, window_k, active, t_arm)
+        return _Window(out_d, window_k, active)
 
     def _expert_path(self, tokens: int) -> Optional[str]:
         """The expert layer's path in a program of ``tokens`` rows, as the
@@ -999,18 +935,10 @@ class LLMEngine:
             ann.set_metadata(tokens=sum(len(t) for _, t in taken))
         return taken
 
-    def _notify(self, w: _Window, taken) -> None:
+    def _notify(self, taken) -> None:
         """What of a window's emit need not precede the next launch: the
-        arm clock and the per-token hook."""
+        per-token hook."""
         with tracing.annotate("engine.emit", part="notify"):
-            if self.G:
-                self._spec_streak = 0
-                # per-ARITY EMA: short windows have different sync
-                # amortization (and their own _stack compiles), so
-                # each arity gets its own sample stream — the verify
-                # gate compares against the arity it would displace
-                self._observe_arm(("window", w.k), w.k,
-                                  self._arm_clock() - w.t_arm)
             for req, toks in taken:
                 self._tell(req, toks)
 
@@ -1277,7 +1205,7 @@ class LLMEngine:
     def stats(self) -> Dict[str, Any]:
         """Engine signals for the serve autoscaler + dashboard ``/api/llm``
         panel: queue depth, slot occupancy, block-pool pressure, prefix /
-        speculative / handoff counters, and the devices this engine's
+        handoff counters, and the devices this engine's
         process holds (platform, kind, HBM in use and peak).  Host-side
         bookkeeping and allocator counters only — no device sync."""
         from ray_tpu.util.health import device_memory_stats
@@ -1314,7 +1242,6 @@ class LLMEngine:
                if self.prefill_attention is not None else {}),
             "prefix_cache": dict(self.blocks.stats),
             "prefill_chunks": self.prefill_stats["chunks"],
-            "spec": dict(self.spec_stats),
             "handoff": dict(self.handoff_stats),
             "model": self.model.name,
             "counters": dict(self.counters),
@@ -1569,11 +1496,10 @@ class LLMEngine:
         self.prefill_stats["chunks"] += 1
         return ("partial", None, take)
 
-    def _ensure_decode_blocks(self, active: List[int],
-                              horizon: int = 1) -> List[int]:
-        """Allocate blocks covering the next ``horizon`` write positions
-        for each active slot, preempting the youngest request when the
-        pool is exhausted (vLLM recompute preemption)."""
+    def _ensure_decode_blocks(self, active: List[int]) -> List[int]:
+        """Allocate blocks covering a whole window's write positions (the
+        next ``K``) for each active slot, preempting the youngest request
+        when the pool is exhausted (vLLM recompute preemption)."""
         for i in list(active):
             req = self._slots[i]
             if req is None or req.done:
@@ -1582,7 +1508,7 @@ class LLMEngine:
             # max_tokens are discarded (and clamp to scratch), so reserving
             # blocks for them could only cause needless preemption
             remaining = max(1, req.sampling.max_tokens - req.num_generated)
-            last_pos = min(int(self._cur_len[i]) + min(horizon, remaining)
+            last_pos = min(int(self._cur_len[i]) + min(self.K, remaining)
                            - 1, self.max_len - 1)
             blk_idx = last_pos // self.bs
             for p, held in zip(self._pools, [req.blocks] + req.more_blocks):
@@ -1631,8 +1557,6 @@ class LLMEngine:
         self.blocks.stats["preemptions"] += 1
         return i
 
-    # -- speculative decoding ------------------------------------------------
-
     def _window_arity(self, active: List[int]) -> int:
         """The decode-window length step() would run for these slots:
         min(K, longest remaining budget)."""
@@ -1645,172 +1569,12 @@ class LLMEngine:
             rem = max(rem, r)
         return max(1, min(self.K, rem))
 
-    def _observe_arm(self, key, tokens: float, elapsed: float):
-        """EMA per key ("verify" or ("window", arity)); a key's first
-        sample is discarded — it includes jit COMPILATION, not
-        throughput."""
-        if elapsed <= 0 or tokens <= 0:
-            return
-        if key not in self._arm_seen:
-            self._arm_seen.add(key)
-            return
-        tps = tokens / elapsed
-        prev = self._arm_tps.get(key)
-        self._arm_tps[key] = tps if prev is None else (
-            0.7 * prev + 0.3 * tps)
-
-    def reset_spec_state(self):
-        """Reset every drafter/bandit state field to its initial value —
-        the ONE place the defaults live (benchmarks and tests use this
-        instead of poking private fields)."""
-        self._spec_ema = 1.0
-        self._spec_backoff = 0
-        self._spec_backoff_len = 8
-        self._spec_dry = 0
-        self._spec_streak = 0
-        # keyed "verify" and ("window", arity) — per-arity EMAs
-        self._arm_tps: Dict[Any, float] = {}
-        self.spec_stats.update(proposed=0, accepted=0, verify_steps=0,
-                               backoffs=0, dry_rests=0)
-
-    def _spec_rest(self, dry: bool = False):
-        """Rest the drafter for a growing number of steps (ONE escalation
-        rule for every trigger).  ``dry`` rests (persistent draftless
-        scans — the drafter had nothing to say) are counted separately
-        from ``backoffs`` (the bandit judged the window faster, or
-        acceptance collapsed): consumers watching whether speculation
-        is LOSING must not conflate it with merely idling."""
-        self.spec_stats["dry_rests" if dry else "backoffs"] += 1
-        self._spec_backoff = self._spec_backoff_len
-        self._spec_backoff_len = min(self._spec_backoff_len * 2, 256)
-
-    def _try_speculate(self, active: List[int]) -> bool:
-        """Prompt-lookup speculative step: draft up to G tokens per active
-        slot from its own history, verify pending + drafts in ONE batched
-        ``paged_verify_step``, accept the longest greedy-matching prefix
-        plus the bonus token.  Returns False (caller falls back to the
-        plain decode window) when any active slot samples (temp > 0 —
-        greedy acceptance would skew its distribution) or when any slot
-        lacks a draft: a verify pass advances a draftless slot only 1
-        token per host sync, so speculating a partially-drafting batch
-        would starve those slots of the K-step window amortization."""
-        import jax.numpy as jnp
-
-        from ray_tpu.models.generation import _propose_ngram
-
-        if any(self._slots[i].sampling.temperature > 0.0 for i in active):
-            return False
-        if self._spec_backoff > 0:
-            self._spec_backoff -= 1
-            return False
-        if self._arm_tps.get("verify") is not None and self._spec_streak >= 16:
-            # periodic window probe: an always-drafting, high-acceptance
-            # workload would otherwise NEVER sample the window arm and
-            # the bandit could lock into a slower verify path forever
-            self._spec_streak = 0
-            return False
-        # arm timing starts HERE: the drafting scan is a cost unique to
-        # the verify path, so it must count against that arm
-        t_arm = self._arm_clock()
-        drafts: Dict[int, List[int]] = {}
-        for i in active:
-            req = self._slots[i]
-            # bounded lookup window: drafts are only proposals, so a cap
-            # keeps the per-step host scan O(window), not O(sequence)
-            # (slice BEFORE concatenating — the full lists are long)
-            W = self.spec_lookup_window
-            hist = (req.prompt_tokens[-W:] + req.out_tokens[-W:])[-W:]
-            drafts[i] = _propose_ngram(hist, self.G, self.spec_ngram)[:self.G]
-        if not any(drafts.values()):
-            # a run of FULLY draftless steps rests the drafter like low
-            # acceptance does: never-drafting workloads must not pay
-            # the history scan every single step.  A draftless MINORITY
-            # lane rides the verify pass with an empty proposal instead
-            # (it still gets its bonus token — exactly a 1-token window),
-            # so one non-repetitive request can't veto speculation for
-            # the whole batch.
-            self._spec_dry += 1
-            if self._spec_dry >= 4:
-                self._spec_dry = 0
-                self._spec_rest(dry=True)
-            return False
-        self._spec_dry = 0
-        active = self._ensure_decode_blocks(active, horizon=self.G + 1)
-        if not active:
-            return True  # everything was preempted; step's retire handles it
-        # the window arity this verify DISPLACES — computed before
-        # acceptance mutates budgets, so the gate compares like-for-like
-        displaced_arity = self._window_arity(active)
-        tokens = np.zeros((self.B, self.G + 1), np.int32)
-        for i in active:
-            tokens[i, 0] = self._next_token[i]
-            d = drafts.get(i, [])
-            tokens[i, 1:1 + len(d)] = d
-        # reuse the resident tables mirror: _ensure_decode_blocks sets
-        # _dev_dirty whenever it actually grows a table
-        self._refresh_device_mirrors()
-        logits_d, self.pool = self._verify(
-            self.params, jnp.asarray(tokens), jnp.asarray(self._cur_len),
-            self._tables_d, self.pool)
-        preds = np.asarray(jnp.argmax(logits_d, -1))  # ONE sync: [B, G+1]
-        arm_elapsed = self._arm_clock() - t_arm
-        self.spec_stats["verify_steps"] += 1
-        accepted_last: Dict[int, int] = {}
-        for i in active:
-            req = self._slots[i]
-            if req is None or req.done:
-                continue
-            d = drafts.get(i, [])
-            a = 0
-            while a < len(d) and d[a] == int(preds[i, a]):
-                a += 1
-            accepted_last[i] = a
-            self.spec_stats["proposed"] += len(d)
-            self.spec_stats["accepted"] += a
-            # pending + a accepted drafts now hold valid cache positions;
-            # the bonus becomes the new pending token (not yet written)
-            self._cur_len[i] += 1 + a
-            for tok in d[:a]:
-                self._record_token(i, req, int(tok))
-                if req.done:
-                    break
-            if not req.done:
-                self._record_token(i, req, int(preds[i, a]))
-        self._dev = None  # cur/next advanced on host; tables unchanged
-        n_prop = sum(len(drafts.get(i, [])) for i in active)
-        n_acc = sum(accepted_last.get(i, 0) for i in active)
-        self._spec_streak += 1
-        self._observe_arm(
-            "verify",
-            sum(1 + a for a in accepted_last.values())
-            / max(1, len(accepted_last)),
-            arm_elapsed)
-        w = self._arm_tps.get(("window", displaced_arity))
-        v = self._arm_tps.get("verify")
-        if w is not None and v is not None and v < 0.9 * w:
-            # the window arm is measurably faster on THIS link/hardware
-            # (e.g. sync-dominated, where K tokens/sync beats G+1):
-            # rest regardless of acceptance
-            self._spec_rest()
-            return True
-        if n_prop:
-            self._spec_ema = 0.7 * self._spec_ema + 0.3 * (n_acc / n_prop)
-        if self._spec_ema < 0.35:
-            self._spec_rest()
-            # re-probe just above the floor: ONE more bad verify
-            # re-triggers with the doubled rest (escalation reachable),
-            # while a good one climbs the EMA back toward keeping on
-            self._spec_ema = 0.45
-        elif self._spec_ema > 0.6:
-            self._spec_backoff_len = 8  # healthy again: cheap re-probes
-        return True
-
     # -- internals ----------------------------------------------------------
 
-    def _record_token(self, i: int, req: Request, tok: int,
-                      hold: bool = False):
-        """One sampled token into the request's state.  ``hold``: a first
-        token, kept back from ``on_token`` while the request goes on."""
+    def _record_token(self, i: int, req: Request, tok: int):
+        """A request's first token (the one its prefill samples; a window's
+        go through ``_fetch_and_commit``) into its state, kept back from
+        ``on_token`` while the request goes on."""
         sp = req.sampling
         if sp.stop_token_id is not None and tok == sp.stop_token_id:
             req.done = True
@@ -1826,7 +1590,7 @@ class LLMEngine:
                 or len(req.prompt_tokens) + len(req.out_tokens)
                 >= self.max_len - 1):
             req.done = True
-        if hold and not req.done:
+        if not req.done:
             req.held.append(tok)
         else:
             self._tell(req, [tok])
@@ -1864,8 +1628,7 @@ class LLMEngine:
 
     def _refresh_device_mirrors(self):
         """Bring the device mirrors of the decode inputs up to the host's,
-        each only where the host changed it — ONE invariant for both the
-        decode window and the verify path:
+        each only where the host changed it:
 
         * the block tables, when a row changed (admit / retire / preempt /
           table growth set ``_dev_dirty``).  First the row of every slot
@@ -1874,7 +1637,7 @@ class LLMEngine:
           reads nothing for that slot and writes its position to the
           scratch block, never into blocks about to be released;
         * ``(tok_d, cur_d)`` and the temperatures, when a request entered
-          a slot or the verify arm advanced the host (``_dev`` is None).
+          a slot (``_dev`` is None).
           A table that grew does NOT invalidate them: after a window's
           commit the host's ``_next_token`` / ``_cur_len`` of a slot that
           goes on are what the window's last step left on the device, so

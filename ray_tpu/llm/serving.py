@@ -284,7 +284,7 @@ class _EngineHost:
                 pass
 
     def _sampling_from_body(self, body: Dict[str, Any]):
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.models.paged_generation import SamplingParams
 
         return SamplingParams(
             temperature=float(body.get("temperature", 0.7)),

@@ -23,8 +23,8 @@ from ray_tpu.models.moe import (  # noqa: F401
     moe_loss,
     moe_param_specs,
 )
+from ray_tpu.models.paged_generation import SamplingParams  # noqa: F401
 from ray_tpu.models.generation import (  # noqa: F401
-    SamplingParams,
     decode_step,
     generate,
     init_kv_cache,

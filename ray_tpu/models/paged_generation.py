@@ -12,15 +12,15 @@ TPU-native redesign of the same ideas:
 * All shapes are static — one compiled decode program forever — and the
   decode step's attention takes one of two paths, chosen by
   ``decode_attention_path`` from what it can see (the pool's keys, a
-  mesh, speculation, the backend), never by a knob:
+  mesh, the backend), never by a knob:
 
   - ``"paged_kernel"`` (dense pool, one query token, one device, TPU):
     ``ops/pallas/paged_attention.py`` reads each slot's *live* blocks in
     place out of the stacked pool, by block table and length; a slot
     whose table row is all scratch (a freed slot) reads nothing.  What a
     step moves follows the tokens held, not the tables' capacity.
-  - ``"gather"`` (int8 pool, ``paged_verify_step``, an engine with a
-    mesh, any other backend): gather every slot's whole table
+  - ``"gather"`` (int8 pool, an engine with a mesh, any other
+    backend): gather every slot's whole table
     (``[b, MB·bs]`` keys, MB = max_len/block_size) and mask by
     ``cur_len``.  XLA-friendly, sharding-transparent, and the plain
     reference the kernel is tested against.
@@ -33,19 +33,26 @@ TPU-native redesign of the same ideas:
   freshly allocated blocks.
 
 The block manager / prefix hash-chain lives in ``llm/engine.py`` (host
-side, pure numpy); this module is only the jittable math.
+side, pure numpy); this module is only the jittable math, and what that
+math is made of: the cached decoder layer (``_layer_with_cache``, its two
+masked attentions, ``_stacked_layers``) and ``SamplingParams``.  The
+dense-cache reference (``generation.py``, beside this file) takes them
+from here; nothing here or under ``llm/`` imports that file.
 """
 
 from __future__ import annotations
+
+import dataclasses
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import tracing
 from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.models.generation import (_layer_with_cache, _stacked_layers,
-                                        sliding_window_mask)
-from ray_tpu.ops.layers import rms_norm, rope_frequencies
+from ray_tpu.ops.attention import sliding_window_mask
+from ray_tpu.ops.layers import (apply_rope, heads_projection, rms_norm,
+                                rope_frequencies, swiglu)
 
 
 def init_kv_pool(cfg: LlamaConfig, num_blocks: int, block_size: int,
@@ -130,7 +137,7 @@ def _gather_kv(pool, i, block_tables, dt):
     return k, v
 
 
-def decode_attention_path(pool, *, mesh=None, spec_tokens: int = 0) -> str:
+def decode_attention_path(pool, *, mesh=None) -> str:
     """Which attention the decode step runs, from what can be seen, never
     from a knob.  Which pool takes which arm of
     ``ops/pallas/paged_attention.py``:
@@ -144,15 +151,12 @@ def decode_attention_path(pool, *, mesh=None, spec_tokens: int = 0) -> str:
       leading columns, the caller's scale);
     * ``"gather"`` for an int8 pool (no kernel arm yet), a mesh (the pool
       is sharded over KV heads; the kernel is not under ``shard_map``
-      yet), speculation (the S > 1 verify has no kernel arm, and its
-      greedy acceptance is held token-exact against the decode window, so
-      both arms run one attention) and every backend but TPU
+      yet) and every backend but TPU
       (``ops/attention.py`` keeps Pallas off the CPU path the same way).
 
     Mosaic wants a page's rows and the row's width tile-aligned; other
     shapes gather."""
-    off_kernel = (mesh is not None or spec_tokens
-                  or jax.default_backend() != "tpu")
+    off_kernel = mesh is not None or jax.default_backend() != "tpu"
     if "kv" in pool:
         bs, width = pool["kv"].shape[2:]
         return ("gather" if off_kernel or width % 128 or bs % 16
@@ -161,6 +165,121 @@ def decode_attention_path(pool, *, mesh=None, spec_tokens: int = 0) -> str:
     if ("k_scale" in pool or off_kernel or hd % 128 or (bs * kvh) % 16):
         return "gather"
     return "paged_kernel"
+
+
+def _gqa_attend(q, k, v, mask):
+    """q [b,sq,H,hd], k/v [b,sk,KVH,hd], mask [b,sq,sk] -> [b,sq,H,hd]."""
+    b, sq, H, hd = q.shape
+    kvh = k.shape[2]
+    group = H // kvh
+    q = q.reshape(b, sq, kvh, group, hd)
+    logits = jnp.einsum("bqkgh,bskh->bkgqs", q, k,
+                        preferred_element_type=jnp.float32)
+    logits = logits / jnp.sqrt(hd).astype(logits.dtype)
+    logits = jnp.where(mask[:, None, None, :, :], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bkgqs,bskh->bqkgh", probs, v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, sq, H, hd).astype(q.dtype)
+
+
+def _gqa_attend_quant(q, k_q, ks, v_q, vs, mask):
+    """Int8-KV attention with the scales folded AROUND the matmuls.
+
+    The int8 cache values convert to ``q.dtype`` inside the dots (no
+    dequantized ``[b,sk,KVH,hd]`` tensor materializes in HBM) and the
+    per-(token, kv-head) scales apply to the ``[.., sq, sk]``-shaped
+    scores/probs instead — exact, because the scale is constant along
+    the contracted ``hd`` axis: ``q·(k_q·s) == (q·k_q)·s`` and
+    ``(p·s)·v_q == p·(v_q·s)``.
+
+    Measured on v5e @ 7B decode: wins at LARGE table capacity (194 vs
+    160 tok/s at max_len 512) where the avoided dequant-materialization
+    traffic dominates, loses at small capacity (230 vs 295 at max_len
+    176) where the int8-operand dot's slower mixed-precision path
+    dominates — callers gate on block-table capacity
+    (``paged_generation.INT8_FOLD_MIN_CONTEXT``).
+
+    q [b,sq,H,hd]; k_q/v_q [b,sk,KVH,hd] int8; ks/vs [b,sk,KVH];
+    mask [b,sq,sk].
+    """
+    b, sq, H, hd = q.shape
+    kvh = k_q.shape[2]
+    group = H // kvh
+    qg = q.reshape(b, sq, kvh, group, hd)
+    logits = jnp.einsum("bqkgh,bskh->bkgqs", qg, k_q.astype(q.dtype),
+                        preferred_element_type=jnp.float32)
+    scale_k = ks.transpose(0, 2, 1)[:, :, None, None, :]  # [b,kvh,1,1,sk]
+    logits = logits * scale_k.astype(logits.dtype)
+    logits = logits / jnp.sqrt(hd).astype(logits.dtype)
+    logits = jnp.where(mask[:, None, None, :, :], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    scale_v = vs.transpose(0, 2, 1)[:, :, None, None, :]
+    probs = (probs * scale_v.astype(probs.dtype)).astype(q.dtype)
+    out = jnp.einsum("bkgqs,bskh->bqkgh", probs, v_q.astype(q.dtype),
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, sq, H, hd).astype(q.dtype)
+
+
+def _layer_with_cache(x, lp, layer_kv, *, cfg, cos, sin, mask,
+                      positions=None, attend=None):
+    """One decoder layer reading/returning its kv (cache-enabled twin of
+    ``llama._decoder_layer``; same weights, ragged-mask attention).
+
+    ``layer_kv(k, v)`` merges with the cache and returns either
+    ``(k_all, v_all)`` (dense) or ``(k_q, ks, v_q, vs)`` (int8 values +
+    per-token-head scales — routed through the scale-folded attend).
+    ``attend(q, k, v) -> [b, s, H, hd]`` replaces both the merge and the
+    masked attention for a caller that never materializes the merged cache
+    (the paged decode kernel); ``layer_kv`` and ``mask`` are then unused.
+
+    The layer's parts carry the name scopes of ``docs/observability.md``
+    (``attn.proj``, ``attn.cache``, ``attn.core``, ``attn.out``, ``ffn``):
+    a profiler trace's device time is cut by them."""
+    b, s, h = x.shape
+    dt = cfg.dtype
+    with tracing.scope("attn.proj"):
+        y = rms_norm(x, lp["attn_norm"])
+        q = heads_projection(y, lp["wq"].astype(dt), cfg.num_heads)
+        k = heads_projection(y, lp["wk"].astype(dt), cfg.num_kv_heads)
+        v = heads_projection(y, lp["wv"].astype(dt), cfg.num_kv_heads)
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
+    if attend is not None:
+        attn = attend(q, k, v)  # opens attn.cache and attn.core itself
+    else:
+        with tracing.scope("attn.cache"):
+            merged = layer_kv(k, v)  # merge with cache; full keys/vals
+        with tracing.scope("attn.core"):
+            if len(merged) == 4:
+                attn = _gqa_attend_quant(q, *merged, mask)
+            else:
+                attn = _gqa_attend(q, merged[0], merged[1], mask)
+    with tracing.scope("attn.out"):
+        x = x + (attn.reshape(b, s, -1) @ lp["wo"].astype(dt))
+    with tracing.scope("ffn"):
+        y = rms_norm(x, lp["mlp_norm"])
+        act = swiglu(y @ lp["w_gate"].astype(dt), y @ lp["w_up"].astype(dt))
+        x = x + act @ lp["w_down"].astype(dt)
+    return x, (k, v)
+
+
+def _stacked_layers(params):
+    """Iterate stacked layer params [L, ...] without lax.scan (generation
+    caches differ per layer; a python loop keeps it simple and L is static).
+
+    What ``a[i]`` costs in the compiled program: nothing, where a product
+    reads it.  XLA:TPU makes the layer's slice of the stacked parameter an
+    operand of the product's own fusion (``fusion(%params__layers____wo__,
+    ...)``) and streams the weight from where it lies; the seven weights of
+    ``_layer_with_cache`` are all read so since ``heads_projection`` keeps
+    wq, wk and wv from being transposed first (PR 35; compiled for the v5e
+    in ``tests/test_flash_compile_v5e.py``).  It is a copy only for an
+    operand of a Mosaic call or under a ``lax.scan`` over the steps
+    (``paged_decode_sample``)."""
+    L = jax.tree.leaves(params["layers"])[0].shape[0]
+    for i in range(L):
+        yield i, jax.tree.map(lambda a: a[i], params["layers"])
 
 
 def _lm_head(params, cfg, x):
@@ -189,7 +308,7 @@ def paged_decode_step(params, token, cur_len, block_tables, pool,
     ``block_tables[i, cur_len // bs][cur_len % bs]``.
 
     ``attn``: the ``decode_attention_path`` the caller resolved (the engine
-    knows its mesh and whether it speculates); None resolves it here from
+    knows its mesh); None resolves it here from
     the pool and the backend.  On the kernel path a slot whose first table
     entry is the scratch block holds no request (the engine zeroes a freed
     slot's row): it attends over nothing and its logits are discarded.
@@ -308,57 +427,6 @@ def prefill_suffix(params, tokens, length, start_pos, prefix_k, prefix_v,
     return last, pool
 
 
-def paged_verify_step(params, tokens, cur_len, block_tables, pool,
-                      cfg: LlamaConfig):
-    """Speculative-decoding verify against block-table caches: feed S
-    tokens per slot in ONE forward (``tokens[:, 0]`` is the pending
-    last-accepted token, ``1..S-1`` the draft proposals).
-
-    ``logits[:, j]`` predicts the token at position ``cur_len+j+1``, so
-    greedy acceptance compares ``argmax(logits[:, j])`` with draft token
-    ``j+1`` — the paged counterpart of the dense ``verify_step``
-    (``models/generation.py``).  KV for all S positions is written at
-    ``cur_len..cur_len+S-1`` through the block tables (pad / overflow
-    lanes clamp into the scratch block); slots past the accepted prefix
-    hold draft-conditioned KV but stay invisible (masks are
-    ``<= position``) and are overwritten when those positions are
-    genuinely reached.  The reference reaches this via vLLM's
-    speculative/prompt-lookup decoding; here it is a first-class pool op.
-    """
-    b, S = tokens.shape
-    MB = block_tables.shape[1]
-    bs = pool["k"].shape[2]
-    ML = MB * bs
-    hd = cfg.resolved_head_dim
-    dt = cfg.dtype
-    cos, sin = rope_frequencies(hd, ML, cfg.rope_theta)
-    positions = cur_len[:, None] + jnp.arange(S)[None, :]  # [b, S]
-    safe_pos = jnp.minimum(positions, ML - 1)
-    x = embed_tokens(params, tokens, dt)
-    idx = jnp.arange(ML)
-    # query at global position p sees pool slots <= p (its own included);
-    # earlier same-chunk tokens are visible because each layer stores the
-    # whole chunk's KV before gathering
-    mask = idx[None, None, :] <= safe_pos[:, :, None]
-    if cfg.sliding_window is not None:
-        mask &= sliding_window_mask(safe_pos[:, :, None],
-                                    idx[None, None, :], cfg.sliding_window)
-    rows = jnp.arange(b)[:, None]
-    blk = block_tables[rows, safe_pos // bs]  # [b, S]
-    off = safe_pos % bs
-
-    for i, lp in _stacked_layers(params):
-        def merge(k, v, i=i):
-            nonlocal pool
-            pool = _store_kv(pool, i, blk, off, k, v)  # k/v [b, S, KVH, hd]
-            g = _gather_kv(pool, i, block_tables, dt)
-            return tuple(a.reshape(b, ML, *a.shape[3:]) for a in g)
-
-        x, _ = _layer_with_cache(x, lp, merge, cfg=cfg, cos=cos, sin=sin,
-                                 mask=mask, positions=safe_pos)
-    return _lm_head(params, cfg, x), pool
-
-
 def paged_decode_sample(params, token, cur_len, block_tables, pool, key,
                         temps, cfg: LlamaConfig, attn: str | None = None):
     """One decode step with ON-DEVICE sampling, shaped for host-free
@@ -388,6 +456,15 @@ def paged_decode_sample(params, token, cur_len, block_tables, pool, key,
                                      pool, cfg=cfg, attn=attn)
     nxt, key = sample_next(logits, key, temps)
     return nxt, cur_len + 1, key, pool
+
+
+@dataclasses.dataclass(frozen=True)
+class SamplingParams:
+    temperature: float = 1.0
+    top_k: int = 0  # 0 = disabled
+    top_p: float = 1.0  # 1.0 = disabled
+    max_tokens: int = 64
+    stop_token_id: Optional[int] = None
 
 
 def sample_next(logits, key, temps):

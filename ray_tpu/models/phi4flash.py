@@ -504,14 +504,13 @@ def store_slabs(pages, layer, blocks, offsets, slabs):
     return view.reshape(pages.shape)
 
 
-def decode_attention_path(pool, *, mesh=None, spec_tokens: int = 0) -> str:
+def decode_attention_path(pool, *, mesh=None) -> str:
     """``paged_generation.decode_attention_path``'s rule for the two pools
     of positions, whose blocks are pages already: the paged kernel on one
     TPU device where a page's rows and width are tile-aligned, the gathered
     cache everywhere else."""
     rows, width = pool[FULL]["k"].shape[2:]
-    off_kernel = (mesh is not None or spec_tokens
-                  or jax.default_backend() != "tpu")
+    off_kernel = mesh is not None or jax.default_backend() != "tpu"
     return ("gather" if off_kernel or width % 128 or rows % 16
             else "paged_kernel")
 

@@ -18,14 +18,14 @@ configuration's type: ``served_model(cfg)`` returns the model's
   key, temps[B], cfg=, attn=) -> (token, cur_len, key, pool, *counters)``:
   one step with on-device sampling, everything the next step needs a
   device array;
-* ``decode_attention_path(pool, mesh=, spec_tokens=) -> str``: which
-  attention the decode step runs, from what it sees;
+* ``decode_attention_path(pool, mesh=) -> str``: which attention the
+  decode step runs, from what it sees;
 
-and optionally ``verify_step`` (speculation), ``param_specs`` (an engine
-with a mesh), ``handoff`` (``export_kv`` / ``adopt_prefilled`` know its
-pool), ``layer_types`` (below), ``expert_path(cfg, tokens) -> str`` (a model
-with an expert layer: which path ``ops/experts.py:expert_path`` gives a
-program of ``tokens`` rows, the engine's ``experts`` label),
+and optionally ``param_specs`` (an engine with a mesh), ``handoff``
+(``export_kv`` / ``adopt_prefilled`` know its pool), ``layer_types``
+(below), ``expert_path(cfg, tokens) -> str`` (a model with an expert
+layer: which path ``ops/experts.py:expert_path`` gives a program of
+``tokens`` rows, the engine's ``experts`` label),
 ``prefill_attention_path(cfg, bucket, prefix) -> str`` (a model whose
 prefill has more than one attention path: which one the program of a
 ``bucket``-token suffix after ``prefix`` padded cached positions runs, the
@@ -83,7 +83,6 @@ class ServedModel:
     presets: Dict[str, Callable[[], Any]]
     # the presets at toy widths, written in the float32 the CPU tests run
     test_presets: Tuple[str, ...] = ()
-    verify_step: Optional[Callable] = None
     param_specs: Optional[Callable] = None
     handoff: bool = False
     layer_types: Optional[Callable] = None
@@ -126,8 +125,7 @@ def _llama() -> ServedModel:
         presets={n: getattr(LlamaConfig, n) for n in (
             "tiny", "llama2_7b", "llama2_13b", "llama3_8b")},
         test_presets=("tiny",),
-        verify_step=pg.paged_verify_step, param_specs=llama_param_specs,
-        handoff=True)
+        param_specs=llama_param_specs, handoff=True)
 
 
 def _longcat() -> ServedModel:

@@ -75,8 +75,8 @@ def swiglu(gate: jnp.ndarray, up: jnp.ndarray) -> jnp.ndarray:
 def heads_projection(y: jnp.ndarray, w: jnp.ndarray, heads: int) -> jnp.ndarray:
     """``y [..., hidden] @ w [hidden, heads * hd]`` handed on as
     ``[..., heads, hd]``: the one place a cached decoder layer projects onto
-    its heads (``generation._layer_with_cache``: wq, wk, wv; ``longcat``'s
-    ``w_qb``).
+    its heads (``paged_generation._layer_with_cache``: wq, wk, wv;
+    ``longcat``'s ``w_qb``).
 
     The barrier keeps the product apart from the reshape.  Written as
     ``(y @ w).reshape(...)``, or as an einsum onto ``w.reshape(hidden, heads,

@@ -19,8 +19,9 @@ with the positions past ``length``: the MXU is bound by the key tiles it
 loads, which are the same either way, and no per-head slice of a page (a
 strided sublane read of packed bf16) is ever taken.  Keys, values and
 probabilities enter the matmuls in the pool's dtype, scores, softmax and the
-accumulator are float32: what ``models/generation._gqa_attend`` does on the
-gathered cache, which stays the reference this kernel is tested against.
+accumulator are float32: what ``models/paged_generation._gqa_attend`` does
+on the gathered cache, which stays the reference this kernel is tested
+against.
 
 Two arms of the one kernel, picked by what the caller hands it:
 

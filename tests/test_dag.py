@@ -476,6 +476,29 @@ class TestJitFusion:
         finally:
             compiled.teardown()
 
+    def test_teardown_ends_a_fused_tasks_loop(self):
+        """A fused task has no out_channel of its own, so a read of the
+        channels teardown closed must end its loop itself: left running it
+        read the closed channel again for ever (a core an actor until the
+        cluster went, and ``teardown`` waited out its whole timeout)."""
+        import time
+
+        from ray_tpu.dag.compiled_dag import _exec_loop_status
+
+        w = JitWorker.remote()
+        with InputNode() as inp:
+            dag = w.scale.options(jit=True).bind(
+                w.scale.options(jit=True).bind(inp))
+        compiled = dag.experimental_compile()
+        x = np.ones(4, np.float32)
+        np.testing.assert_allclose(
+            np.asarray(compiled.execute(x).get(timeout=90)), 4.0 * x)
+        t0 = time.monotonic()
+        compiled.teardown(timeout=10)
+        assert time.monotonic() - t0 < 5.0
+        assert ray_tpu.get(w._remote_call.remote(
+            _exec_loop_status, compiled.dag_id), timeout=10)["done"]
+
     def test_mid_run_value_consumed_by_later_task(self):
         w = JitWorker.remote()
         with InputNode() as inp:

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu._private import tracing
-from ray_tpu.models.generation import SamplingParams
+from ray_tpu.llm import SamplingParams
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODELS = ("tiny", "longcat_flash_tiny", "smallthinker_tiny")
@@ -100,17 +100,11 @@ def programs():
         record(eng, "_decode1", (name, "engine.decode"))
         record(eng, "_prefill", (name, "engine.prefill"))
         eng.generate([[3, 4, 5, 6, 7], [8, 9]], sp)
-    eng = LLMEngine(served.preset("tiny"), batch_slots=2, max_len=64,
-                    decode_window=4, spec_tokens=3)
-    record(eng, "_verify", ("tiny", "engine.verify"))
-    eng.generate([[5, 4, 5, 4, 5, 4, 5, 4]],
-                 SamplingParams(temperature=0.0, max_tokens=12))
     return out
 
 
 @pytest.mark.parametrize("model,program", [
-    *((m, p) for m in MODELS for p in ("engine.decode", "engine.prefill")),
-    ("tiny", "engine.verify")])
+    (m, p) for m in MODELS for p in ("engine.decode", "engine.prefill")])
 def test_every_product_of_a_served_program_names_its_program_and_part(
         programs, model, program):
     module, ops = programs[(model, program)]

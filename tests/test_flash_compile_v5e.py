@@ -685,7 +685,7 @@ def test_the_layers_q_k_v_are_the_plain_products_bit_for_bit(s, tp):
     float32 parameters cast to the bf16 of the products."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from ray_tpu.models.generation import _layer_with_cache
+    from ray_tpu.models.paged_generation import _layer_with_cache
     from ray_tpu.models.llama import LlamaConfig, _layer_init
     from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies
 

@@ -3,17 +3,11 @@
 import numpy as np
 import pytest
 
-# JAX-compile-heavy tier: deselect with -m 'not slow' for fast runs
-pytestmark = pytest.mark.slow
-
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.generation import (
-    SamplingParams,
-    generate,
-    init_kv_cache,
-)
+from ray_tpu.llm import SamplingParams
+from ray_tpu.models.generation import generate, init_kv_cache
 from ray_tpu.models.llama import LlamaConfig, llama_apply, llama_init
 
 
@@ -37,34 +31,6 @@ def test_cached_greedy_matches_full_forward(tiny_model):
             logits = llama_apply(params, jnp.asarray([toks]), cfg)
             assert int(jnp.argmax(logits[0, -1])) == expected
             toks.append(expected)
-
-
-def test_speculative_matches_greedy(tiny_model):
-    """Prompt-lookup speculative decoding must reproduce greedy output
-    exactly (the acceptance rule only keeps argmax-agreeing tokens).
-    Repetitive prompts make the n-gram drafter actually fire; a ragged
-    non-repetitive prompt exercises the empty-draft decode fallback."""
-    cfg, params = tiny_model
-    prompts = [[5, 9, 5, 9, 5, 9], [7, 1, 2, 8, 4], [3, 4, 3, 4, 3]]
-    sp = SamplingParams(temperature=0.0, max_tokens=10)
-    greedy = generate(params, cfg, prompts, sp)
-    for k in (2, 4):
-        spec = generate(params, cfg, prompts, sp, speculative=k)
-        assert spec == greedy
-    # stop tokens must truncate identically: reuse a token greedy produced
-    stop = greedy[0][len(greedy[0]) // 2] if greedy[0] else 0
-    sp_stop = SamplingParams(temperature=0.0, max_tokens=10,
-                             stop_token_id=stop)
-    assert (generate(params, cfg, prompts, sp_stop, speculative=3)
-            == generate(params, cfg, prompts, sp_stop))
-
-
-def test_speculative_requires_greedy(tiny_model):
-    cfg, params = tiny_model
-    with pytest.raises(ValueError, match="greedy"):
-        generate(params, cfg, [[1, 2, 3]],
-                 SamplingParams(temperature=0.5, max_tokens=4),
-                 speculative=2)
 
 
 def test_sampling_params(tiny_model):
@@ -102,70 +68,6 @@ def test_engine_continuous_batching():
     # different prompts diverge (the engine isn't collapsing lanes)
     assert outs[0].token_ids != outs[1].token_ids or \
         outs[1].token_ids != outs[2].token_ids
-
-
-class _TickClock:
-    """Deterministic bandit clock: every read advances one tick, so each
-    arm's measured elapsed is exactly 1 unit and per-arm tokens/s is a
-    pure function of the WORKLOAD (tokens yielded per pass) — a loaded
-    box's scheduling stalls can't flip the win-arm decision."""
-
-    def __init__(self):
-        self.t = 0
-
-    def __call__(self):
-        self.t += 1
-        return self.t
-
-
-def test_engine_speculative_matches_plain():
-    """Paged prompt-lookup speculative decoding (spec_tokens=G) must be
-    token-EXACT vs the plain engine: greedy acceptance only keeps tokens
-    argmax would have produced.  Repetitive prompts make the drafter
-    fire; a non-repetitive one rides the verify pass with an empty
-    proposal (bonus token only) instead of vetoing the whole batch."""
-    import jax.numpy as jnp
-
-    from ray_tpu.llm import LLMEngine
-    from ray_tpu.models.llama import llama_init
-
-    cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32)
-    params = llama_init(jax.random.PRNGKey(0), cfg)
-    sp = SamplingParams(temperature=0.0, max_tokens=24)
-    prompts = [[5, 9, 5, 9, 5, 9], [7, 1, 2, 8, 4], [3, 4, 3, 4, 3, 4]]
-    plain = LLMEngine(cfg, params, batch_slots=4, max_len=96)
-    ref = plain.generate(prompts, sp)
-    # window=1 so the spec check runs every token; with the fixed seed
-    # the tiny model cycles quickly, so the n-gram drafter fires.  The
-    # injected tick clock makes the bandit's arm timings workload-pure
-    # (verify yields >= 1 token per tick, same as the 1-token window),
-    # so the run is deterministic on any machine.
-    spec = LLMEngine(cfg, params, batch_slots=4, max_len=96,
-                     spec_tokens=4, decode_window=1,
-                     arm_clock=_TickClock())
-    got = spec.generate(prompts, sp)
-    for a, b in zip(ref, got):
-        assert a.token_ids == b.token_ids, (a.token_ids, b.token_ids)
-    # the verify path actually ran and proposed drafts
-    assert spec.spec_stats["verify_steps"] > 0
-    assert spec.spec_stats["proposed"] > 0
-
-
-def test_engine_speculative_sampling_falls_back():
-    """A batch with any sampling (temp>0) slot must skip speculation —
-    greedy acceptance would skew its distribution — and still finish."""
-    import jax.numpy as jnp
-
-    from ray_tpu.llm import LLMEngine
-    from ray_tpu.models.llama import llama_init
-
-    cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32)
-    params = llama_init(jax.random.PRNGKey(0), cfg)
-    eng = LLMEngine(cfg, params, batch_slots=2, max_len=64, spec_tokens=4)
-    outs = eng.generate([[5, 9, 5, 9, 5, 9]],
-                        SamplingParams(temperature=0.8, max_tokens=6))
-    assert len(outs[0].token_ids) == 6
-    assert eng.spec_stats["verify_steps"] == 0
 
 
 def test_engine_chunked_prefill_matches():
@@ -450,12 +352,11 @@ def test_paged_kernel_engine_matches_full_recompute(tiny_model, monkeypatch):
     ("tpu", {}, "paged_kernel"),
     ("tpu", {"kv_cache_dtype": "int8"}, "gather"),
     ("tpu", {"mesh": "tp1"}, "gather"),
-    ("tpu", {"spec_tokens": 2}, "gather"),
-], ids=["cpu_backend", "tpu_dense", "int8_pool", "mesh", "spec_tokens"])
+], ids=["cpu_backend", "tpu_dense", "int8_pool", "mesh"])
 def test_engine_reads_its_attention_path_off_its_input(monkeypatch, backend,
                                                        kwargs, want):
-    """Who takes the kernel is decided by the pool's keys, the mesh,
-    speculation and the backend, with no argument of its own, and
+    """Who takes the kernel is decided by the pool's keys, the mesh and
+    the backend, with no argument of its own, and
     ``stats()`` reports it.  (Nothing is run: on this CPU a "tpu" backend
     is only what the predicate is told.)"""
     from ray_tpu.llm import LLMEngine
@@ -763,60 +664,6 @@ def test_tp2_engine_int8_kv_matches_single_device():
     assert ref[0].token_ids == got[0].token_ids
 
 
-def test_engine_speculative_win_arm_beats_window():
-    """VERDICT r4 weak #7: the regime speculative decoding EXISTS for —
-    decode_window <= G+1 with high acceptance — exercised for real.  A
-    plain run first discovers the model's greedy steady loop; using that
-    loop as the prompt makes prompt-lookup drafts accept from the first
-    step, so the bandit must KEEP the verify arm on (zero rests) and its
-    own throughput measurement must show verify beating the window arm."""
-    from ray_tpu.llm import LLMEngine
-
-    cfg = LlamaConfig.tiny(num_layers=2, dtype=jnp.float32)
-    params = llama_init(jax.random.PRNGKey(0), cfg)
-
-    # phase 1: drive the model INTO its greedy steady loop and keep the
-    # WHOLE converged trajectory as the phase-2 prompt.  (Truncating to
-    # the trailing cycle changes the model state — a fresh context of
-    # just the loop tokens continues differently — which is why the old
-    # tail-only prompt mispredicted and made this test flaky.)
-    warm = LLMEngine(cfg, params, batch_slots=1, max_len=512)
-    warm_out = warm.generate([[5, 6, 7, 8]],
-                             SamplingParams(temperature=0.0,
-                                            max_tokens=400))[0]
-    tail = [5, 6, 7, 8] + warm_out.token_ids
-
-    # phase 2: decode_window=1 <= G+1=5 — every window sync yields 1
-    # token, a high-acceptance verify yields up to 5.  The bandit runs
-    # on the injected tick clock, so its per-arm tokens/s is tokens per
-    # PASS — a pure function of the seeded workload, identical on every
-    # machine (the old wall-clock timings flipped under load).
-    eng = LLMEngine(cfg, params, batch_slots=1, max_len=1024,
-                    spec_tokens=4, decode_window=1,
-                    arm_clock=_TickClock())
-    out = eng.generate([list(tail)],
-                       SamplingParams(temperature=0.0,
-                                      max_tokens=300))[0]
-    assert len(out.token_ids) == 300
-    st = eng.spec_stats
-    acc = st["accepted"] / max(1, st["proposed"])
-    v = eng._arm_tps.get("verify")
-    w = eng._arm_tps.get(("window", 1))
-    assert st["verify_steps"] >= 40, st
-    assert acc >= 0.8, f"steady-loop workload should accept: {acc} ({st})"
-    # the bandit kept the win arm on: a rest would mean it judged the
-    # window faster (or acceptance collapsed)
-    assert st["backoffs"] == 0, st
-    # and its own per-arm throughput EMAs agree: verify > window
-    assert v is not None and w is not None, eng._arm_tps
-    assert v > w, f"verify arm must beat the 1-token window: {eng._arm_tps}"
-    # token-exactness vs the plain engine on the same workload
-    plain = LLMEngine(cfg, params, batch_slots=1, max_len=1024)
-    ref = plain.generate([list(tail)],
-                         SamplingParams(temperature=0.0, max_tokens=300))[0]
-    assert out.token_ids == ref.token_ids
-
-
 def test_llm_server_coalesces_concurrent_requests():
     """Admission settle (round 5): concurrent requests dribbling into the
     serving loop must coalesce into shared decode batches instead of the
@@ -862,7 +709,7 @@ def test_llm_server_settle_deferral_bounded():
     import time as time_mod
 
     from ray_tpu.llm.serving import LLMServer
-    from ray_tpu.models.generation import SamplingParams
+    from ray_tpu.llm import SamplingParams
 
     cls = LLMServer._target  # undecorated class
     srv = cls({"model": "tiny", "batch_slots": 8, "max_len": 128}, 1)

@@ -426,7 +426,7 @@ def _drain(eng, collect=None):
 @pytest.mark.slow
 class TestEngineHandoff:
     def test_export_adopt_parity_with_colocated(self):
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.llm import SamplingParams
 
         ref_eng, pre, dec = _tiny_engines(3)
         rng = np.random.default_rng(0)
@@ -455,7 +455,7 @@ class TestEngineHandoff:
         """Mutate the prefill pool AFTER export (more traffic) and the
         decode pool AFTER adopt — the other side's outputs must not
         change (the gather/scatter produce owned buffers)."""
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.llm import SamplingParams
 
         ref_eng, pre, dec = _tiny_engines(3)
         rng = np.random.default_rng(1)
@@ -477,7 +477,7 @@ class TestEngineHandoff:
     def test_int8_kv_ship_round_trip(self):
         """int8 pools ship values AND scales; parity vs an int8
         colocated engine on the CPU backend (satellite)."""
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.llm import SamplingParams
 
         ref_eng, pre, dec = _tiny_engines(3, kv_cache_dtype="int8")
         rng = np.random.default_rng(2)
@@ -498,7 +498,7 @@ class TestEngineHandoff:
             assert res[d].token_ids == r.token_ids
 
     def test_kv_dtype_mismatch_rejected(self):
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.llm import SamplingParams
 
         pre, dec = _tiny_engines(2)
         dec_int8 = _tiny_engines(1, kv_cache_dtype="int8")[0]
@@ -518,7 +518,7 @@ class TestEngineHandoff:
         import jax
 
         from ray_tpu.llm.engine import LLMEngine
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.llm import SamplingParams
         from ray_tpu.models.llama import LlamaConfig, llama_init
 
         cfg = LlamaConfig.tiny()
@@ -540,7 +540,7 @@ class TestEngineHandoff:
         import jax
 
         from ray_tpu.llm.engine import LLMEngine
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.llm import SamplingParams
         from ray_tpu.models.llama import LlamaConfig, llama_init
 
         cfg = LlamaConfig.tiny()
@@ -561,7 +561,7 @@ class TestEngineHandoff:
     def test_adopted_prefix_serves_local_prefix_hits(self):
         """Grafted chain keys make the SHIPPED prefix hit for future
         local prompts — the prefix cache composes across the handoff."""
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.llm import SamplingParams
 
         pre, dec = _tiny_engines(2)
         rng = np.random.default_rng(3)
@@ -581,7 +581,7 @@ class TestEngineHandoff:
         assert did is not None
 
     def test_abort_releases_export_and_adopt_queue(self):
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.llm import SamplingParams
 
         pre, dec = _tiny_engines(2)
         sp = SamplingParams(temperature=0.0, max_tokens=8)
@@ -612,7 +612,7 @@ class TestChunkedPrefillAccounting:
         """Satellite audit: ``Request.blocks`` / ``chunk_blocks`` refs
         are HELD across admissions — an abort between chunks must
         release them so the LRU can evict every block again."""
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.llm import SamplingParams
 
         (eng,) = _tiny_engines(1, prefill_chunk=16)
         sp = SamplingParams(temperature=0.0, max_tokens=8)
@@ -640,7 +640,7 @@ class TestChunkedPrefillAccounting:
         """After an abort between chunks, unrelated requests admit and
         complete with correct accounting (no phantom refs starving the
         pool)."""
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.llm import SamplingParams
 
         ref_eng, eng = _tiny_engines(2, prefill_chunk=16)
         sp = SamplingParams(temperature=0.0, max_tokens=12)
@@ -663,7 +663,7 @@ class TestChunkedPrefillAccounting:
         import jax
 
         from ray_tpu.llm.engine import LLMEngine
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.llm import SamplingParams
         from ray_tpu.models.llama import LlamaConfig, llama_init
 
         cfg = LlamaConfig.tiny()
@@ -691,7 +691,7 @@ class TestChunkedPrefillAccounting:
         """Satellite: a second prompt sharing the first's prefix re-hits
         the chunked prefill's registered blocks — admissions after
         chunking keep the prefix cache warm."""
-        from ray_tpu.models.generation import SamplingParams
+        from ray_tpu.llm import SamplingParams
 
         ref_eng, eng = _tiny_engines(2, prefill_chunk=16)
         rng = np.random.default_rng(7)

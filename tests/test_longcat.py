@@ -40,7 +40,7 @@ import pytest
 
 from cells.families import longcat_flash_reference as reference
 from ray_tpu.llm.engine import LLMEngine
-from ray_tpu.models.generation import SamplingParams
+from ray_tpu.llm import SamplingParams
 from ray_tpu.models import longcat
 from ray_tpu.models.longcat import (LongcatConfig, _moe, absorbed_pair,
                                     gather_latent_prefix, init_latent_pool,
@@ -284,7 +284,6 @@ def test_decode_attention_path_reads_the_pool(monkeypatch):
     assert decode_attention_path(real) == "latent_kernel"
     assert decode_attention_path(dense) == "paged_kernel"
     assert decode_attention_path(tiny) == "gather"  # 8 rows a page
-    assert decode_attention_path(real, spec_tokens=2) == "gather"
     assert decode_attention_path(real, mesh=object()) == "gather"
 
 
@@ -330,8 +329,6 @@ def test_unsupported_options_raise():
     kw = dict(tokenizer=_Ids(), batch_slots=2, max_len=32, block_size=8)
     with pytest.raises(ValueError, match="kv_dtype"):
         LLMEngine(cfg, kv_cache_dtype="int8", **kw)
-    with pytest.raises(NotImplementedError, match="verify"):
-        LLMEngine(cfg, spec_tokens=2, **kw)
     with pytest.raises(NotImplementedError, match="mesh"):
         LLMEngine(cfg, mesh=object(), **kw)
     eng = LLMEngine(cfg, **kw)

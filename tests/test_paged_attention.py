@@ -2,9 +2,9 @@
 
 CPU, through the Pallas interpreter, at small shapes: the same pool, block
 tables and lengths go through ``ops/pallas/paged_attention.py`` and through
-``paged_generation._gather_kv`` + ``generation._gqa_attend`` (the plain
-reference, and still the path of int8 pools, meshes, speculation and every
-backend but TPU).
+``paged_generation._gather_kv`` + ``paged_generation._gqa_attend`` (the
+plain reference, and still the path of int8 pools, meshes and every backend
+but TPU).
 
 Tolerance.  With a float32 pool both sides multiply exact float32 values
 and differ only in the order of their float32 sums (online softmax a block
@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models.generation import _gqa_attend
+from ray_tpu.models.paged_generation import _gqa_attend
 from ray_tpu.models.paged_generation import _gather_kv
 from ray_tpu.ops.attention import sliding_window_mask
 from ray_tpu.ops.pallas.paged_attention import paged_attention

@@ -40,7 +40,7 @@ from cells.families import phi4flash_reference as reference
 from ray_tpu._private import tracing
 from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.models import phi4flash as pf
-from ray_tpu.models.generation import SamplingParams
+from ray_tpu.llm import SamplingParams
 from ray_tpu.models.served import preset, served_model
 from ray_tpu.ops import ssm
 from ray_tpu.ops.attention import reference_attention
@@ -610,7 +610,6 @@ def test_no_record_left_fails_the_request_by_name_not_the_batch():
 
 @pytest.mark.parametrize("kwargs,match", [
     (dict(prefill_chunk=16), "prefill_chunk"),
-    (dict(spec_tokens=2), "verify"),
     (dict(mesh=object()), "mesh"),
     (dict(kv_cache_dtype="int8"), "kv_dtype"),
 ])

@@ -37,7 +37,7 @@ import pytest
 from cells.families import smallthinker_reference as reference
 from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.models import smallthinker as st
-from ray_tpu.models.generation import SamplingParams
+from ray_tpu.llm import SamplingParams
 from ray_tpu.models.served import preset, served_model
 from ray_tpu.ops.attention import reference_attention
 from ray_tpu.ops.experts import held_experts_ffn, reglu, route_top_k
@@ -351,9 +351,6 @@ def test_a_model_with_a_window_type_refuses_what_it_does_not_take():
     with pytest.raises(NotImplementedError, match="prefill_chunk"):
         LLMEngine(cfg, tokenizer=_Ids(), max_len=64, block_size=4,
                   prefill_chunk=16)
-    with pytest.raises(NotImplementedError, match="verify"):
-        LLMEngine(cfg, tokenizer=_Ids(), max_len=64, block_size=4,
-                  spec_tokens=2)
     _, eng = _engine()
     prompt = list(range(40))
     sp = SamplingParams(max_tokens=4, temperature=0.0, stop_token_id=None)
