@@ -145,7 +145,7 @@ def decode_attention_path(pool, *, mesh=None) -> str:
     * a dense pool ``{"k", "v": [L, NB, bs, KVH, hd]}`` on one TPU device
       -> ``"paged_kernel"`` (the dense arm: two pools of equal head
       width, ``hd ** -0.5``);
-    * a latent pool ``{"kv": [A, NB, bs, W]}`` (``models/longcat.py``: one
+    * a latent pool ``{"kv": [A, NB, bs, W]}`` (``models/mla.py``: one
       row a token that all heads share) on one TPU device ->
       ``"latent_kernel"`` (the latent arm: one pool, the values its
       leading columns, the caller's scale);

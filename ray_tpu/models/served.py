@@ -148,6 +148,27 @@ def _longcat() -> ServedModel:
         counters=("moe_pairs_held", "moe_experts_hit", "moe_zero_picks"))
 
 
+def _deepseek_v3() -> ServedModel:
+    from ray_tpu.models import deepseek_v3 as ds
+    from ray_tpu.models.paged_generation import decode_attention_path
+
+    return ServedModel(
+        name="deepseek_v3", init=ds.deepseek_v3_init,
+        init_pool=ds.init_latent_pool, prefill_suffix=ds.prefill_suffix,
+        gather_prefix=ds.gather_latent_prefix,
+        decode_sample=ds.decode_sample,
+        decode_attention_path=decode_attention_path,
+        presets={"deepseek_v3_tiny": ds.DeepseekV3Config.tiny,
+                 "gigachat3_1_702b": ds.DeepseekV3Config},
+        test_presets=("deepseek_v3_tiny",),
+        expert_path=_expert_path,
+        prefill_attention_path=lambda cfg, bucket, prefix:
+            ds.prefill_attention_path(bucket, prefix),
+        # LongCat's three (0 picks of a zero-compute expert: it has none)
+        # and the tokens whose kept groups reach this chip's experts
+        counters=ds.COUNTERS)
+
+
 def _smallthinker() -> ServedModel:
     from ray_tpu.models import smallthinker as st
 
@@ -185,6 +206,7 @@ def _phi4flash() -> ServedModel:
 # modules are imported when it is first asked for, so that a process which
 # serves one model loads one model
 _MODELS = {"LlamaConfig": _llama, "LongcatConfig": _longcat,
+           "DeepseekV3Config": _deepseek_v3,
            "SmallThinkerConfig": _smallthinker,
            "Phi4FlashConfig": _phi4flash}
 

@@ -203,7 +203,7 @@ def attention_impl(seq_q: int, mesh: Optional[Mesh] = None,
     queries: 'ring' when the mesh shards the sequence (sp > 1), the Pallas
     flash kernel on a TPU from 256 queries on, the reference elsewhere (the
     CPU's test meshes).  Read by callers that have a path of their own to
-    fall back to (``models/longcat.py``)."""
+    fall back to (``models/mla.py``)."""
     if (mesh is not None and sp_axis in mesh.axis_names
             and mesh.shape[sp_axis] > 1):
         return "ring"

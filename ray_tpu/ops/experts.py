@@ -5,9 +5,10 @@ experts.  The router scores all of them; the chip computes what ITS experts
 add for the tokens routed to them, and what the absent experts would add is
 somebody else's part of the sum (on one chip of a cut deployment: left out).
 
-``route_top_k`` is the router (softmax scores, an additive selection bias
-that picks but does not weigh, the picked weights renormalised where the
-model says so).  ``held_experts_ffn`` is the dispatch: **sorted**, never a
+``route_top_k`` is the router (softmax or sigmoid scores, an additive
+selection bias that picks but does not weigh, picks limited to the best
+groups of experts and the picked weights renormalised where the model says
+so).  ``held_experts_ffn`` is the dispatch: **sorted**, never a
 dense all-experts product, no capacity and no dropped token.
 
 Shared by both of its paths: every (token, pick) pair gets a key, its
@@ -56,24 +57,42 @@ from ray_tpu.ops.layers import swiglu
 
 
 def route_top_k(y, w_router, bias, k: int, scale: float,
-                renormalise: bool = False):
+                renormalise: bool = False, score: str = "softmax",
+                groups: tuple[int, int] | None = None):
     """y ``[T, H]``, w_router ``[H, N]``, bias ``[N]`` or None -> (idx
-    ``[T, k]`` int32, weight ``[T, k]`` float32).
+    ``[T, k]`` int32, weight ``[T, k]`` float32), and with ``groups`` a
+    third: kept ``[T, n_group]`` bool, the groups a token's picks came from.
 
-    ``p = softmax(y W_r)`` in float32 over all N outputs; the k largest of
-    ``p + bias`` are picked; a pick's weight is its own ``p`` (no bias),
-    divided by the sum over the k picked where ``renormalise``
-    (``norm_topk_prob``), times ``scale``.  The product runs at the highest
+    ``p = softmax(y W_r)`` in float32 over all N outputs (``score``
+    ``"sigmoid"``: each output's own sigmoid); the k largest of ``p + bias``
+    are picked; a pick's weight is its own ``p`` (no bias), divided by the
+    sum over the k picked where ``renormalise`` (``norm_topk_prob``), times
+    ``scale``.  ``groups = (n_group, topk_group)`` limits the picks to
+    groups (DeepSeek-V3's ``noaux_tc``): the N outputs are ``n_group`` runs
+    of consecutive experts, a group's score is the sum of its two largest
+    ``p + bias``, the ``topk_group`` best groups stay and the others'
+    ``p + bias`` are set to ``-inf`` before the k are picked (what bounds
+    the chips a token travels to).  The product runs at the highest
     precision: a pick is a discrete choice, and N is small."""
     logits = jnp.matmul(y.astype(jnp.float32), w_router.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
-    p = jax.nn.softmax(logits, axis=-1)
-    _, idx = lax.top_k(p if bias is None else p + bias.astype(jnp.float32),
-                       k)
+    p = jax.nn.sigmoid(logits) if score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    choice = p if bias is None else p + bias.astype(jnp.float32)
+    if groups is not None:
+        n_group, topk_group = groups
+        by_group = choice.reshape(choice.shape[0], n_group, -1)
+        group_score = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)
+        _, best = lax.top_k(group_score, topk_group)
+        kept = jnp.any(best[:, :, None] == jnp.arange(n_group), axis=1)
+        choice = jnp.where(kept[:, :, None], by_group,
+                           -jnp.inf).reshape(choice.shape)
+    _, idx = lax.top_k(choice, k)
     weight = jnp.take_along_axis(p, idx, axis=-1)
     if renormalise:
         weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
-    return idx.astype(jnp.int32), weight * scale
+    out = (idx.astype(jnp.int32), weight * scale)
+    return out if groups is None else (*out, kept)
 
 
 def default_chunk(pairs: int) -> int:

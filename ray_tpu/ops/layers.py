@@ -7,6 +7,7 @@ cast back, the standard TPU-stability recipe for bf16 activations.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -32,6 +33,51 @@ def rope_frequencies(
     t = jnp.arange(max_seq_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)
     return jnp.cos(freqs), jnp.sin(freqs)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature for a context stretched ``factor``
+    times: ``0.1 * mscale * ln(factor) + 1`` (1 where nothing is
+    stretched)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_correction_range(head_dim: int, theta: float, original_max_len: int,
+                          beta_fast: float, beta_slow: float
+                          ) -> Tuple[int, int]:
+    """The rotary pairs between which YaRN blends: pair ``i`` turns
+    ``original_max_len * theta^(-2i/d) / 2pi`` times over the original
+    context; pairs below ``low`` (more than ``beta_fast`` turns) keep their
+    frequency, pairs above ``high`` (fewer than ``beta_slow``) are
+    interpolated."""
+    def pair(turns):
+        return (head_dim * math.log(original_max_len / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(pair(beta_fast)), 0),
+            min(math.ceil(pair(beta_slow)), head_dim - 1))
+
+
+def yarn_rope_frequencies(
+    head_dim: int, max_seq_len: int, theta: float, *, factor: float,
+    original_max_len: int, beta_fast: float = 32.0, beta_slow: float = 1.0,
+    mscale: float = 1.0, mscale_all_dim: float = 0.0,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``rope_frequencies`` under YaRN (``rope_scaling`` of type ``yarn`` as
+    DeepSeek-V3's family publishes it): pair ``i`` keeps ``f_i =
+    theta^(-2i/d)`` below the correction range, takes ``f_i / factor``
+    above it and a linear blend inside; cos and sin are scaled by
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``.
+    The table acts at every position, not only past ``original_max_len``."""
+    low, high = yarn_correction_range(head_dim, theta, original_max_len,
+                                      beta_fast, beta_slow)
+    i = jnp.arange(head_dim // 2, dtype=jnp.float32)
+    freq = 1.0 / theta ** (2 * i / head_dim)
+    ramp = jnp.clip((i - low) / max(high - low, 0.001), 0.0, 1.0)
+    inv_freq = freq * (1 - ramp) + freq / factor * ramp
+    t = jnp.arange(max_seq_len, dtype=jnp.float32)
+    freqs = jnp.outer(t, inv_freq)
+    m = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+    return jnp.cos(freqs) * m, jnp.sin(freqs) * m
 
 
 def apply_rope(

@@ -32,7 +32,7 @@ Two arms of the one kernel, picked by what the caller hands it:
   of latent rows, one row a token that all H heads share.  A page is copied
   once; the keys are its whole width, the values its first ``value_width``
   columns (a lane-aligned slice of what is already in VMEM), and the scale
-  is the caller's: absorbed latent attention (``models/longcat.py``), whose
+  is the caller's: absorbed latent attention (``models/mla.py``), whose
   softmax scale belongs to the up-projected head, not to the row's width.
 """
 

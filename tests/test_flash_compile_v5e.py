@@ -6,7 +6,8 @@ function (PR 38) and Phi-4-mini-flash's decode step writing a token's
 slab into its page as one update (PR 40), LongCat-Flash's uncached
 prefill through the flash forward at 192-wide keys beside 128-wide values
 (PR 43) and its decode step reading the absorbed pair in place (PR 45),
-compiled for a v5e that is described, not attached.
+GigaChat3.1's flash forward at 192 beside 192 and its decode step on both
+kernels (PR 47), compiled for a v5e that is described, not attached.
 
 The interpreter cannot see what the chip's compiler refuses: more VMEM
 than a kernel may use (the backward keeps dK/dV whole in scratch and sets
@@ -143,6 +144,24 @@ def test_the_forward_compiles_at_192_wide_keys_beside_128_wide_values(
     assert _mosaic_calls(text) == [
         2 * [f"bf16[64,{s},192]"] + [f"bf16[64,{s},128]"]]
     assert re.search(rf"= \(bf16\[64,{s},128\][^=]*custom-call\(", text)
+
+
+@pytest.mark.parametrize("s", [2048, 256])
+def test_the_forward_compiles_at_192_wide_keys_beside_192_wide_values(
+        one_chip, monkeypatch, s):
+    """GigaChat3.1's widths (64 heads, keys ``qk_nope + qk_rope`` = 192,
+    values ``v_head_dim`` = 192, the YaRN scale 0.144680 and not
+    ``192^-0.5``), bf16, the longest bucket and the shortest the rule gives
+    the kernel: one Mosaic call, every operand and the output 192 wide as
+    they stand."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = jax.ShapeDtypeStruct((1, s, 64, 192), jnp.bfloat16,
+                                 sharding=one_chip)
+    text = jax.jit(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, scale=0.144680)).lower(
+            shape, shape, shape).compile().as_text()
+    assert _mosaic_calls(text) == [3 * [f"bf16[64,{s},192]"]]
+    assert re.search(rf"= \(bf16\[64,{s},192\][^=]*custom-call\(", text)
 
 
 def test_longcat_uncached_prefill_attends_through_the_flash_kernel(
@@ -486,6 +505,52 @@ def test_longcat_decode_step_reads_the_absorbed_pair_in_place(longcat_decode):
     # the products read the parameters themselves (or XLA's own fetch of
     # one in the parameter's layout): nothing of w_kvb is an operand
     assert "w_uk" in text and "w_uv" in text and "w_kvb" not in text
+
+
+def test_gigachat_decode_step_runs_both_kernels_and_names_its_parts(
+        one_chip, monkeypatch):
+    """GigaChat3.1-702B-A36B's decode step at the cell's widths (one
+    leading dense layer and one expert layer of its six, 16 held experts,
+    16 032 rows, 128 slots of 320 blocks): the latent arm of the paged
+    kernel a layer over a pool of ``L`` rows (values 192 wide leave the
+    cached row what it is: the kernel's result is ``[slots, heads, 512]``),
+    the expert decode kernel at ``H`` 7168, and after XLA:TPU's fusion at
+    least 95% of the instructions a trace will time carry ``engine.decode``
+    and a part of the vocabulary; the shared expert's products carry
+    ``experts.shared`` inside ``experts``."""
+    from ray_tpu.models import deepseek_v3 as ds
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = ds.DeepseekV3Config(
+        vocab_size=16032, num_layers=2, dense_layers=1, first_expert=80,
+        held_experts=16, max_seq_len=5120, param_dtype=jnp.bfloat16)
+    B, bs, MB = 128, 16, 320
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    params = jax.eval_shape(
+        functools.partial(ds.deepseek_v3_init, cfg=cfg), key)
+    pool = jax.eval_shape(lambda: ds.init_latent_pool(cfg, 512, bs))
+    assert pool["kv"].shape == (2, 512, bs, 640)
+    assert served_expert_path(cfg, B) == "decode_kernel"
+    text = _compile_decode(
+        functools.partial(ds.decode_sample, cfg=cfg, attn="latent_kernel"),
+        params, pool, B, MB, one_chip)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    latent = re.findall(r"= bf16\[128,64,512\][^=]*custom-call\(", text)
+    assert len(latent) == 2  # a layer
+    assert any("bf16[16,7168,2048]" in " ".join(c)
+               for c in _mosaic_calls(text))  # the held experts, in place
+    counted, bare = _unscoped(text)
+    assert counted >= 60 and len(bare) <= 0.05 * counted, bare
+    assert re.search(r'op_name="[^"]*/experts/experts\.shared/[^"]*dot_general',
+                     text)
+    assert "w_kvb" not in text  # the decode step reads the derived pair
+
+
+def served_expert_path(cfg, tokens):
+    from ray_tpu.models.served import served_model
+
+    return served_model(cfg).expert_path(cfg, tokens)
 
 
 def test_smallthinker_decode_step_names_its_parts(one_chip, monkeypatch):
