@@ -7,10 +7,32 @@ layers of a model share ONE traced and lowered kernel: lowering a Pallas
 kernel is Python work no compile cache saves) and a page is addressed as
 ``pool[layer, block_tables[s, p]]``, ``bs * KVH * hd`` contiguous elements.
 Per slot only pages ``p < ceil(length / bs)`` are copied (and, under a
-sliding window, none that lie wholly before it), ``pages_per_block`` of them
-a compute block, double-buffered: while a block is attended the next one
-(the same slot's, or the first block of the next slot that holds anything)
-is already in flight.  A slot with length 0 copies nothing and returns zeros.
+sliding window, none that lie wholly before it), ``pages`` of them a compute
+block.  A slot with length 0 copies nothing and returns zeros.
+
+The page copies are a pipeline ``depth`` buffers a pool deep.  The kernel
+takes a call's live compute blocks as ONE sequence over all slots (a slot's
+blocks from the first its window leaves to its last, then those of the next
+slot that holds anything) and a *cursor* walks that sequence ``depth - 1``
+blocks ahead of the block being attended: the first grid step starts the
+copies of the sequence's first ``depth - 1`` blocks, and every block, once
+its own copies have been waited for, starts those of the block the cursor
+is at, into the buffer attended just before, and moves the cursor on.  So
+``depth - 1`` blocks' copies are on their way while one is attended,
+whichever slots they belong to: a slot one block long (most of a windowed
+layer's and of the latent cells' slots hold one to six) gives the pipeline
+no reason to drain.  Empty slots cost the cursor one read of a table of
+links (``_init``).  At the sequence's end the cursor stops, so every copy
+started is waited for exactly once, by the block it belongs to, and none is
+in flight when the last grid step returns.  Between grid steps the cursor
+lives in SMEM, inside a slot's loop it is carried.
+
+Who chooses: ``pipeline_plan``, a static function of what ``_call`` sees (a
+page's bytes, the number of pools, the table's width) and of no option, so
+a program's depth is a constant of that program.  ``_init`` zeroes all
+``depth`` buffers once: a page a block does not copy (past the length,
+before the window) keeps what its buffer held before, zeros and then older
+pages, which is finite, so its masked product stays 0.
 
 A page's rows are ``(token, kv head)`` pairs, so a block of P pages is a
 ``[P * bs * KVH, hd]`` key matrix.  All H query heads are multiplied against
@@ -50,6 +72,11 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
+# words of the kernel's SMEM state before its table of links: the cursor
+# (slot, block, buffer) of the next compute block whose copies start
+_CURSOR = 3
+
+
 def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, *refs,
             window, pages, block_size, max_blocks, scale, value_width=None):
     if value_width is None:  # dense arm: a pool of keys and one of values
@@ -61,17 +88,9 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, *refs,
     s = pl.program_id(0)
     layer = layer_ref[0]
     nslots = pl.num_programs(0)
+    depth = kbuf.shape[0]  # buffers a pool
     rows = kbuf.shape[1] * kbuf.shape[2]  # (token, kv head) pairs a block
     tokens = pages * block_size
-
-    @pl.when(s == 0)
-    def _init():
-        state[0] = 0  # buffer of the next slot's first block
-        state[1] = 0  # 1: that block's copies are already in flight
-        # pages a block does not copy keep what the buffer held before:
-        # finite (zeros, then older pages), so a masked 0 x stale stays 0
-        for _, buffer in pools:
-            buffer[...] = jnp.zeros_like(buffer)
 
     def first_token(length):
         return 0 if window is None else jnp.maximum(length - window, 0)
@@ -79,26 +98,109 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, *refs,
     def first_block(length):
         return 0 if window is None else lax.div(first_token(length), tokens)
 
-    def copies(slot, j, buf, act):
-        """start / wait the live pages of compute block j of ``slot``."""
-        length = len_ref[slot]
+    def end_block(length):
+        return lax.div(length + tokens - 1, tokens)
+
+    def length_of(slot):  # ``slot`` may be the sequence's end, ``nslots``
+        return len_ref[jnp.minimum(slot, nslots - 1)]
+
+    def next_buffer(buf):
+        return jnp.where(buf + 1 == depth, 0, buf + 1)
+
+    def live_pages(length, j):
+        """(first, number) of the pages of compute block j that a slot of
+        ``length`` positions reads: one run of the block's ``pages``."""
         n_pages = lax.div(length + block_size - 1, block_size)
         lo_page = (0 if window is None
                    else lax.div(first_token(length), block_size))
-        for p in range(pages):
-            page = j * pages + p
+        lo = jnp.clip(lo_page - j * pages, 0, pages)
+        return lo, jnp.clip(n_pages - j * pages, 0, pages) - lo
 
-            @pl.when((page >= lo_page) & (page < n_pages))
-            def _():
-                blk = tab_ref[slot * max_blocks + page]
-                for w, (hbm, buffer) in enumerate(pools):
-                    dma = pltpu.make_async_copy(
-                        hbm.at[layer, blk], buffer.at[buf, p],
-                        sems.at[w, buf])
-                    if act == "start":
-                        dma.start()
-                    else:
-                        dma.wait()
+    def start_copies(slot, length, j, buf):
+        """start the copies of block j of ``slot`` into buffer ``buf``.  A
+        block that is all live (most are) in one straight run at constant
+        offsets, any other in a loop over its live pages: a branch a page
+        costs the scalar core more than the copy."""
+        lo, n = live_pages(length, j)
+
+        def start(p):
+            blk = tab_ref[slot * max_blocks + j * pages + p]
+            for w, (hbm, buffer) in enumerate(pools):
+                pltpu.make_async_copy(hbm.at[layer, blk], buffer.at[buf, p],
+                                      sems.at[w, buf]).start()
+
+        @pl.when(n == pages)
+        def _():
+            for p in range(pages):
+                start(p)
+
+        @pl.when(n < pages)
+        def _():
+            lax.fori_loop(lo, lo + n, lambda p, _: start(p), None)
+
+    def wait_copies(length, j, buf):
+        """wait for what ``start_copies`` started for block j of a slot of
+        ``length`` positions.  A DMA semaphore counts bytes, so one wait
+        takes a run of pages: the block's live pages in power-of-two runs,
+        a wait a set bit of their number (one for a block all live)."""
+        _, n = live_pages(length, j)
+
+        def wait(size):
+            for w, (_, buffer) in enumerate(pools):
+                run = buffer.at[buf, pl.ds(0, size)]  # its bytes, not where
+                pltpu.make_async_copy(run, run, sems.at[w, buf]).wait()
+
+        size = 1 << (pages.bit_length() - 1)
+        while size:
+            pl.when(jnp.bitwise_and(n, size) != 0)(
+                functools.partial(wait, size))
+            size //= 2
+
+    def slot_after(slot):
+        """(slot, first block) of the first slot after ``slot`` that holds
+        anything; ``nslots`` is the sequence's end."""
+        slot = state[_CURSOR + slot + 1]
+        return slot, first_block(length_of(slot))
+
+    def produce(cursor):
+        """start the copies of the block the cursor ``(slot, block, buffer)``
+        is at and return the cursor moved on; at the sequence's end, where
+        it stays, nothing starts."""
+        slot, j, buf = cursor
+        more = slot < nslots
+        length = length_of(slot)
+
+        @pl.when(more)
+        def _():
+            start_copies(slot, length, j, buf)
+
+        slot, j = lax.cond(more & (j + 1 >= end_block(length)),
+                           lambda: slot_after(slot), lambda: (slot, j + 1))
+        return slot, j, next_buffer(buf)
+
+    @pl.when(s == 0)
+    def _init():
+        # pages a block does not copy keep what the buffer held before:
+        # finite (zeros, then older pages), so a masked 0 x stale stays 0
+        for _, buffer in pools:
+            buffer[...] = jnp.zeros_like(buffer)
+
+        # state[_CURSOR + i]: the first slot from slot i on that holds
+        # anything, so the cursor steps over empty slots in one read
+        def link(i, nxt):
+            slot = nslots - 1 - i
+            state[_CURSOR + slot + 1] = nxt
+            return jnp.where(len_ref[slot] > 0, slot, nxt)
+
+        state[_CURSOR] = lax.fori_loop(0, nslots, link, nslots)
+        # the sequence's first depth - 1 blocks, whichever slots they are
+        # of, go into buffers 0 .. depth - 2; each block attended then
+        # starts one more
+        zero = jnp.int32(0)
+        cursor = lax.fori_loop(0, depth - 1, lambda _, c: produce(c),
+                               (*slot_after(zero - 1), zero))
+        for i, word in enumerate(cursor):
+            state[i] = word
 
     length = len_ref[s]
 
@@ -108,43 +210,17 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, *refs,
 
     @pl.when(length > 0)
     def _attend():
-        j0 = first_block(length)
-        j1 = lax.div(length + tokens - 1, tokens)
-        buf0 = state[0]
-
-        @pl.when(state[1] == 0)
-        def _():
-            copies(s, j0, buf0, "start")
-
         q = q_ref[...]
         lo = first_token(length)
 
         def body(j, carry):
-            m, l, acc = carry
-            buf = jnp.bitwise_and(buf0 + (j - j0), 1)
-            nxt = 1 - buf
-
-            @pl.when(j + 1 < j1)
-            def _():
-                copies(s, j + 1, nxt, "start")
-
-            @pl.when(j + 1 == j1)
-            def _():
-                # the next slot that holds anything: its first block
-                # travels while this slot's last one is attended
-                ns = lax.fori_loop(
-                    s + 1, nslots,
-                    lambda i, n: jnp.where(
-                        (n == nslots) & (len_ref[i] > 0), i, n), nslots)
-
-                @pl.when(ns < nslots)
-                def _():
-                    copies(ns, first_block(len_ref[ns]), nxt, "start")
-
-                state[0] = nxt
-                state[1] = (ns < nslots).astype(jnp.int32)
-
-            copies(s, j, buf, "wait")
+            m, l, acc, cursor = carry
+            # the cursor's buffer is the one attended last; this block's is
+            # the next of the ring.  Once it has landed, the block
+            # depth - 1 ahead may go where the last one was
+            buf = next_buffer(cursor[2])
+            wait_copies(length, j, buf)
+            cursor = produce(cursor)
             k = kbuf[buf].reshape(rows, kbuf.shape[-1])
             v = (vbuf[buf].reshape(rows, vbuf.shape[-1])
                  if value_width is None else k[:, :value_width])
@@ -160,14 +236,17 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, *refs,
             acc = alpha * acc + lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            return m_new, l, acc
+            return m_new, l, acc, cursor
 
         H = q_ref.shape[0]
-        m, l, acc = lax.fori_loop(
-            j0, j1, body,
+        m, l, acc, cursor = lax.fori_loop(
+            first_block(length), end_block(length), body,
             (jnp.full((H, 1), _NEG_INF, jnp.float32),
              jnp.zeros((H, 1), jnp.float32),
-             jnp.zeros(o_ref.shape, jnp.float32)))
+             jnp.zeros(o_ref.shape, jnp.float32),
+             tuple(state[i] for i in range(_CURSOR))))
+        for i, word in enumerate(cursor):
+            state[i] = word
         o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
@@ -175,6 +254,44 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, *refs,
 # otherwise: 8 pages of 16 tokens x 8 KV heads.  [H, rows] float32 scores
 # are the kernel's largest value.
 _BLOCK_ROWS = 1024
+
+# Bytes of page copies to keep on their way while a block is attended: what
+# HBM delivers in a copy's start-to-done latency (819 GB/s x 1.3 us).
+# Measured alone on a v5e (PERF.md section 6, PR 49): Phi-4-mini-flash's
+# full layers, 0.47 MiB a block, take 749 us a call with one block in
+# flight and 460, 462, 462 with two, three and five.
+_IN_FLIGHT_BYTES = 1 << 20
+# VMEM the page buffers of all pools may take together.  A kernel is given
+# 16 MiB unless its call asks for more; the [H, rows] float32 scores, their
+# exponentials and the probabilities' copy are 0.6 MiB at 64 heads, q and
+# the output (twice each) 0.3 MiB, so a quarter of the 16 is far from the
+# limit.  A dense block of 1024 rows x 128 bf16 in two pools is 0.5 MiB, a
+# latent block of 1024 rows x 640 1.25 MiB: three buffers are 3.75 MiB.
+_PIPELINE_BYTES = 4 << 20
+# buffers a pool.  Under 3 nothing is in flight across a block's own wait;
+# past 8 a small block's look-ahead only lengthens the fill.
+_MIN_DEPTH, _MAX_DEPTH = 3, 8
+
+
+def pipeline_plan(page_rows: int, width: int, itemsize: int, n_pools: int,
+                  max_blocks: int) -> tuple[int, int]:
+    """``(pages, depth)`` of a call: pages a compute block, and buffers of
+    that size a pool that the copy pipeline runs through.  A static function
+    of the pools' page ``[page_rows, width]``, their number and the block
+    table's width, so a constant of each program.
+
+    ``pages``: as many as ``_BLOCK_ROWS`` rows hold, never more than a
+    table lists.  ``depth``: the block attended and as many behind it as
+    hold ``_IN_FLIGHT_BYTES`` (blocks of all pools; the look-ahead is
+    counted over all slots, so a table one block wide is no reason for a
+    shallower one), within ``_PIPELINE_BYTES`` of VMEM, from ``_MIN_DEPTH``
+    to ``_MAX_DEPTH``: 3 buffers at the cells' blocks of 0.5 and 1.25 MiB,
+    4 at Phi-4-mini-flash's 0.47."""
+    pages = min(max(1, _BLOCK_ROWS // page_rows), max_blocks)
+    block_bytes = n_pools * pages * page_rows * width * itemsize
+    depth = min(1 + -(-_IN_FLIGHT_BYTES // block_bytes),
+                _PIPELINE_BYTES // block_bytes, _MAX_DEPTH)
+    return pages, max(depth, _MIN_DEPTH)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths, *, layer,
@@ -240,8 +357,10 @@ def _call(kernel, q, pools, block_tables, lengths, layer, *, kv_heads,
     b, H, _ = q.shape
     _, _, page_rows, width = pools[0].shape
     MB = block_tables.shape[1]
-    P = pages_per_block or max(1, _BLOCK_ROWS // page_rows)
-    P = min(P, MB)
+    n = len(pools)
+    P, depth = pipeline_plan(page_rows, width, pools[0].dtype.itemsize, n, MB)
+    if pages_per_block:  # the tests' lever
+        P = min(pages_per_block, MB)
     rows = P * page_rows
     # constants of the program: which rows belong to a head's KV group,
     # and a row's position inside its block
@@ -250,7 +369,6 @@ def _call(kernel, q, pools, block_tables, lengths, layer, *, kv_heads,
         r % kv_heads == np.arange(H, dtype=np.int32)[:, None]
         // (H // kv_heads), 0.0, _NEG_INF).astype(np.float32)  # [H, rows]
     tok = r // kv_heads  # [1, rows]
-    n = len(pools)
     return pl.pallas_call(
         functools.partial(kernel, pages=P, max_blocks=MB),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -264,11 +382,11 @@ def _call(kernel, q, pools, block_tables, lengths, layer, *, kv_heads,
             out_specs=pl.BlockSpec((None, H, out_width),
                                    lambda s, *_: (s, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, P, page_rows, width), pool.dtype)
+                pltpu.VMEM((depth, P, page_rows, width), pool.dtype)
                 for pool in pools
             ] + [
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.SMEM((2,), jnp.int32),
+                pltpu.SemaphoreType.DMA((n, depth)),
+                pltpu.SMEM((_CURSOR + b + 1,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, H, out_width), q.dtype),

@@ -394,6 +394,17 @@ def _compile_decode(*args):
     return _compiled_decode(*args).as_text()
 
 
+def _page_buffer_bytes(page_rows, width, n_pools, max_blocks):
+    """VMEM the paged kernel's page buffers take in a program whose bf16
+    pools have pages ``[page_rows, width]``: ``depth`` buffers a pool of
+    ``pages`` pages each (``paged_attention.pipeline_plan``, which the
+    kernel's one ``pallas_call`` sizes its scratch by)."""
+    from ray_tpu.ops.pallas.paged_attention import pipeline_plan
+
+    pages, depth = pipeline_plan(page_rows, width, 2, n_pools, max_blocks)
+    return depth * n_pools * pages * page_rows * width * 2
+
+
 def test_the_dense_decode_step_reads_wq_wk_wv_in_place(one_chip, monkeypatch):
     """``paged_decode_sample`` at Mistral-7B-v0.3's widths (2 of the cell's
     22 layers, its 32 slots, block size and ``max_len``, a tenth of its
@@ -538,6 +549,9 @@ def test_gigachat_decode_step_runs_both_kernels_and_names_its_parts(
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     latent = re.findall(r"= bf16\[128,64,512\][^=]*custom-call\(", text)
     assert len(latent) == 2  # a layer
+    # the copy pipeline Mosaic just took at this shape: 3 buffers of 64
+    # pages [16, 640] of the one pool (PR 49; 2 buffers until then)
+    assert _page_buffer_bytes(bs, 640, 1, MB) == 3 * 1_310_720
     assert any("bf16[16,7168,2048]" in " ".join(c)
                for c in _mosaic_calls(text))  # the held experts, in place
     counted, bare = _unscoped(text)
@@ -624,6 +638,9 @@ def test_phi4flash_decode_step_writes_slabs(one_chip, monkeypatch):
         params, pool, B, MB, one_chip)
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 16  # reads
+    # the copy pipeline Mosaic just took at this shape: 4 buffers of 6
+    # pages [160, 128] in each of two pools (PR 49; 2 buffers until then)
+    assert _page_buffer_bytes(bs * 10, 128, 2, MB) == 4 * 2 * 245_760
     scatters = _scatters(text)
     assert all(n <= B for _, n in scatters), scatters
     # keys and values of the full layer and the eight window layers (XLA
