@@ -34,6 +34,13 @@ batching, paged attention, automatic prefix caching,
   retire and at preemption, never grown, never published to the prefix
   cache.  A type also says how many layers READ its pool (``"readers"``)
   where they are not the layers that store it.
+* **Runs**: a slot is handed its blocks in aligned runs of adjacent
+  blocks, as many as the decode kernel moves with one copy descriptor
+  (``ops/pallas/paged_attention.py:page_run``, a rule of the pool's page):
+  at admission and whenever its table crosses a multiple of the run, where
+  the pool has a whole run, else one block at a time, as ever.  Up to a
+  run less one block a slot and pool are so held ahead of the sequence,
+  listed and released like any other block (``_BlockManager``).
 * **Preemption**: out of blocks mid-decode → the youngest request is
   rolled back to the queue (its tokens re-prefill later), matching vLLM's
   recompute-preemption policy.
@@ -158,39 +165,139 @@ class GenerationOutput:
 
 
 class _BlockManager:
-    """Host-side pool bookkeeping: free list, refcounts, prefix hash chain
+    """Host-side pool bookkeeping: free blocks, refcounts, prefix hash chain
     with LRU retention of refcount-0 blocks (vLLM's automatic prefix
-    caching, evict-last)."""
+    caching, evict-last).
 
-    def __init__(self, num_blocks: int):
+    **Runs.**  The pool is cut into aligned runs of ``run`` adjacent blocks
+    (``ops/pallas/paged_attention.py:page_run``: as many pages as one copy
+    descriptor of the decode kernel should move), and a block is
+    *available* when it is free or LRU-retained.  A run whose blocks are
+    all available is *whole* and waits in ``whole_free`` (no block of it
+    cached) or ``whole_cached``, in the order they became whole;
+    ``alloc_run`` hands one out entire, free ones first, so that ``run``
+    consecutive entries of a slot's table name adjacent blocks and the
+    kernel copies them with one descriptor.  ``alloc`` (one block) takes
+    from broken runs first (``free``, FIFO), then breaks a whole free run,
+    and evicts the oldest cached block last, as ever.  A run is whole again
+    the moment its last block comes back, in whatever order; the run that
+    holds the scratch block 0, and a tail shorter than ``run``, never are.
+    With ``run`` 1 every available block is a whole run and the order of
+    allocation is what it was: free blocks first in, first out, then the
+    cache's oldest."""
+
+    def __init__(self, num_blocks: int, run: int = 1):
         # block 0 is the jit-side scratch block (padding / masked writes)
         self.num_blocks = num_blocks
-        self.free: collections.deque = collections.deque(
-            range(1, num_blocks))
+        self.run = run
         self.refs: Dict[int, int] = {}
         self.key_of: Dict[int, Any] = {}
         self.by_key: Dict[Any, int] = {}
         self.lru: "collections.OrderedDict[Any, int]" = \
             collections.OrderedDict()
+        # dicts as ordered sets: the free blocks of broken runs, and the
+        # first blocks of whole runs
+        self.free: Dict[int, None] = {}
+        self.whole_free: Dict[int, None] = {}
+        self.whole_cached: Dict[int, None] = {}
+        # available blocks, a run and in all
+        self._avail = [0] * -(-num_blocks // run)
+        self._n_avail = 0
         self.stats = {"prefix_hits": 0, "prefix_blocks_reused": 0,
                       "evictions": 0, "preemptions": 0,
                       "adopted_blocks": 0}
+        for bid in range(1, num_blocks):
+            self._gain(bid)
 
     def available(self) -> int:
-        return len(self.free) + len(self.lru)
+        return self._n_avail
+
+    def free_count(self) -> int:
+        return self._n_avail - len(self.lru)
+
+    def _gain(self, bid: int) -> None:
+        """``bid`` became available (in ``key_of``: LRU-retained, else
+        free): into ``free``, or its run is whole again."""
+        first = bid - bid % self.run
+        self._n_avail += 1
+        self._avail[bid // self.run] += 1
+        if self._avail[bid // self.run] < self.run:
+            if bid not in self.key_of:
+                self.free[bid] = None
+            return
+        cached = False
+        for b in range(first, first + self.run):
+            if b in self.key_of:
+                cached = True
+            else:
+                self.free.pop(b, None)
+        (self.whole_cached if cached else self.whole_free)[first] = None
+
+    def _lose(self, bid: int) -> None:
+        """``bid``, available, is being taken alone: out of ``free``, or
+        its whole run breaks and the run's other free blocks go there."""
+        first = bid - bid % self.run
+        if self._avail[bid // self.run] == self.run:
+            self.whole_free.pop(first, None)
+            self.whole_cached.pop(first, None)
+            for b in range(first, first + self.run):
+                if b != bid and b not in self.key_of:
+                    self.free[b] = None
+        else:
+            self.free.pop(bid, None)
+        self._avail[bid // self.run] -= 1
+        self._n_avail -= 1
+
+    def _evict(self, bid: int) -> None:
+        """Forget the cached contents of ``bid`` (no-op for a free one)."""
+        key = self.key_of.pop(bid, None)
+        if key is not None:
+            self.by_key.pop(key, None)
+            self.lru.pop(key, None)
+            self.stats["evictions"] += 1
 
     def alloc(self) -> Optional[int]:
         if self.free:
-            bid = self.free.popleft()
+            bid = next(iter(self.free))
+        elif self.whole_free:
+            bid = next(iter(self.whole_free))  # the run breaks
         elif self.lru:
-            key, bid = self.lru.popitem(last=False)  # evict oldest cached
-            self.by_key.pop(key, None)
-            self.key_of.pop(bid, None)
-            self.stats["evictions"] += 1
+            bid = next(iter(self.lru.values()))  # evict oldest cached
         else:
             return None
+        self._lose(bid)
+        self._evict(bid)
         self.refs[bid] = 1
         return bid
+
+    def alloc_run(self) -> Optional[int]:
+        """The first block of a whole run, all ``run`` of them now held
+        once; None when no run is whole."""
+        for whole in (self.whole_free, self.whole_cached):
+            if whole:
+                first = next(iter(whole))
+                del whole[first]
+                break
+        else:
+            return None
+        self._avail[first // self.run] = 0
+        self._n_avail -= self.run
+        for bid in range(first, first + self.run):
+            self._evict(bid)
+            self.refs[bid] = 1
+        return first
+
+    def take(self, at: int, room: int) -> Optional[List[int]]:
+        """The blocks for a table's entries from ``at`` on: a whole run
+        where ``at`` starts a group of ``run`` entries, ``room`` entries
+        may be filled and a run is whole, else one block.  None: the pool
+        is exhausted."""
+        if self.run > 1 and at % self.run == 0 and room >= self.run:
+            first = self.alloc_run()
+            if first is not None:
+                return list(range(first, first + self.run))
+        bid = self.alloc()
+        return None if bid is None else [bid]
 
     def acquire_cached(self, key) -> Optional[int]:
         """Prefix hit: bump the block's refcount (reviving it from the
@@ -199,6 +306,7 @@ class _BlockManager:
         if bid is None:
             return None
         if key in self.lru:
+            self._lose(bid)
             del self.lru[key]
             self.refs[bid] = 0
         self.refs[bid] = self.refs.get(bid, 0) + 1
@@ -221,8 +329,7 @@ class _BlockManager:
         key = self.key_of.get(bid)
         if key is not None:
             self.lru[key] = bid  # retain contents for future prefix hits
-        else:
-            self.free.append(bid)
+        self._gain(bid)
 
     def adopt(self, keys: List[Any]) -> Optional[List[int]]:
         """Allocate one block per entry of ``keys`` for KV grafted from a
@@ -255,26 +362,44 @@ class _BlockManager:
             if k is not None and self.by_key.get(k) == b:
                 del self.by_key[k]
             self.refs.pop(b, None)
-            self.free.append(b)
+            self._gain(b)
 
     def assert_integrity(self) -> None:
         """Audit invariant (tests): every non-scratch block is in exactly
         one of {free, LRU-retained, refcounted}, and every refcount is
         positive — the abort/preemption paths must never leak or
-        double-free a block."""
-        free = set(self.free)
+        double-free a block.  A free block is in ``free`` or in a whole
+        run; a run is in ``whole_free`` / ``whole_cached`` exactly while
+        all its blocks are available."""
         lru = set(self.lru.values())
         refed = set(self.refs)
         assert all(n > 0 for n in self.refs.values()), \
             f"non-positive refcounts: {self.refs}"
+        assert not (lru & refed), f"blocks both cached and held: {lru & refed}"
+        assert lru == set(self.key_of) - refed, \
+            f"cached blocks out of the LRU: {lru ^ (set(self.key_of) - refed)}"
+        free = set(self.free)
         assert not (free & lru), f"blocks both free and cached: {free & lru}"
         assert not (free & refed), f"blocks both free and held: {free & refed}"
-        assert not (lru & refed), f"blocks both cached and held: {lru & refed}"
-        everything = free | lru | refed
-        expect = set(range(1, self.num_blocks))
-        assert everything == expect, \
-            (f"block accounting leak: missing {expect - everything}, "
-             f"phantom {everything - expect}")
+        for first in range(0, self.num_blocks, self.run):
+            run = set(range(max(first, 1),
+                            min(first + self.run, self.num_blocks)))
+            avail = run - refed
+            assert self._avail[first // self.run] == len(avail), \
+                f"run {first}: {self._avail[first // self.run]} != {avail}"
+            whole = (first in self.whole_free) + (first in self.whole_cached)
+            if len(avail) < self.run:
+                assert not whole, f"broken run {first} listed as whole"
+                assert avail - lru <= free, \
+                    f"block accounting leak: missing {avail - lru - free}"
+            else:
+                assert whole == 1 and (first in self.whole_cached) == bool(
+                    avail & lru), f"whole run {first} listed {whole} times"
+                assert not (avail & free), \
+                    f"blocks of whole run {first} in free: {avail & free}"
+        assert free <= set(range(1, self.num_blocks)), \
+            f"phantom {free - set(range(1, self.num_blocks))}"
+        assert self._n_avail == sum(self._avail)
 
 
 @dataclasses.dataclass
@@ -291,6 +416,7 @@ class _LayerPool:
     tables: np.ndarray
     readers: int
     state: bool = False
+    pages: int = 1  # of a compute block of the decode kernel
 
     def held(self) -> int:
         return self.blocks.num_blocks - 1 - self.blocks.available()
@@ -373,12 +499,18 @@ class LLMEngine:
         # blocks a request holds in ``Request.blocks``; ``_more`` the rest
         one = {"kv": {"layers": cfg.num_layers, "window": None}}
         sizes = self.num_blocks if types else {"kv": self.num_blocks}
+        # (pages a compute block of the decode kernel, pages a copy of
+        # it): the blocks a slot is handed at once; a state type's records
+        # come one by one
+        plan = {t: (1, 1) if spec.get("state") else self._page_plan(
+            self.pool[t] if types else self.pool)
+            for t, spec in (types or one).items()}
         pools = [_LayerPool(t, spec["layers"], spec["window"],
-                            _BlockManager(sizes[t]),
+                            _BlockManager(sizes[t], plan[t][1]),
                             np.zeros((self.B, 1 if spec.get("state")
                                       else self.MB), np.int32),
                             spec.get("readers", spec["layers"]),
-                            bool(spec.get("state")))
+                            bool(spec.get("state")), plan[t][0])
                  for t, spec in (types or one).items()]
         if pools[0].window is not None or pools[0].state:
             raise ValueError(f"{model.name}: the first layer type keeps "
@@ -486,6 +618,22 @@ class LLMEngine:
         # however many prefills) until the first tokens' fetch takes them
         self._prefill_sum: Optional[Any] = None
         self._prefill_calls = 0
+
+    def _page_plan(self, pool: Dict[str, Any]) -> Tuple[int, int]:
+        """``(pages, run)`` of one type's pool of positions as the decode
+        kernel cuts it: the pages of a compute block and those of one copy
+        descriptor, read off the same page as the kernel reads them (the
+        pool's first leaf's, ``k`` or ``kv``: ``[L, NB, *page]``) and this
+        engine's table width.  ``run`` is what a slot is handed at once, so
+        that the kernel finds its runs."""
+        from ray_tpu.ops.pallas.paged_attention import (page_run,
+                                                        pipeline_plan)
+
+        leaf = next(iter(pool.values()))
+        rows, width = int(np.prod(leaf.shape[2:-1])), leaf.shape[-1]
+        size = leaf.dtype.itemsize
+        pages, _ = pipeline_plan(rows, width, size, 1, self.MB)
+        return pages, page_run(rows, width, size, pages)
 
     def _shard_over_mesh(self, mesh) -> None:
         """Tensor-parallel inference: place params by the logical-axis rule
@@ -821,8 +969,9 @@ class LLMEngine:
         each pool holds; for a state type the records a step reads and
         writes (one an active slot) and the records held."""
         lens = self._cur_len[active]
+        pages = self._live_pages(active)
         if not self._by_type:
-            return {"live_tokens": int(lens.sum())}
+            return {"live_tokens": int(lens.sum()), **pages}
         by = {p.name: int((np.minimum(lens, p.window) if p.window
                            else lens).sum()) for p in self._kv_pools}
         layers = sum(p.readers for p in self._kv_pools)
@@ -835,7 +984,28 @@ class LLMEngine:
             if p.state:  # a record a slot is read and written, whole
                 out[f"live_tokens_{p.name}"] = len(active)
                 out[f"{p.name}_records_held"] = p.held()
-        return out
+        return {**out, **pages}
+
+    def _live_pages(self, active: List[int]) -> Dict[str, int]:
+        """``engine.dispatch_window``'s ``pages_live`` and
+        ``pages_in_runs``: the pages the decode kernel copies a layer for
+        the active slots (those a slot's length reaches and its type's
+        window has not left), summed over the pools of positions, and
+        those of them it copies ``run`` a descriptor (``run_flags``: the
+        groups live whole in a compute block whose every such group names
+        adjacent blocks).  What the allocator's runs are worth to the
+        kernel."""
+        from ray_tpu.ops.pallas.paged_attention import run_flags
+
+        lens = self._cur_len[active]
+        live = runs = 0
+        for p in self._kv_pools:
+            in_runs, whole, pages = run_flags(
+                p.tables[active], lens, run=p.blocks.run, pages=p.pages,
+                block_size=self.bs, window=p.window, xp=np)
+            live += int(pages.sum())
+            runs += p.blocks.run * int((whole & in_runs[..., None]).sum())
+        return {"pages_live": live, "pages_in_runs": runs}
 
     def _window_blocks(self, window: Optional[int]) -> int:
         """The most blocks a slot holds in a pool whose blocks die behind
@@ -1078,10 +1248,11 @@ class LLMEngine:
         # (O(log MB) compiles), not per distinct block count — an
         # unbucketed gather recompiles a pool-sized program for every
         # new prompt length, inside the engine lock
-        n = len(req.blocks)
+        # the blocks its prompt and first token fill: none held ahead
+        n = min(len(req.blocks), -(-(len(req.prompt_tokens) + 1) // self.bs))
         P = _bucket(n, self.MB + 1)
         ids = np.zeros(P, np.int32)
-        ids[:n] = req.blocks
+        ids[:n] = req.blocks[:n]
         kv = self._gather_blocks(self.pool, jnp.asarray(ids))
         jax.block_until_ready(kv)
         for bid in req.blocks:
@@ -1230,7 +1401,7 @@ class LLMEngine:
             "slots_total": self.B,
             "slot_occupancy": round(used / self.B, 4),
             "blocks_total": capacity,
-            "blocks_free": sum(len(p.blocks.free) for p in kv),
+            "blocks_free": sum(p.blocks.free_count() for p in kv),
             "blocks_cached": sum(len(p.blocks.lru) for p in kv),
             "blocks_available": available,
             "block_pressure": round(1.0 - available / capacity, 4),
@@ -1349,11 +1520,10 @@ class LLMEngine:
         if len(hit_blocks) > len(pinned):
             self.blocks.stats["prefix_hits"] += 1
 
-        new_blocks = [self.blocks.alloc() for _ in range(need)]
-        req.blocks = hit_blocks + new_blocks
-        req.more_blocks = [
-            [0] * lo + [p.blocks.alloc() for _ in range(hi - lo)]
-            for p, (lo, hi) in zip(self._more, more)]
+        req.blocks = self._extend(self._pools[0], list(hit_blocks),
+                                  len(hit_blocks) + need)
+        req.more_blocks = [self._extend(p, [0] * lo, hi)
+                           for p, (lo, hi) in zip(self._more, more)]
         req.chunk_blocks = []  # refs transferred into req.blocks
         req.cached_prefix_len = cached_len
         self._queue.popleft()
@@ -1375,6 +1545,19 @@ class LLMEngine:
         self._dev = None  # a request entered: its token, position, temp
         # device array; caller batch-samples all admissions in one sync
         return ("full", logits, len(suffix))
+
+    def _extend(self, p: _LayerPool, held: List[int], upto: int,
+                ahead: bool = True) -> List[int]:
+        """Grow a request's list of blocks in pool ``p`` to ``upto``
+        entries, from a pool known to hold as many: in whole runs from each
+        multiple of the pool's run on (``_BlockManager.take``), the last of
+        which may reach past ``upto`` where ``ahead`` allows it: blocks
+        held ahead, in the table and the list like any other, which no
+        program reads before the sequence grows into them."""
+        while len(held) < upto:
+            held += p.blocks.take(len(held), (p.tables.shape[1] if ahead
+                                              else upto) - len(held))
+        return held
 
     def _yield_chunk_pins(self, include_head: bool = False):
         """Break the pinned-chunk livelock: when an allocation stalls on
@@ -1480,7 +1663,9 @@ class LLMEngine:
                 return self._admit(i, budget)  # retry with freed blocks
             return None  # pool pressure: try again later
         chunk = toks[cached_len:cached_len + take]
-        new_blocks = [self.blocks.alloc() for _ in range(n_need)]
+        new_blocks = self._extend(
+            self._pools[0], list(hit_blocks), len(hit_blocks) + n_need,
+            ahead=False)[len(hit_blocks):]  # all to be filled and pinned
         # each chunk re-gathers the whole pinned prefix (O(n^2/chunk)
         # copy traffic over the prompt) — a constant factor of chunked
         # attention's inherent O(n^2) KV reads and far below decode's
@@ -1498,8 +1683,10 @@ class LLMEngine:
 
     def _ensure_decode_blocks(self, active: List[int]) -> List[int]:
         """Allocate blocks covering a whole window's write positions (the
-        next ``K``) for each active slot, preempting the youngest request
-        when the pool is exhausted (vLLM recompute preemption)."""
+        next ``K``) for each active slot, a run at a time where the table
+        crosses a multiple of the pool's run (``_BlockManager.take``),
+        preempting the youngest request when the pool is exhausted (vLLM
+        recompute preemption)."""
         for i in list(active):
             req = self._slots[i]
             if req is None or req.done:
@@ -1515,8 +1702,9 @@ class LLMEngine:
                 if p.state:
                     continue  # a record does not grow with the position
                 while blk_idx >= len(held) and self._slots[i] is req:
-                    bid = p.blocks.alloc()
-                    if bid is None:
+                    got = p.blocks.take(len(held),
+                                        p.tables.shape[1] - len(held))
+                    if got is None:
                         # cheapest relief first: a queued prompt's forfeited
                         # chunk pins cost at most one chunk recompute, vs a
                         # whole-request re-prefill for a preemption
@@ -1525,8 +1713,8 @@ class LLMEngine:
                         if self._preempt_youngest() is None:
                             break
                         continue  # self-preempted: slot is back in the queue
-                    held.append(bid)
-                    p.tables[i, len(held) - 1] = bid
+                    p.tables[i, len(held):len(held) + len(got)] = got
+                    held += got
                     self._dev_dirty = True
         return [i for i in active if self._slots[i] is not None
                 and not self._slots[i].done]

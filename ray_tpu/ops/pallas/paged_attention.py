@@ -27,9 +27,32 @@ started is waited for exactly once, by the block it belongs to, and none is
 in flight when the last grid step returns.  Between grid steps the cursor
 lives in SMEM, inside a slot's loop it is carried.
 
-Who chooses: ``pipeline_plan``, a static function of what ``_call`` sees (a
-page's bytes, the number of pools, the table's width) and of no option, so
-a program's depth is a constant of that program.  ``_init`` zeroes all
+What the one scalar core does a call is what bounds a call of small pages
+(PERF.md section 6, PR 50), so two things keep it short.  **Runs of pages.**
+Where ``run`` consecutive entries of a slot's table name adjacent blocks of
+the pool, in order, ONE descriptor copies the ``run`` pages
+(``hbm[layer, blk:blk + run]`` into ``run`` pages of the buffer); the serve
+loop's block manager hands a slot its blocks in such runs
+(``llm/engine.py:_BlockManager``).  ``_call`` works out, from the tables and
+the lengths (``run_flags``, a small XLA op), one flag a slot and compute
+block: whether every group of ``run`` entries that is live whole is such a
+run.  A flagged block starts a descriptor a group, and a page at a time
+only what the length or the window's start cut off a group; any other
+block starts a page a descriptor, as every block did before.  The bytes
+copied, where they land and the semaphore's count are the same either way,
+so the waits and every sum are too: for equal pool contents the output is
+the same to the bit whatever the block ids.  A branch costs the core more
+than a descriptor, so a block all live starts its copies in one straight
+run at constant offsets and any other in straight runs of powers of two, a
+branch a set bit of their number.  **Slots a grid step.**  A grid step
+costs 0.4 us whether its slot holds anything, so a step takes up to
+``_SLOTS_A_STEP`` slots (the largest divisor of their number) and loops
+over them; the cursor walks the same sequence.
+
+Who chooses: ``pipeline_plan`` and ``page_run``, static functions of what
+``_call`` sees (a page's bytes, the number of pools, the table's width) and
+of no option, so a program's depth and run are constants of that program.
+``_init`` zeroes all
 ``depth`` buffers once: a page a block does not copy (past the length,
 before the window) keeps what its buffer held before, zeros and then older
 pages, which is finite, so its masked product stays 0.
@@ -77,17 +100,18 @@ _NEG_INF = -1e30
 _CURSOR = 3
 
 
-def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, *refs,
-            window, pages, block_size, max_blocks, scale, value_width=None):
+def _kernel(layer_ref, len_ref, tab_ref, run_ref, q_ref, group_ref, tok_ref,
+            *refs, window, pages, run, block_size, max_blocks, scale,
+            value_width=None):
     if value_width is None:  # dense arm: a pool of keys and one of values
         k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, state = refs
         pools = ((k_hbm, kbuf), (v_hbm, vbuf))
     else:  # latent arm: the values are columns of the key page
         k_hbm, o_ref, kbuf, sems, state = refs
         pools = ((k_hbm, kbuf),)
-    s = pl.program_id(0)
+    step = pl.program_id(0)
     layer = layer_ref[0]
-    nslots = pl.num_programs(0)
+    nslots = len_ref.shape[0]
     depth = kbuf.shape[0]  # buffers a pool
     rows = kbuf.shape[1] * kbuf.shape[2]  # (token, kv head) pairs a block
     tokens = pages * block_size
@@ -117,26 +141,64 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, *refs,
         return lo, jnp.clip(n_pages - j * pages, 0, pages) - lo
 
     def start_copies(slot, length, j, buf):
-        """start the copies of block j of ``slot`` into buffer ``buf``.  A
-        block that is all live (most are) in one straight run at constant
-        offsets, any other in a loop over its live pages: a branch a page
-        costs the scalar core more than the copy."""
+        """start the copies of block j of ``slot`` into buffer ``buf``.
+        Where the block's flag is set (every group of ``run`` table entries
+        that is live whole names adjacent blocks of the pool, ``_call``),
+        one descriptor a group and a page a descriptor for what the length
+        or the window's start cut off a group; else a page a descriptor.
+        A block all live (most are) starts its copies in one straight run
+        at constant offsets, any other in straight runs of powers of two,
+        a branch each: a branch costs the scalar core more than a copy."""
         lo, n = live_pages(length, j)
 
-        def start(p):
+        def start(p, size):
             blk = tab_ref[slot * max_blocks + j * pages + p]
+            if size > 1:  # a page is indexed, a run of them sliced
+                blk, p = pl.ds(blk, size), pl.ds(p, size)
             for w, (hbm, buffer) in enumerate(pools):
                 pltpu.make_async_copy(hbm.at[layer, blk], buffer.at[buf, p],
                                       sems.at[w, buf]).start()
 
-        @pl.when(n == pages)
-        def _():
-            for p in range(pages):
-                start(p)
+        def start_many(first, count, most, size):
+            """``count`` (under ``most``) copies of ``size`` pages each,
+            from page ``first`` on: a branch a set bit of ``count``."""
+            chunk = 1 << ((most - 1).bit_length() - 1) if most > 1 else 0
+            while chunk:
+                def straight(first=first, chunk=chunk):
+                    for i in range(chunk):
+                        start(first + i * size, size)
 
-        @pl.when(n < pages)
-        def _():
-            lax.fori_loop(lo, lo + n, lambda p, _: start(p), None)
+                took = jnp.bitwise_and(count, chunk)
+                pl.when(took != 0)(straight)
+                first = first + took * size
+                chunk //= 2
+
+        def by(size):
+            """the block in copies of ``size`` pages; the pages that the
+            length or the window's start cut off a group one by one"""
+            def whole():  # all live: constant offsets
+                for p in range(0, pages, size):
+                    start(p, size)
+
+            def part():
+                head = 0 if window is None or size == 1 else jnp.minimum(
+                    lax.rem(size - lax.rem(lo, size), size), n)
+                groups_live = lax.div(n - head, size)
+                if window is not None:
+                    start_many(lo, head, size, 1)
+                start_many(lo + head, groups_live, pages // size, size)
+                start_many(lo + head + groups_live * size,
+                           n - head - groups_live * size, size, 1)
+
+            pl.when(n == pages)(whole)
+            pl.when(n < pages)(part)
+
+        if run == 1:
+            by(1)
+        else:
+            at = slot * -(-max_blocks // pages) + j
+            lax.cond(run_ref[at] != 0, functools.partial(by, run),
+                     functools.partial(by, 1))
 
     def wait_copies(length, j, buf):
         """wait for what ``start_copies`` started for block j of a slot of
@@ -147,8 +209,8 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, *refs,
 
         def wait(size):
             for w, (_, buffer) in enumerate(pools):
-                run = buffer.at[buf, pl.ds(0, size)]  # its bytes, not where
-                pltpu.make_async_copy(run, run, sems.at[w, buf]).wait()
+                span = buffer.at[buf, pl.ds(0, size)]  # its bytes, not where
+                pltpu.make_async_copy(span, span, sems.at[w, buf]).wait()
 
         size = 1 << (pages.bit_length() - 1)
         while size:
@@ -178,7 +240,7 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, *refs,
                            lambda: slot_after(slot), lambda: (slot, j + 1))
         return slot, j, next_buffer(buf)
 
-    @pl.when(s == 0)
+    @pl.when(step == 0)
     def _init():
         # pages a block does not copy keep what the buffer held before:
         # finite (zeros, then older pages), so a masked 0 x stale stays 0
@@ -202,52 +264,55 @@ def _kernel(layer_ref, len_ref, tab_ref, q_ref, group_ref, tok_ref, *refs,
         for i, word in enumerate(cursor):
             state[i] = word
 
-    length = len_ref[s]
+    def one_slot(i, _):
+        length = len_ref[step * q_ref.shape[0] + i]
 
-    @pl.when(length == 0)
-    def _empty():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        @pl.when(length == 0)
+        def _empty():
+            o_ref[i] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
 
-    @pl.when(length > 0)
-    def _attend():
-        q = q_ref[...]
-        lo = first_token(length)
+        @pl.when(length > 0)
+        def _attend():
+            q = q_ref[i]
+            lo = first_token(length)
 
-        def body(j, carry):
-            m, l, acc, cursor = carry
-            # the cursor's buffer is the one attended last; this block's is
-            # the next of the ring.  Once it has landed, the block
-            # depth - 1 ahead may go where the last one was
-            buf = next_buffer(cursor[2])
-            wait_copies(length, j, buf)
-            cursor = produce(cursor)
-            k = kbuf[buf].reshape(rows, kbuf.shape[-1])
-            v = (vbuf[buf].reshape(rows, vbuf.shape[-1])
-                 if value_width is None else k[:, :value_width])
-            sc = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-            sc = sc * scale + group_ref[...]
-            pos = tok_ref[...] + j * tokens
-            sc = jnp.where((pos < length) & (pos >= lo), sc, _NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(sc - m_new)
-            l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-            acc = alpha * acc + lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return m_new, l, acc, cursor
+            def body(j, carry):
+                m, l, acc, cursor = carry
+                # the cursor's buffer is the one attended last; this
+                # block's is the next of the ring.  Once it has landed, the
+                # block depth - 1 ahead may go where the last one was
+                buf = next_buffer(cursor[2])
+                wait_copies(length, j, buf)
+                cursor = produce(cursor)
+                k = kbuf[buf].reshape(rows, kbuf.shape[-1])
+                v = (vbuf[buf].reshape(rows, vbuf.shape[-1])
+                     if value_width is None else k[:, :value_width])
+                sc = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+                sc = sc * scale + group_ref[...]
+                pos = tok_ref[...] + j * tokens
+                sc = jnp.where((pos < length) & (pos >= lo), sc, _NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(sc - m_new)
+                l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+                acc = alpha * acc + lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return m_new, l, acc, cursor
 
-        H = q_ref.shape[0]
-        m, l, acc, cursor = lax.fori_loop(
-            first_block(length), end_block(length), body,
-            (jnp.full((H, 1), _NEG_INF, jnp.float32),
-             jnp.zeros((H, 1), jnp.float32),
-             jnp.zeros(o_ref.shape, jnp.float32),
-             tuple(state[i] for i in range(_CURSOR))))
-        for i, word in enumerate(cursor):
-            state[i] = word
-        o_ref[...] = (acc / l).astype(o_ref.dtype)
+            H = q_ref.shape[1]
+            m, l, acc, cursor = lax.fori_loop(
+                first_block(length), end_block(length), body,
+                (jnp.full((H, 1), _NEG_INF, jnp.float32),
+                 jnp.zeros((H, 1), jnp.float32),
+                 jnp.zeros(o_ref.shape[1:], jnp.float32),
+                 tuple(state[n] for n in range(_CURSOR))))
+            for n, word in enumerate(cursor):
+                state[n] = word
+            o_ref[i] = (acc / l).astype(o_ref.dtype)
+
+    lax.fori_loop(0, q_ref.shape[0], one_slot, None)
 
 
 # (token, kv head) rows a compute block holds unless the caller says
@@ -292,6 +357,66 @@ def pipeline_plan(page_rows: int, width: int, itemsize: int, n_pools: int,
     depth = min(1 + -(-_IN_FLIGHT_BYTES // block_bytes),
                 _PIPELINE_BYTES // block_bytes, _MAX_DEPTH)
     return pages, max(depth, _MIN_DEPTH)
+
+
+# Bytes a copy descriptor should move where a slot's blocks lie side by side
+# in the pool, and the most pages it takes to get there.  Measured alone on a
+# v5e at the cells' shapes (PERF.md section 6, PR 50; us a call at runs of
+# 1 / 2 / 4 / 8 / 16 pages): 20 KB latent pages 234 / 200 / 189 / 189 / 187
+# (GigaChat's call) and 154 / 138 / 131 / 136 / 132 (LongCat's), SmallThinker's
+# 16 KB pages 191 / 150 / 145 / 143 / 144: flat from 64 KiB on, and a longer
+# run only holds more blocks ahead of a slot.
+_RUN_BYTES = 64 << 10
+_MAX_RUN = 8
+# slots a grid step: a step costs 0.4 us whether its slots hold anything
+_SLOTS_A_STEP = 8
+
+
+def page_run(page_rows: int, width: int, itemsize: int, pages: int) -> int:
+    """Pages a copy descriptor where a slot's blocks are adjacent in the
+    pool: the smallest power of two that makes ``_RUN_BYTES`` of a pool's
+    page ``[page_rows, width]``, as far as powers of two divide ``pages``
+    (a run never straddles two compute blocks) and never past
+    ``_MAX_RUN``.  A static function of shapes, asked by ``_call`` for the
+    kernel and by the serve loop's block manager, which hands a slot its
+    blocks in aligned runs of this many (``llm/engine.py:_BlockManager``):
+    4 at the latent pools' 20 KB pages and SmallThinker's 16, 2 at
+    Mistral's 32 and Phi-4-mini-flash's 40."""
+    run = 1
+    while (run * page_rows * width * itemsize < _RUN_BYTES
+           and run < _MAX_RUN and pages % (2 * run) == 0):
+        run *= 2
+    return run
+
+
+def run_flags(block_tables, lengths, *, run: int, pages: int,
+              block_size: int, window: int | None, xp=jnp):
+    """What of a call's tables one descriptor a ``run`` of pages can copy.
+    Returns ``(in_runs, whole, live)``: ``live[s]``, the pages slot s
+    reads (under its length, past its window's start); ``whole[s, j, g]``,
+    whether group g (its ``run`` table entries) of the slot's compute block
+    j is live whole; ``in_runs[s, j]``, whether every such group of the
+    block names ``run`` adjacent blocks of the pool in order, which is when
+    the kernel copies the block's groups whole (a block with one group
+    that is no run is copied a page a descriptor).  ``xp``: for the
+    device's tables (``_call``) and, with numpy, the host's
+    (``LLMEngine._live_pages``) alike."""
+    b, MB = block_tables.shape
+    n = MB // run
+    groups = block_tables[:, :n * run].reshape(b, n, run)
+    adjacent = (groups == groups[:, :, :1]
+                + np.arange(run, dtype=np.int32)).all(axis=-1)
+    first = np.arange(n, dtype=np.int32) * run  # a group's first page
+    end = -(-lengths // block_size)
+    start = (0 * end if window is None
+             else xp.maximum(lengths - window, 0) // block_size)
+    whole = (first >= start[:, None]) & (first + run <= end[:, None])
+    # by compute block: the table's last block may be cut short
+    shape = (b, -(-MB // pages), pages // run)
+    pad = ((0, 0), (0, shape[1] * shape[2] - n))
+    whole = xp.pad(whole, pad).reshape(shape)
+    adjacent = xp.pad(adjacent, pad).reshape(shape)
+    return (adjacent | ~whole).all(axis=-1), whole, end - start
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, lengths, *, layer,
@@ -350,10 +475,11 @@ def latent_paged_attention(q, pool, block_tables, lengths, *, layer,
         pages_per_block=pages_per_block, interpret=interpret)
 
 
-def _call(kernel, q, pools, block_tables, lengths, layer, *, kv_heads,
-          out_width, pages_per_block, interpret):
+def _call(q, pools, block_tables, lengths, layer, *, window, block_size,
+          kv_heads, out_width, pages_per_block, interpret, **arm):
     """The one ``pallas_call`` of both arms.  ``pools``: the stacked
-    pool(s) with a page as a matrix ``[L, NB, bs * kv_heads, width]``."""
+    pool(s) with a page as a matrix ``[L, NB, bs * kv_heads, width]``;
+    ``arm``: what else ``_kernel`` is told (scale, value width)."""
     b, H, _ = q.shape
     _, _, page_rows, width = pools[0].shape
     MB = block_tables.shape[1]
@@ -361,6 +487,8 @@ def _call(kernel, q, pools, block_tables, lengths, layer, *, kv_heads,
     P, depth = pipeline_plan(page_rows, width, pools[0].dtype.itemsize, n, MB)
     if pages_per_block:  # the tests' lever
         P = min(pages_per_block, MB)
+    R = page_run(page_rows, width, pools[0].dtype.itemsize, P)
+    G = max(g for g in range(1, _SLOTS_A_STEP + 1) if b % g == 0)
     rows = P * page_rows
     # constants of the program: which rows belong to a head's KV group,
     # and a row's position inside its block
@@ -369,17 +497,20 @@ def _call(kernel, q, pools, block_tables, lengths, layer, *, kv_heads,
         r % kv_heads == np.arange(H, dtype=np.int32)[:, None]
         // (H // kv_heads), 0.0, _NEG_INF).astype(np.float32)  # [H, rows]
     tok = r // kv_heads  # [1, rows]
+    in_runs, _, _ = run_flags(block_tables, lengths, run=R, pages=P,
+                              block_size=block_size, window=window)
     return pl.pallas_call(
-        functools.partial(kernel, pages=P, max_blocks=MB),
+        functools.partial(_kernel, window=window, block_size=block_size,
+                          pages=P, run=R, max_blocks=MB, **arm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(b,),
+            num_scalar_prefetch=4,
+            grid=(b // G,),
             in_specs=[
-                pl.BlockSpec((None, H, q.shape[2]), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec((G, H, q.shape[2]), lambda s, *_: (s, 0, 0)),
                 pl.BlockSpec((H, rows), lambda s, *_: (0, 0)),
                 pl.BlockSpec((1, rows), lambda s, *_: (0, 0)),
             ] + [pl.BlockSpec(memory_space=pl.ANY)] * n,
-            out_specs=pl.BlockSpec((None, H, out_width),
+            out_specs=pl.BlockSpec((G, H, out_width),
                                    lambda s, *_: (s, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((depth, P, page_rows, width), pool.dtype)
@@ -394,7 +525,9 @@ def _call(kernel, q, pools, block_tables, lengths, layer, *, kv_heads,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(layer.reshape(1), lengths.astype(jnp.int32),
-      block_tables.reshape(-1).astype(jnp.int32), q, group, tok, *pools)
+      block_tables.reshape(-1).astype(jnp.int32),
+      in_runs.reshape(-1).astype(jnp.int32),
+      q, group, tok, *pools)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -410,20 +543,17 @@ def _paged_attention(q, k_pool, v_pool, block_tables, lengths, layer, *,
     else:
         KVH, bs = kv_heads, k_pool.shape[2] // kv_heads
         k_pages, v_pages = k_pool, v_pool
-    kernel = functools.partial(
-        _kernel, window=window, block_size=bs,
-        scale=float(hd) ** -0.5 if scale is None else float(scale))
-    return _call(kernel, q, (k_pages, v_pages), block_tables, lengths, layer,
-                 kv_heads=KVH, out_width=hd,
-                 pages_per_block=pages_per_block, interpret=interpret)
+    return _call(q, (k_pages, v_pages), block_tables, lengths, layer,
+                 window=window, block_size=bs, kv_heads=KVH, out_width=hd,
+                 pages_per_block=pages_per_block, interpret=interpret,
+                 scale=float(hd) ** -0.5 if scale is None else float(scale))
 
 
 @functools.partial(jax.jit, static_argnames=(
     "value_width", "scale", "pages_per_block", "interpret"))
 def _latent_paged_attention(q, pool, block_tables, lengths, layer, *,
                             value_width, scale, pages_per_block, interpret):
-    kernel = functools.partial(_kernel, window=None, block_size=pool.shape[2],
-                               scale=float(scale), value_width=value_width)
-    return _call(kernel, q, (pool,), block_tables, lengths, layer,
-                 kv_heads=1, out_width=value_width,
-                 pages_per_block=pages_per_block, interpret=interpret)
+    return _call(q, (pool,), block_tables, lengths, layer, window=None,
+                 block_size=pool.shape[2], kv_heads=1, out_width=value_width,
+                 pages_per_block=pages_per_block, interpret=interpret,
+                 scale=float(scale), value_width=value_width)

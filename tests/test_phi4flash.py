@@ -574,7 +574,9 @@ def test_a_long_prompt_holds_its_last_window_of_blocks_and_one_record():
     eng.step()  # admission, the first token and one window of 4
     full, window, state = (p.tables[0] for p in eng._pools)
     cur = int(eng._cur_len[0])
-    assert cur == 65 and (full != 0).sum() == 17  # past a block boundary
+    # past a block boundary; what lies behind is the run held ahead
+    ahead = -(-17 // eng.blocks.run) * eng.blocks.run
+    assert cur == 65 and full[:17].all() and not full[ahead:].any()
     dead = (cur + 1 - 8) // 4
     assert not window[:dead].any() and window[dead:17].all()
     assert eng.counters["window_blocks_released"] == dead - 13 == 1
@@ -718,7 +720,7 @@ def test_a_model_without_a_state_type_reads_as_before(monkeypatch):
     assert set(w) == {"k", "active", "attn", "carried", "experts",
                       "live_tokens", "live_tokens_full",
                       "live_tokens_window", "blocks_held_full",
-                      "blocks_held_window"}
+                      "blocks_held_window", "pages_live", "pages_in_runs"}
     assert w["live_tokens"] == round((20 * 2 + 8 * 6) / 8)
     one = LLMEngine(preset("tiny"), tokenizer=_Ids(), batch_slots=2,
                     max_len=64)

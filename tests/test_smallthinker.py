@@ -77,12 +77,13 @@ def _integrity(eng):
 
 def _window_rows_are_bounded(eng):
     """No slot holds more of the window type's blocks than the window, a
-    decode window ahead of it and the two blocks the ends cut."""
+    decode window ahead of it and the two blocks the ends cut, beside the
+    rest of the run it was last handed."""
     window = eng._pools[1]
     most = eng._window_blocks(window.window)
     assert most == 8 // 4 + 1 + 1  # window / block + 1, and the one ahead
     held = (window.tables != 0).sum(axis=1)
-    assert held.max() <= most, held
+    assert held.max() <= most + window.blocks.run - 1, held
     return held
 
 
@@ -210,13 +211,14 @@ def test_a_long_prompt_is_admitted_into_its_last_window_of_blocks():
     eng.step()  # admission, the first token and one window of 4
     full, window = (p.tables[0] for p in eng._pools)
     cur = int(eng._cur_len[0])
-    assert cur == 65 and (full != 0).sum() == 17
+    ahead = -(-17 // eng.blocks.run) * eng.blocks.run  # a run held ahead
+    assert cur == 65 and full[:17].all() and not full[ahead:].any()
     dead = (cur + 1 - 8) // 4
     assert not window[:dead].any() and window[dead:17].all()
     # the 13 blocks before the prompt's last window were never allocated:
     # what has been given back is what the one decode window left behind
     assert eng.counters["window_blocks_released"] == dead - 13 == 1
-    assert _window_rows_are_bounded(eng)[0] == 3
+    assert _window_rows_are_bounded(eng)[0] == 3 + ahead - 17
     while eng.has_unfinished():
         eng.step()
     _integrity(eng)
