@@ -301,10 +301,13 @@ PART_SCOPES = ("embed", "attn.proj", "attn.cache", "attn.core", "attn.out",
 # attention alone (models/phi4flash.py): the convolution in front of a
 # state-space scan, the scan (prefill) or its one-position update (decode),
 # the gated memory unit, the differential combination and its norm.  A
-# reader that knows two levels still finds ``attn.core``.  And inside
+# reader that knows two levels still finds ``attn.core``.  The same for a
+# Gated DeltaNet layer (models/gigachat3_5.py: ``gdn.*``) and for the gate
+# on a latent block's output (models/mla.py: ``attn.gate``).  And inside
 # ``experts``: the shared expert beside the routed ones
 # (models/deepseek_v3.py).
 DETAIL_SCOPES = ("ssm.conv", "ssm.scan", "ssm.update", "gmu", "diff",
+                 "gdn.conv", "gdn.scan", "gdn.update", "attn.gate",
                  "experts.shared")
 
 

@@ -59,8 +59,7 @@ from ray_tpu.models.mla import (gather_latent_prefix, init_latent_pool,
 from ray_tpu.models.paged_generation import (decode_attention_path,
                                              embed_tokens, sample_next)
 from ray_tpu.ops.experts import held_experts_ffn, route_top_k
-from ray_tpu.ops.layers import (rms_norm, swiglu, yarn_mscale,
-                                yarn_rope_frequencies)
+from ray_tpu.ops.layers import rms_norm, swiglu
 
 __all__ = ["DeepseekV3Config", "decode_sample", "decode_step",
            "deepseek_v3_apply", "deepseek_v3_init", "gather_latent_prefix",
@@ -72,7 +71,7 @@ COUNTERS = ("moe_pairs_held", "moe_experts_hit", "moe_zero_picks",
 
 
 @dataclasses.dataclass(frozen=True)
-class DeepseekV3Config(mla.LatentWidths):
+class DeepseekV3Config(mla.YarnLatentWidths):
     """GigaChat3.1-702B-A36B's published values."""
     vocab_size: int = 128256
     hidden_size: int = 7168
@@ -123,13 +122,6 @@ class DeepseekV3Config(mla.LatentWidths):
     def attention_blocks(self) -> int:
         """What the latent pool stacks: one block a layer."""
         return self.num_layers
-
-    @property
-    def softmax_scale(self) -> float:
-        """YaRN's temperature enters squared, at every position."""
-        m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
-        return float(self.qk_nope_head_dim
-                     + self.qk_rope_head_dim) ** -0.5 * m * m
 
     @property
     def held_groups(self) -> tuple[bool, ...]:
@@ -201,13 +193,7 @@ def deepseek_v3_init(key: jax.Array, cfg: DeepseekV3Config) -> Dict[str, Any]:
 
 # ------------------------------------------------------------------ blocks
 
-def _rope_table(cfg: DeepseekV3Config, positions: int):
-    return yarn_rope_frequencies(
-        cfg.qk_rope_head_dim, positions, cfg.rope_theta,
-        factor=cfg.rope_factor,
-        original_max_len=cfg.rope_original_max_len,
-        beta_fast=cfg.rope_beta_fast, beta_slow=cfg.rope_beta_slow,
-        mscale=cfg.rope_mscale, mscale_all_dim=cfg.rope_mscale_all_dim)
+_rope_table = mla.yarn_rope_table
 
 
 def _mlp(x, fp, cfg: DeepseekV3Config):

@@ -21,8 +21,9 @@ handed (``LatentWidths`` says what it has to offer): the block's widths
 (``v_head_dim`` 128 beside 192), whether the two latents are scaled after
 their norms (``mla_scale_q_lora`` / ``mla_scale_kv_lora``: LongCat's own),
 the softmax scale (``softmax_scale``: ``(nope + rope)^-0.5``, times YaRN's
-temperature where the model stretches its context) and how many attention
-blocks the pool stacks (``attention_blocks``).  The rotary table is the
+temperature where the model stretches its context), whether the heads'
+outputs are gated before ``W_o`` (``gated_attention``) and how many
+attention blocks the pool stacks (``attention_blocks``).  The rotary table is the
 caller's (``cos``, ``sin``).  The programs around the block (a prefill of a
 suffix, a decode step) are each model's own, because they walk its own
 stack; what such a program does an attention block (``SuffixAttend``,
@@ -39,7 +40,8 @@ whole table elsewhere (the CPU, the tests' reference): ``attend_rows``.
 An attention block's leaves: ``norm [H]``, ``w_qa [H, qr]``, ``q_norm
 [qr]``, ``w_qb [qr, nh * (dn + dr)]``, ``w_kva [H, kr + dr]``, ``kv_norm
 [kr]``, ``w_kvb [kr, nh * (dn + dv)]``, ``w_uk`` / ``w_uv`` (derived), ``w_o
-[nh * dv, H]``.  The rope columns of ``w_qb`` and ``w_kva`` are held
+[nh * dv, H]``, and ``w_g [H, nh * dv]`` where the model gates the block's
+output (``gated_attention``: ``output_gate``).  The rope columns of ``w_qb`` and ``w_kva`` are held
 de-interleaved (published column ``2i`` at ``i``, ``2i+1`` at ``i + d/2``:
 ``ops/layers.apply_rope`` rotates ``(i, i + d/2)`` where the published code
 rotates ``(2i, 2i+1)``; a score is a dot product and does not see the
@@ -53,7 +55,8 @@ import jax.numpy as jnp
 
 from ray_tpu._private import tracing
 from ray_tpu.ops.attention import attention_impl, dot_product_attention
-from ray_tpu.ops.layers import apply_rope, heads_projection, rms_norm
+from ray_tpu.ops.layers import (apply_rope, heads_projection, rms_norm,
+                                yarn_mscale, yarn_rope_frequencies)
 
 _LANES = 128
 
@@ -68,6 +71,8 @@ class LatentWidths:
     # the latents scaled by sqrt(H / rank) after their norms
     mla_scale_q_lora = False
     mla_scale_kv_lora = False
+    # a gate on the block's output, before W_o (``output_gate``)
+    gated_attention = False
 
     @property
     def latent_width(self) -> int:
@@ -78,6 +83,32 @@ class LatentWidths:
     @property
     def softmax_scale(self) -> float:
         return float(self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+
+
+class YarnLatentWidths(LatentWidths):
+    """A model that stretches its context by YaRN as DeepSeek-V3's family
+    publishes it (fields ``rope_theta``, ``rope_factor``,
+    ``rope_original_max_len``, ``rope_beta_fast``, ``rope_beta_slow``,
+    ``rope_mscale``, ``rope_mscale_all_dim``): the table is
+    ``yarn_rope_table``'s, and the temperature enters the softmax scale
+    squared, at every position."""
+
+    @property
+    def softmax_scale(self) -> float:
+        m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+        return float(self.qk_nope_head_dim
+                     + self.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def yarn_rope_table(cfg: YarnLatentWidths, positions: int):
+    """(cos, sin) over ``positions`` for the block's rope columns; at a
+    factor of 1 the plain table."""
+    return yarn_rope_frequencies(
+        cfg.qk_rope_head_dim, positions, cfg.rope_theta,
+        factor=cfg.rope_factor,
+        original_max_len=cfg.rope_original_max_len,
+        beta_fast=cfg.rope_beta_fast, beta_slow=cfg.rope_beta_slow,
+        mscale=cfg.rope_mscale, mscale_all_dim=cfg.rope_mscale_all_dim)
 
 
 # ------------------------------------------------------------------ params
@@ -117,10 +148,11 @@ def init_block(w, ones, cfg):
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     w_kvb = w(kr, nh * (dn + dv))
     w_uk, w_uv = absorbed_pair(w_kvb, cfg)
+    gate = {"w_g": w(H, nh * dv)} if cfg.gated_attention else {}
     return {"norm": ones(H), "w_qa": w(H, qr), "q_norm": ones(qr),
             "w_qb": w(qr, nh * (dn + dr)), "w_kva": w(H, kr + dr),
             "kv_norm": ones(kr), "w_kvb": w_kvb, "w_uk": w_uk,
-            "w_uv": w_uv, "w_o": w(nh * dv, H)}
+            "w_uv": w_uv, **gate, "w_o": w(nh * dv, H)}
 
 
 # ------------------------------------------------------------------ blocks
@@ -150,6 +182,29 @@ def project(x, ap, cfg, cos, sin, positions):
         return q[..., :dn], q_pe, c_kv, k_pe
 
 
+def output_gate(x, ap, cfg):
+    """x ``[..., H]`` (the block's normed input) -> ``sigmoid(x W_g) [...,
+    nh, dv]`` where the model gates its attention block's output (``out =
+    (attn * sigmoid(x W_g)) W_o``, a head's own columns of ``W_g`` on its
+    own values), None where it does not: ``plain`` and ``absorbed`` take
+    it as ``gate``.  The product goes through ``heads_projection``: folded
+    with the reshape onto the heads, XLA:TPU transposed the whole ``W_g``
+    (117 MB) every decode step (my chip run, PR 52)."""
+    if not cfg.gated_attention:
+        return None
+    with tracing.scope("attn.proj"):
+        g = heads_projection(x, ap["w_g"].astype(cfg.dtype), cfg.num_heads)
+        return jax.nn.sigmoid(g.astype(jnp.float32)).astype(cfg.dtype)
+
+
+def _gated(out, gate):
+    """``out [..., nh, dv]`` times its gate."""
+    if gate is None:
+        return out
+    with tracing.scope("attn.core"), tracing.scope("attn.gate"):
+        return out * gate
+
+
 def prefill_attention_path(seq: int, prefix: int, impl: str = "auto",
                            rule=attention_impl) -> str:
     """Which form ``seq`` queries attend through after ``prefix`` cached rows
@@ -173,13 +228,14 @@ def prefill_attention_path(seq: int, prefix: int, impl: str = "auto",
 _HEAD_GROUP = 16
 
 
-def plain(q_nope, q_pe, c_kv, k_pe, mask, ap, cfg, path: str):
+def plain(q_nope, q_pe, c_kv, k_pe, mask, ap, cfg, path: str, gate=None):
     """The non-absorbed form over rows ``c_kv [b, t, kr]`` / ``k_pe
     [b, t, dr]`` (up-projected here); mask ``[b, s, t]``; ``path`` what the
     caller's ``prefill_attention_path(s, t - s)`` gave.  With ``t == s``
     the callers' mask is causal for every live query, and the flash kernel
     takes it from there: all heads in one call, keys ``[k_nope | k_pe]``
-    beside values of their own width, no score matrix in HBM."""
+    beside values of their own width, no score matrix in HBM.  ``gate``:
+    ``output_gate``'s, on the heads' outputs before ``W_o``."""
     b, s, nh, dn = q_nope.shape
     t = c_kv.shape[1]
     dt, dv, scale = cfg.dtype, cfg.v_head_dim, cfg.softmax_scale
@@ -214,15 +270,17 @@ def plain(q_nope, q_pe, c_kv, k_pe, mask, ap, cfg, path: str):
                 a.reshape(*a.shape[:2], nh // g, g, a.shape[-1]), 2, 0)
             out = jax.lax.map(heads, tuple(split(a) for a in parts))
             out = jnp.moveaxis(out, 0, 2).reshape(b, s, nh, dv)
+    out = _gated(out, gate)
     with tracing.scope("attn.out"):
         return out.reshape(b, s, nh * dv) @ ap["w_o"].astype(dt)
 
 
-def absorbed(q_nope, q_pe, ap, cfg, attend_rows):
+def absorbed(q_nope, q_pe, ap, cfg, attend_rows, gate=None):
     """One query token a slot, ``W_kvb`` absorbed.  q_nope ``[b, nh, dn]``,
     q_pe ``[b, nh, dr]``; ``attend_rows(q [b, nh, W]) -> [b, nh, kr]``
     scores the query against the cached rows and returns the weighted
-    ``c_kv``.
+    ``c_kv``.  ``gate``: ``output_gate``'s, which multiplies after
+    ``w_uv`` (the heads' values are not there before it).
 
     Both products are batched over the heads and read the block's derived
     pair (``absorbed_pair``), never ``w_kvb``: as strided halves of that one
@@ -254,6 +312,8 @@ def absorbed(q_nope, q_pe, ap, cfg, attend_rows):
     with tracing.scope("attn.out"):  # out of it again, then W_o
         out = jnp.einsum("bhk,hkd->bhd", o_lat, ap["w_uv"].astype(dt),
                          preferred_element_type=jnp.float32).astype(dt)
+    out = _gated(out, gate)
+    with tracing.scope("attn.out"):
         return out.reshape(b, nh * dv) @ ap["w_o"].astype(dt)
 
 
@@ -333,6 +393,13 @@ def attend_rows(kv, block: int, block_tables, cur_len, lengths, cfg,
 
 # ------------------------------------------------- a program's attention
 
+def _gate_of(xn, ap, cfg) -> dict:
+    """``plain`` / ``absorbed``'s ``gate`` argument, only where the model
+    has one: a model without it calls them as it always did."""
+    gate = output_gate(xn, ap, cfg)
+    return {} if gate is None else {"gate": gate}
+
+
 class SuffixAttend:
     """``attend(x_normed [1, S, H], ap) -> [1, S, H]`` of a b=1 prefill of a
     prompt *suffix* (``S`` padded tokens, ``length`` live, from position
@@ -370,7 +437,7 @@ class SuffixAttend:
                 [self.prefix[1][a][None].astype(dt), k_pe], 1)
         self.block += 1
         return plain(q_nope, q_pe, c_all, pe_all, self.mask, ap, cfg,
-                     self.path)
+                     self.path, **_gate_of(xn, ap, cfg))
 
 
 class StepAttend:
@@ -409,4 +476,5 @@ class StepAttend:
         self.block += 1
         with tracing.scope("attn.proj"):
             q_nope, q_pe = q_nope[:, 0], q_pe[:, 0]
-        return self.absorbed(q_nope, q_pe, ap, cfg, rows)[:, None]
+        return self.absorbed(q_nope, q_pe, ap, cfg, rows,
+                             **_gate_of(xn[:, 0], ap, cfg))[:, None]

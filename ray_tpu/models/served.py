@@ -39,7 +39,10 @@ the call, never by a wrong answer.
 A model whose layers do not all keep the same positions supplies
 ``layer_types(cfg) -> {type: {"layers": n, "window": None | int}}``, the
 type without a window first.  The engine then builds a pool, a block
-manager and a block table for each type: ``init_pool`` takes
+manager and a block table for each type (a type's pool holds blocks of
+positions, as key/value pages or as latent rows: whichever arm of the paged
+decode kernel the model's ``decode_attention_path`` names reads it; or
+records, below): ``init_pool`` takes
 ``num_blocks`` by type and returns ``{type: pool}``, ``decode_sample``
 takes ``block_tables`` by type, ``prefill_suffix`` its ``dst_blocks`` by
 type, and a window type's blocks that lie wholly behind the window go back
@@ -202,13 +205,31 @@ def _phi4flash() -> ServedModel:
         counters=("positions", "cross_positions"))
 
 
+def _gigachat3_5() -> ServedModel:
+    from ray_tpu.models import gigachat3_5 as gc
+
+    return ServedModel(
+        name="gigachat3_5", init=gc.gigachat3_5_init,
+        init_pool=gc.init_pools, prefill_suffix=gc.prefill_suffix,
+        gather_prefix=gc.gather_prefix, decode_sample=gc.decode_sample,
+        decode_attention_path=gc.decode_attention_path,
+        presets={"gigachat3_5_tiny": gc.GigaChat35Config.tiny,
+                 "gigachat3_5_432b": gc.GigaChat35Config},
+        test_presets=("gigachat3_5_tiny",), layer_types=gc.layer_types,
+        expert_path=_expert_path,
+        prefill_attention_path=lambda cfg, bucket, prefix:
+            gc.prefill_attention_path(bucket, prefix),
+        counters=gc.COUNTERS)  # LongCat's three
+
+
 # configuration class -> the function that builds its ServedModel: a model's
 # modules are imported when it is first asked for, so that a process which
 # serves one model loads one model
 _MODELS = {"LlamaConfig": _llama, "LongcatConfig": _longcat,
            "DeepseekV3Config": _deepseek_v3,
            "SmallThinkerConfig": _smallthinker,
-           "Phi4FlashConfig": _phi4flash}
+           "Phi4FlashConfig": _phi4flash,
+           "GigaChat35Config": _gigachat3_5}
 
 
 @functools.lru_cache(maxsize=None)

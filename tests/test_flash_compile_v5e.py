@@ -7,7 +7,8 @@ slab into its page as one update (PR 40), LongCat-Flash's uncached
 prefill through the flash forward at 192-wide keys beside 128-wide values
 (PR 43) and its decode step reading the absorbed pair in place (PR 45),
 GigaChat3.1's flash forward at 192 beside 192 and its decode step on both
-kernels (PR 47), compiled for a v5e that is described, not attached.
+kernels (PR 47), the gated delta rule's decode update and chunked scan
+(PR 52), compiled for a v5e that is described, not attached.
 
 The interpreter cannot see what the chip's compiler refuses: more VMEM
 than a kernel may use (the backward keeps dK/dV whole in scratch and sets
@@ -647,6 +648,59 @@ def test_phi4flash_decode_step_writes_slabs(one_chip, monkeypatch):
     # folds the leading dimensions as it likes; the window is the slab)
     assert [n for dims, n in scatters if dims[-3:] == (5, 2, 128)] == 18 * [B]
     assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
+
+
+# ------------------ the gated delta rule's two programs (PR 52: ops/delta.py)
+
+def test_the_delta_update_rewrites_the_records_where_they_lie(one_chip):
+    """GigaChat3.5's decode update at its cell's widths (128 slots, 4
+    layers of 129 records of 64 heads x [128, 128] float32, 2.2 GB): ONE
+    Mosaic call whose record operand is aliased to its result, so the
+    compiled program holds the pool once (no temporary of its size), and
+    a slot's whole record of a layer (4 MiB) is one block: two in and two
+    out in flight fit the VMEM the call asks for."""
+    from ray_tpu.ops import delta
+
+    b, Hv, d, L, R = 128, 64, 128, 4, 129
+    f32 = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.float32, sharding=one_chip)
+    rec = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+
+    def update(q, k, v, alpha, beta, records, rec):
+        return delta.delta_update_records(
+            q, k, v, alpha, beta, records, 2, rec, path="kernel",
+            interpret=False)
+
+    compiled = jax.jit(update, donate_argnums=(5,)).lower(
+        f32(b, Hv, d), f32(b, Hv, d), f32(b, Hv, d), f32(b, Hv), f32(b, Hv),
+        f32(L, R, Hv, d, d), rec).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    m = compiled.memory_analysis()
+    pool = L * R * Hv * d * d * 4
+    assert m.alias_size_in_bytes >= pool
+    assert m.temp_size_in_bytes < 0.05 * pool
+
+
+def test_the_chunked_delta_scan_compiles_for_a_prompt_bucket(one_chip):
+    """The prefill's scan at the cell's largest bucket (1024 positions, 64
+    value heads of 128): plain XLA, the triangular solve included, and
+    temporaries of some tens of MB beside 9.5 GB of weights."""
+    from ray_tpu.ops import delta
+
+    S, Hv, d = 1024, 64, 128
+    f32 = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.float32, sharding=one_chip)
+
+    def scan(q, k, v, g, beta):
+        return delta.chunked_delta_scan(
+            q, k, v, g, beta, jnp.zeros((1, Hv, d, d), jnp.float32), 700)
+
+    compiled = jax.jit(scan).lower(
+        f32(1, S, Hv, d), f32(1, S, Hv, d), f32(1, S, Hv, d), f32(1, S, Hv),
+        f32(1, S, Hv)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
 
 
 # ------------------- the train cell's head and loss as one function (PR 38)
