@@ -53,13 +53,16 @@ slot.
 **The cache is two pools**, one a layer type (``layer_types``):
 ``{"latent": {"kv": [A, NB, bs, W]}`` (``models/mla.py``'s pool of rows, its
 decode arm ``latent_paged_attention``) ``, "state": {"s": [D, R, Hv, d, d]
-float32, "conv": [D, R, 3 C] }}`` with ``A`` latent blocks, ``D`` DeltaNet
-layers and ``C`` the convolution's channels.  The state type's axis 1 is a
-RECORD, one a request (record 0 the scratch one), and its table is one entry
-a slot.  A decode step updates the live slots' records where they lie
-(``ops/delta.py:delta_update_records``); a prefill scans the prompt from a
-zero state (``chunked_delta_scan``) and writes its one record.  A prompt is
-prefilled whole, from position 0: no cached prefix, no chunks.
+float32, "conv": [D, R, 3 P, d] }}`` with ``A`` latent blocks, ``D`` DeltaNet
+layers and the convolution's ``C = P d`` channels held a row a head (a
+record's tail is whole tiles, which a kernel's block can name).  The state
+type's axis 1 is a RECORD, one a request (record 0 the scratch one), and its
+table is one entry a slot.  A decode step hands a layer's ``qkv``, gates and
+both pools to ONE operation (``ops/delta.py:delta_update_records``), which
+moves the live slots' records and tails where they lie and touches no
+other; a prefill scans the prompt from a zero state (``chunked_delta_scan``)
+and writes its one record.  A prompt is prefilled whole, from position 0: no
+cached prefix, no chunks.
 """
 
 from __future__ import annotations
@@ -80,7 +83,7 @@ from ray_tpu.models.paged_generation import (decode_attention_path as
 from ray_tpu.ops import delta
 from ray_tpu.ops.experts import held_experts_ffn, route_top_k
 from ray_tpu.ops.layers import heads_projection, rms_norm, swiglu
-from ray_tpu.ops.ssm import causal_conv1d, causal_conv1d_step, silu
+from ray_tpu.ops.ssm import causal_conv1d
 
 LATENT, STATE = "latent", "state"
 # what the programs return beside the rest, in this order: LongCat's names
@@ -155,6 +158,11 @@ class GigaChat35Config(mla.YarnLatentWidths):
                 f"{self.linear_value_heads} value heads on "
                 f"{self.linear_key_heads} key heads: a key head serves a "
                 f"whole number of value heads")
+        if self.conv_channels % self.linear_value_head_dim:
+            raise ValueError(
+                f"{self.conv_channels} convolution channels in rows of "
+                f"{self.linear_value_head_dim}: the tails' pool holds them "
+                f"a row a value head wide")
 
     @property
     def num_held(self) -> int:
@@ -359,21 +367,6 @@ def _gdn_inputs(u, gp, cfg: GigaChat35Config):
         return qkv, z, beta, g
 
 
-def _gdn_heads(qkv, cfg: GigaChat35Config):
-    """The convolution's output ``[..., C]`` -> q, k ``[..., Hv, d]`` (unit
-    length, ``q`` also ``/ sqrt(d)``; a key head repeated for the value
-    heads it serves) and v ``[..., Hv, d]``, float32."""
-    Hk, Hv = cfg.linear_key_heads, cfg.linear_value_heads
-    dk = cfg.linear_key_head_dim
-    x = silu(qkv)
-    q, k, v = jnp.split(x, [Hk * dk, 2 * Hk * dk], axis=-1)
-    lead = x.shape[:-1]
-    q = delta.l2_normalise(q.reshape(*lead, Hk, dk)) * dk ** -0.5
-    k = delta.l2_normalise(k.reshape(*lead, Hk, dk))
-    q, k = (jnp.repeat(a, Hv // Hk, axis=-2) for a in (q, k))
-    return q, k, v.reshape(*lead, Hv, -1).astype(jnp.float32)
-
-
 def _gdn_out(o, z, gp, cfg: GigaChat35Config):
     """o ``[..., Hv, d]`` float32, z the same in the model's dtype -> the
     mixer's output ``[..., H]``: the zero-centred gated norm a head, the
@@ -401,7 +394,8 @@ def _gdn_sequence(u, gp, cfg: GigaChat35Config, length):
             qkv, tail = causal_conv1d(
                 qkv, gp["conv_w"], jnp.zeros((C,), jnp.float32),
                 jnp.zeros((b, K - 1, C), cfg.dtype), length)
-            q, k, v = _gdn_heads(qkv, cfg)
+            q, k, v = delta.delta_heads_of(
+                qkv, cfg.linear_key_heads, Hv, cfg.linear_key_head_dim)
         with tracing.scope("gdn.scan"):
             o, state = delta.chunked_delta_scan(
                 q, k, v, g, beta,
@@ -490,7 +484,8 @@ def init_pools(cfg: GigaChat35Config, num_blocks: Dict[str, int],
                         cfg.linear_key_head_dim, cfg.linear_value_head_dim),
                        jnp.float32),
         "conv": jnp.zeros((D, R, (cfg.linear_conv_kernel - 1)
-                           * cfg.conv_channels), cfg.dtype)}
+                           * cfg.conv_channels // cfg.linear_value_head_dim,
+                           cfg.linear_value_head_dim), cfg.dtype)}
     return pools
 
 
@@ -536,7 +531,8 @@ def prefill_suffix(params, tokens, length, start_pos, prefix_ckv,
         out, s, tail = _gdn_sequence(u, gp, cfg, length)
         with tracing.scope("attn.cache"):  # the request's one record
             state["s"] = state["s"].at[a, rec].set(s[0])
-            state["conv"] = state["conv"].at[a, rec].set(tail[0])
+            state["conv"] = state["conv"].at[a, rec].set(
+                tail[0].reshape(state["conv"].shape[2:]))
         return out
 
     x, stats = _layers(params, embed_tokens(params, tokens, cfg.dtype),
@@ -557,36 +553,24 @@ def decode_step(params, token, cur_len, block_tables, pool,
     routed to no expert, moves no record and is not counted."""
     if attn is None:
         attn = decode_attention_path(pool)
-    b = token.shape[0]
     ML = block_tables[LATENT].shape[1] * pool[LATENT]["kv"].shape[2]
     with tracing.scope("attn.proj"):  # the rotary table
         cos, sin = _rope_table(cfg, ML)
     attend = mla.StepAttend(pool[LATENT], cfg, cos, sin, cur_len,
                             block_tables[LATENT], attn)
     state = dict(pool[STATE])
-    R = state["conv"].shape[1]
     with tracing.scope("attn.cache"):
         rec = jnp.where(attend.live, block_tables[STATE][:, 0], 0)
-        slot_of = jnp.zeros((R,), jnp.int32).at[rec].set(jnp.arange(b))
-    update = delta.delta_update_path(state["s"])
+    update = delta.delta_update_path(state)
 
     def mix(a, u, gp):
         qkv, z, beta, g = _gdn_inputs(u[:, 0], gp, cfg)
         with tracing.scope("attn.core"):
-            with tracing.scope("gdn.conv"):
-                # the tails are small (96 KiB a record): every record's is
-                # rewritten where it lies in one elementwise pass, a slot's
-                # input carried to its record and the output back (a scatter
-                # of 128 rows XLA:TPU runs as a loop of 128 updates, 1 ms a
-                # layer; a record no slot holds takes some slot's input and
-                # is garbage, as the scratch record is)
-                y, tail = causal_conv1d_step(qkv[slot_of], gp["conv_w"],
-                                             state["conv"][a])
-                state["conv"] = state["conv"].at[a].set(tail)
-                q, k, v = _gdn_heads(y[rec], cfg)
-            with tracing.scope("gdn.update"):
-                o, state["s"] = delta.delta_update_records(
-                    q, k, v, jnp.exp(g), beta, state["s"], a, rec, update)
+            # the convolution, the heads' vectors and the rule, on the live
+            # slots' tails and records where they lie
+            o, new = delta.delta_update_records(
+                qkv, jnp.exp(g), beta, gp["conv_w"], state, a, rec, update)
+        state.update(new)
         return _gdn_out(o, z, gp, cfg)[:, None]
 
     x, stats = _layers(params,

@@ -11,27 +11,40 @@ The recurrence, one value head (``k`` of unit length, ``alpha`` in (0, 1],
     S_t  = S'_t + k_t (beta_t (v_t - S'_t^T k_t))^T
     o_t  = S_t^T q_t
 
-**At decode** (``delta_update_records``) a request's record is read once
-and written once, where it lies.  Both reductions are taken of the record
-as it was read, because the update is of rank one::
+**At decode** (``delta_update_records``) a layer's whole update is ONE
+operation from what the projections made to the two pools: the slot's row of
+``qkv``, its ``alpha`` and ``beta``, the convolution's taps, the records
+``[L, R, Hv, dk, dv]`` float32 and the convolution's tails ``[L, R, (K - 1)
+P, d]`` (``P = C / d`` rows of one head's width: a record's tail is whole
+tiles, so a block can name it).  A request's record and tail are read once
+and written once, where they lie.  Both reductions are taken of the record as
+it was read, because the update is of rank one; key head ``j`` serves the
+value heads ``h`` with ``h // (Hv / Hk) = j`` and is never repeated::
 
-    d_t = beta_t v_t - S_{t-1}^T (alpha_t beta_t k_t)
-    o_t = S_{t-1}^T (alpha_t q_t) + d_t (k_t . q_t)
+    u_t = S_{t-1}^T k_t
+    d_t = beta_t v_t - alpha_t beta_t u_t
+    o_t = alpha_t S_{t-1}^T q_t + d_t (k_t . q_t)
     S_t = alpha_t S_{t-1} + k_t d_t^T
 
-On one TPU device that pass is a Pallas kernel (``delta_update_path``): a
-grid step a slot, the slot's whole record ``[Hv, dk, dv]`` of one layer
-its block, found through the slot's record number (a prefetched scalar) and
-aliased to the output, so that only the live slots' records move.  The
-slots are visited live ones first: the idle ones all name the scratch
-record 0, and a block whose index does not change is neither fetched nor
-written again.  ``S`` is held ``[dk, dv]`` with ``dv`` on the lanes, so the
-vectors that multiply along ``dk`` (``k``, ``alpha beta k``, ``alpha q``)
-come as COLUMNS (``[dk, heads]``: a head's is a lane slice, broadcast over
-the lanes) and those along ``dv`` (``beta v``, ``alpha``, ``k . q``) as
-rows; all of them are made outside, in one small fusion.  Elsewhere (the
-CPU, the tests' reference) the live records are gathered, updated by
-``delta_step`` and scattered back.
+On one TPU device that is a Pallas kernel (``delta_update_path``): a grid
+step a slot, the slot's record ``[Hv, dk, dv]`` and tail ``[(K - 1) P, d]``
+of one layer its blocks, both found through the slot's record number (a
+prefetched scalar) and both aliased to their pools, so that only the live
+slots' records and tails move and XLA never copies a pool.  The slots are
+visited live ones first: the idle ones all name the scratch record 0, and a
+block whose index does not change is neither fetched nor written again.
+Before the head loop the step makes the token's vectors itself, from the
+slot's ``qkv`` viewed ``[P, d]`` (a row a head): the convolution's one
+position (the sum in float32, rounded to the model's dtype as
+``ops/ssm.py:causal_conv1d_step`` rounds it), ``delta_heads`` and ``k . q``.
+``S`` is held ``[dk, dv]`` with ``dv`` on the lanes, so ``k`` and ``q``
+multiply it as COLUMNS: ``[k | q | alpha, beta]`` is transposed once,
+``[d, d]``, a key head's column is a lane slice broadcast over the lanes for
+both value heads it serves, and ``alpha``, ``beta`` come out of the same
+transpose as columns over the heads.  Elsewhere (the CPU, the tests'
+reference, a width off the tiles) the live slots' tails and records are
+gathered, taken through ``causal_conv1d_step``, ``delta_heads_of`` and
+``delta_step`` as they stand, and scattered back.
 
 **At prefill** (``chunked_delta_scan``) the recurrence over a prompt is cut
 into chunks of ``SCAN_CHUNK`` positions.  Inside a chunk, with ``G_i`` the
@@ -59,6 +72,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu._private import tracing
+from ray_tpu.ops.ssm import causal_conv1d_step, silu
 
 SCAN_CHUNK = 64
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -91,43 +107,90 @@ def delta_step(q, k, v, alpha, beta, state):
     return jnp.sum(s * q[..., None], axis=-2), s
 
 
-def delta_update_path(records) -> str:
+def delta_heads(q, k, v):
+    """The convolution's output by head -> what the rule takes: ``silu``
+    (rounded to the model's dtype, as ``ops/ssm.py:silu`` rounds it), then
+    ``q`` and ``k`` of unit length over ``dk`` (``q`` also ``/ sqrt(dk)``)
+    and ``v``, all float32.  q, k ``[..., Hk, dk]``; v ``[..., Hv, dv]``."""
+    q, k, v = silu(q), silu(k), silu(v)
+    return (l2_normalise(q) * q.shape[-1] ** -0.5, l2_normalise(k),
+            v.astype(jnp.float32))
+
+
+def delta_heads_of(x, key_heads: int, value_heads: int, dk: int):
+    """``delta_heads`` of the convolution's output ``[..., C]`` (``[q | k |
+    v]``, the heads leading): q, k ``[..., Hv, dk]`` (a key head repeated
+    for the value heads it serves) and v ``[..., Hv, dv]``."""
+    lead = x.shape[:-1]
+    q, k, v = jnp.split(x, [key_heads * dk, 2 * key_heads * dk], axis=-1)
+    q, k, v = delta_heads(q.reshape(*lead, key_heads, dk),
+                          k.reshape(*lead, key_heads, dk),
+                          v.reshape(*lead, value_heads, -1))
+    q, k = (jnp.repeat(a, value_heads // key_heads, axis=-2) for a in (q, k))
+    return q, k, v
+
+
+def delta_update_path(state) -> str:
     """Which update a decode step runs, from what it sees: ``"kernel"`` on
-    a TPU where a head's state is whole tiles (an engine with a mesh is
-    refused before it gets here), ``"gather"`` elsewhere."""
-    dk, dv = records.shape[-2:]
-    if jax.default_backend() != "tpu" or dk % 8 or dv % 128:
+    a TPU where a head's state is whole square tiles, as wide as the tails'
+    rows, and the heads fit one transpose (an engine with a mesh is refused
+    before it gets here), ``"gather"`` elsewhere."""
+    Hv, dk, dv = state["s"].shape[-3:]
+    if (jax.default_backend() != "tpu" or dk != dv or dv % 128 or Hv > dk
+            or state["conv"].shape[-1] != dv):
         return "gather"
     return "kernel"
 
 
-def delta_update_records(q, k, v, alpha, beta, records, layer: int, rec,
+def delta_update_records(qkv, alpha, beta, conv_w, state, layer: int, rec,
                          path: str | None = None,
                          interpret: bool | None = None):
-    """One position for every slot, on the slots' own records.
+    """One position of a Gated DeltaNet layer for every slot, on the slots'
+    own records and tails.
 
-    q, k ``[b, Hv, dk]``; v ``[b, Hv, dv]``; alpha, beta ``[b, Hv]``;
-    records ``[L, R, Hv, dk, dv]`` float32 (record 0 the scratch one);
-    ``layer`` static; rec ``[b]`` int32: each slot's record, 0 for a slot
-    that holds no request.  Returns ``(o [b, Hv, dv] float32, records)``;
-    an idle slot's ``o`` is of no use, and the scratch record is garbage."""
+    qkv ``[b, C]`` (the convolution's input, ``[q | k | v]``, the model's
+    dtype); alpha, beta ``[b, Hv]``; conv_w ``[K, C]``; state ``{"s": [L, R,
+    Hv, dk, dv] float32, "conv": [L, R, (K - 1) P, d]}`` (``P d = C``;
+    record 0 the scratch one); ``layer`` static; rec ``[b]`` int32: each
+    slot's record, 0 for a slot that holds no request.  Returns ``(o [b, Hv,
+    dv] float32, state)``; an idle slot's ``o`` is of no use (zero from the
+    kernel), the scratch record and its tail are garbage, and a record no
+    slot holds is not touched."""
     if path is None:
-        path = delta_update_path(records)
+        path = delta_update_path(state)
     if path == "kernel":
-        return _kernel_update(q, k, v, alpha, beta, records, layer, rec,
-                              interpret)
-    o, s = delta_step(q, k, v, alpha, beta, records[layer, rec])
-    return o, records.at[layer, rec].set(s)
+        if interpret is None:
+            interpret = jax.default_backend() != "tpu"
+        with tracing.scope("gdn.update"):
+            return _kernel_update(qkv, alpha, beta, conv_w, state, rec,
+                                  layer=layer, interpret=interpret)
+    b, C = qkv.shape
+    Hv, dk, dv = state["s"].shape[-3:]
+    tails = state["conv"]
+    with tracing.scope("gdn.conv"):
+        y, tail = causal_conv1d_step(qkv, conv_w,
+                                     tails[layer, rec].reshape(b, -1))
+        q, k, v = delta_heads_of(y, (C - Hv * dv) // (2 * dk), Hv, dk)
+    with tracing.scope("gdn.update"):
+        o, s = delta_step(q, k, v, alpha, beta, state["s"][layer, rec])
+        return o, {"s": state["s"].at[layer, rec].set(s),
+                   "conv": tails.at[layer, rec].set(
+                       tail.reshape(b, *tails.shape[2:]))}
 
 
-def _kernel(rec_ref, slot_ref, cols_ref, rows_ref, s_ref, o_ref, out_ref, *,
-            heads: int):
-    """A slot's record of one layer.  cols ``[dk, 3 Hv]``: ``k | alpha beta
-    k | alpha q`` as columns; rows ``[3, Hv, dv]``: ``beta v``, ``alpha``,
-    ``k . q``; s / out ``[Hv, dk, dv]``; o ``[Hv, dv]``."""
+def _kernel(rec_ref, slot_ref, x_ref, gate_ref, w_ref, s_ref, t_ref, o_ref,
+            s_out, t_out, cols_ref, rows_ref, kq_ref, *, key_heads: int):
+    """A slot's grid step on one layer.  x ``[P, d]``: the slot's row of
+    ``qkv``, a row a head; gate ``[8, d]``: ``alpha`` and ``beta`` its first
+    two rows; w ``[K, P, d]`` float32; s / s_out ``[Hv, dk, dv]``; t / t_out
+    ``[(K - 1) P, d]``; o ``[Hv, dv]``.  Scratch: cols ``[d, n]`` (``k | q |
+    gate`` transposed), rows ``[3, Hv, dv]`` (``alpha``, ``beta v``, ``alpha
+    beta`` over the lanes), kq ``[Hk, dv]``."""
     from jax.experimental import pallas as pl
 
     i = pl.program_id(0)
+    Hk, (Hv, dk, dv) = key_heads, s_ref.shape
+    (K, P, _), n = w_ref.shape, cols_ref.shape[1]
 
     @pl.when(rec_ref[i] == 0)
     def _():  # no request: nothing moves, and the output is defined
@@ -135,76 +198,90 @@ def _kernel(rec_ref, slot_ref, cols_ref, rows_ref, s_ref, o_ref, out_ref, *,
 
     @pl.when(rec_ref[i] != 0)
     def _():
-        cols = cols_ref[...]
-        for h in range(heads):
-            s = s_ref[h]
-            kc = cols[:, h:h + 1]
-            kb = cols[:, heads + h:heads + h + 1]
-            qa = cols[:, 2 * heads + h:2 * heads + h + 1]
-            d = rows_ref[0, h:h + 1, :] - jnp.sum(s * kb, axis=0,
-                                                  keepdims=True)
-            o_ref[h:h + 1, :] = (jnp.sum(s * qa, axis=0, keepdims=True)
-                                 + d * rows_ref[2, h:h + 1, :])
-            out_ref[h] = s * rows_ref[1, h:h + 1, :] + kc * d
+        # the convolution's one position, as causal_conv1d_step sums it
+        taps = [t_ref[j * P:(j + 1) * P, :] for j in range(K - 1)]
+        taps.append(x_ref[...])
+        y = sum(t.astype(jnp.float32) * w_ref[j]
+                for j, t in enumerate(taps)).astype(x_ref.dtype)
+        for j in range(1, K):
+            t_out[(j - 1) * P:j * P, :] = taps[j]
+        q, k, v = delta_heads(y[:Hk], y[Hk:2 * Hk], y[2 * Hk:])
+        cols_ref[...] = jnp.concatenate(
+            [k, q, gate_ref[...],
+             jnp.zeros((n - 2 * Hk - 8, dk), jnp.float32)], axis=0).T
+        alpha, beta = (
+            jnp.broadcast_to(cols_ref[:Hv, 2 * Hk + j:2 * Hk + j + 1],
+                             (Hv, dv)) for j in (0, 1))
+        rows_ref[0], rows_ref[1], rows_ref[2] = alpha, beta * v, alpha * beta
+        kq_ref[...] = jnp.broadcast_to(
+            jnp.sum(k * q, axis=-1, keepdims=True), (Hk, dv))
+        for j in range(Hk):  # a key head's columns serve Hv / Hk heads
+            kc = jnp.broadcast_to(cols_ref[:, j:j + 1], (dk, dv))
+            qc = jnp.broadcast_to(cols_ref[:, Hk + j:Hk + j + 1], (dk, dv))
+            for h in range(j * (Hv // Hk), (j + 1) * (Hv // Hk)):
+                s, a = s_ref[h], rows_ref[0, h:h + 1, :]
+                d = (rows_ref[1, h:h + 1, :] - rows_ref[2, h:h + 1, :]
+                     * jnp.sum(s * kc, axis=0, keepdims=True))
+                o_ref[h:h + 1, :] = (
+                    a * jnp.sum(s * qc, axis=0, keepdims=True)
+                    + d * kq_ref[j:j + 1, :])
+                s_out[h] = s * a + kc * d
 
 
 @functools.partial(jax.jit, static_argnames=("layer", "interpret"))
-def _kernel_call(cols, rows, records, rec, *, layer: int, interpret: bool):
+def _kernel_update(qkv, alpha, beta, conv_w, state, rec, *, layer: int,
+                   interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b = rec.shape[0]
+    records, tails = state["s"], state["conv"]
+    b, K = rec.shape[0], conv_w.shape[0]
     _, _, Hv, dk, dv = records.shape
+    P = qkv.shape[1] // dk
+    Hk = (P - Hv) // 2
+    n = -(-(2 * Hk + 8) // dk) * dk  # the transposed block's columns
     # live slots first, then the idle ones, which all name record 0
     order = jnp.argsort(rec == 0, stable=True).astype(jnp.int32)
+    gate = jnp.pad(jnp.stack([alpha, beta], 1).astype(jnp.float32),
+                   ((0, 0), (0, 6), (0, dk - Hv)))
+    # the barrier keeps the product that made qkv apart from its view by
+    # heads (ops/layers.py:heads_projection: folded, XLA:TPU transposes the
+    # whole W_qkv every step); the relayout falls on 128 rows
+    x = jax.lax.optimization_barrier(qkv).reshape(b, P, dk)
+    by_slot = lambda i, rec, slot: (slot[i], 0, 0)  # noqa: E731
+    record = pl.BlockSpec((None, None, Hv, dk, dv),
+                          lambda i, rec, slot: (layer, rec[i], 0, 0, 0))
+    tail = pl.BlockSpec((None, None, (K - 1) * P, dk),
+                        lambda i, rec, slot: (layer, rec[i], 0, 0))
     block = Hv * dk * dv * 4
-    small = (dk * max(3 * Hv, 128) + 3 * Hv * dv + Hv * dv) * 4
-    o, records = pl.pallas_call(
-        functools.partial(_kernel, heads=Hv),
+    small = (K + 2) * P * dk * 4  # the taps, qkv, the tail in and out
+    o, records, tails = pl.pallas_call(
+        functools.partial(_kernel, key_heads=Hk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b,),
-            in_specs=[
-                pl.BlockSpec((None, dk, 3 * Hv),
-                             lambda i, rec, slot: (slot[i], 0, 0)),
-                pl.BlockSpec((None, 3, Hv, dv),
-                             lambda i, rec, slot: (slot[i], 0, 0, 0)),
-                pl.BlockSpec((None, None, Hv, dk, dv),
-                             lambda i, rec, slot: (layer, rec[i], 0, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((None, Hv, dv),
-                             lambda i, rec, slot: (slot[i], 0, 0)),
-                pl.BlockSpec((None, None, Hv, dk, dv),
-                             lambda i, rec, slot: (layer, rec[i], 0, 0, 0)),
-            ]),
+            in_specs=[pl.BlockSpec((None, P, dk), by_slot),
+                      pl.BlockSpec((None, 8, dk), by_slot),
+                      pl.BlockSpec((K, P, dk), lambda i, rec, slot: (0, 0, 0)),
+                      record, tail],
+            out_specs=[pl.BlockSpec((None, Hv, dv), by_slot), record, tail],
+            scratch_shapes=[pltpu.VMEM((dk, n), jnp.float32),
+                            pltpu.VMEM((3, Hv, dv), jnp.float32),
+                            pltpu.VMEM((Hk, dv), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((b, Hv, dv), jnp.float32),
-                   jax.ShapeDtypeStruct(records.shape, records.dtype)],
-        # the records are rewritten where they lie (operand 4 counts the
-        # two prefetched scalars)
-        input_output_aliases={4: 1},
+                   jax.ShapeDtypeStruct(records.shape, records.dtype),
+                   jax.ShapeDtypeStruct(tails.shape, tails.dtype)],
+        # the records and the tails are rewritten where they lie (operands
+        # 5 and 6 count the two prefetched scalars)
+        input_output_aliases={5: 1, 6: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # a record in and one out, each double-buffered, and the rest
             vmem_limit_bytes=4 * block + 4 * small + (8 << 20)),
         interpret=interpret,
         name="gated_delta_update",
-    )(rec[order], order, cols, rows, records)
-    return o, records
-
-
-def _kernel_update(q, k, v, alpha, beta, records, layer, rec, interpret):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
-    alpha = alpha.astype(jnp.float32)[..., None]
-    beta = beta.astype(jnp.float32)[..., None]
-    cols = jnp.swapaxes(
-        jnp.concatenate([k, alpha * beta * k, alpha * q], axis=1), 1, 2)
-    rows = jnp.stack(
-        [beta * v, jnp.broadcast_to(alpha, v.shape),
-         jnp.broadcast_to(jnp.sum(k * q, -1, keepdims=True), v.shape)], 1)
-    return _kernel_call(cols, rows, records, rec, layer=layer,
-                        interpret=interpret)
+    )(rec[order], order, x, gate,
+      conv_w.astype(jnp.float32).reshape(K, P, dk), records, tails)
+    return o, {"s": records, "conv": tails}
 
 
 # ------------------------------------------------------------ a sequence

@@ -74,6 +74,9 @@ def causal_conv1d_step(x, w, tail):
     """One position of ``causal_conv1d`` (no bias) with the tail held FLAT:
     x ``[r, I]``; w ``[K, I]``; tail ``[r, (K - 1) I]``, the ``K - 1``
     inputs before ``x``, oldest first.  Returns ``(y [r, I], tail')``.
+    What GigaChat3.5's decode update runs off the chip (``ops/delta.py:
+    delta_update_records``, the gathered path, on the live slots' tails);
+    on the chip its kernel sums the same taps in the same order itself.
 
     Every operand stays a matrix ``[r, I]`` (lane slices of the tail at
     multiples of ``I``): viewed ``[r, K - 1, I]`` the ``K - 1`` rows would

@@ -8,7 +8,9 @@ prefill through the flash forward at 192-wide keys beside 128-wide values
 (PR 43) and its decode step reading the absorbed pair in place (PR 45),
 GigaChat3.1's flash forward at 192 beside 192 and its decode step on both
 kernels (PR 47), the gated delta rule's decode update and chunked scan
-(PR 52), compiled for a v5e that is described, not attached.
+(PR 52) and GigaChat3.5's decode step around that update, both state pools
+aliased through it (PR 53), compiled for a v5e that is described, not
+attached.
 
 The interpreter cannot see what the chip's compiler refuses: more VMEM
 than a kernel may use (the backward keeps dK/dV whole in scratch and sets
@@ -381,7 +383,8 @@ def _compiled_decode(step, params, pool, B, MB, one_chip):
     step = functools.partial(tracing.scoped, "engine.decode", step)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    tables = ({t: i32(B, 1 if "ssm" in pool[t] else MB) for t in pool}
+    tables = ({t: i32(B, 1 if {"ssm", "s"} & set(pool[t]) else MB)
+               for t in pool}
               if "k" not in pool and "kv" not in pool else i32(B, MB))
     args = (params, i32(B), i32(B), tables, pool, key,
             jax.ShapeDtypeStruct((B,), jnp.float32))
@@ -652,34 +655,95 @@ def test_phi4flash_decode_step_writes_slabs(one_chip, monkeypatch):
 
 # ------------------ the gated delta rule's two programs (PR 52: ops/delta.py)
 
-def test_the_delta_update_rewrites_the_records_where_they_lie(one_chip):
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_the_delta_update_rewrites_the_records_where_they_lie(one_chip,
+                                                               dtype):
     """GigaChat3.5's decode update at its cell's widths (128 slots, 4
-    layers of 129 records of 64 heads x [128, 128] float32, 2.2 GB): ONE
-    Mosaic call whose record operand is aliased to its result, so the
-    compiled program holds the pool once (no temporary of its size), and
-    a slot's whole record of a layer (4 MiB) is one block: two in and two
-    out in flight fit the VMEM the call asks for."""
+    layers of 129 records of 64 heads x [128, 128] float32, 2.2 GB, and
+    their tails of 3 x 16 384 channels, bfloat16 in the cell): ONE Mosaic
+    call from the slot's row of ``qkv`` to both pools, the records' and the
+    tails' operands aliased to its results, so the compiled program holds
+    each pool once (no temporary of their size).  A slot's whole record of a
+    layer (4 MiB) and its tail (96 KiB) are blocks: two in and two out in
+    flight fit the VMEM the call asks for, the convolution, the heads'
+    vectors and the one ``[128, 128]`` transpose beside them."""
     from ray_tpu.ops import delta
 
-    b, Hv, d, L, R = 128, 64, 128, 4, 129
-    f32 = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
-        s, jnp.float32, sharding=one_chip)
-    rec = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+    b, Hv, d, L, R, C, K = 128, 64, 128, 4, 129, 16384, 4
+    arr = lambda dt, *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=one_chip)
+    f32 = functools.partial(arr, jnp.float32)
+    state = {"s": f32(L, R, Hv, d, d),
+             "conv": arr(dtype, L, R, (K - 1) * C // d, d)}
 
-    def update(q, k, v, alpha, beta, records, rec):
+    def update(qkv, alpha, beta, conv_w, state, rec):
         return delta.delta_update_records(
-            q, k, v, alpha, beta, records, 2, rec, path="kernel",
+            qkv, alpha, beta, conv_w, state, 2, rec, path="kernel",
             interpret=False)
 
-    compiled = jax.jit(update, donate_argnums=(5,)).lower(
-        f32(b, Hv, d), f32(b, Hv, d), f32(b, Hv, d), f32(b, Hv), f32(b, Hv),
-        f32(L, R, Hv, d, d), rec).compile()
+    compiled = jax.jit(update, donate_argnums=(4,)).lower(
+        arr(dtype, b, C), f32(b, Hv), f32(b, Hv), arr(dtype, K, C), state,
+        arr(jnp.int32, b)).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     m = compiled.memory_analysis()
-    pool = L * R * Hv * d * d * 4
-    assert m.alias_size_in_bytes >= pool
-    assert m.temp_size_in_bytes < 0.05 * pool
+    records = L * R * Hv * d * d * 4
+    tails = L * R * (K - 1) * C * jnp.dtype(dtype).itemsize
+    assert m.alias_size_in_bytes >= records + tails
+    assert m.temp_size_in_bytes < 0.05 * records
+
+
+def test_gigachat35_decode_step_moves_no_pool_around_its_update(
+        one_chip, monkeypatch):
+    """GigaChat3.5-432B-A28B's decode step as its cell runs it (published
+    layers 0, 4-7: four Gated DeltaNet layers and one latent block, 16 held
+    experts, 16 032 rows, 128 slots, 129 records): four
+    ``gated_delta_update`` calls, each handed both state pools as they came
+    out of the one before (aliased: the program holds them once, its
+    temporaries a few MB), beside the latent arm of the paged kernel and
+    four expert kernels.  What the parent's step did around its kernel is
+    gone (PERF.md section 5, PR 52's traced run): XLA's copies of the
+    tails' pool (``bf16[4,129,49152]``, twice rematerialised, 0.40 ms a
+    step) and the kernel's column vectors laid out (``copy
+    f32[16,8,32,128]``, two a layer, 0.30 ms).  The relayout that is left
+    falls on the 128 rows of ``qkv`` (4 MB a layer), behind the barrier
+    that keeps ``W_qkv`` (235 MB) from being transposed with it."""
+    from ray_tpu.models import gigachat3_5 as gc
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = gc.GigaChat35Config(
+        vocab_size=16032, num_layers=5, dense_layers=1,
+        full_attention_layers=(4,), first_expert=80, held_experts=16,
+        max_seq_len=4112, param_dtype=jnp.bfloat16)
+    B, bs, MB, R = 128, 16, 257, 129
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    params = jax.eval_shape(
+        functools.partial(gc.gigachat3_5_init, cfg=cfg), key)
+    pool = jax.eval_shape(
+        lambda: gc.init_pools(cfg, {"latent": 512, "state": R}, bs))
+    tails = pool["state"]["conv"]
+    assert tails.shape == (4, R, 384, 128) and tails.dtype == jnp.bfloat16
+    assert gc.delta.delta_update_path(pool["state"]) == "kernel"
+    compiled = _compiled_decode(
+        functools.partial(gc.decode_sample, cfg=cfg, attn="latent_kernel"),
+        params, pool, B, MB, one_chip)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    assert len(re.findall(r"%gated_delta_update[.\d]* = ", text)) == 4
+    copies = re.findall(r"= (\w+\[[\d,]*\])\S* copy\(", text)
+    assert copies and not {
+        "bf16[4,129,49152]", "bf16[129,49152]", "bf16[4,129,384,128]",
+        "bf16[129,384,128]", "f32[16,8,32,128]"} & set(copies), copies
+    assert "bf16[16384,7168]" not in copies  # W_qkv, transposed
+    m = compiled.memory_analysis()
+    pools = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                for a in jax.tree.leaves(pool))
+    assert m.alias_size_in_bytes >= pools
+    assert m.temp_size_in_bytes < 0.05 * pool["state"]["s"].size * 4
+    counted, bare = _unscoped(text)
+    assert counted >= 100 and len(bare) <= 0.05 * counted, bare
 
 
 def test_the_chunked_delta_scan_compiles_for_a_prompt_bucket(one_chip):

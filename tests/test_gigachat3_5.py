@@ -10,8 +10,10 @@ Seeded float32 at toy widths on the CPU:
     bias that matter (all are neutral at initialisation);
 (b) ``ops/delta.py``: the chunked scan against the sequential recurrence at
     lengths that are no multiple of a chunk and shorter than the bucket; the
-    decode update's one-pass form and its Pallas kernel (interpreter)
-    against the recurrence as written, idle slots untouched;
+    decode update as one operation (the convolution's position, the heads'
+    vectors, the rule), gathered and as its Pallas kernel (interpreter),
+    against the composition it replaced, on records and tails, idle slots
+    and unheld records untouched;
 (c) prefill then decode through ``LLMEngine`` (the latent pool and the
     records) against the reference's full forward, on logits; a record's
     life through preemption;
@@ -49,6 +51,7 @@ from ray_tpu.models.gigachat3_5 import (GigaChat35Config, gigachat3_5_apply,
                                         gigachat3_5_init)
 from ray_tpu.models.served import preset, served_model
 from ray_tpu.ops import delta
+from ray_tpu.ops.ssm import causal_conv1d_step
 
 TOL = 5e-5
 _model = family.model_of  # the configuration as the reference takes it
@@ -198,30 +201,49 @@ def test_the_chunked_scan_is_the_sequential_recurrence(s, length, chunk):
         assert float(jnp.max(jnp.abs(got_s - cut))) < 2e-6
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("Hk", [2, 4], ids=["half-the-heads", "every-head"])
 @pytest.mark.parametrize("path", ["gather", "kernel"])
-def test_the_decode_update_moves_the_live_records_alone(path):
-    """One position on the slots' own records, through the gathered path
-    and through the Pallas kernel (the interpreter): the recurrence as it
-    is written (``delta_step``), layer 0 and the records no slot holds
-    untouched, an idle slot (record 0) moving nothing."""
-    q, k, v, g, beta, _ = _delta_inputs(5, 1, 4, 16, seed=2)
-    q, k, v, alpha, beta = q[:, 0], k[:, 0], v[:, 0], jnp.exp(g[:, 0]), \
-        beta[:, 0]
-    records = jax.random.normal(jax.random.PRNGKey(9), (2, 6, 4, 16, 16))
+def test_the_decode_update_moves_the_live_records_alone(path, Hk, dtype):
+    """One position of a layer as ONE operation on the slots' own records
+    and tails, through the gathered path and through the Pallas kernel (the
+    interpreter), against the composition it replaced on the gathered live
+    records: the convolution's position (``causal_conv1d_step``), the
+    heads' vectors (``delta_heads_of``: a key head serving ``Hv / Hk`` value
+    heads) and the recurrence as it is written (``delta_step``).  The tails
+    to the bit (the model's dtype, float32 or bfloat16); layer 0 and every
+    record AND TAIL no slot holds untouched; an idle slot (record 0) moving
+    nothing."""
+    b, L, R, K, Hv, d = 5, 2, 6, 4, 4, 16
+    C = (2 * Hk + Hv) * d
+    ks = jax.random.split(jax.random.PRNGKey(2), 6)
+    qkv = jax.random.normal(ks[0], (b, C)).astype(dtype)
+    alpha = jnp.exp(-0.3 * jax.nn.softplus(jax.random.normal(ks[1], (b, Hv))))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[2], (b, Hv)))
+    conv_w = (0.5 * jax.random.normal(ks[3], (K, C))).astype(dtype)
+    state = {"s": jax.random.normal(ks[4], (L, R, Hv, d, d)),
+             "conv": jax.random.normal(
+                 ks[5], (L, R, (K - 1) * C // d, d)).astype(dtype)}
     rec = jnp.asarray([3, 0, 1, 0, 5], jnp.int32)
-    live = np.asarray(rec) != 0
-    want_o, want_s = delta.delta_step(q, k, v, alpha, beta, records[1, rec])
+    live, free = np.asarray(rec) != 0, jnp.asarray([2, 4])
+    y, want_tail = causal_conv1d_step(
+        qkv, conv_w, state["conv"][1, rec].reshape(b, -1))
+    want_o, want_s = delta.delta_step(
+        *delta.delta_heads_of(y, Hk, Hv, d), alpha, beta, state["s"][1, rec])
     got_o, got = delta.delta_update_records(
-        q, k, v, alpha, beta, records, 1, rec, path=path,
+        qkv, alpha, beta, conv_w, state, 1, rec, path=path,
         **({"interpret": True} if path == "kernel" else {}))
     assert float(jnp.max(jnp.abs(got_o - want_o)[live])) < 5e-6
-    assert float(jnp.max(jnp.abs(got[1, rec] - want_s)[live])) < 2e-6
-    assert jnp.array_equal(got[0], records[0])
-    assert jnp.array_equal(got[1, jnp.asarray([2, 4])],
-                           records[1, jnp.asarray([2, 4])])
+    assert float(jnp.max(jnp.abs(got["s"][1, rec] - want_s)[live])) < 2e-6
+    assert got["conv"].dtype == dtype and jnp.array_equal(
+        got["conv"][1, rec].reshape(b, -1)[live], want_tail[live])
+    for leaf in ("s", "conv"):
+        assert jnp.array_equal(got[leaf][0], state[leaf][0]), leaf
+        assert jnp.array_equal(got[leaf][1, free], state[leaf][1, free]), leaf
     if path == "kernel":  # an idle slot's output is defined
         assert not np.asarray(got_o)[~live].any()
-    assert delta.delta_update_path(records) == "gather"  # the CPU
+    assert delta.delta_update_path(state) == "gather"  # the CPU
 
 
 # ----------------------------------------------------- (c) the engine
@@ -234,7 +256,8 @@ def test_engine_decodes_through_the_latent_pool_and_the_records():
         1, eng.num_blocks["latent"], 8, 128)
     assert eng.pool["state"]["s"].shape == (3, 5, 4, 16, 16)
     assert eng.pool["state"]["s"].dtype == jnp.float32
-    assert eng.pool["state"]["conv"].shape == (3, 5, 3 * 128)
+    # a record's tail: 3 taps of the 128 channels, a row a head's width
+    assert eng.pool["state"]["conv"].shape == (3, 5, 3 * 8, 16)
     assert [(p.name, p.state, p.blocks.run) for p in eng._pools] == [
         ("latent", False, eng._pools[0].blocks.run), ("state", True, 1)]
     rng = np.random.default_rng(0)
