@@ -50,11 +50,16 @@ def init(
     ``Node.start_ray_processes`` (``node.py:1467``).
     """
     global _node_services
+    from ray_tpu._private import tracing
     from ray_tpu._private import worker as worker_mod
     from ray_tpu._private.node import NodeServices, default_resources
     from ray_tpu._private.worker import CoreWorker, WorkerMode
 
-    with _init_lock:
+    # one ``init`` span with a child for each phase this thread blocks in
+    # (docs/observability.md).  There is no GCS yet to publish to: they
+    # stay in the local buffer, which outlives ``shutdown()``.
+    with _init_lock, tracing.span("init",
+                                  attrs={"address": address or "local"}):
         if worker_mod.global_worker is not None:
             if ignore_reinit_error:
                 return RuntimeInfo(_node_services.gcs_addr if _node_services else address or "")
@@ -79,7 +84,11 @@ def init(
             if resources:
                 base.update({k: float(v) for k, v in resources.items()})
             _node_services = NodeServices()
-            gcs_addr = _node_services.start_head(base, labels, _system_config)
+            # the head process (GCS + raylet + zygote) from its spawn to
+            # its address file; its own parts are this span's children
+            with tracing.span("init.start_head"):
+                gcs_addr = _node_services.start_head(
+                    base, labels, _system_config)
             session_dir = _node_services.session_dir
         else:
             gcs_addr = address
@@ -94,8 +103,11 @@ def init(
             try:
                 from ray_tpu._private.rpc import mint_mid
 
-                nodes = await c.call("get_all_nodes")
-                job_id = await c.call("next_job_id", _mid=mint_mid())
+                with tracing.span("init.gcs"):  # the GCS answers
+                    job_id = await c.call("next_job_id", _mid=mint_mid())
+                # the raylet stands in the node table with what it detected
+                with tracing.span("init.raylet"):
+                    nodes = await c.call("get_all_nodes")
                 return nodes, job_id
             finally:
                 await c.close()
@@ -117,20 +129,21 @@ def init(
             info = run_sync(_info())
             session_dir = info["session_dir"]
 
-        core = CoreWorker(
-            mode=WorkerMode.DRIVER,
-            session_dir=session_dir,
-            gcs_addr=gcs_addr,
-            raylet_addr=raylet_addr,
-            node_id=head["node_id"],
-            job_id=JobID.from_int(job_no),
-        )
-        core.start()
-        core.namespace = namespace or ""
-        worker_mod.global_worker = core
-        core.run_coro(core.gcs.call(
-            "add_job", job_id=job_no,
-            info={"driver_pid": _pid(), "driver_addr": core.serve_addr}))
+        with tracing.span("init.connect"):  # the driver's core worker
+            core = CoreWorker(
+                mode=WorkerMode.DRIVER,
+                session_dir=session_dir,
+                gcs_addr=gcs_addr,
+                raylet_addr=raylet_addr,
+                node_id=head["node_id"],
+                job_id=JobID.from_int(job_no),
+            )
+            core.start()
+            core.namespace = namespace or ""
+            worker_mod.global_worker = core
+            core.run_coro(core.gcs.call(
+                "add_job", job_id=job_no,
+                info={"driver_pid": _pid(), "driver_addr": core.serve_addr}))
         if log_to_driver:
             # worker prints stream back to this process's stdout
             core.start_log_streaming()
@@ -160,18 +173,24 @@ def is_initialized() -> bool:
 def shutdown():
     """Disconnect the driver and stop the cluster if this driver started it."""
     global _node_services
+    from ray_tpu._private import tracing
     from ray_tpu._private import worker as worker_mod
 
     with _init_lock:
-        if worker_mod.global_worker is not None:
-            try:
-                worker_mod.global_worker.shutdown()
-            except Exception:
-                pass
-            worker_mod.global_worker = None
-        if _node_services is not None:
-            _node_services.stop()
-            _node_services = None
+        if worker_mod.global_worker is None and _node_services is None:
+            return
+        # closes after the last publish: like ``init`` it stays in the
+        # local buffer, for whoever reads it in this process afterwards
+        with tracing.span("shutdown"):
+            if worker_mod.global_worker is not None:
+                try:
+                    worker_mod.global_worker.shutdown()
+                except Exception:
+                    pass
+                worker_mod.global_worker = None
+            if _node_services is not None:
+                _node_services.stop()
+                _node_services = None
 
 
 def get(refs: Union[ObjectRef, Sequence[ObjectRef]], *, timeout: Optional[float] = None):

@@ -29,7 +29,15 @@ from __future__ import annotations
 import glob
 import os
 import sys
+import time
 from typing import Dict, List, Optional
+
+from ray_tpu._private import tracing
+
+# when this worker process began (``worker_proc.main`` stamps it), and
+# (chips, when) a lease bound it to chips: ``worker.chip_acquire``'s stamps
+worker_started_at: Optional[float] = None
+_bound: Optional[tuple] = None
 
 
 class TPUAcceleratorManager:
@@ -172,12 +180,36 @@ def bind_tpu_chips(chip_ids: List[int], node_chips: int) -> bool:
     own topology discovery alone (the host may be one of a multi-host
     slice).  False when a backend is already live here: it can no
     longer be bound."""
+    global _bound
     if jax_backend_initialized():
         return False
     if len(chip_ids) < node_chips:
         TPUAcceleratorManager.set_visible_chips(os.environ, chip_ids)
     pin_jax_platform("tpu")
+    _bound = (len(chip_ids), time.time())
     return True
+
+
+def record_chip_acquire() -> None:
+    """Record ``worker.chip_acquire`` [``chips``, ``bound_s``], once: from
+    this worker's start to its first backend initialisation returning (of
+    it ``bound_s`` before the lease bound it to its chips).  Called by the
+    code that first touches the backend, right after its first
+    ``jax.devices()`` (``LLMEngine.__init__``, ``train.session.get_mesh``):
+    forcing the backend from :func:`bind_tpu_chips` would break
+    ``jax.distributed.initialize()``.  Nothing in a process that was not
+    bound to chips."""
+    global _bound
+    if _bound is None:
+        return
+    (chips, bound), _bound = _bound, None
+    start = worker_started_at or bound
+    tracing.watch_builds()  # jax is imported by now, whoever imported it
+    if tracing.is_enabled():
+        tracing.record_span(
+            "worker.chip_acquire", start, time.time(),
+            tracing.current_or_root().child(), kind="startup",
+            attrs={"chips": chips, "bound_s": round(bound - start, 3)})
 
 
 def detect_resources() -> Dict[str, float]:

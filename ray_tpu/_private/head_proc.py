@@ -16,6 +16,7 @@ import json
 import logging
 import os
 import sys
+import time
 
 
 # strong refs to fire-and-forget startup tasks (the event loop keeps only
@@ -67,6 +68,9 @@ async def _start_client_server(session_dir, gcs, raylet, client_port: int):
 
 
 def main():
+    # wall stamps of this process's start-up, for the driver's
+    # ``init.start_head.<part>`` spans (node.py:_record_head_parts)
+    stamps = [("python", time.time())]  # interpreter up, ray_tpu imported
     parser = argparse.ArgumentParser()
     parser.add_argument("--session-dir", required=True)
     parser.add_argument("--resources", required=True, help="json resource map")
@@ -82,6 +86,7 @@ def main():
     from ray_tpu._private.gcs import GcsServer
     from ray_tpu._private.raylet import Raylet
 
+    stamps.append(("imports", time.time()))
     loop = asyncio.new_event_loop()
     asyncio.set_event_loop(loop)
 
@@ -96,9 +101,11 @@ def main():
 
     async def _start():
         await gcs.start(port=args.port)
+        stamps.append(("gcs", time.time()))
         raylet.gcs_addr = gcs.addr
         raylet.gcs.addr = gcs.addr
         await raylet.start()
+        stamps.append(("raylet", time.time()))
         # dashboard on the same loop (reference: dashboard head process);
         # off by RAY_TPU_DASHBOARD=0
         if os.environ.get("RAY_TPU_DASHBOARD", "1") != "0":
@@ -128,6 +135,10 @@ def main():
                 _start_client_server(args.session_dir, gcs, raylet,
                                      client_port)))
 
+        stamps.append(("services", time.time()))  # dashboard, client proxy
+        with open(os.path.join(args.session_dir,
+                               "head_startup.json"), "w") as f:
+            json.dump(stamps, f)
         # head marker for the driver: address file
         addr_file = os.path.join(args.session_dir, "gcs_address")
         with open(addr_file + ".tmp", "w") as f:

@@ -19,6 +19,8 @@ import sys
 import time
 from typing import Any, Dict, Optional
 
+from ray_tpu._private import tracing
+
 logger = logging.getLogger(__name__)
 
 _SESSION_ROOT = "/tmp/ray_tpu"
@@ -103,6 +105,7 @@ class NodeServices:
             for k, v in system_config.items():
                 env[f"RAY_TPU_{k.upper()}"] = str(v)
         log = open(os.path.join(self.session_dir, "logs", "head.log"), "ab")
+        spawned = time.time()
         self.head_proc = subprocess.Popen(
             [
                 sys.executable, "-m", "ray_tpu._private.head_proc",
@@ -123,6 +126,7 @@ class NodeServices:
                 with open(addr_file) as f:
                     self.gcs_addr = f.read().strip()
                 atexit.register(self.stop)
+                self._record_head_parts(spawned)
                 return self.gcs_addr
             if self.head_proc.poll() is not None:
                 log_path = os.path.join(self.session_dir, "logs", "head.log")
@@ -136,6 +140,24 @@ class NodeServices:
                     f"head process exited rc={self.head_proc.returncode}\n{tail}")
             time.sleep(0.05)
         raise TimeoutError("timed out waiting for head to start")
+
+    def _record_head_parts(self, spawned: float) -> None:
+        """The head's own stamps (``head_proc`` leaves them beside its
+        address file) as ``init.start_head.<part>`` spans, back to back
+        from the spawn, under the span that waited for the head."""
+        if not tracing.is_enabled():
+            return
+        try:
+            with open(os.path.join(self.session_dir,
+                                   "head_startup.json")) as f:
+                stamps = json.load(f)
+        except (OSError, ValueError):
+            return
+        parent = tracing.current_or_root()
+        for part, at in stamps:
+            tracing.record_span(f"init.start_head.{part}", spawned, at,
+                                parent.child(), kind="startup")
+            spawned = at
 
     def stop(self):
         if not self._owns_cluster:
@@ -165,13 +187,15 @@ class NodeServices:
         except Exception:
             pass
         if self.head_proc is not None:
-            try:
-                self.head_proc.wait(timeout=3)
-            except Exception:
+            with tracing.span("shutdown.wait", attrs={
+                    "what": "head", "pid": self.head_proc.pid}):
                 try:
-                    self.head_proc.kill()
+                    self.head_proc.wait(timeout=3)
                 except Exception:
-                    pass
+                    try:
+                        self.head_proc.kill()
+                    except Exception:
+                        pass
             self.head_proc = None
         self._cleanup_shm()
 

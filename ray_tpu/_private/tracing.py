@@ -38,13 +38,16 @@ Overhead contract: with ``RAY_TPU_TRACING=0`` every hook is one dict/env
 check (no allocation, no lock) and neither sink is written.  Enabled, a
 span is one ``time.time()`` pair plus a deque append, and an annotation
 one ``TraceMe`` that goes nowhere unless a profiler session runs.  Read on
-the v5e host's CPU (PERF.md section 6, PR 24): ``annotate`` 2.0 us without
-a session, 7.1 us inside one with the Python tracer on, 0.8 us switched
-off; ``span`` 23 us, 1.8 us switched off.  The serve loop makes ~16
-annotations a decode window of ~800 ms, and three ``--trace 0`` runs each
-of the ``serve-chat-steady`` cell with tracing on and switched off read the
-same median time per token (56.995 and 56.990 ms; the runs of either side
-spread 0.3 ms).
+the v5e host's CPU (PERF.md section 6, PR 54; PR 24 read the same):
+``annotate`` 2.1 us without a session, 7.6 us inside one with the Python
+tracer on, 0.9 us switched off (``serve.publish_stats`` with its nine
+stats: 2.8 / 11.8 us, once in 2 s); ``span`` 23 us, 2.1 us switched off;
+``build_counters`` 0.4 us; the build ledger's listener 34 us a call, three
+calls a program built, none on a step that builds nothing.  The serve loop
+makes ~16 annotations a decode window of ~225 ms, and three ``--trace 0``
+runs each of the ``serve-chat-steady`` cell with tracing on and switched
+off read medians of 14.38 and 14.43 ms a token (on 14.29-14.46, off
+13.99-14.82: the cell's own spread is larger than any difference).
 
 A third face, for code that JAX traces: :func:`scope` is a name scope
 INSIDE a device program (``engine.decode`` / ``attn.proj``: the program,
@@ -52,6 +55,17 @@ then the part of the model).  It is metadata of the compiled instructions,
 so it has no run-time path and nothing to switch off; the profiler's trace
 carries it on every device instruction (``docs/observability.md`` has the
 vocabulary, ``cells/parts.py`` the readers).
+
+A fourth, for the programs JAX builds: :func:`watch_builds` is the
+process's build ledger.  One pair of ``jax.monitoring`` listeners counts
+every function traced, lowered, compiled or loaded from the persistent
+cache (:func:`build_counters`, always on: a listener runs only while JAX
+builds) and, with tracing on, writes each backend compile into both sinks
+as ``xla.build``, under the context that paid for it.  With the start-up
+spans (``init``, ``worker.chip_acquire``, ``engine.startup``,
+``train.startup``: plain :func:`span` calls where a process starts) it
+says where set-up goes and which program was built while requests waited
+(``docs/observability.md``, ``cells/startup.py``).
 
 Span-hygiene (enforced by the ``span-hygiene`` raylint rule): ``span()``
 and ``trace()`` are context managers and must be entered with ``with``;
@@ -64,6 +78,7 @@ import contextlib
 import contextvars
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -412,6 +427,136 @@ def trace(name: str, *, attrs: Optional[Dict[str, Any]] = None
     finally:
         _current.reset(token)
         record_span(name, start, time.time(), ctx, kind="root", attrs=attrs)
+
+
+# ---------------------------------------------------------------------------
+# the build ledger: the programs JAX builds or loads in this process
+# ---------------------------------------------------------------------------
+
+_BUILD_EVENT = "/jax/core/compile/backend_compile_duration"
+_LOWER_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration")
+# the persistent cache's hit, fired inside the compile's span on its thread
+# (the whole name starts with JAX's cache module; node.py alone names that)
+_CACHE_HIT_SUFFIX = "/cache_hits"
+# JAX's own rule for a module's name (``jit(<unknown>)`` -> ``jit__unknown``):
+# what the compile cache's files and the profiler's ``XLA Modules`` say
+_MODULE_NAME_RE = re.compile(r"[^\w.-]")
+
+_build_lock = threading.Lock()
+_builds_watched = False
+_builds: Dict[str, float] = {"built": 0, "loaded": 0, "build_s": 0.0,
+                             "load_s": 0.0, "lower_s": 0.0}
+
+
+class _BuildThread(threading.local):
+    """A building thread's side of the ledger: ``spans`` the phases counted
+    so far, innermost last (a phase that ends later and began earlier holds
+    them, and counts only what they left); ``lower_s`` traced and lowered
+    since the thread's last build; ``hit`` the cache answered inside the
+    build that is ending."""
+
+    def __init__(self):
+        self.spans: List[tuple] = []
+        self.lower_s = 0.0
+        self.hit = False
+
+
+_build_tls = _BuildThread()
+
+
+def build_counters() -> Dict[str, float]:
+    """What :func:`watch_builds` has counted in this process: ``built``
+    (programs the backend compiled), ``loaded`` (programs the persistent
+    cache handed back), the seconds of either (``build_s``, ``load_s``) and
+    ``lower_s``, the seconds spent tracing functions and lowering them to
+    MLIR.  Nested phases are counted once (a function traced while another
+    is, a constant folded by a program built inside a trace), so the three
+    sum to the time the building threads spent on programs."""
+    with _build_lock:
+        return dict(_builds)
+
+
+def _own_seconds(start: float, end: float) -> float:
+    """``end - start`` less the phases already counted inside it, on this
+    thread; the phase takes their place."""
+    held = _build_tls.spans
+    inner = 0.0
+    while held and held[-1][0] >= start:
+        s, e = held.pop()
+        inner += e - s
+    held.append((start, end))
+    if len(held) > 256:  # siblings of phases long closed
+        del held[:128]
+    return max(0.0, end - start - inner)
+
+
+def _on_build_span(event: str, start: float, end: float, **kw) -> None:
+    try:
+        if event in _LOWER_EVENTS:
+            own = _own_seconds(start, end)
+            _build_tls.lower_s += own
+            with _build_lock:
+                _builds["lower_s"] += own
+            return
+        if event != _BUILD_EVENT:
+            return
+        own = _own_seconds(start, end)
+        cached, _build_tls.hit = _build_tls.hit, False
+        lower_ms = round(_build_tls.lower_s * 1e3, 3)
+        _build_tls.lower_s = 0.0
+        with _build_lock:
+            _builds["loaded" if cached else "built"] += 1
+            _builds["load_s" if cached else "build_s"] += own
+        if not is_enabled():
+            return
+        program = _MODULE_NAME_RE.sub(
+            "_", str(kw.get("fun_name", ""))).rstrip("_")
+        record_span("xla.build", start, end, current_or_root().child(),
+                    kind="build", attrs={"program": program,
+                                         "cached": int(cached),
+                                         "lower_ms": lower_ms})
+        # an instant at the build's end, on the building thread's line of a
+        # profiler trace: a reader takes [end - ms, end]
+        with annotate("xla.build", program=program,
+                      ms=round((end - start) * 1e3, 3), cached=int(cached)):
+            pass
+    except Exception:  # noqa: BLE001 — a listener must never fail a build
+        pass
+
+
+def _on_build_event(event: str, **kw) -> None:
+    if event.endswith(_CACHE_HIT_SUFFIX):
+        _build_tls.hit = True
+
+
+def watch_builds() -> bool:
+    """Start the process's build ledger: ONE time-span listener and ONE
+    event listener with ``jax.monitoring``, which deliver every function
+    traced, lowered and compiled (or loaded from the persistent cache) with
+    its stamps and name.  Idempotent; ``jax`` is looked up in
+    ``sys.modules`` and never imported, so a process without it registers
+    nothing and returns False (call again once it has).
+
+    The counters (:func:`build_counters`) are always on: a listener runs
+    only while JAX builds, nothing is added to a step's path.  With tracing
+    enabled every backend compile is also an ``xla.build`` span in the host
+    buffer under the current context (``program``, ``cached``,
+    ``lower_ms``: what its thread traced and lowered since its last build)
+    and one instant ``xla.build`` annotation at its end (``program``,
+    ``ms``, ``cached``) for a profiler session.  Trace and lower phases are
+    counted, not recorded: an eager initialisation makes hundreds."""
+    global _builds_watched
+    monitoring = getattr(sys.modules.get("jax"), "monitoring", None)
+    if monitoring is None:
+        return False
+    with _build_lock:
+        if _builds_watched:
+            return True
+        _builds_watched = True
+    monitoring.register_event_time_span_listener(_on_build_span)
+    monitoring.register_event_listener(_on_build_event)
+    return True
 
 
 # ---------------------------------------------------------------------------

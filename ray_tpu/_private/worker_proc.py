@@ -23,9 +23,10 @@ def main():
     # (accelerators.py — one process per chip): until the raylet binds
     # it to the chips of a TPU lease, JAX in here is the CPU — never a
     # chip some other worker holds a lease for.
-    from ray_tpu._private.accelerators import pin_jax_platform
+    from ray_tpu._private import accelerators
 
-    pin_jax_platform("cpu")
+    accelerators.worker_started_at = time.time()
+    accelerators.pin_jax_platform("cpu")
     session_dir = os.environ["RAY_TPU_SESSION_DIR"]
     gcs_addr = os.environ["RAY_TPU_GCS_ADDR"]
     raylet_addr = os.environ["RAY_TPU_RAYLET_ADDR"]

@@ -52,6 +52,7 @@ The default tokenizer is the in-repo byte-level BPE (``llm/bpe.py``);
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import time
 import functools
@@ -60,7 +61,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ray_tpu._private import tracing
+from ray_tpu._private import accelerators, tracing
 from ray_tpu.models.paged_generation import SamplingParams
 
 
@@ -441,6 +442,11 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
+        # set-up's parts in seconds (stats()["startup"]), each an
+        # ``engine.startup.<part>`` span under the ``engine.startup`` of
+        # whoever builds the engine (serving.py:_build_engine)
+        self.startup: Dict[str, float] = {}
+        entered = time.time()
         from ray_tpu.models.served import served_model
 
         # the programs come from the model whose configuration this is
@@ -482,45 +488,52 @@ class LLMEngine:
                 for t, spec in types.items()}
             self.num_blocks = {t: asked.get(t) or self.B * most[t] + 1
                                for t in types}
-        if params is None:
-            params = model.init(jax.random.PRNGKey(seed), cfg)
+        with self._startup_phase("backend"):
+            jax.devices()  # the process's first touch of its backend
+            accelerators.record_chip_acquire()
+        # until the weights are dispatched, not until they are there: the
+        # wait for them overlaps the pool and the first program's lowering
+        with self._startup_phase("weights"):
+            if params is None:
+                params = model.init(jax.random.PRNGKey(seed), cfg)
         self.params = params
         self._key = jax.random.PRNGKey(seed + 1)
 
         # kv_cache_dtype="int8": ~half the pool HBM -> ~2x the slots fit
         # next to the weights (vLLM kv_cache_dtype, TPU-native)
         self.kv_cache_dtype = kv_cache_dtype
-        self.pool = model.init_pool(cfg, self.num_blocks, self.bs,
-                                    kv_dtype=kv_cache_dtype)
-        if mesh is not None:
-            self._shard_over_mesh(mesh)
-        # the host's side of each pool; ``blocks`` / ``_tables`` are the
-        # first's (the only one's, for a model of one layer type), whose
-        # blocks a request holds in ``Request.blocks``; ``_more`` the rest
-        one = {"kv": {"layers": cfg.num_layers, "window": None}}
-        sizes = self.num_blocks if types else {"kv": self.num_blocks}
-        # (pages a compute block of the decode kernel, pages a copy of
-        # it): the blocks a slot is handed at once; a state type's records
-        # come one by one
-        plan = {t: (1, 1) if spec.get("state") else self._page_plan(
-            self.pool[t] if types else self.pool)
-            for t, spec in (types or one).items()}
-        pools = [_LayerPool(t, spec["layers"], spec["window"],
-                            _BlockManager(sizes[t], plan[t][1]),
-                            np.zeros((self.B, 1 if spec.get("state")
-                                      else self.MB), np.int32),
-                            spec.get("readers", spec["layers"]),
-                            bool(spec.get("state")), plan[t][0])
-                 for t, spec in (types or one).items()]
-        if pools[0].window is not None or pools[0].state:
-            raise ValueError(f"{model.name}: the first layer type keeps "
-                             f"every position (a slot is live where its "
-                             f"first table holds a block)")
-        self._pools, self._more = pools, pools[1:]
-        # the pools of positions: what "blocks" counts in stats()
-        self._kv_pools = [p for p in pools if not p.state]
-        self._by_type = types is not None  # programs take tables by type
-        self.blocks = pools[0].blocks
+        with self._startup_phase("pool"):
+            self.pool = model.init_pool(cfg, self.num_blocks, self.bs,
+                                        kv_dtype=kv_cache_dtype)
+            if mesh is not None:
+                self._shard_over_mesh(mesh)
+            # the host's side of each pool; ``blocks`` / ``_tables`` are the
+            # first's (the only one's, for a model of one layer type), whose
+            # blocks a request holds in ``Request.blocks``; ``_more`` the rest
+            one = {"kv": {"layers": cfg.num_layers, "window": None}}
+            sizes = self.num_blocks if types else {"kv": self.num_blocks}
+            # (pages a compute block of the decode kernel, pages a copy of
+            # it): the blocks a slot is handed at once; a state type's records
+            # come one by one
+            plan = {t: (1, 1) if spec.get("state") else self._page_plan(
+                self.pool[t] if types else self.pool)
+                for t, spec in (types or one).items()}
+            pools = [_LayerPool(t, spec["layers"], spec["window"],
+                                _BlockManager(sizes[t], plan[t][1]),
+                                np.zeros((self.B, 1 if spec.get("state")
+                                          else self.MB), np.int32),
+                                spec.get("readers", spec["layers"]),
+                                bool(spec.get("state")), plan[t][0])
+                     for t, spec in (types or one).items()]
+            if pools[0].window is not None or pools[0].state:
+                raise ValueError(f"{model.name}: the first layer type keeps "
+                                 f"every position (a slot is live where its "
+                                 f"first table holds a block)")
+            self._pools, self._more = pools, pools[1:]
+            # the pools of positions: what "blocks" counts in stats()
+            self._kv_pools = [p for p in pools if not p.state]
+            self._by_type = types is not None  # programs take tables by type
+            self.blocks = pools[0].blocks
         # the decode step's attention, read off what is in front of us:
         # "paged_kernel" / "latent_kernel" (live blocks read in place) for
         # a dense / latent pool on one TPU device, "gather" for the rest
@@ -618,6 +631,17 @@ class LLMEngine:
         # however many prefills) until the first tokens' fetch takes them
         self._prefill_sum: Optional[Any] = None
         self._prefill_calls = 0
+        # this constructor's wall: the parts above and the little between
+        self.startup["total_s"] = round(time.time() - entered, 3)
+
+    @contextlib.contextmanager
+    def _startup_phase(self, part: str):
+        """One part of ``__init__``: an ``engine.startup.<part>`` span, and
+        its seconds in ``stats()["startup"]``."""
+        with tracing.span(f"engine.startup.{part}", kind="startup"):
+            t0 = time.time()
+            yield
+            self.startup[f"{part}_s"] = round(time.time() - t0, 3)
 
     def _page_plan(self, pool: Dict[str, Any]) -> Tuple[int, int]:
         """``(pages, run)`` of one type's pool of positions as the decode
@@ -1417,6 +1441,11 @@ class LLMEngine:
             "model": self.model.name,
             "counters": dict(self.counters),
             "devices": device_memory_stats(),
+            # where set-up went, and the programs this process has built
+            # or loaded since: a sum that rises in steady state is a shape
+            # the warm-up did not meet (docs/observability.md)
+            "startup": dict(self.startup),
+            "builds": tracing.build_counters(),
         }
 
     # -- admission / prefill ------------------------------------------------

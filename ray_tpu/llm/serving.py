@@ -47,20 +47,26 @@ def _build_engine(engine_kwargs: Optional[Dict[str, Any]],
     """Shared engine construction (by-name config so the DRIVER never has
     to import jax; inference weights default to bf16).  ``model`` names a
     preset of any served model (``ray_tpu/models/served.py``)."""
-    from ray_tpu.llm.engine import LLMEngine
-    from ray_tpu.models.served import preset
+    # a replica's set-up, from here to the engine's last line: the
+    # engine's own parts (their sum is stats()["startup"]["total_s"]) are
+    # this span's children, the imports below its self time, and every
+    # program built under it an ``xla.build``
+    with tracing.span("engine.startup", kind="startup"):
+        from ray_tpu.llm.engine import LLMEngine
+        from ray_tpu.models.served import preset
 
-    kw = dict(engine_kwargs or {})
-    cfg = kw.pop("cfg", None)
-    model = kw.pop("model", None)
-    if cfg is None:
-        cfg = preset(model or "tiny", serve_max_len=kw.get("max_len", 0))
-    mesh = None
-    if tensor_parallel_size > 1:
-        from ray_tpu.parallel import MeshConfig, create_mesh
+        tracing.watch_builds()
+        kw = dict(engine_kwargs or {})
+        cfg = kw.pop("cfg", None)
+        model = kw.pop("model", None)
+        if cfg is None:
+            cfg = preset(model or "tiny", serve_max_len=kw.get("max_len", 0))
+        mesh = None
+        if tensor_parallel_size > 1:
+            from ray_tpu.parallel import MeshConfig, create_mesh
 
-        mesh = create_mesh(MeshConfig(dp=1, tp=tensor_parallel_size))
-    return LLMEngine(cfg, mesh=mesh, **kw)
+            mesh = create_mesh(MeshConfig(dp=1, tp=tensor_parallel_size))
+        return LLMEngine(cfg, mesh=mesh, **kw)
 
 
 class _EngineHost:
@@ -223,8 +229,25 @@ class _EngineHost:
         if now - self._last_publish < STATS_PUBLISH_INTERVAL_S:
             return
         self._last_publish = now
-        with tracing.annotate("serve.publish_stats"):
+        with tracing.annotate("serve.publish_stats",
+                              **self._startup_snapshot()):
             self._publish_stats()
+
+    def _startup_snapshot(self) -> Dict[str, Any]:
+        """What ``serve.publish_stats`` carries into a profiler's trace:
+        the process's build ledger and the engine's set-up, as scalars.
+        The trace's last one says where the replica's set-up went, and what
+        it has built since, to a reader that holds nothing but the trace."""
+        if not tracing.is_enabled():
+            return {}
+        b = tracing.build_counters()
+        up = getattr(self.engine, "startup", {})
+        return {"built": b["built"], "loaded": b["loaded"],
+                "build_ms": round(b["build_s"] * 1e3, 1),
+                "load_ms": round(b["load_s"] * 1e3, 1),
+                "lower_ms": round(b["lower_s"] * 1e3, 1),
+                **{f"startup_{part}_s": up.get(f"{part}_s", 0.0)
+                   for part in ("backend", "weights", "pool", "total")}}
 
     def _publish_stats(self):
         try:

@@ -16,6 +16,7 @@ import time
 import uuid
 from typing import Any, Callable, Dict, List, Optional
 
+from ray_tpu._private import tracing
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.checkpoint_manager import CheckpointManager
 from ray_tpu.train.config import Result, RunConfig, ScalingConfig
@@ -136,20 +137,30 @@ class TrainController:
         self._generation += 1
         self._rank_row_counts = {}
         group = WorkerGroup(sc, f"{self.name}/g{self._generation}")
-        group.start()
-
-        shards = self._split_datasets(sc.num_workers, group)
-        dist_env = (self.dist_env_fn(group) if self.dist_env_fn else None)
-        # the REQUESTED mesh ships to every generation unchanged; workers
-        # resolve it against the devices they actually see (clamp_to), so
-        # mesh shape is a runtime decision — an elastic restart onto
-        # fewer chips re-forms a valid smaller mesh from the same request
-        group.run_train_fn(
-            self.fn_payload, self.train_loop_config,
-            self.checkpoint_manager.latest, shards, dist_env,
-            mesh_config=sc.mesh_config(),
-            axis_rules=sc.logical_axis_rules,
-            ckpt_planes=self._wire_replica_plane(group))
+        # a generation's start, as fit() waits for it: the workers placed
+        # and alive, then the mesh's coordination wired and every loop
+        # started with the mesh it is to form (a worker's own side of it,
+        # where it holds chips, is its ``worker.chip_acquire`` span)
+        with tracing.span("train.startup", kind="startup", attrs={
+                "workers": sc.num_workers,
+                "generation": self._generation}):
+            with tracing.span("train.startup.workers", kind="startup"):
+                group.start()
+            with tracing.span("train.startup.mesh", kind="startup"):
+                shards = self._split_datasets(sc.num_workers, group)
+                dist_env = (self.dist_env_fn(group) if self.dist_env_fn
+                            else None)
+                # the REQUESTED mesh ships to every generation unchanged;
+                # workers resolve it against the devices they actually see
+                # (clamp_to), so mesh shape is a runtime decision — an
+                # elastic restart onto fewer chips re-forms a valid
+                # smaller mesh from the same request
+                group.run_train_fn(
+                    self.fn_payload, self.train_loop_config,
+                    self.checkpoint_manager.latest, shards, dist_env,
+                    mesh_config=sc.mesh_config(),
+                    axis_rules=sc.logical_axis_rules,
+                    ckpt_planes=self._wire_replica_plane(group))
         return group
 
     def _wire_replica_plane(self, group: WorkerGroup):

@@ -363,10 +363,13 @@ def get_mesh():
 
     import jax
 
+    from ray_tpu._private import accelerators, tracing
     from ray_tpu.parallel.mesh import MeshConfig, create_mesh
 
+    tracing.watch_builds()  # where start_loop came before jax's import
     requested = s.mesh_config or MeshConfig(dp=-1)
     n = len(jax.devices())
+    accelerators.record_chip_acquire()  # the worker's first backend
     concrete = requested.clamp_to(n)
     try:
         fits = requested.resolve(n) == concrete.resolve(n)

@@ -96,9 +96,12 @@ class TrainWorker:
         axis_rules: Any = None,
         ckpt_plane: Optional[Dict[str, Any]] = None,
     ) -> None:
-        from ray_tpu._private import serialization
+        from ray_tpu._private import serialization, tracing
         from ray_tpu.train import session as session_mod
 
+        # the session's programs, from the first one on, in the process's
+        # build ledger (a worker forked from the zygote has jax imported)
+        tracing.watch_builds()
         fn = serialization.loads(fn_payload)
         ckpt = Checkpoint(checkpoint_path) if checkpoint_path else None
         sess = session_mod._start_session(

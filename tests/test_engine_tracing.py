@@ -644,3 +644,104 @@ def test_start_and_stop_profile_write_a_trace(server, tmp_path):
              for line in p.lines for e in line.events}
     assert {"engine.step", "engine.dispatch_window", "serve.lock_wait",
             "serve.deliver"} <= names
+
+
+# ---------------------------------------------------------------------------
+# set-up: the engine's parts, the programs built, and what a trace carries
+# ---------------------------------------------------------------------------
+
+STARTUP_PARTS = ("backend", "weights", "pool")
+
+
+@pytest.mark.parametrize("how", ["direct", "built"])
+def test_startup_parts_account_for_the_total(tiny, how):
+    """``stats()["startup"]``: the three parts sum to ``total_s`` (the
+    constructor's wall, whoever calls it) within 5%, and each is an
+    ``engine.startup.<part>`` span; built the way a replica builds it, they
+    are the children of one ``engine.startup``."""
+    from ray_tpu.llm import LLMEngine
+    from ray_tpu.llm.serving import _build_engine
+
+    cfg, _ = tiny
+    tracing.clear_local()
+    # a pool of its own shape each: its zeros are a program never built
+    kwargs = dict(batch_slots=2 if how == "direct" else 3, max_len=80,
+                  decode_window=4, seed=3)
+    t0 = time.time()
+    eng = (LLMEngine(cfg, **kwargs) if how == "direct"
+           else _build_engine(dict(kwargs, cfg=cfg), 1))
+    wall = time.time() - t0
+    up = eng.stats()["startup"]
+    assert set(up) == {f"{p}_s" for p in STARTUP_PARTS} | {"total_s"}
+    assert up["weights_s"] > 0  # llama_init, until it is dispatched
+    assert sum(up[f"{p}_s"] for p in STARTUP_PARTS) == pytest.approx(
+        up["total_s"], rel=0.05, abs=0.02)
+    assert up["total_s"] <= wall + 0.001
+    spans = tracing.local_spans(include_open=False)
+    parts = [s for s in spans if s["name"].startswith("engine.startup.")]
+    assert [s["name"].rsplit(".", 1)[1] for s in parts] == list(
+        STARTUP_PARTS)
+    for s in parts:
+        assert s["end"] - s["start"] == pytest.approx(
+            up[s["name"].rsplit(".", 1)[1] + "_s"], abs=0.05)
+    whole = [s for s in spans if s["name"] == "engine.startup"]
+    if how == "direct":
+        assert whole == []
+    else:
+        (whole,) = whole
+        assert {s["parent_span_id"] for s in parts} == {whole["span_id"]}
+        # the pool's programs were built under it
+        built = [s for s in spans if s["name"] == "xla.build"
+                 and whole["start"] <= s["start"] <= whole["end"]]
+        assert built and {s["trace_id"] for s in built} == {
+            whole["trace_id"]}
+
+
+def test_a_window_length_never_run_is_a_build_and_a_repeated_one_is_not(
+        tiny):
+    """ROADMAP A6d as a number: the engine stacks a window's tokens with a
+    program of the window's length, so a length the warm-up did not meet is
+    built (or loaded: the cache's directory would not show it) while
+    requests wait.  ``stats()["builds"]`` rising between two calls is how
+    an operator, and the benchmark, sees it."""
+    from ray_tpu.llm import LLMEngine
+
+    cfg, params = tiny
+    tracing.watch_builds()
+    eng = LLMEngine(cfg, params, batch_slots=2, max_len=64, decode_window=8)
+
+    def programs():
+        b = eng.stats()["builds"]
+        return b["built"] + b["loaded"]
+
+    def run(max_tokens):
+        before = programs()
+        eng.generate([[5, 6, 7, 8]], SamplingParams(
+            temperature=0.0, max_tokens=max_tokens))
+        return programs() - before
+
+    assert run(9) >= 1  # a prefill, the step, a window of 8
+    assert run(9) == 0
+    assert run(12) >= 1  # 8 then 3: a length never run
+    assert run(12) == 0
+    assert run(9) == 0
+    assert set(eng.stats()["builds"]) == {
+        "built", "loaded", "build_s", "load_s", "lower_s"}
+
+
+def test_publish_stats_carries_the_ledger_and_the_startup(server, tmp_path):
+    want = {"built", "loaded", "build_ms", "load_ms", "lower_ms",
+            "startup_backend_s", "startup_weights_s", "startup_pool_s",
+            "startup_total_s"}
+    with _Profile(tmp_path) as prof:
+        server._last_publish = 0.0  # due at the loop's next turn
+        deadline = time.time() + 10
+        while server._last_publish == 0.0 and time.time() < deadline:
+            time.sleep(0.01)
+        time.sleep(0.05)
+    stats = prof.named("serve.publish_stats")[-1][3]
+    assert set(stats) == want
+    assert stats["built"] + stats["loaded"] >= 1  # the fixture's warm-up
+    assert stats["startup_total_s"] == server.engine.stats()[
+        "startup"]["total_s"] > 0
+    assert stats["build_ms"] + stats["load_ms"] + stats["lower_ms"] > 0

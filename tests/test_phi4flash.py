@@ -653,6 +653,23 @@ def test_no_prefix_hit_no_handoff_and_the_programs_say_so():
 
 # ------------------------------------ (e) what the engine and scopes say
 
+class _Open:
+    """One entered annotation: ``set_metadata`` lands on its own stats
+    (another may open inside it: an ``xla.build`` at a compile's end)."""
+
+    def __init__(self, stats):
+        self.stats = stats
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **more):
+        self.stats.update(more)
+
+
 class _Spans:
     """Stands in for ``tracing.annotate`` in the engine: keeps each
     annotation's stats by name (``set_metadata`` included)."""
@@ -662,16 +679,7 @@ class _Spans:
 
     def __call__(self, name, **stats):
         self.seen.append((name, stats))
-        return self
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set_metadata(self, **more):
-        self.seen[-1][1].update(more)
+        return _Open(stats)
 
     def named(self, name):
         return [s for n, s in self.seen if n == name]

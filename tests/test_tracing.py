@@ -18,12 +18,19 @@ Tiers:
 """
 
 import json
+import os
+import subprocess
+import sys
+import threading
 import time
 
+import jax
 import pytest
 
 import ray_tpu
 from ray_tpu._private import tracing
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
@@ -505,3 +512,241 @@ class TestStepLedger:
         snap = metrics.collect_local()
         hist = snap["train_step_bucket_s"]["histogram"]
         assert any(h["tags"].get("group") == "span-check" for h in hist)
+
+
+# ---------------------------------------------------------------------------
+# 5. the build ledger (watch_builds) and the start-up spans
+# ---------------------------------------------------------------------------
+
+
+def _builds(name=None):
+    return [s for s in tracing.local_spans(include_open=False)
+            if s["name"] == "xla.build"
+            and (name is None or s["attrs"]["program"] == name)]
+
+
+def _built_or_loaded():
+    c = tracing.build_counters()
+    return c["built"] + c["loaded"]
+
+
+class TestBuildLedger:
+    def test_watching_twice_registers_one_listener_of_each_kind(self):
+        from jax._src import monitoring
+
+        assert tracing.watch_builds() is True
+        assert tracing.watch_builds() is True
+        assert monitoring.get_event_time_span_listeners().count(
+            tracing._on_build_span) == 1
+        assert monitoring.get_event_listeners().count(
+            tracing._on_build_event) == 1
+
+    def test_a_new_shape_is_one_build_under_its_name(self, fresh_tracing):
+        import jax.numpy as jnp
+        import numpy as np
+
+        tracing.watch_builds()
+
+        def ledger_probe(x):
+            return jnp.tanh(x) * 2 + 1
+
+        fn = jax.jit(ledger_probe)
+        x = np.ones((3, 11), np.float32)
+        before, c0 = _built_or_loaded(), tracing.build_counters()
+        with tracing.span("probe.caller") as ctx:
+            t0 = time.time()
+            fn(x).block_until_ready()
+            t1 = time.time()
+        assert _built_or_loaded() == before + 1
+        (b,) = _builds("jit_ledger_probe")
+        assert t0 <= b["start"] <= b["end"] <= t1
+        assert b["attrs"]["cached"] in (0, 1)
+        assert 0 < b["attrs"]["lower_ms"] <= (b["start"] - t0) * 1e3 + 1
+        # under the context that paid for it, as the timeline shows it
+        assert b["trace_id"] == ctx.trace_id
+        assert b["parent_span_id"] == ctx.span_id
+        c1 = tracing.build_counters()
+        grown = (c1["build_s"] + c1["load_s"] + c1["lower_s"]
+                 - c0["build_s"] - c0["load_s"] - c0["lower_s"])
+        assert 0 < grown <= t1 - t0
+        # the same shape again: nothing is traced, lowered or built
+        fn(x).block_until_ready()
+        assert tracing.build_counters() == c1
+        assert len(_builds("jit_ledger_probe")) == 1
+
+    def test_a_program_from_the_persistent_cache_is_loaded(
+            self, fresh_tracing, tmp_path):
+        import numpy as np
+        from jax._src import compilation_cache
+
+        tracing.watch_builds()
+        keep = {k: getattr(jax.config, k) for k in (
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")}
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        compilation_cache.reset_cache()
+        try:
+            def cached_probe(x):
+                return x * 3 - 1
+
+            x = np.ones((5, 13), np.float32)
+            c0 = tracing.build_counters()
+            jax.jit(cached_probe)(x).block_until_ready()
+            c1 = tracing.build_counters()
+            assert (c1["built"], c1["loaded"]) == (
+                c0["built"] + 1, c0["loaded"])
+            assert any(f.startswith("jit_cached_probe-")
+                       for f in os.listdir(tmp_path))
+            jax.clear_caches()  # what a new process starts with
+            jax.jit(cached_probe)(x).block_until_ready()
+            c2 = tracing.build_counters()
+            assert (c2["built"], c2["loaded"]) == (
+                c1["built"], c1["loaded"] + 1)
+            assert c2["load_s"] > c1["load_s"]
+            assert c2["build_s"] == c1["build_s"]
+            assert [b["attrs"]["cached"]
+                    for b in _builds("jit_cached_probe")] == [0, 1]
+        finally:
+            for k, v in keep.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
+
+    def test_switched_off_the_counters_rise_and_no_span_is_written(
+            self, fresh_tracing, monkeypatch):
+        import numpy as np
+
+        tracing.watch_builds()
+        monkeypatch.setenv(tracing.ENV_ENABLED, "0")
+        before = _built_or_loaded()
+        jax.jit(lambda x: x + 7)(np.ones((2, 17), np.float32))
+        assert _built_or_loaded() == before + 1
+        assert tracing.local_spans() == []
+
+    def test_nested_phases_are_counted_once(self, fresh_tracing):
+        """A constant folded inside a trace: its own trace, lowering and
+        build lie inside the outer function's trace, which counts only
+        what they left."""
+        compile_ = "/jax/core/compile/"
+        c0 = tracing.build_counters()
+
+        def on_a_thread():
+            for event, s, e in (("jaxpr_trace_duration", 2, 3),
+                                ("jaxpr_to_mlir_module_duration", 3, 4),
+                                ("backend_compile_duration", 4, 6),
+                                ("jaxpr_trace_duration", 1, 10),
+                                ("jaxpr_to_mlir_module_duration", 10, 11),
+                                ("backend_compile_duration", 11, 15)):
+                tracing._on_build_span(compile_ + event, float(s), float(e),
+                                       fun_name="jit(<unknown>)")
+
+        t = threading.Thread(target=on_a_thread)
+        t.start()
+        t.join()
+        c1 = tracing.build_counters()
+        assert c1["built"] - c0["built"] == 2
+        assert c1["build_s"] - c0["build_s"] == pytest.approx(6.0)
+        assert c1["lower_s"] - c0["lower_s"] == pytest.approx(8.0)
+        inner, outer = _builds("jit__unknown")
+        assert inner["attrs"]["lower_ms"] == pytest.approx(2000.0)
+        assert outer["attrs"]["lower_ms"] == pytest.approx(6000.0)
+
+    def test_a_process_without_jax_registers_nothing(self):
+        code = ("import sys\n"
+                "from ray_tpu._private import tracing\n"
+                "assert tracing.watch_builds() is False\n"
+                "assert tracing.build_counters()['built'] == 0\n"
+                "assert not [m for m in sys.modules if m == 'jax' "
+                "or m.startswith('jax.')]\n"
+                "print('ok')\n")
+        proc = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            timeout=120, env=dict(os.environ, PYTHONPATH=ROOT))
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert proc.stdout.strip() == "ok"
+
+
+def test_chip_acquire_is_recorded_once_by_whoever_first_touches_the_backend(
+        fresh_tracing, monkeypatch):
+    from ray_tpu._private import accelerators
+
+    accelerators.record_chip_acquire()  # not bound to chips: nothing
+    assert tracing.local_spans() == []
+    now = time.time()
+    monkeypatch.setattr(accelerators, "worker_started_at", now - 2.0)
+    monkeypatch.setattr(accelerators, "_bound", (4, now - 0.5))
+    monkeypatch.setenv(tracing.ENV_ENABLED, "0")
+    accelerators.record_chip_acquire()  # switched off: consumed, no span
+    assert tracing.local_spans() == [] and accelerators._bound is None
+    monkeypatch.setenv(tracing.ENV_ENABLED, "1")
+    monkeypatch.setattr(accelerators, "_bound", (4, now - 0.5))
+    jax.devices()
+    accelerators.record_chip_acquire()
+    accelerators.record_chip_acquire()  # the second toucher: nothing
+    (s,) = [s for s in tracing.local_spans(include_open=False)
+            if s["name"] == "worker.chip_acquire"]
+    assert s["attrs"] == {"chips": 4, "bound_s": 1.5}
+    assert s["start"] == now - 2.0 and now <= s["end"] < now + 5.0
+    assert not [t for t in threading.enumerate() if "chip" in t.name]
+
+
+def _children(spans, parent_name):
+    (parent,) = [s for s in spans if s["name"] == parent_name]
+    return parent, [s for s in spans
+                    if s.get("parent_span_id") == parent["span_id"]]
+
+
+def test_init_fit_and_shutdown_leave_their_spans_in_the_buffer(
+        no_cluster, fresh_tracing):
+    """The driver's buffer outlives ``shutdown()`` (nothing is left to
+    publish to): the benchmark's ``init_s`` reads it there."""
+    from ray_tpu.train import JaxTrainer, ScalingConfig
+
+    def loop():
+        from ray_tpu import train
+
+        train.get_context().get_mesh()
+        train.report({"spans": [
+            s["name"] for s in tracing.local_spans(include_open=False)]})
+
+    t0 = time.time()
+    ray_tpu.init(num_cpus=4, num_tpus=0)
+    t1 = time.time()
+    result = JaxTrainer(loop, scaling_config=ScalingConfig(
+        num_workers=1)).fit()
+    assert result.error is None
+    # a worker bound to no chip records no ``worker.chip_acquire``
+    assert "worker.chip_acquire" not in result.metrics["spans"]
+    ray_tpu.shutdown()
+    assert not ray_tpu.is_initialized()
+    spans = tracing.local_spans(include_open=False)
+
+    init, parts = _children(spans, "init")
+    assert t0 <= init["start"] and init["end"] <= t1
+    assert [s["name"] for s in sorted(parts, key=lambda s: s["start"])] == [
+        "init.start_head", "init.gcs", "init.raylet", "init.connect"]
+    # the phases account for the call: their self times sum to it
+    assert sum(s["end"] - s["start"] for s in parts) == pytest.approx(
+        init["end"] - init["start"], rel=0.05, abs=0.1)
+    head, head_parts = _children(spans, "init.start_head")
+    assert [s["name"].rsplit(".", 1)[1] for s in sorted(
+        head_parts, key=lambda s: s["start"])] == [
+        "python", "imports", "gcs", "raylet", "services"]
+    assert sum(s["end"] - s["start"] for s in head_parts) == pytest.approx(
+        head["end"] - head["start"], rel=0.05, abs=0.1)
+
+    startup, parts = _children(spans, "train.startup")
+    assert startup["attrs"]["workers"] == 1
+    assert [s["name"] for s in sorted(parts, key=lambda s: s["start"])] == [
+        "train.startup.workers", "train.startup.mesh"]
+
+    down, (wait,) = _children(spans, "shutdown")
+    assert wait["name"] == "shutdown.wait"
+    assert wait["attrs"]["what"] == "head" and wait["attrs"]["pid"] > 0
+    assert down["start"] <= wait["start"] <= wait["end"] <= down["end"]
+    # a second shutdown has nothing to do and records nothing
+    ray_tpu.shutdown()
+    assert len([s for s in tracing.local_spans()
+                if s["name"] == "shutdown"]) == 1
